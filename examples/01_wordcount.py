@@ -7,7 +7,7 @@ Counterpart of the reference's basic graph tests (src/graph_test) in spirit:
 a tiny end-to-end PipeGraph with a self-checking result.
 """
 import _common
-_common.select_backend()
+_common.bootstrap()
 
 import jax.numpy as jnp
 import numpy as np

@@ -6,7 +6,7 @@ The flagship macro-benchmark (bench.py runs it at 1M-tuple batches on TPU);
 this example runs it small and checks the window counts against an oracle.
 """
 import _common
-_common.select_backend()
+_common.bootstrap()
 
 import numpy as np
 import windflow_tpu as wf

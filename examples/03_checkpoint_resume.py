@@ -8,7 +8,7 @@ supervised exactly-once recovery (SupervisedPipeline) and elastic mesh
 rescaling.
 """
 import _common
-_common.select_backend()
+_common.bootstrap()
 
 import os
 

@@ -3,13 +3,12 @@ mesh — batch axis on ``dp`` (operator replication), key-state tables on ``key`
 (Key_Farm whole-key ownership) — and verified oracle-identical to the
 single-device run.
 
-Run with real chips, or anywhere with a virtual mesh:
+Run on a host with several chips, or anywhere on a virtual CPU mesh:
 
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 WF_CPU=1 \
-        python examples/04_multichip.py
+    JAX_PLATFORMS=cpu python examples/04_multichip.py
 """
 import _common
-_common.select_backend(virtual_devices=8)
+_common.bootstrap(virtual_devices=8)
 
 import sys
 
@@ -50,8 +49,8 @@ def run(sharded):
     return sorted(out)
 
 if jax.device_count() < 2:
-    print("multichip example needs >= 2 devices: run with real chips or\n"
-          "  WF_CPU=1 python examples/04_multichip.py   (8-device virtual mesh)")
+    print("multichip example needs >= 2 devices: run on a multi-chip host or\n"
+          "  JAX_PLATFORMS=cpu python examples/04_multichip.py   (virtual mesh)")
     sys.exit(1)
 
 single = run(sharded=False)

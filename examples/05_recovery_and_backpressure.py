@@ -14,7 +14,7 @@ deliberate overflow — the two r05 hardening contracts, end to end.
    of the reference's FF_BOUNDED_BUFFER — it blocks, it never drops).
 """
 import _common
-_common.select_backend()
+_common.bootstrap()
 
 import numpy as np
 import jax

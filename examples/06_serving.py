@@ -16,7 +16,7 @@ committed tuple.
    is never shed and every one of its bids reaches the sink.
 """
 import _common
-_common.select_backend()
+_common.bootstrap()
 
 import json
 import os

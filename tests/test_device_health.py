@@ -596,17 +596,3 @@ def test_wf_state_cli_merge(tmp_path, capsys):
     assert rc == 0
     data = json.loads(out)
     assert data["merged_from"] == 2 and len(data["hosts"]) == 2
-
-
-def test_bench_health_compile_stats():
-    bench_dir = REPO
-    spec = importlib.util.spec_from_file_location(
-        "wf_bench_health", os.path.join(bench_dir, "bench.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    stats = mod._health_compile_stats(steps=3, batch=512)
-    assert stats["steps"] == 3
-    assert stats["compiles"] >= 1
-    assert stats["retraces_unexpected"] == 0
-    assert 0 < stats["compiles_per_step"] <= stats["compiles"]
-    assert dh.get_active() is None                # ledger restored

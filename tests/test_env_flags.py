@@ -1,6 +1,6 @@
 """Env-flag inventory gate — every ``WF_*`` environment variable read
 anywhere in the tree must be documented in ``docs/ENV_FLAGS.md`` including
-*when* it is read (the ADVICE round-5 footgun: trace-time reads are baked
+*when* it is read (the footgun: trace-time reads are baked
 into cached executables, so an undocumented flag toggled mid-process silently
 does nothing).
 

@@ -15,7 +15,7 @@ EXAMPLES = sorted(f for f in os.listdir(os.path.join(REPO, "examples"))
 
 @pytest.mark.parametrize("name", EXAMPLES)
 def test_example_runs(name):
-    env = dict(os.environ, WF_CPU="1")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run([sys.executable, os.path.join(REPO, "examples", name)],
                           capture_output=True, text=True, timeout=600, env=env)
     assert proc.returncode == 0, f"{name} failed:\n{proc.stdout}\n{proc.stderr}"

@@ -121,3 +121,73 @@ def test_hist_many_keys_tiled():
     got = jax.jit(lambda *a: keyed_pane_histogram(*a, K, P))(
         jnp.asarray(key), jnp.asarray(pane), jnp.asarray(valid))
     np.testing.assert_array_equal(np.asarray(got), ref_hist(key, pane, valid, K, P))
+
+
+# ---- exactness under a TPU's default matmul precision ----------------------
+# A TPU runs an f32 dot at default precision as ONE bf16 pass (8 mantissa
+# bits). The CPU backend does not, so every CPU test of the one-hot matmul
+# forms passed while table values / per-chunk counts beyond 256 came back
+# rounded on the chip (chip_smoke.py leg C, PR 21). The fixture imitates the
+# chip: any dot_general that does not ask for HIGHEST gets its f32 operands
+# rounded through bf16 first.
+
+@pytest.fixture
+def tpu_default_dot(monkeypatch):
+    real = jax.lax.dot_general
+    highest = jax.lax.Precision.HIGHEST
+
+    def through_bf16(x):
+        if x.dtype == jnp.float32:
+            return x.astype(jnp.bfloat16).astype(jnp.float32)
+        return x
+
+    def emulated(lhs, rhs, dimension_numbers, precision=None, **kw):
+        if precision != highest and precision != (highest, highest):
+            lhs, rhs = through_bf16(lhs), through_bf16(rhs)
+        return real(lhs, rhs, dimension_numbers, precision=precision, **kw)
+
+    monkeypatch.setattr(jax.lax, "dot_general", emulated)
+    # the imitation bites: 257 is not a bf16 value
+    got = jax.lax.dot_general(jnp.full((1, 1), 257.0), jnp.ones((1, 1)),
+                              (((1,), (0,)), ((), ())))
+    assert float(got[0, 0]) == 256.0
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("table", [
+    np.arange(1000, dtype=np.int32) * 65 + 536,                  # up to 2^16
+    (np.arange(1000, dtype=np.int32) * 16777 - (1 << 23)),       # |v| < 2^24
+    np.random.default_rng(0).standard_normal(1000).astype(np.float32) * 1e3,
+], ids=["i32_2e16", "i32_2e24", "f32"])
+def test_lookup_exact_beyond_bf16(tpu_default_dot, table, impl):
+    idx = np.random.default_rng(1).integers(0, len(table), 1024).astype(np.int32)
+    got = table_lookup(jnp.asarray(table), jnp.asarray(idx), impl=impl)
+    np.testing.assert_array_equal(np.asarray(got), table[idx])
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas_mm"])
+def test_hist_counts_beyond_bf16(tpu_default_dot, impl):
+    """A one-key stream: ~1000 counts per (key, pane, chunk)."""
+    C, K, P = 4096, 4, 16
+    key = np.zeros(C, np.int32)
+    pane = (np.arange(C) // 2000 + P - 1).astype(np.int32)
+    valid = np.arange(C) % 41 != 0
+    got = keyed_pane_histogram(jnp.asarray(key), jnp.asarray(pane),
+                               jnp.asarray(valid), K, P, impl=impl)
+    want = ref_hist(key, pane, valid, K, P)
+    assert want.max() > 256
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_segment_fold_pallas_limbs_beyond_bf16(tpu_default_dot):
+    from windflow_tpu.ops.segment import segment_fold
+    rng = np.random.default_rng(2)
+    C, S = 2048, 64
+    vals = rng.integers(-(1 << 31), 1 << 31, C, dtype=np.int64).astype(np.int32)
+    seg = rng.integers(0, S, C).astype(np.int32)
+    valid = rng.random(C) < 0.9
+    want = np.zeros(S, np.int64)
+    np.add.at(want, seg[valid], vals[valid].astype(np.int64))
+    got = segment_fold(jnp.asarray(vals), jnp.asarray(seg), jnp.asarray(valid),
+                       S, impl="pallas")
+    np.testing.assert_array_equal(np.asarray(got), want.astype(np.int32))

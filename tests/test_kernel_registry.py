@@ -438,3 +438,40 @@ def test_lookup_parity_through_registry(monkeypatch):
                                       err_msg=impl_env)
     from windflow_tpu.ops.registry import REGISTRY
     REGISTRY.reset_records()
+
+
+# ------------------------------------------------- the one interpret rule
+
+def test_one_interpret_rule_for_every_backend(monkeypatch):
+    """cpu interprets, tpu compiles, anything else is an error — and all
+    five kernel modules ask this one function."""
+    import inspect
+    import jax
+    from windflow_tpu.ops import (bitonic, histogram, lookup, pallas_kernels,
+                                  segment)
+    for backend, want in (("cpu", True), ("tpu", False)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert registry.pallas_interpret() is want
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        registry.pallas_interpret()
+    for mod in (bitonic, histogram, lookup, pallas_kernels, segment):
+        src = inspect.getsource(mod)
+        assert "pallas_interpret()" in src and "default_backend" not in src
+
+
+def test_refused_impl_raises_on_tpu_only(monkeypatch):
+    """An impl Mosaic refuses stays registered, runs interpreted on cpu, and
+    raises with Mosaic's message when selected on tpu — explicitly or through
+    the environment; nothing swaps in the XLA form."""
+    import jax
+    assert registry.resolve_impl("histogram", impl="pallas",
+                                 record=False) == "pallas"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(registry.KernelRefused, match="multiple of 128"):
+        registry.resolve_impl("histogram", impl="pallas", record=False)
+    monkeypatch.setenv("WF_KERNEL_IMPL", "ordering_merge=pallas")
+    with pytest.raises(registry.KernelRefused, match="shape cast"):
+        registry.resolve_impl("ordering_merge", record=False)
+    assert registry.resolve_impl("histogram", impl="pallas_mm",
+                                 record=False) == "pallas_mm"

@@ -702,18 +702,3 @@ def test_wf_slo_exit_2_contracts(tmp_path):
                          capture_output=True, text=True, env=env)
     assert out.returncode == 2
     assert "duplicate" in out.stderr
-
-
-# ------------------------------------------------------------- bench row
-
-
-def test_bench_slo_stats():
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-        row = bench._slo_stats(total_batches=10, batch=2048)
-    finally:
-        sys.path.remove(REPO)
-    assert row["slos"] == len(slo.default_specs())
-    assert row["pages"] == 0
-    assert row["worst_burn"] >= 0.0

@@ -507,46 +507,3 @@ def test_xprof_trace_external_session_chained_error(tmp_path, monkeypatch):
     monkeypatch.setattr("jax.profiler.stop_trace", lambda: None)
     with stats.xprof_trace(str(tmp_path / "y")):
         pass
-
-
-# ------------------------------------------------------- bench_trend smoke
-
-def test_bench_trend_reports_failed_rounds(tmp_path):
-    """The r01-style failed round (rc=1, parsed=null) is REPORTED, never
-    silently skipped; regressions flag against best-so-far."""
-    rounds = [
-        {"n": 1, "rc": 1, "tail": "Traceback ...\nRuntimeError: boom",
-         "parsed": None},
-        {"n": 2, "rc": 0, "tail": "",
-         "parsed": {"metric": "m", "value": 100.0, "unit": "t/s",
-                    "vs_baseline": 1.0}},
-        {"n": 3, "rc": 0, "tail": "",
-         "parsed": {"metric": "m", "value": 80.0, "unit": "t/s",
-                    "vs_baseline": 0.8}},
-        {"n": 4, "rc": 0, "tail": "stale capture",
-         "parsed": {"metric": "m", "value": 120.0, "unit": "t/s",
-                    "stale": True, "staleness_reason": "device down"}},
-    ]
-    for r in rounds:
-        (tmp_path / f"BENCH_r{r['n']:02d}.json").write_text(json.dumps(r))
-    (tmp_path / "MULTICHIP_r01.json").write_text(json.dumps(
-        {"n_devices": 8, "rc": 124, "ok": False, "skipped": False,
-         "tail": ""}))
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_trend.py"),
-         "--root", str(tmp_path)], capture_output=True, text=True)
-    assert r.returncode == 1                  # one regressed round
-    out = r.stdout
-    assert "| r01 | FAILED" in out and "rc=1" in out and "boom" in out
-    assert "| r02 | BEST" in out
-    assert "| r03 | REGRESSED" in out and "below best-so-far" in out
-    assert "| r04 | STALE" in out            # stale never sets the best
-    assert "| r01 | FAILED | 8 | rc=124 (timeout)" in out
-
-
-def test_bench_trend_on_this_repo_exits_clean():
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_trend.py")],
-        capture_output=True, text=True)
-    assert r.returncode in (0, 1)             # real rounds may regress
-    assert "| r01 | FAILED" in r.stdout       # the rc=1 round is visible

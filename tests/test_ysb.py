@@ -83,7 +83,7 @@ def test_count_lift_detected_inside_chain_trace():
     unless evaluated under jax.ensure_compile_time_eval(). When the blanket
     except swallowed that, the YSB windowed-count chain silently took the
     serialized segment-sum fallback for its panes update — ~5.4 ms/step at 1M
-    batch on-chip, the whole window-stage anomaly of BASELINE.md's ablation."""
+    batch on-chip, the whole window-stage anomaly of the r05 ablation."""
     import jax
 
     _, ops, chain, step = _chain_step(2048, 16, 16)

@@ -1,9 +1,15 @@
-"""ctypes binding for the native host runtime (SPSC queues + thread pinning).
+"""ctypes binding for the native host runtime (SPSC queues, thread pinning,
+record framing).
 
-Builds ``libwfnative.so`` from ``spsc_queue.cpp`` on first import if missing (g++ is
-part of the toolchain); falls back to a pure-Python deque shim when no compiler is
-available so the threaded scheduler still works (correctness first, the native ring is
-the fast path)."""
+``libwfnative.so`` is built from ``spsc_queue.cpp`` + ``ingest.cpp`` on first
+use and REBUILT whenever it is older than a source or the Makefile — the
+library is gitignored and survives checkouts, so a binary that merely exists
+proves nothing about the code beside it. A build or load failure raises
+:class:`NativeBuildError` with the compiler's output: the served path
+(``RecordSource`` framing, the threaded drivers' rings) never drops to a slower
+implementation on its own. The pure-Python shims (deque ring, numpy framing)
+run only when ``WF_PYTHON_SHIM=1`` asks for them — a host without a C++
+toolchain."""
 
 from __future__ import annotations
 
@@ -15,69 +21,75 @@ from collections import deque
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SO = os.path.join(_DIR, "libwfnative.so")
+#: what the library is built from; any of these newer than the .so = stale
+_SOURCES = ("spsc_queue.cpp", "ingest.cpp", "Makefile")
 
 _lib = None
+_load_lock = threading.Lock()
 
 
-_load_failed = False            # sticky: a failed build/load is not retried per call
+class NativeBuildError(RuntimeError):
+    """``libwfnative.so`` could not be built or loaded."""
 
 
-def _build():
-    """Compile to a temp name and rename over the target only on success — a stale
-    but working .so is never destroyed by a failed rebuild."""
-    tmp = _SO + ".tmp"
+def _stale() -> bool:
     try:
-        subprocess.run(["make", "-C", _DIR, f"TARGET={os.path.basename(tmp)}"],
-                       check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _SO)
+        built = os.path.getmtime(_SO)
+    except OSError:
         return True
-    except Exception:
+    return any(os.path.getmtime(os.path.join(_DIR, f)) > built
+               for f in _SOURCES)
+
+
+def _build() -> None:
+    """Compile to a per-process temp name and rename over the target only on
+    success (concurrent first imports — bench children, test subprocesses —
+    each build their own file; the last rename wins, all are identical)."""
+    tmp = f"{os.path.basename(_SO)}.{os.getpid()}.tmp"
+    try:
+        proc = subprocess.run(["make", "-C", _DIR, f"TARGET={tmp}"],
+                              capture_output=True, text=True, timeout=120)
+        if proc.returncode != 0:
+            raise NativeBuildError(
+                f"building {_SO} failed (rc={proc.returncode}):\n"
+                f"{proc.stdout[-2000:]}\n{proc.stderr[-4000:]}")
+        os.replace(os.path.join(_DIR, tmp), _SO)
+    except (OSError, subprocess.TimeoutExpired) as e:
+        raise NativeBuildError(f"building {_SO} failed: {e}") from e
+    finally:
         try:
-            os.remove(tmp)
+            os.remove(os.path.join(_DIR, tmp))
         except OSError:
             pass
-        return False
 
 
 def _load():
-    global _lib, _load_failed
+    """The bound library; None only under ``WF_PYTHON_SHIM=1``."""
+    global _lib
     if _lib is not None:
         return _lib
-    if _load_failed:
+    if os.environ.get("WF_PYTHON_SHIM", "") not in ("", "0"):
         return None
-
-    def fail():
-        global _load_failed
-        _load_failed = True
-        return None
-
-    if not os.path.exists(_SO) and not _build():
-        return fail()
-    try:
-        lib = ctypes.CDLL(_SO)
-    except OSError:
-        return fail()
-    if not _bind(lib):
-        # stale .so: it predates some symbol in _SYMBOLS (the library is
-        # gitignored and survives pulls) — rebuild once, else fall back to the
-        # pure-Python shims. Staleness is derived from the SAME table the
-        # binding uses, so it cannot drift from the binding code.
-        del lib
-        if not _build():
-            return fail()
+    with _load_lock:
+        if _lib is not None:
+            return _lib
+        if _stale():
+            _build()
         try:
             lib = ctypes.CDLL(_SO)
-        except OSError:
-            return fail()
-        if not _bind(lib):
-            return fail()
-    _lib = lib
-    return lib
+        except OSError as e:
+            raise NativeBuildError(f"loading {_SO} failed: {e}") from e
+        missing = _bind(lib)
+        if missing:
+            raise NativeBuildError(
+                f"{_SO} lacks symbol {missing!r} although it is newer than "
+                f"its sources — _SYMBOLS and the C++ sources disagree")
+        _lib = lib
+        return lib
 
 
 _P = ctypes.POINTER
-#: every exported symbol with its signature — the single source of truth for
-#: both binding and stale-.so detection (None restype = ctypes default c_int)
+#: every exported symbol with its signature (None restype = ctypes default c_int)
 _SYMBOLS = [
     ("wf_queue_create", ctypes.c_void_p, [ctypes.c_uint64]),
     ("wf_queue_destroy", None, [ctypes.c_void_p]),
@@ -108,16 +120,16 @@ _SYMBOLS = [
 ]
 
 
-def _bind(lib) -> bool:
-    """Bind every symbol in ``_SYMBOLS``; False if any is missing (stale .so)."""
+def _bind(lib):
+    """Bind every symbol in ``_SYMBOLS``; returns the first missing name."""
     for name, restype, argtypes in _SYMBOLS:
         if not hasattr(lib, name):
-            return False
+            return name
         fn = getattr(lib, name)
         if restype is not None:
             fn.restype = restype
         fn.argtypes = argtypes
-    return True
+    return None
 
 
 class SPSCQueue:
@@ -133,7 +145,7 @@ class SPSCQueue:
         self._seq = 0
         if lib is not None:
             self._q = lib.wf_queue_create(capacity)
-        else:                               # pure-Python fallback
+        else:                               # WF_PYTHON_SHIM=1
             self._q = None
             self._dq = deque()
             self._cap = capacity
@@ -184,8 +196,8 @@ def unpack_records(records, fields=None):
     """AoS -> SoA in one native pass: ``records`` is a numpy structured array
     (the framing of network/disk ingest); returns ``{field: contiguous column}``.
     The native counterpart of the reference's per-tuple Source/Shipper copy path
-    (``wf/source.hpp:184``, ``wf/shipper.hpp:87``). Falls back to numpy per-field
-    copies when the native library is unavailable."""
+    (``wf/source.hpp:184``, ``wf/shipper.hpp:87``). numpy per-field copies under
+    ``WF_PYTHON_SHIM=1`` or for non-contiguous input."""
     import numpy as np
     lib = _load()
     dt = records.dtype
@@ -315,8 +327,8 @@ def pack_records(columns: dict, dtype):
 def hash_keys_native(keys, num_slots: int):
     """Native key->slot hashing, bit-identical to
     ``windflow_tpu.batch.hash_key_to_slot``: 32-bit FNV-1a for string/bytes arrays,
-    Knuth uint64 multiply for integer arrays. Returns int32 slots, or None when the
-    native library is unavailable (caller falls back to the Python path)."""
+    Knuth uint64 multiply for integer arrays. Returns int32 slots, or None under
+    ``WF_PYTHON_SHIM=1`` (caller takes the Python path)."""
     import numpy as np
     lib = _load()
     if lib is None:
@@ -370,7 +382,7 @@ def native_available() -> bool:
 def queue_selfbench(n: int = 2_000_000, capacity: int = 1024) -> float:
     """Raw ring throughput (tokens/s), measured entirely in C across two
     threads (``wf_queue_selfbench``) — the number the reference's FastFlow
-    SPSC queues compete on. Returns 0.0 without the native library."""
+    SPSC queues compete on. Returns 0.0 under ``WF_PYTHON_SHIM=1``."""
     lib = _load()
     if lib is None:
         return 0.0

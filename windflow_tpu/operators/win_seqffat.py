@@ -520,7 +520,9 @@ class Win_SeqFFAT(Basic_Operator):
 def _detect_count_lift(lift, batch) -> bool:
     """True iff ``lift`` provably returns the constant scalar 1 for every tuple:
     its jaxpr output must not depend on the input vars, and its value on a zero
-    tuple must be 1. Conservative — any doubt returns False."""
+    tuple must be 1. Conservative: a constant that cannot be made concrete
+    (ConcretizationTypeError) is not a proof; any other error in the user's
+    lift propagates."""
     import numpy as np
     dummy = TupleRef(
         key=jax.ShapeDtypeStruct((), CTRL_DTYPE),
@@ -528,40 +530,34 @@ def _detect_count_lift(lift, batch) -> bool:
         ts=jax.ShapeDtypeStruct((), CTRL_DTYPE),
         data=jax.tree.map(lambda l: jax.ShapeDtypeStruct(l.shape[1:], l.dtype),
                           batch.payload))
+    from jax.extend.core import Literal
+    jaxpr = jax.make_jaxpr(lift)(dummy).jaxpr
+    tainted = {id(v) for v in jaxpr.invars}
+    for eqn in jaxpr.eqns:
+        if any(not isinstance(v, Literal) and id(v) in tainted
+               for v in eqn.invars):
+            tainted |= {id(v) for v in eqn.outvars}
+    if any(not isinstance(v, Literal) and id(v) in tainted
+           for v in jaxpr.outvars):
+        return False
+    zero = TupleRef(
+        key=np.zeros((), np.int32), id=np.zeros((), np.int32),
+        ts=np.zeros((), np.int32),
+        data=jax.tree.map(lambda l: np.zeros(l.shape[1:], l.dtype),
+                          batch.payload))
     try:
-        from jax.extend import core as jex_core
-        literal_t = jex_core.Literal
-    except ImportError:
-        from jax._src.core import Literal as literal_t
-    try:
-        closed = jax.make_jaxpr(lift)(dummy)
-        jaxpr = closed.jaxpr
-        tainted = {id(v) for v in jaxpr.invars}
-        for eqn in jaxpr.eqns:
-            if any(not isinstance(v, literal_t) and id(v) in tainted
-                   for v in eqn.invars):
-                tainted |= {id(v) for v in eqn.outvars}
-        if any(not isinstance(v, literal_t) and id(v) in tainted
-               for v in jaxpr.outvars):
-            return False
-        zero = TupleRef(
-            key=np.zeros((), np.int32), id=np.zeros((), np.int32),
-            ts=np.zeros((), np.int32),
-            data=jax.tree.map(lambda l: np.zeros(l.shape[1:], l.dtype),
-                              batch.payload))
         # Detection runs INSIDE the chain's jit trace, where every jnp op —
         # even a constant like jnp.ones(()) — returns a tracer of the ambient
-        # trace and float() raises ConcretizationTypeError. Without the escape
-        # hatch the blanket except returned False and the YSB/windowed-count
-        # chain silently took the serialized segment-sum fallback for the
-        # panes update (~5.4 ms/step at 1M batch, the whole window-stage
-        # anomaly of BASELINE.md's ablation); standalone probes passed
-        # detection and never saw it.
+        # trace; compile-time eval makes the constant concrete. (Without it
+        # the windowed-count chain took the serialized segment-sum path for
+        # a whole round unnoticed; tests/test_ysb.py pins it.)
         with jax.ensure_compile_time_eval():
             out = jax.tree.leaves(lift(zero))
             return (len(out) == 1 and np.shape(out[0]) == ()
                     and float(out[0]) == 1.0)
-    except Exception:
+    except jax.errors.ConcretizationTypeError:
+        # the constant is built from a value of an enclosing trace (a lift
+        # closing over a traced array): not provably 1
         return False
 
 

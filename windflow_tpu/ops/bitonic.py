@@ -130,9 +130,10 @@ def sort_network(prim, sec, chan, idx):
 
 def _pallas_network(prim, sec, chan, idx, stages_fn, interpret: bool):
     import jax.experimental.pallas as pl
+    from .registry import pallas_interpret
 
     n = prim.shape[0]
-    interpret = interpret or jax.default_backend() != "tpu"
+    interpret = interpret or pallas_interpret()
 
     def kern(p_ref, s_ref, c_ref, i_ref, po_ref, so_ref, co_ref, io_ref):
         p, s, c, i = stages_fn(p_ref[...], s_ref[...], c_ref[...], i_ref[...])
@@ -182,4 +183,9 @@ from .registry import register_kernel  # noqa: E402  (registration footer)
 register_kernel("ordering_merge", "xla", merge_network, reference=True,
                 backends=("xla",), default=True)
 register_kernel("ordering_merge", "pallas", merge_network_pallas,
-                backends=("pallas-tpu", "pallas-interpret"))
+                backends=("pallas-interpret",),
+                tpu_refusal="infer-vector-layout: unsupported shape cast "
+                            "(tpu.reshape vector<32x2x128xi32> -> "
+                            "vector<64x2x64xi32>) — a butterfly of stride "
+                            "< 128 splits the lane dimension (TPU v5 lite, "
+                            "jax 0.9.0)")

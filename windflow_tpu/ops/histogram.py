@@ -105,7 +105,10 @@ def keyed_pane_histogram(key: jax.Array, pane: jax.Array, valid: jax.Array,
         ohp = (slot.reshape(-1)[:, None]
                == jnp.arange(P, dtype=slot.dtype)).astype(jnp.float32)  # [R*L, P]
         flat = jnp.transpose(h3, (1, 0, 2)).reshape(K, R * locality)
+        # `flat` holds per-chunk COUNTS (up to `chunk`): HIGHEST, because a
+        # TPU's default f32 dot is one bf16 pass and rounds counts past 256
         out = jax.lax.dot_general(flat, ohp, (((1,), (0,)), ((), ())),
+                                  precision=jax.lax.Precision.HIGHEST,
                                   preferred_element_type=jnp.float32)
         return out.astype(jnp.int32)
 
@@ -131,10 +134,10 @@ def keyed_pane_histogram(key: jax.Array, pane: jax.Array, valid: jax.Array,
             # guards identical keeps the impls interchangeable)
             return _scatter_hist(key, pane, valid, K, P)
         # "pallas": dynamic-slice store of the [K, L] chunk histogram into the
-        # ring (8-wide store at a traced lane offset — Mosaic may refuse the
-        # minor-dim dynamic slice on some generations). "pallas_mm": placement
-        # by one-hot matmul into the full [K, P+L] block (static stores only —
-        # guaranteed to lower, more VPU adds per chunk).
+        # ring (8-wide store at a traced lane offset — Mosaic refuses it on
+        # TPU, see the registration below; interpret mode only). "pallas_mm":
+        # placement by one-hot matmul into the full [K, P+L] block (static
+        # stores only, more VPU adds per chunk) — the form that compiles.
         placement = "mm" if impl == "pallas_mm" else "ds"
         fast = lambda _: _pallas_fast(key, pane, valid, K, P,  # noqa: E731
                                       chunk, locality, placement=placement)
@@ -195,12 +198,13 @@ def keyed_pane_histogram_pallas(key: jax.Array, pane: jax.Array,
 def _pallas_fast(key, pane, valid, K, P, chunk, locality, *,
                  placement: str = "ds", interpret: bool = False):
     import jax.experimental.pallas as pl
+    from .registry import pallas_interpret
 
     C = key.shape[0]
     L = int(locality)
     R = C // chunk
     big = jnp.iinfo(pane.dtype).max
-    interpret = interpret or jax.default_backend() == "cpu"
+    interpret = interpret or pallas_interpret()
 
     def kern(key_ref, pane_ref, valid_ref, out_ref):
         r = pl.program_id(0)
@@ -217,10 +221,13 @@ def _pallas_fast(key, pane, valid, K, P, chunk, locality, *,
         local = pc - base
         ok = vc & (local < L)
         lr = jnp.where(ok, local, 0)
-        ohk = ((kc[:, None] == jax.lax.broadcasted_iota(
-            kc.dtype, (chunk, K), 1)) & ok[:, None]).astype(jnp.bfloat16)
-        ohl = ((lr[:, None] == jax.lax.broadcasted_iota(
-            lr.dtype, (chunk, L), 1)) & ok[:, None]).astype(jnp.bfloat16)
+        # dead lanes are masked through the key operand (-1 matches no key):
+        # Mosaic has no i1 [chunk] -> [chunk, 1] reshape for `ok[:, None]`
+        km = jnp.where(ok, kc, -1)
+        ohk = (km[:, None] == jax.lax.broadcasted_iota(
+            kc.dtype, (chunk, K), 1)).astype(jnp.bfloat16)
+        ohl = (lr[:, None] == jax.lax.broadcasted_iota(
+            lr.dtype, (chunk, L), 1)).astype(jnp.bfloat16)
         h = jax.lax.dot_general(ohk, ohl, (((0,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # [K, L]
         start = base % P                      # [0, P): contiguous in P + L cols
@@ -236,6 +243,7 @@ def _pallas_fast(key, pane, valid, K, P, chunk, locality, *,
                        jnp.int32, (L, P + L), 0)).astype(jnp.float32)
             out_ref[...] += jax.lax.dot_general(
                 h, ohp, (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,    # h holds counts
                 preferred_element_type=jnp.float32)
 
     padded = pl.pallas_call(
@@ -260,6 +268,10 @@ from .registry import register_kernel  # noqa: E402  (registration footer)
 register_kernel("histogram", "xla", keyed_pane_histogram, reference=True,
                 backends=("xla",), default=True)
 register_kernel("histogram", "pallas", keyed_pane_histogram_pallas,
-                backends=("pallas-tpu", "pallas-interpret"))
+                backends=("pallas-interpret",),
+                tpu_refusal="cannot statically prove that index in dimension "
+                            "1 is a multiple of 128 — the [K, L] chunk "
+                            "histogram is stored at the traced lane offset "
+                            "base % P (TPU v5 lite, jax 0.9.0)")
 register_kernel("histogram", "pallas_mm", keyed_pane_histogram_pallas,
                 backends=("pallas-tpu", "pallas-interpret"))

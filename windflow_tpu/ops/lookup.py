@@ -21,6 +21,14 @@ SELECT_MAX_ROWS_2D = 2048
 FACTORED_MAX_ROWS = 1 << 16
 
 
+#: The one-hot row-select matmuls below carry table VALUES in their f32 operand.
+#: A TPU runs an f32 dot at default precision as ONE bf16 pass — 8 mantissa bits,
+#: so any value beyond 256 would come back rounded (seen on the chip: every table
+#: of chip_smoke.py's leg C). HIGHEST keeps all 24 bits; with a single nonzero
+#: term per output the product is then bit-exact.
+_EXACT = jax.lax.Precision.HIGHEST
+
+
 def _exact_in_f32(table: jax.Array) -> bool:
     """True when every table value is exactly representable in float32 (so a one-hot
     f32 matmul — a sum with a single nonzero term — reproduces it bit-exactly)."""
@@ -124,6 +132,7 @@ def _pallas_factored_lookup(table: jax.Array, idx: jax.Array, *,
     Selected by ``table_lookup`` when ``WF_LOOKUP_IMPL=pallas`` (or
     ``impl="pallas"``) and the geometry allows (C a multiple of 128)."""
     import jax.experimental.pallas as pl
+    from .registry import pallas_interpret
 
     C, K = idx.shape[0], table.shape[0]
     BLK = _pallas_block(C)
@@ -131,7 +140,7 @@ def _pallas_factored_lookup(table: jax.Array, idx: jax.Array, *,
     K2 = 128
     K1 = (K + K2 - 1) // K2
     t2 = jnp.pad(table, (0, K1 * K2 - K)).astype(jnp.float32).reshape(K1, K2)
-    interpret = interpret or jax.default_backend() == "cpu"
+    interpret = interpret or pallas_interpret()
 
     def kern(t_ref, i_ref, o_ref):
         idxb = i_ref[...]
@@ -141,6 +150,7 @@ def _pallas_factored_lookup(table: jax.Array, idx: jax.Array, *,
             idxb.dtype, (BLK, K1), 1)).astype(jnp.float32)
         rows = jax.lax.dot_general(ohhi, t_ref[...],
                                    (((1,), (0,)), ((), ())),
+                                   precision=_EXACT,
                                    preferred_element_type=jnp.float32)
         ohlo = lo[:, None] == jax.lax.broadcasted_iota(
             idxb.dtype, (BLK, K2), 1)
@@ -209,20 +219,24 @@ def _join_probe_xla(table_keys, table_vals, probe, valid):
 def _join_probe_pallas(table_keys, table_vals, probe, valid, *,
                        interpret: bool = False):
     import jax.experimental.pallas as pl
+    from .registry import pallas_interpret
 
     C, K = probe.shape[0], table_keys.shape[0]
     BLK = _pallas_block(C)
     assert BLK, f"capacity {C} not blockable; caller must gate on _pallas_block"
     vdt = table_vals.dtype
-    interpret = interpret or jax.default_backend() != "tpu"
+    interpret = interpret or pallas_interpret()
 
     def kern(tk_ref, tv_ref, p_ref, ok_ref, vals_ref, hit_ref):
-        p = p_ref[...]
+        # the validity mask is applied to the [BLK] results, not the [BLK, K]
+        # compare: Mosaic has no i1 [BLK] -> [BLK, 1] reshape
         ok = ok_ref[...] != 0
-        oh = (p[:, None] == tk_ref[...][None, :]) & ok[:, None]  # [BLK, K]
-        hit_ref[...] = jnp.any(oh, axis=1).astype(jnp.int32)
-        vals_ref[...] = jnp.sum(
+        oh = p_ref[...][:, None] == tk_ref[...][None, :]         # [BLK, K]
+        hit = (jnp.max(oh.astype(jnp.int32), axis=1) > 0) & ok
+        vals = jnp.sum(
             jnp.where(oh, tv_ref[...][None, :], jnp.zeros((), vdt)), axis=1)
+        hit_ref[...] = hit.astype(jnp.int32)
+        vals_ref[...] = jnp.where(hit, vals, jnp.zeros((), vdt))
 
     vals, hit = pl.pallas_call(
         kern,
@@ -761,6 +775,7 @@ def _factored_lookup(table: jax.Array, idx: jax.Array) -> jax.Array:
     lo = idx - hi * K2
     ohhi = (hi[:, None] == jnp.arange(K1, dtype=idx.dtype)).astype(jnp.float32)
     rows = jax.lax.dot_general(ohhi, t2, (((1,), (0,)), ((), ())),
+                               precision=_EXACT,
                                preferred_element_type=jnp.float32)   # [C, K2]
     ohlo = lo[:, None] == jnp.arange(K2, dtype=idx.dtype)
     out = jnp.sum(jnp.where(ohlo, rows, 0.0), axis=1)

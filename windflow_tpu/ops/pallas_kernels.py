@@ -10,16 +10,10 @@ reference GPU engine's ``ComputeBatch_Kernel`` (one thread per window,
 ``wf/win_seq_gpu.hpp:57-82``), here one *tile row* per window.
 
 ``masked_window_reduce``: given window contents ``[W, L]`` + occupancy mask, produce
-per-window sums — the hot aggregation of Win_Seq non-incremental sum windows. Falls
-back to the XLA formulation off-TPU (and under ``interpret=True`` in tests).
-
-A/B verdict (measured on TPU v5 lite, 2026-07-30, min over 5×100 async iters):
-XLA 10.1/10.9/13.3 µs vs Pallas 15.7/12.2/14.4 µs at [1024,1024]/[4096,512]/
-[8192,256]. The op reads ~8-12 MB per call — it is HBM-bandwidth-bound and XLA's
-fused where+reduce already runs at the roofline, so the data path keeps the XLA
-formulation (``Iterable.sum``) and this kernel stands as the documented negative
-result the decision rule in BASELINE.md calls for. ``bench.py::bench_pallas_ab``
-re-measures every capture; adopt if a future libtpu flips the verdict.
+per-window sums — the hot aggregation of Win_Seq non-incremental sum windows. The
+data path itself uses the XLA formulation (``Iterable.sum``); whether this kernel
+beats it has not been measured on the current code (ROADMAP A10 decides, C4 deletes
+the loser).
 """
 
 from __future__ import annotations
@@ -29,11 +23,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    HAVE_PALLAS = True
-except Exception:                                     # pragma: no cover
-    HAVE_PALLAS = False
+from jax.experimental import pallas as pl
+
+from .registry import pallas_interpret
 
 #: row-tile height per grid step (W axis); L is processed whole per row-tile.
 ROW_TILE = 256
@@ -74,29 +66,16 @@ def _pallas_masked_sum(vals, mask, *, interpret=False):
     return out[0]
 
 
-#: (W, L, interpret) -> False once Mosaic refused the shape (compile errors
-#: surface at first call, AFTER jit tracing — they cannot be caught inside the
-#: jitted body, so the XLA fallback lives out here).
-_pallas_ok: dict = {}
-
-
 def masked_window_reduce(vals: jax.Array, mask: jax.Array, *,
                          interpret: bool = False) -> jax.Array:
-    """Per-window masked sum of ``vals [W, L]`` under ``mask [W, L]`` -> ``[W]``."""
+    """Per-window masked sum of ``vals [W, L]`` under ``mask [W, L]`` -> ``[W]``.
+
+    Shapes the row-tile grid cannot block (``W % ROW_TILE`` or ``L % 128``)
+    take the XLA formulation; every other shape runs the Pallas kernel —
+    interpreted on the CPU backend, compiled by Mosaic on TPU, where a
+    lowering failure raises (it is never swapped for the XLA form)."""
     W, L = vals.shape
-    key = (W, L, interpret)
-    if (not HAVE_PALLAS or W % ROW_TILE or L % 128
-            or not _pallas_ok.get(key, True)
-            # Under an enclosing trace the Mosaic compile error would surface
-            # at the OUTER jit's compile, past this try/except, and the
-            # trace-time success line would poison the cache — so traced calls
-            # take the XLA formulation (which is also the measured winner).
-            or isinstance(vals, jax.core.Tracer)):
+    if W % ROW_TILE or L % 128:
         return _xla_jit(vals, mask)
-    try:
-        out = _pallas_masked_sum(vals, mask, interpret=interpret)
-        _pallas_ok[key] = True
-        return out
-    except Exception:                                  # lowering unsupported
-        _pallas_ok[key] = False
-        return _xla_jit(vals, mask)
+    return _pallas_masked_sum(vals, mask,
+                              interpret=interpret or pallas_interpret())

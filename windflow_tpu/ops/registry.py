@@ -57,18 +57,28 @@ def _deprecated_alias_choice(kernel: str) -> Optional[str]:
     return None if value in ("", "0") else value
 
 
+class KernelRefused(RuntimeError):
+    """Selecting an impl the TPU compiler is known to refuse. Raised at
+    selection — the registry never substitutes another impl."""
+
+
 class KernelImpl:
     """One registered implementation of a kernel family."""
 
-    __slots__ = ("kernel", "name", "fn", "reference", "backends")
+    __slots__ = ("kernel", "name", "fn", "reference", "backends",
+                 "tpu_refusal")
 
     def __init__(self, kernel: str, name: str, fn: Optional[Callable],
-                 reference: bool, backends: Tuple[str, ...]):
+                 reference: bool, backends: Tuple[str, ...],
+                 tpu_refusal: Optional[str] = None):
         self.kernel = kernel
         self.name = name
         self.fn = fn
         self.reference = reference
         self.backends = backends
+        #: Mosaic's own message when it refuses to compile this impl on TPU
+        #: (recorded from a chip run); None = compiles
+        self.tpu_refusal = tpu_refusal
 
     def __repr__(self) -> str:
         return (f"KernelImpl({self.kernel}:{self.name}"
@@ -84,16 +94,27 @@ def device_kind() -> str:
     return _dk()
 
 
+def pallas_interpret() -> bool:
+    """THE interpret rule every Pallas kernel module shares: interpret on
+    the ``cpu`` backend (tests, rehearsals), compile with Mosaic on ``tpu``,
+    and refuse any other platform — a kernel that silently ran interpreted
+    on an accelerator would pass every test and measure nothing."""
+    import jax
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas kernels run interpreted on 'cpu' and compiled on 'tpu'; "
+        f"the default backend is {backend!r} — select the xla impl "
+        f"(WF_KERNEL_IMPL=xla) on this platform")
+
+
 def pallas_backend() -> str:
     """Which Pallas execution mode a ``pallas`` impl would use right now:
-    ``"pallas-tpu"`` on a TPU backend, ``"pallas-interpret"`` elsewhere (the
-    kernels all auto-enable ``interpret=True`` off-TPU)."""
-    try:
-        import jax
-        return ("pallas-tpu" if jax.default_backend() == "tpu"
-                else "pallas-interpret")
-    except Exception:                         # noqa: BLE001 — no backend
-        return "pallas-interpret"
+    ``"pallas-tpu"`` or ``"pallas-interpret"`` (see :func:`pallas_interpret`)."""
+    return "pallas-interpret" if pallas_interpret() else "pallas-tpu"
 
 
 def _parse_kernel_impl_env(value: str) -> Dict[str, str]:
@@ -138,14 +159,19 @@ class KernelRegistry:
                         fn: Optional[Callable] = None, *,
                         reference: bool = False,
                         backends: Tuple[str, ...] = ("xla",),
-                        default: bool = False) -> None:
+                        default: bool = False,
+                        tpu_refusal: Optional[str] = None) -> None:
         """Register ``impl`` for ``kernel``. ``reference`` marks the
         byte-identical oracle every other impl is parity-tested against;
         ``default`` (implied by the first registration) is the selection
-        when nothing overrides. Re-registration replaces (module reload)."""
+        when nothing overrides. ``tpu_refusal`` records Mosaic's message
+        for an impl it will not compile: selecting that impl on a TPU
+        backend raises :class:`KernelRefused` (it still runs interpreted on
+        CPU). Re-registration replaces (module reload)."""
         with self._lock:
             fam = self._impls.setdefault(kernel, {})
-            fam[impl] = KernelImpl(kernel, impl, fn, reference, backends)
+            fam[impl] = KernelImpl(kernel, impl, fn, reference, backends,
+                                   tpu_refusal)
             if default or kernel not in self._default:
                 self._default[kernel] = impl
 
@@ -233,6 +259,11 @@ class KernelRegistry:
         code, so an env change can neither invalidate them nor make the
         staleness comparison meaningful."""
         choice = self._select(kernel, spec_key, impl)
+        refusal = self._impls[kernel][choice].tpu_refusal
+        if refusal is not None and not pallas_interpret():
+            raise KernelRefused(
+                f"kernel {kernel!r} impl {choice!r} does not compile on TPU "
+                f"(Mosaic: {refusal}); select another impl")
         if record and impl is None:
             dk = device_kind()
             with self._lock:
@@ -293,9 +324,11 @@ REGISTRY = KernelRegistry()
 def register_kernel(kernel: str, impl: str, fn: Optional[Callable] = None, *,
                     reference: bool = False,
                     backends: Tuple[str, ...] = ("xla",),
-                    default: bool = False) -> None:
+                    default: bool = False,
+                    tpu_refusal: Optional[str] = None) -> None:
     REGISTRY.register_kernel(kernel, impl, fn, reference=reference,
-                             backends=backends, default=default)
+                             backends=backends, default=default,
+                             tpu_refusal=tpu_refusal)
 
 
 def resolve_impl(kernel: str, *, spec_key: str = "",

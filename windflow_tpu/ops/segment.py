@@ -102,12 +102,13 @@ def _pallas_segment_fold(values, seg, valid, S, *, interpret: bool = False):
     result equals XLA's integer ``segment_sum`` bit-for-bit, including on
     overflow and after the final cast to a narrower input dtype."""
     import jax.experimental.pallas as pl
+    from .registry import pallas_interpret
 
     C = values.shape[0]
     dtype = values.dtype
     S_pad = -(-S // FOLD_S_TILE) * FOLD_S_TILE
     R = C // FOLD_CHUNK
-    interpret = interpret or jax.default_backend() != "tpu"
+    interpret = interpret or pallas_interpret()
 
     def kern(v_ref, s_ref, ok_ref, out_ref):
         r = pl.program_id(0)
@@ -123,11 +124,14 @@ def _pallas_segment_fold(values, seg, valid, S, *, interpret: bool = False):
                  ((vi >> 11) & 0x7FF).astype(jnp.float32),
                  (vi >> 22).astype(jnp.float32)]
         for s0 in range(0, S_pad, FOLD_S_TILE):
-            oh = (((sg[:, None] - s0) == jax.lax.broadcasted_iota(
-                sg.dtype, (FOLD_CHUNK, FOLD_S_TILE), 1)) &
-                  ok[:, None]).astype(jnp.float32)
+            # dead lanes need no mask here: their limbs are already 0
+            oh = ((sg[:, None] - s0) == jax.lax.broadcasted_iota(
+                sg.dtype, (FOLD_CHUNK, FOLD_S_TILE), 1)).astype(jnp.float32)
+            # HIGHEST: an 11-bit limb does not survive the single bf16 pass
+            # a TPU gives an f32 dot by default
             p0, p1, p2 = (jax.lax.dot_general(
                 l[None, :], oh, (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=jnp.float32).astype(jnp.int32)
                 for l in limbs)                            # [1, S_TILE] each
             part = p0 + (p1 << 11) + (p2 << 22)            # wrapping i32

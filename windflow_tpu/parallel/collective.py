@@ -35,21 +35,10 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.segment import segment_rank
-
-try:                                     # jax >= 0.4.35
-    from jax import shard_map as _shard_map
-except ImportError:                      # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-#: the replication-check kwarg was renamed check_rep -> check_vma across jax versions
-_CHECK_KW = ("check_vma" if "check_vma" in _inspect.signature(_shard_map).parameters
-             else "check_rep")
-
 
 def _axis_size(mesh: Mesh, axis: str) -> int:
     return mesh.shape[axis]
@@ -101,7 +90,7 @@ def wmr_map_reduce(map_fn: Callable, combine: Callable, mesh: Mesh, *,
     # the folded all_gather of the generic path is replicated by construction, but
     # the static varying-axes checker can't prove it — disable the check there
     return _shard_map(local, mesh=mesh, in_specs=(P(axis), P(axis)),
-                      out_specs=P(), **{_CHECK_KW: known})
+                      out_specs=P(), check_vma=known)
 
 
 # -- ring pane exchange ----------------------------------------------------------------

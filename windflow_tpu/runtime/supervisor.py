@@ -4,7 +4,7 @@ The reference's failure model is ``exit(EXIT_FAILURE)`` (SURVEY §5 — no resta
 no rescaling); state is lost. Here operator state is a pytree threaded through a
 compiled step, which makes recovery structural:
 
-- **Detection**: any exception surfacing from a step (device fault, tunnel loss,
+- **Detection**: any exception surfacing from a step (device fault, lost link,
   user-function error materializing at the sink) is caught by the supervisor loop;
   a ``step_timeout`` watchdog additionally converts a *hung* step into a
   detectable :class:`~windflow_tpu.runtime.faults.WatchdogTimeout`.
