@@ -50,15 +50,21 @@ def test_compile_cache_rule(monkeypatch, tmp_path):
     updates = []
     monkeypatch.setattr(jax.config, "update",
                         lambda k, v: updates.append((k, v)))
-    # set: JAX reads the variable itself; the code sets nothing
+    # an entry's key takes the program's metadata (scope paths, source lines)
+    # wherever the cache lives: a profile is read by them
+    # (each operation located by its own source line, not by the call stack)
+    metadata = [("jax_compilation_cache_include_metadata_in_key", True),
+                ("jax_traceback_in_locations_limit", 1)]
+    # set: JAX reads the variable itself; the code sets no directory
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     assert compile_cache.enable_compile_cache() == str(tmp_path)
-    assert updates == []
+    assert updates == metadata
+    del updates[:]
     # unset: the checkout-relative path, the same one the rehearsal's
     # process printed from another cwd (process 2 of 2)
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
     assert compile_cache.enable_compile_cache() == CACHE
-    assert updates == [("jax_compilation_cache_dir", CACHE)]
+    assert updates == metadata + [("jax_compilation_cache_dir", CACHE)]
 
 
 def test_bench_refuses_to_measure_without_a_tpu(monkeypatch):

@@ -477,8 +477,9 @@ def test_journal_default_stays_per_event(tmp_path):
 def test_xprof_trace_nested_session_clear_error(tmp_path, monkeypatch):
     import windflow_tpu.stats as stats
     calls = []
+    # (xprof_trace passes ProfileOptions: the Python tracer is off)
     monkeypatch.setattr("jax.profiler.start_trace",
-                        lambda d: calls.append(("start", d)))
+                        lambda d, profiler_options: calls.append(("start", d)))
     monkeypatch.setattr("jax.profiler.stop_trace",
                         lambda: calls.append(("stop",)))
     with stats.xprof_trace(str(tmp_path / "a")):
@@ -495,7 +496,7 @@ def test_xprof_trace_nested_session_clear_error(tmp_path, monkeypatch):
 def test_xprof_trace_external_session_chained_error(tmp_path, monkeypatch):
     import windflow_tpu.stats as stats
 
-    def boom(d):
+    def boom(d, profiler_options):
         raise RuntimeError("Only one profile may be run at a time.")
     monkeypatch.setattr("jax.profiler.start_trace", boom)
     with pytest.raises(RuntimeError, match="another profiler session") as ei:
@@ -503,7 +504,8 @@ def test_xprof_trace_external_session_chained_error(tmp_path, monkeypatch):
             pass
     assert isinstance(ei.value.__cause__, RuntimeError)
     # the guard did not latch: a later (now-working) session is allowed
-    monkeypatch.setattr("jax.profiler.start_trace", lambda d: None)
+    monkeypatch.setattr("jax.profiler.start_trace",
+                        lambda d, profiler_options: None)
     monkeypatch.setattr("jax.profiler.stop_trace", lambda: None)
     with stats.xprof_trace(str(tmp_path / "y")):
         pass

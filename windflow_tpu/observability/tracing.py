@@ -30,7 +30,16 @@ Three pieces:
   and a drill-down of the slowest batches (``scripts/wf_trace.py`` is the
   CLI over both).
 
-Everything is **off by default** and follows the ``monitoring=`` / ``faults=``
+- **Spans on the profiler's clock**: :func:`span` is the one way a runtime
+  site marks an interval.  It always opens a ``jax.profiler.TraceAnnotation``
+  (a TraceMe: recorded only while a profiler session is open, on the same
+  clock as the device plane, so ``wf.xprof_trace`` / ``scripts/wf_profile.py``
+  show the program's own spans beside the device's operations) and, for a
+  traced batch under an active :class:`Tracer`, the flight recorder's
+  begin/end rows.  The span names (``wf.source.*``, ``wf.drive.*``,
+  ``wf.chain.*``, ``wf.sink.*``) are listed in ``docs/ARCHITECTURE.md``.
+
+The flight recorder is **off by default** and follows the ``monitoring=`` / ``faults=``
 / ``control=`` convention: ``trace=`` kwarg on every driver, or process-wide::
 
     WF_TRACE=1                 # defaults: ./wf_trace output directory
@@ -58,6 +67,11 @@ from . import journal as _journal
 #: name as ``windflow_tpu.batch.TRACE_META_ATTR`` (documented there); kept as
 #: a literal so this module stays importable without JAX.
 TRACE_META_ATTR = "_wf_trace"
+
+#: host-side sidecar attribute carrying the batch's offered position (set at
+#: every driver's source boundary by :func:`ingest`, tracer or no tracer): the
+#: identifier the ``pos`` argument of one batch's :func:`span`s share
+POS_ATTR = "_wf_pos"
 
 #: record kinds (flight-recorder rows and the flight.jsonl schema)
 K_INGEST = "ingest"        # trace id minted at the source boundary
@@ -155,12 +169,24 @@ def tid_of(batch) -> Optional[int]:
     return getattr(batch, TRACE_META_ATTR, None)
 
 
+def pos_of(batch) -> Optional[int]:
+    """Offered position riding on ``batch``, or None (not stamped by a
+    driver's :func:`ingest`, or lost across a hop nobody carried)."""
+    return getattr(batch, POS_ATTR, None)
+
+
 def carry(src, dst) -> None:
-    """Propagate the trace id across an operator hop (compiled pushes return
-    NEW Batch objects; the sidecar attribute does not survive jit)."""
+    """Propagate the trace id and the offered position across an operator hop
+    (compiled pushes return NEW Batch objects; the sidecar attributes do not
+    survive jit)."""
+    if dst is None:
+        return
     tid = getattr(src, TRACE_META_ATTR, None)
-    if tid is not None and dst is not None:
+    if tid is not None:
         object.__setattr__(dst, TRACE_META_ATTR, tid)
+    pos = getattr(src, POS_ATTR, None)
+    if pos is not None:
+        object.__setattr__(dst, POS_ATTR, pos)
 
 
 # ----------------------------------------------------------- flight recorder
@@ -451,6 +477,7 @@ def get_active() -> Optional[Tracer]:
 
 def ingest(batch, pos: int, stream: int = 0,
            extras: Optional[dict] = None) -> None:
+    object.__setattr__(batch, POS_ATTR, pos)
     tr = _active
     if tr is not None:
         tr.ingest(batch, pos, stream, extras=extras)
@@ -481,6 +508,76 @@ def abort_open(reason: str) -> None:
     tr = _active
     if tr is not None:
         tr.abort_open(reason)
+
+
+# ------------------------------------------------ spans on the profiler's clock
+
+#: ``jax.profiler.TraceAnnotation``, resolved by the first :func:`span` (this
+#: module stays importable without JAX)
+_annotation = None
+
+
+def span(name: str, batch=None, **counts):
+    """``with span(name, batch=None, **counts):`` — THE way a runtime site marks
+    an interval.  Always a ``jax.profiler.TraceAnnotation(name, **counts)``: a
+    TraceMe, recorded only while a profiler session is open (one atomic load
+    otherwise), on the clock the device plane uses; ``counts`` (``pos``,
+    bytes, queue depths; a None is left out) ride as the event's arguments,
+    so ratios are taken at the boundary the span marks.  With ``batch`` given,
+    a :class:`Tracer` active and the batch traced, the flight recorder also
+    gets its begin/end rows under stage ``name`` (``Tracer.service``)."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    if None in counts.values():
+        counts = {k: v for k, v in counts.items() if v is not None}
+    tr = _active
+    if tr is None or batch is None:
+        return _annotation(name, **counts)
+    return _RecordedSpan(_annotation(name, **counts), tr, batch, name)
+
+
+class _RecordedSpan:
+    """A :func:`span` that also writes the flight recorder's rows."""
+
+    __slots__ = ("_ann", "_tracer", "_batch", "_name", "_svc")
+
+    def __init__(self, ann, tracer: Tracer, batch, name: str):
+        self._ann = ann
+        self._tracer = tracer
+        self._batch = batch
+        self._name = name
+        self._svc = None
+
+    def __enter__(self) -> "_RecordedSpan":
+        self._svc = self._tracer.service(self._batch, self._name)
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._ann.__exit__(*exc)
+        # a span that raised stays open in the flight recorder: a supervisor's
+        # abort_open closes it with the reason
+        if self._svc is not None and exc[0] is None:
+            self._svc.done()
+        return False
+
+
+def name_thread(name: str) -> None:
+    """Give the calling thread ``name`` in the OS too: the profiler labels a
+    host line by the OS thread name, and Python (before 3.14) names a thread
+    only for itself, so every line would read ``python3``.  Linux's
+    ``prctl(PR_SET_NAME)``, 15 bytes; elsewhere the line keeps its number."""
+    import sys
+    if not sys.platform.startswith("linux"):
+        return
+    import ctypes
+    prctl = ctypes.CDLL(None, use_errno=True).prctl
+    prctl.argtypes = [ctypes.c_int, ctypes.c_char_p, ctypes.c_ulong,
+                      ctypes.c_ulong, ctypes.c_ulong]
+    prctl.restype = ctypes.c_int
+    prctl(15, name.encode()[:15], 0, 0, 0)         # PR_SET_NAME
 
 
 # ------------------------------------------------------------------ loading

@@ -78,6 +78,12 @@ class Basic_Operator:
     def getParallelism(self) -> int:
         return self._parallelism
 
+    def scope_name(self) -> str:
+        """``Class:name`` — the ``jax.named_scope`` a compiled chain traces
+        this operator's ``apply`` under, so the profiler's device operations
+        carry their operator (HLO metadata only: no equation changes)."""
+        return f"{type(self).__name__}:{self._name}".replace("/", "_")
+
     def getRoutingMode(self) -> routing_modes_t:
         return self.routing
 

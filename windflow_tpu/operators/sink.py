@@ -25,6 +25,7 @@ from ..basic import routing_modes_t
 from ..batch import Batch, tuple_refs
 from ..context import RuntimeContext
 from ..meta import classify_sink
+from ..observability import tracing as _tracing
 from .base import Basic_Operator
 
 
@@ -49,6 +50,7 @@ class Sink(Basic_Operator):
         self.routing = routing_modes_t.KEYBY if keyed else routing_modes_t.FORWARD
         self.async_depth = int(async_depth)
         self._shipper = None
+        self._nbytes_by_cap = {}    # capacity -> bytes of one result batch
         self.context = context or RuntimeContext(parallelism, 0)
 
     def _deliver(self, view):
@@ -57,7 +59,7 @@ class Sink(Basic_Operator):
         else:
             self.fn(view)
 
-    def _deliver_host(self, host: Batch):
+    def _deliver_host(self, host: Batch, pos: Optional[int] = None):
         v = host.valid
         # the whole batch crossed device->host to get here: count the transfer
         # (wf/stats_record.hpp:78-80 bytes_copied_dh) + live-tuple ingress
@@ -68,13 +70,18 @@ class Sink(Basic_Operator):
         rec.record_input(n_live)
         if not n_live:
             return
-        self._deliver({
-            "key": host.key[v], "id": host.id[v], "ts": host.ts[v],
-            "payload": jax.tree.map(lambda a: a[v], host.payload),
-        })
+        with _tracing.span("wf.sink.deliver", pos=pos, n_live=n_live):
+            self._deliver({
+                "key": host.key[v], "id": host.id[v], "ts": host.ts[v],
+                "payload": jax.tree.map(lambda a: a[v], host.payload),
+            })
 
     def consume(self, batch: Optional[Batch]):
         """Host-side: deliver one batch (or None at EOS) to the user callback."""
+        with _tracing.span("wf.sink.consume", pos=_tracing.pos_of(batch)):
+            self._consume(batch)
+
+    def _consume(self, batch: Optional[Batch]):
         if self.async_depth:
             if self._shipper is None:
                 from ..runtime.async_sink import AsyncResultShipper
@@ -91,7 +98,15 @@ class Sink(Basic_Operator):
         if batch is None:
             self._deliver(None)
             return
-        self._deliver_host(jax.tree.map(np.asarray, batch))
+        pos = _tracing.pos_of(batch)
+        nbytes = self._nbytes_by_cap.get(batch.capacity)
+        if nbytes is None:
+            nbytes = self._nbytes_by_cap[batch.capacity] = sum(
+                a.nbytes for a in jax.tree.leaves(batch))
+        # waits for the device to finish the batch, then copies it back
+        with _tracing.span("wf.sink.d2h", pos=pos, bytes=nbytes):
+            host = jax.tree.map(np.asarray, batch)
+        self._deliver_host(host, pos)
 
 
 class ReduceSink(Basic_Operator):

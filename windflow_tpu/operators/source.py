@@ -32,7 +32,32 @@ from ..basic import routing_modes_t, DEFAULT_BATCH_SIZE
 from ..batch import Batch, CTRL_DTYPE, hash_key_to_slot
 from ..context import RuntimeContext
 from ..meta import classify_source
+from ..observability import tracing as _tracing
 from .base import Basic_Operator
+
+
+def _device_nbytes(batch) -> int:
+    """Bytes a batch holds on the device: every leaf at its canonical dtype
+    (without x64 the transfer narrows 8-byte columns to 4)."""
+    return sum(int(np.prod(np.shape(a), dtype=np.int64))
+               * jax.dtypes.canonicalize_dtype(a.dtype).itemsize
+               for a in jax.tree.leaves(batch))
+
+
+def _pulled(it: Iterator, first: int) -> Iterator:
+    """``it``'s items, each pull under a ``wf.source.next`` span: the time the
+    user's iterator takes to produce (or blocks before) its next chunk.
+    ``first`` is the stream position of the first item."""
+    it = iter(it)
+    pos = first
+    while True:
+        with _tracing.span("wf.source.next", pos=pos):
+            try:
+                item = next(it)
+            except StopIteration:
+                return
+        pos += 1
+        yield item
 
 
 def prefetch_to_device(host_batches: Iterator[Batch], depth: int = 3,
@@ -65,14 +90,27 @@ def prefetch_to_device(host_batches: Iterator[Batch], depth: int = 3,
                 continue
         return False
 
+    nbytes_by_cap = {}              # a source's batches share their dtypes
+
     def worker():
+        _tracing.name_thread("wf-prefetch")
         try:
-            for hb in host_batches:
+            # pos: the batch's offered position (this iterator is the whole
+            # stream, from its start), as the drive loop's spans carry it
+            for pos, hb in enumerate(host_batches):
                 while (pause_event is not None and pause_event.is_set()
                        and not stop.is_set()):
                     time.sleep(0.001)
-                if not put_guarded(jax.device_put(hb)):
-                    return
+                nbytes = nbytes_by_cap.get(hb.capacity)
+                if nbytes is None:
+                    nbytes = nbytes_by_cap[hb.capacity] = _device_nbytes(hb)
+                with _tracing.span("wf.source.h2d", pos=pos, bytes=nbytes):
+                    db = jax.device_put(hb)
+                # blocked here = the queue is full: the drive loop is the
+                # slower side
+                with _tracing.span("wf.source.put", pos=pos):
+                    if not put_guarded(db):
+                        return
             put_guarded(END)
         except BaseException as e:      # noqa: BLE001 — re-raised at consumer
             put_guarded((ERR, e))
@@ -80,8 +118,14 @@ def prefetch_to_device(host_batches: Iterator[Batch], depth: int = 3,
     threading.Thread(target=worker, daemon=True,  # wf-lint: thread-role[prefetch]
                      name="wf-prefetch").start()
     try:
+        pos = 0
         while True:
-            item = q.get()
+            # blocked here = the queue is empty: the prefetch thread is the
+            # slower side (``queued`` says how far ahead it was)
+            with _tracing.span("wf.drive.ingest_wait", pos=pos,
+                               queued=q.qsize()):
+                item = q.get()
+            pos += 1
             if item is END:
                 return
             if isinstance(item, tuple) and len(item) == 2 and item[0] is ERR:
@@ -191,15 +235,26 @@ class SourceBase(Basic_Operator):
         def pad_to(a):
             a = np.asarray(a)
             return np.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1))
-        ids = np.arange(next_id, next_id + batch_size, dtype=np.int32)
-        return Batch(
-            key=(pad_to(key).astype(np.int32) if key is not None
-                 else np.zeros(batch_size, np.int32)),
-            id=ids,
-            ts=pad_to(ts).astype(np.int32) if ts is not None else ids,
-            payload=jax.tree.map(pad_to, payload),
-            valid=np.arange(batch_size) < n,
-        )
+        # bytes_out: int32 key, id, ts and the bool mask (13 a lane) and every
+        # payload column padded (a source's chunks share their dtypes: once);
+        # pos: callers count the chunk before framing it
+        lane = getattr(self, "_framed_lane_nbytes", None)
+        if lane is None:
+            lane = self._framed_lane_nbytes = 13 + sum(
+                np.asarray(a).dtype.itemsize
+                * int(np.prod(np.shape(a)[1:], dtype=np.int64))
+                for a in jax.tree.leaves(payload))
+        with _tracing.span("wf.source.frame", pos=self._emitted - 1,
+                           bytes_out=batch_size * lane):
+            ids = np.arange(next_id, next_id + batch_size, dtype=np.int32)
+            return Batch(
+                key=(pad_to(key).astype(np.int32) if key is not None
+                     else np.zeros(batch_size, np.int32)),
+                id=ids,
+                ts=pad_to(ts).astype(np.int32) if ts is not None else ids,
+                payload=jax.tree.map(pad_to, payload),
+                valid=np.arange(batch_size) < n,
+            )
 
 
 class DeviceSource(SourceBase):
@@ -344,7 +399,7 @@ class GeneratorSource(SourceBase):
 
     def _host_batches(self, batch_size: int = DEFAULT_BATCH_SIZE, cursor=None):
         skip, it = self._open_seek(cursor)
-        for i, item in enumerate(it):
+        for i, item in enumerate(_pulled(it, self._emitted - skip)):
             if i < skip:        # cheap replay skip: no framing, no transfer
                 continue
             self._emitted += 1
@@ -422,15 +477,16 @@ class RecordSource(SourceBase):
         unpack = (unpack_records if self.framing_workers == 1 else
                   lambda r: parallel_unpack(r, workers=self.framing_workers))
         skip, it = self._open_seek(cursor)
-        for i, rec in enumerate(it):
+        for i, rec in enumerate(_pulled(it, self._emitted - skip)):
             if i < skip:        # cheap replay skip: no unpack, no framing
                 continue
             self._emitted += 1
-            rec = np.asarray(rec, self.dtype)
-            n = rec.shape[0]
-            cols = unpack(rec)
-            key = (self._ingest_key(cols[self.key_field])
-                   if self.key_field else None)
+            n = len(rec)
+            with _tracing.span("wf.source.unpack", pos=self._emitted - 1,
+                               bytes_in=n * self.dtype.itemsize):
+                cols = unpack(np.asarray(rec, self.dtype))
+                key = (self._ingest_key(cols[self.key_field])
+                       if self.key_field else None)
             ts = cols[self.ts_field] if self.ts_field else None
             payload = {f: cols[f] for f in self.payload_fields}
             nid = self._next_id

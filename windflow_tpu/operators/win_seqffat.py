@@ -333,8 +333,9 @@ class Win_SeqFFAT(Basic_Operator):
         K, P = self.num_keys, self.P
         valid = batch.valid
         if self.spec.is_cb:
-            rank = segment_rank(batch.key, valid)
-            pos = table_lookup(state.count, batch.key) + rank
+            with jax.named_scope("rank"):
+                rank = segment_rank(batch.key, valid)
+                pos = table_lookup(state.count, batch.key) + rank
             pane = pos // self.pane_len
             n_dropped = jnp.zeros((), CTRL_DTYPE)    # CB never drops OLD tuples
         else:
@@ -343,36 +344,37 @@ class Win_SeqFFAT(Basic_Operator):
             n_dropped = jnp.sum((valid & ~kept).astype(CTRL_DTYPE))
             valid = kept
             pane = batch.ts // self.pane_len
-        slot = pane % P
-        seg = jnp.where(valid, batch.key * P + slot, K * P)
+        with jax.named_scope("fold"):
+            slot = pane % P
+            seg = jnp.where(valid, batch.key * P + slot, K * P)
 
-        lifted = jax.vmap(self.lift)(
-            TupleRef(key=batch.key, id=batch.id, ts=batch.ts, data=batch.payload))
-        # per-(key,pane-slot) partial of this batch
-        upd = segment_reduce(lifted, seg, valid, K * P,
-                             combine=None if self.combine is jnp.add else self.combine,
-                             identity=self.identity)
-        cnt_upd = segment_reduce(valid.astype(CTRL_DTYPE), seg, valid, K * P)
-        pane_id_upd = segment_reduce(pane, seg, valid, K * P,
-                                     combine=jnp.maximum, identity=-1)
+            lifted = jax.vmap(self.lift)(
+                TupleRef(key=batch.key, id=batch.id, ts=batch.ts, data=batch.payload))
+            # per-(key,pane-slot) partial of this batch
+            upd = segment_reduce(lifted, seg, valid, K * P,
+                                 combine=None if self.combine is jnp.add else self.combine,
+                                 identity=self.identity)
+            cnt_upd = segment_reduce(valid.astype(CTRL_DTYPE), seg, valid, K * P)
+            pane_id_upd = segment_reduce(pane, seg, valid, K * P,
+                                         combine=jnp.maximum, identity=-1)
 
-        touched = cnt_upd.reshape(K, P) > 0
-        new_pane_of = jnp.where(touched, pane_id_upd.reshape(K, P), state.pane_of)
-        # a slot whose pane id advanced (ring wrap) restarts from identity
-        fresh = touched & (new_pane_of != state.pane_of)
+            touched = cnt_upd.reshape(K, P) > 0
+            new_pane_of = jnp.where(touched, pane_id_upd.reshape(K, P), state.pane_of)
+            # a slot whose pane id advanced (ring wrap) restarts from identity
+            fresh = touched & (new_pane_of != state.pane_of)
 
-        def fold(tbl, u):
-            u = u.reshape((K, P) + u.shape[1:])
-            t = jnp.where(_b(fresh, tbl), jnp.asarray(self.identity, tbl.dtype), tbl)
-            m = _b(touched, tbl)
-            if self.combine is jnp.add:
-                return jnp.where(m, t + u, t)
-            return jnp.where(m, self.combine(t, u), t)
+            def fold(tbl, u):
+                u = u.reshape((K, P) + u.shape[1:])
+                t = jnp.where(_b(fresh, tbl), jnp.asarray(self.identity, tbl.dtype), tbl)
+                m = _b(touched, tbl)
+                if self.combine is jnp.add:
+                    return jnp.where(m, t + u, t)
+                return jnp.where(m, self.combine(t, u), t)
 
-        counts_add = segment_reduce(valid.astype(CTRL_DTYPE), batch.key, valid, K)
-        ts_max = segment_reduce(batch.ts, batch.key, valid, K,
-                                combine=jnp.maximum, identity=-1)
-        wm_new = jnp.maximum(state.wm, ts_max)
+            counts_add = segment_reduce(valid.astype(CTRL_DTYPE), batch.key, valid, K)
+            ts_max = segment_reduce(batch.ts, batch.key, valid, K,
+                                    combine=jnp.maximum, identity=-1)
+            wm_new = jnp.maximum(state.wm, ts_max)
         lat = state.lat_hist
         if lat is not None:
             # per-key TB path: lateness vs the MAX per-key watermark — the
@@ -380,10 +382,14 @@ class Win_SeqFFAT(Basic_Operator):
             # buckets even though its own frontier fires late)
             lat = _et.lateness_update(lat, jnp.max(wm_new), batch.ts,
                                       batch.valid)
+        with jax.named_scope("fold"):
+            panes = jax.tree.map(fold, state.panes, upd)
+            pane_count = (jnp.where(fresh, 0, state.pane_count)
+                          + cnt_upd.reshape(K, P))
         return dataclasses.replace(
             state,
-            panes=jax.tree.map(fold, state.panes, upd),
-            pane_count=jnp.where(fresh, 0, state.pane_count) + cnt_upd.reshape(K, P),
+            panes=panes,
+            pane_count=pane_count,
             pane_of=new_pane_of,
             count=state.count + counts_add,
             wm=wm_new,
@@ -458,19 +464,29 @@ class Win_SeqFFAT(Basic_Operator):
         return W
 
     def apply(self, state, batch: Batch):
+        """One scope per phase (``insert``, ``emit``; inside ``insert`` the
+        ``rank`` and the ``fold``), under the operator's own scope that the
+        chain opens: a profile's device operations say which part of the
+        engine they belong to."""
         W = self._resolve_w(batch.capacity)
         self._w = W
-        if self.global_time:
-            state = self._g_insert(state, batch)
-            return self._g_emit(state, W, flush=False)
-        state = self._insert(state, batch)
-        return self._emit(state, W, flush=False)
+        insert, emit = ((self._g_insert, self._g_emit) if self.global_time
+                        else (self._insert, self._emit))
+        with jax.named_scope("insert"):
+            state = insert(state, batch)
+        with jax.named_scope("emit"):
+            return emit(state, W, flush=False)
 
     def flush(self, state):
         W = self._w or self._resolve_w(256)
         if not hasattr(self, "_flush_jit"):
             emit = self._g_emit if self.global_time else self._emit
-            self._flush_jit = jax.jit(lambda st: emit(st, W, flush=True))
+
+            def flush_emit(st):
+                with jax.named_scope(self.scope_name()), \
+                        jax.named_scope("emit"):
+                    return emit(st, W, flush=True)
+            self._flush_jit = jax.jit(flush_emit)
         state, out = self._flush_jit(state)
         self.collect_stats(state)
         if not bool(jnp.any(out.valid)):

@@ -194,6 +194,18 @@ class CompiledChain:  # wf-lint: single-writer[driver, stage]
     def out_spec(self):
         return self.specs[-1]
 
+    def _apply_from(self, i: int, states, batch):
+        """ops[i:] over one batch — the body both the per-batch step and the
+        scan body trace.  Each operator's ``apply`` runs under its
+        ``jax.named_scope`` (``Class:name``): HLO metadata only, so the
+        profiler's device operations name their operator while executables
+        and cost pins stay as they were."""
+        states = list(states)
+        for j in range(i, len(self.ops)):
+            with jax.named_scope(self.ops[j].scope_name()):
+                states[j], batch = self.ops[j].apply(states[j], batch)
+        return tuple(states), batch
+
     def _step_fn(self, i: int):
         if i not in self._steps:
             def step(states, batch):
@@ -207,10 +219,7 @@ class CompiledChain:  # wf-lint: single-writer[driver, stage]
                     hl.note_trace(self.label, i, "step", _health_sig(batch),
                                   capacity=jax.tree.leaves(batch)[0].shape[0]
                                   if jax.tree.leaves(batch) else None)
-                states = list(states)
-                for j in range(i, len(self.ops)):
-                    states[j], batch = self.ops[j].apply(states[j], batch)
-                return tuple(states), batch
+                return self._apply_from(i, states, batch)
             self._steps[i] = jax.jit(step)
         return self._steps[i]
 
@@ -236,10 +245,7 @@ class CompiledChain:  # wf-lint: single-writer[driver, stage]
                         k=leaves[0].shape[0] if leaves else None)
 
                 def body(carry, batch):
-                    carry = list(carry)
-                    for j in range(i, len(self.ops)):
-                        carry[j], batch = self.ops[j].apply(carry[j], batch)
-                    return tuple(carry), batch
+                    return self._apply_from(i, carry, batch)
                 return jax.lax.scan(body, tuple(states), stacked)
             self._steps[key] = jax.jit(scan_step)
         return self._steps[key]
@@ -428,18 +434,22 @@ class CompiledChain:  # wf-lint: single-writer[driver, stage]
         batches = list(batches)
         if len(batches) == 1:
             return [self.push(batches[0], from_op=from_op)]
-        k = len(batches)
-        stacked = stack_batches(batches)
-        if self.device is not None:
-            stacked = jax.device_put(stacked, self.device)
         # per-LAUNCH sampling (the push-path predicate over launch count):
         # every Nth fused dispatch is timed to completion, the other N-1 keep
         # the async queue full
         self._fused_count += 1
+        sampled = self._sampled(self._fused_count)
+        with _tracing.span("wf.chain.push", pos=_tracing.pos_of(batches[0]),
+                           k=len(batches), sampled=int(sampled)):
+            return self._push_many(batches, from_op, sampled)
+
+    def _push_many(self, batches: List[Batch], from_op: int,
+                   sampled: bool) -> List[Batch]:
+        k = len(batches)
+        stacked = stack_batches(batches)
+        if self.device is not None:
+            stacked = jax.device_put(stacked, self.device)
         c = self._fused_count
-        sampled = ((c % self.SERVICE_SAMPLE_EVERY) == 0
-                   or (1 < c < self.SERVICE_SAMPLE_EVERY
-                       and (c & (c - 1)) == 0))
         hl, t0c = self._health_begin("push_many")
         t0 = time.perf_counter() if sampled else 0.0
         states, outs_stacked = self._scan_fn(from_op)(tuple(self.states),
@@ -450,7 +460,8 @@ class CompiledChain:  # wf-lint: single-writer[driver, stage]
             # dispatch" and "device completion" is one extra perf_counter
             # on a path that pays a block_until_ready anyway
             t_disp = time.perf_counter()
-            jax.block_until_ready(outs_stacked)
+            with _tracing.span("wf.chain.sync"):
+                jax.block_until_ready(outs_stacked)
             t_done = time.perf_counter()
             service_s = t_done - t0
             # never attribute a launch that COMPILED (pending trace notes):
@@ -508,18 +519,25 @@ class CompiledChain:  # wf-lint: single-writer[driver, stage]
 
     def push(self, batch: Batch, from_op: int = 0) -> Batch:
         """Run one batch through ops[from_op:]; updates states; returns the out batch."""
+        self._push_count += 1
+        sampled = self._sampled(self._push_count)
+        with _tracing.span("wf.chain.push", pos=_tracing.pos_of(batch), k=1,
+                           sampled=int(sampled)):
+            return self._push(batch, from_op, sampled)
+
+    def _sampled(self, c: int) -> bool:
+        """Is launch number ``c`` timed to completion?  Never #1 — it would
+        time JIT trace + XLA compile, not service. Early launches sample at
+        powers of two (2, 4, 8) so SHORT runs still carry service-time
+        percentiles (the monitoring snapshot's p50/p95/p99 needs samples);
+        steady state samples every SERVICE_SAMPLE_EVERY to keep the async
+        pipeline overlapped."""
+        return ((c % self.SERVICE_SAMPLE_EVERY) == 0
+                or (1 < c < self.SERVICE_SAMPLE_EVERY and (c & (c - 1)) == 0))
+
+    def _push(self, batch: Batch, from_op: int, sampled: bool) -> Batch:
         if self.device is not None:
             batch = jax.device_put(batch, self.device)
-        self._push_count += 1
-        # never sample push #1 — it would time JIT trace + XLA compile, not
-        # service. Early pushes sample at powers of two (2, 4, 8) so SHORT
-        # runs still carry service-time percentiles (the monitoring snapshot's
-        # p50/p95/p99 needs samples); steady state samples every
-        # SERVICE_SAMPLE_EVERY to keep the async pipeline overlapped.
-        c = self._push_count
-        sampled = ((c % self.SERVICE_SAMPLE_EVERY) == 0
-                   or (1 < c < self.SERVICE_SAMPLE_EVERY
-                       and (c & (c - 1)) == 0))
         hl, t0c = self._health_begin("push")
         t0 = time.perf_counter() if sampled else 0.0
         states, out = self._step_fn(from_op)(tuple(self.states), batch)
@@ -529,7 +547,8 @@ class CompiledChain:  # wf-lint: single-writer[driver, stage]
             # device completion wait — riding the block_until_ready this
             # sampled push already pays
             t_disp = time.perf_counter()
-            jax.block_until_ready(out)
+            with _tracing.span("wf.chain.sync"):
+                jax.block_until_ready(out)
             t_done = time.perf_counter()
             service_s = t_done - t0
             # never attribute a launch that COMPILED (pending trace notes):
@@ -595,15 +614,16 @@ class CompiledChain:  # wf-lint: single-writer[driver, stage]
         """EOS: drain every operator in order, cascading flushed batches through the
         remaining suffix. Returns the list of final out-batches produced."""
         outs: List[Batch] = []
-        for i, op in enumerate(self.ops):
-            while True:
-                self.states[i], fb = op.flush(self.states[i])
-                if fb is None:
-                    break
-                if i + 1 < len(self.ops):
-                    outs.append(self.push(fb, from_op=i + 1))
-                else:
-                    outs.append(fb)
+        with _tracing.span("wf.chain.flush"):
+            for i, op in enumerate(self.ops):
+                while True:
+                    self.states[i], fb = op.flush(self.states[i])
+                    if fb is None:
+                        break
+                    if i + 1 < len(self.ops):
+                        outs.append(self.push(fb, from_op=i + 1))
+                    else:
+                        outs.append(fb)
         return outs
 
     def sync_stats(self) -> None:
@@ -862,16 +882,12 @@ class Pipeline:
                 sampled = (mon is not None and self.sink is not None
                            and mon.config.should_sample_e2e(n))
                 t0 = _time.perf_counter() if sampled else 0.0
-                span = _tracing.service(b, "chain")
-                out = self.chain.push(b)
-                if span is not None:
-                    span.done()
-                    _tracing.carry(b, out)
+                with _tracing.span("chain", b):
+                    out = self.chain.push(b)
+                _tracing.carry(b, out)
                 if self.sink is not None:
-                    sspan = _tracing.service(out, "sink")
-                    self.sink.consume(out)
-                    if sspan is not None:
-                        sspan.done()
+                    with _tracing.span("sink", out):
+                        self.sink.consume(out)
                 if sampled:
                     # Sink.consume materialized the batch on the host (or the
                     # sink is in-graph) — this is a true source-framing ->
@@ -903,10 +919,8 @@ class Pipeline:
                 outs = _dispatch.fused_push(self.chain, group, "chain")
                 for b, out in zip(group, outs):
                     if self.sink is not None:
-                        sspan = _tracing.service(out, "sink")
-                        self.sink.consume(out)
-                        if sspan is not None:
-                            sspan.done()
+                        with _tracing.span("sink", out):
+                            self.sink.consume(out)
                     if (mon is not None and self.sink is not None
                             and mon.config.should_sample_e2e(n)):
                         mon.registry.record_e2e(_time.perf_counter() - t0,

@@ -127,7 +127,11 @@ def xprof_trace(logdir: str):
 
     Works on CPU and TPU backends; on TPU the trace includes per-HLO device
     timing, H2D/D2H transfers, and fusion boundaries — the ground truth behind
-    the cost table in docs/ARCHITECTURE.md §5.  Pairs with the host-side
+    the cost table in docs/ARCHITECTURE.md §5 — each device operation under
+    its operator's scope (``Class:name``; the window engine's ``insert`` /
+    ``emit``) — and the program's own host spans (``wf.source.*``,
+    ``wf.drive.*``, ``wf.chain.*``, ``wf.sink.*``: docs/ARCHITECTURE.md,
+    tracing) on the same clock.  Pairs with the host-side
     flight recorder (``trace=`` / ``scripts/wf_trace.py``): load both files
     into Perfetto for device HLO timing beside the per-batch causal timeline.
 
@@ -145,8 +149,13 @@ def xprof_trace(logdir: str):
                 f"trace per process; nest this region inside the existing "
                 f"capture (one file is enough: the trace carries every "
                 f"device event between start and stop) or close it first")
+        # host tracing by TraceMe alone: JAX's default also runs the Python
+        # tracer, whose event per Python call buries the program's own spans
+        # (observability/tracing.py::span) and the device plane under a flood
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
         try:
-            jax.profiler.start_trace(logdir)
+            jax.profiler.start_trace(logdir, profiler_options=opts)
         except RuntimeError as e:
             # a session started OUTSIDE this wrapper (TensorBoard capture
             # button, a direct jax.profiler.start_trace) — same root cause,
