@@ -289,18 +289,32 @@ def test_validate_clean_chain_stays_clean():
     assert not any(c.startswith("WF3") for c in r.codes())
 
 
-def test_validate_supervised_flags_replay_rules():
-    """A float scatter-add chain under a SUPERVISED validation trips WF300
-    (replay context), and stays quiet under plain pipeline validation."""
+def _float_sum_window(win_type):
     src = wf.Source(lambda i: {"v": ((i * 13) % 23).astype(jnp.float32)},
                     total=240, num_keys=3)
     from windflow_tpu.operators.window import WindowSpec
-    from windflow_tpu.basic import win_type_t
     op = wf.Key_FFAT(lambda t: t.v, jnp.add,
-                     spec=WindowSpec(8, 2, win_type_t.CB), num_keys=3)
-    p = wf.Pipeline(src, [op], wf.Sink(lambda v: None), batch_size=48)
+                     spec=WindowSpec(8, 2, win_type), num_keys=3)
+    return wf.Pipeline(src, [op], wf.Sink(lambda v: None), batch_size=48)
+
+
+def test_validate_supervised_flags_replay_rules():
+    """A float scatter-add chain (a time-based float sum: ``segment_sum``
+    per pane) under a SUPERVISED validation trips WF300 (replay context),
+    and stays quiet under plain pipeline validation."""
+    from windflow_tpu.basic import win_type_t
+    p = _float_sum_window(win_type_t.TB)
     assert "WF300" in validate(p, supervised=True).codes()
     assert "WF300" not in validate(p).codes()
+
+
+def test_count_based_float_sum_is_replay_clean():
+    """The count-based insert folds float sums with a segmented scan in
+    sorted order (``ops/segment.py::segment_run_fold``): no float
+    scatter-add is left for a replay to reorder."""
+    from windflow_tpu.basic import win_type_t
+    p = _float_sum_window(win_type_t.CB)
+    assert "WF300" not in validate(p, supervised=True).codes()
 
 
 # ------------------------------------------------------------- the CLI

@@ -299,6 +299,9 @@ def test_lowered_chain_names_every_operator_and_phase(name, monkeypatch):
     if name == "kcb":
         assert f"/{window}/insert/rank/" in hlo
         assert f"/{window}/insert/fold/" in hlo
+        # the count-based insert's own split (ops/segment.py::segment_run_fold)
+        for sub in ("rank/sort", "rank/runs", "rank/scan", "fold/write"):
+            assert f"/{window}/insert/{sub}/" in hlo, sub
     # the compiled program keeps the scopes as op_name metadata and nothing
     # else of it moved
     compiled = lowered.compile()

@@ -210,6 +210,10 @@ STAGE_GAUGES = (
     # key count — the per-operator tier_occupancy pair wf_state.py trends
     # and wf_health.py cross-references against the HBM headroom gauge
     "tier_hot_used", "tier_cold_keys",
+    # operators/win_seqffat.py, count-based windows, set at bind_geometry:
+    # the (key, pane) runs one batch may hold (the size the sorted-order
+    # insert compacts to and writes), the keys and the ring slots per key
+    "ffat_run_budget", "ffat_keys", "ffat_pane_slots",
 )
 
 #: per-operator event-time gauges of the watermark propagation map

@@ -131,6 +131,14 @@ class Win_SeqFFAT(Basic_Operator):
             # very bursty timestamp distributions).
             self.P = _next_pow2(self.wpanes
                                 + max(64, batch_capacity // self.pane_len) + 2)
+        if self.spec.is_cb:
+            # the static sizes the insert is compiled for: (key, pane) runs a
+            # batch may hold, keys, ring slots per key
+            from ..ops.segment import run_budget
+            self._publish_stage_counters({
+                "ffat_run_budget": run_budget(batch_capacity, self.num_keys,
+                                              self.pane_len),
+                "ffat_keys": self.num_keys, "ffat_pane_slots": self.P})
 
     def out_capacity(self, in_capacity: int) -> int:
         if self.global_time:
@@ -324,19 +332,23 @@ class Win_SeqFFAT(Basic_Operator):
 
     def _insert(self, state: FFATState, batch: Batch):
         """Lift each tuple and fold it into its (key, pane) partial: the FlatFAT
-        'update leaf + bubble' (wf/flatfat.hpp:134-240) collapsed into one segment
-        reduction per batch. The additive folds (values, occupancy counts) route
-        through the registry-selectable ``segment_fold`` kernel — see
-        ``_g_insert`` for the selection contract."""
-        from ..ops.segment import segment_rank
+        'update leaf + bubble' (wf/flatfat.hpp:134-240) collapsed into one
+        per-batch update table ``[K*P]`` and an elementwise fold into the ring.
+
+        Count-based windows build the tables in the order one sort makes
+        (:meth:`_cb_updates`: no per-lane scatter). Time-based per-key
+        windows, whose panes come from ``ts`` and are not contiguous inside a
+        key, keep one segment reduction per table; their additive folds
+        (values, occupancy counts) route through the registry-selectable
+        ``segment_fold`` kernel — see ``_g_insert`` for the selection
+        contract. Everything from ``touched`` on is shared."""
         from ..ops.lookup import table_lookup
         K, P = self.num_keys, self.P
         valid = batch.valid
-        if self.spec.is_cb:
-            with jax.named_scope("rank"):
-                rank = segment_rank(batch.key, valid)
-                pos = table_lookup(state.count, batch.key) + rank
-            pane = pos // self.pane_len
+        cb = self.spec.is_cb
+        if cb:
+            upd, cnt_upd, pane_id_upd, counts_add, ts_max = self._cb_updates(
+                state, batch)
             n_dropped = jnp.zeros((), CTRL_DTYPE)    # CB never drops OLD tuples
         else:
             horizon = table_lookup(state.next_win, batch.key) * self.spec.slide
@@ -345,18 +357,19 @@ class Win_SeqFFAT(Basic_Operator):
             valid = kept
             pane = batch.ts // self.pane_len
         with jax.named_scope("fold"):
-            slot = pane % P
-            seg = jnp.where(valid, batch.key * P + slot, K * P)
+            if not cb:
+                slot = pane % P
+                seg = jnp.where(valid, batch.key * P + slot, K * P)
 
-            lifted = jax.vmap(self.lift)(
-                TupleRef(key=batch.key, id=batch.id, ts=batch.ts, data=batch.payload))
-            # per-(key,pane-slot) partial of this batch
-            upd = segment_reduce(lifted, seg, valid, K * P,
-                                 combine=None if self.combine is jnp.add else self.combine,
-                                 identity=self.identity)
-            cnt_upd = segment_reduce(valid.astype(CTRL_DTYPE), seg, valid, K * P)
-            pane_id_upd = segment_reduce(pane, seg, valid, K * P,
-                                         combine=jnp.maximum, identity=-1)
+                lifted = jax.vmap(self.lift)(
+                    TupleRef(key=batch.key, id=batch.id, ts=batch.ts, data=batch.payload))
+                # per-(key,pane-slot) partial of this batch
+                upd = segment_reduce(lifted, seg, valid, K * P,
+                                     combine=None if self.combine is jnp.add else self.combine,
+                                     identity=self.identity)
+                cnt_upd = segment_reduce(valid.astype(CTRL_DTYPE), seg, valid, K * P)
+                pane_id_upd = segment_reduce(pane, seg, valid, K * P,
+                                             combine=jnp.maximum, identity=-1)
 
             touched = cnt_upd.reshape(K, P) > 0
             new_pane_of = jnp.where(touched, pane_id_upd.reshape(K, P), state.pane_of)
@@ -371,9 +384,10 @@ class Win_SeqFFAT(Basic_Operator):
                     return jnp.where(m, t + u, t)
                 return jnp.where(m, self.combine(t, u), t)
 
-            counts_add = segment_reduce(valid.astype(CTRL_DTYPE), batch.key, valid, K)
-            ts_max = segment_reduce(batch.ts, batch.key, valid, K,
-                                    combine=jnp.maximum, identity=-1)
+            if not cb:
+                counts_add = segment_reduce(valid.astype(CTRL_DTYPE), batch.key, valid, K)
+                ts_max = segment_reduce(batch.ts, batch.key, valid, K,
+                                        combine=jnp.maximum, identity=-1)
             wm_new = jnp.maximum(state.wm, ts_max)
         lat = state.lat_hist
         if lat is not None:
@@ -396,6 +410,47 @@ class Win_SeqFFAT(Basic_Operator):
             dropped_old=state.dropped_old + n_dropped,
             lat_hist=lat,
         )
+
+    def _cb_updates(self, state: FFATState, batch: Batch):
+        """The batch's update tables for a count-based window: ``upd``,
+        ``cnt_upd``, ``pane_id_upd`` over ``[K*P]`` and ``counts_add``,
+        ``ts_max`` per key.
+
+        A tuple's pane is ``(count[key] + rank) // pane_len``, so after one
+        stable sort by key every (key, pane) group is one run of neighbouring
+        lanes in stream order. ``ops/segment.py::segment_run_fold`` folds the
+        runs where they lie and hands back at most ``run_budget`` of them;
+        the tables are written run by run (thousands of writes, not a scatter
+        per lane) and the per-key count and watermark come from the runs too.
+        On one v5e at C = 1,048,576 and K = 512 the sort costs 1.7 ms where
+        each of the eight per-lane scatters it replaces cost 4.8-9.2 ms
+        (PERF.md section 6, PR 26). Within a batch two runs of a key never
+        share a ring slot (``bind_geometry``: P > wpanes + C/pane_len + 2),
+        so the writes have unique indices."""
+        from ..ops.segment import segment_run_fold
+        K, P = self.num_keys, self.P
+        lifted = jax.vmap(self.lift)(
+            TupleRef(key=batch.key, id=batch.id, ts=batch.ts, data=batch.payload))
+        with jax.named_scope("rank"):
+            runs = segment_run_fold(
+                [(lifted, self.combine, self.identity),
+                 (batch.ts, jnp.maximum, -1)],
+                batch.key, batch.valid, K, state.count, self.pane_len)
+        with jax.named_scope("fold"), jax.named_scope("write"):
+            run_vals, run_ts = runs.folded
+            seg = jnp.where(runs.live, runs.key * P + runs.chunk % P, K * P)
+
+            def table(fill, rows):
+                init = jnp.broadcast_to(jnp.asarray(fill, rows.dtype),
+                                        (K * P,) + rows.shape[1:])
+                return init.at[seg].set(rows, mode="drop")
+            upd = jax.tree.map(lambda rows: table(self.identity, rows),
+                               run_vals)
+            cnt_upd = table(0, runs.length)
+            pane_id_upd = table(-1, runs.chunk)
+            ts_max = jnp.full((K,), -1, run_ts.dtype).at[
+                jnp.where(runs.live, runs.key, K)].max(run_ts, mode="drop")
+        return upd, cnt_upd, pane_id_upd, runs.key_count, ts_max
 
     # ------------------------------------------------------------------ fire
 
@@ -501,7 +556,8 @@ class Win_SeqFFAT(Basic_Operator):
         import numpy as np
         old = int(np.asarray(state.dropped_old))
         self._stats[0].tuples_dropped_old = old
-        self._publish_stage_counters({"old_drops": old})
+        self._publish_stage_counters({**self.stage_counters(),
+                                      "old_drops": old})
 
     def drop_counters(self, state=None) -> dict:
         if state is None or not hasattr(state, "dropped_old"):

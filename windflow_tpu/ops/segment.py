@@ -9,15 +9,20 @@ sort-by-key the winning strategy at high fan-out
 
 TPU cost discipline (docs/ARCHITECTURE.md §5): permutation gathers cost ~5.6 ns/elem,
 so sorting carries companion arrays through multi-operand ``lax.sort`` (one fused sort,
-no ``take(order)``), per-key bases come from scatter-min first-occurrence + small-table
-lookups, and results return to stream order with a single scatter.
+no ``take(order)``). A scatter over the lanes is no cheaper: at C = 1,048,576 on one
+v5e each costs 4.8-9.2 ms whether it writes 512 segments or 2 M, where the sort of
+the same lanes with three companions costs 1.7 ms (PERF.md section 6, PR 26). So
+what only needs per-group results stays in sorted order (:func:`segment_run_fold`:
+closed-form run boundaries, a ``cumsum`` or a segmented scan, run-sized writes), and
+only what a caller needs lane by lane (:func:`segment_rank`,
+:func:`segment_prefix_scan`) returns to stream order, with a single scatter.
 
 All functions are mask-aware: invalid lanes contribute the combine identity.
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -247,6 +252,121 @@ def _sorted_segment_scan(values, keys, valid, combine, identity):
 
     _, scanned = jax.lax.associative_scan(seg_combine, (starts, sv), axis=0)
     return scanned, seg_keys, seg_valid, orig_idx
+
+
+# ------------------------------------------------- folds in sorted order
+
+def run_budget(capacity: int, num_keys: int, run_len: int) -> int:
+    """Most (key, chunk) runs a batch of ``capacity`` lanes can hold when a
+    key's lanes are numbered onward from any offset and cut every ``run_len``
+    positions: a key with n lanes touches at most ``n // run_len + 2`` chunks
+    (it may start and end mid-chunk), and at most ``min(num_keys, capacity)``
+    keys are present. Never more than one run a lane."""
+    return min(capacity, capacity // run_len + 2 * min(num_keys, capacity))
+
+
+class RunFold(NamedTuple):
+    """What :func:`segment_run_fold` returns: ``R = run_budget(...)`` rows,
+    the live ones first, in (key, chunk) order; and one row per key."""
+    key: jax.Array        # i32[R] key of the run (K - 1 on dead rows)
+    chunk: jax.Array      # i32[R] position // run_len of the run's lanes
+    length: jax.Array     # i32[R] live lanes in the run (0 on dead rows)
+    live: jax.Array       # bool[R]
+    key_count: jax.Array  # i32[K] live lanes per key
+    folded: tuple         # per fold: pytree of [R, ...] (identity on dead rows)
+
+
+def segment_run_fold(folds, keys: jax.Array, valid: jax.Array, num_keys: int,
+                     offset: jax.Array, run_len: int) -> RunFold:
+    """Fold a batch per (key, chunk) run without leaving sorted order.
+
+    A key's live lanes, in stream order, hold positions ``offset[key]``,
+    ``offset[key] + 1``, ...; chunk ``c`` is positions ``[c * run_len,
+    (c + 1) * run_len)`` (a count-based window's pane). ``folds`` is a
+    sequence of ``(values, combine, identity)``: ``values`` a pytree of
+    ``[C, ...]`` leaves, ``combine`` associative (``None`` or ``jnp.add`` for
+    addition), applied leaf by leaf with the earlier lanes on the left.
+
+    One stable multi-operand sort by (dead, key) puts every run's lanes side
+    by side in stream order; nothing returns to stream order. The runs are
+    never searched for among the lanes: the K + 1 key boundaries of the sorted
+    keys and ``offset`` give every run's first and last lane in closed form,
+    K- and R-sized work. An integer sum is then a wrapping ``cumsum``
+    differenced at the run ends (exact: two's complement); every other fold —
+    float sums too, where a prefix difference would cancel — is a segmented
+    scan that combines lane ``i - d`` into lane ``i`` for ``d = 1, 2, 4, ...
+    < run_len`` while both lie in one run, read at the run's last lane.
+
+    Live lanes are ``valid`` with a key in ``[0, num_keys)``; the rest
+    contribute nothing, as in :func:`segment_reduce`."""
+    c, K, L = keys.shape[0], int(num_keys), int(run_len)
+    R = run_budget(c, K, L)
+    ok = valid & (keys >= 0) & (keys < K)
+    with jax.named_scope("sort"):
+        sorted_keys, _, sorted_vals = _sort_by_key(
+            keys, ok, [v for v, _, _ in folds])
+    with jax.named_scope("runs"):
+        # live keys are < K and dead lanes sort last (key = max): key k's
+        # lanes are [edges[k], edges[k + 1])
+        edges = jnp.searchsorted(
+            sorted_keys, jnp.arange(K + 1, dtype=keys.dtype),
+            side="left").astype(jnp.int32)
+        lo, n = edges[:-1], edges[1:] - edges[:-1]
+        first = offset // L
+        n_runs = jnp.where(n > 0, (offset + n - 1) // L - first + 1, 0)
+        csum = jnp.cumsum(n_runs)
+        r = jnp.arange(R, dtype=jnp.int32)
+        live = r < csum[-1]
+        k = jnp.minimum(jnp.searchsorted(csum, r, side="right"),
+                        K - 1).astype(jnp.int32)
+        chunk = jnp.take(first, k) + r - jnp.take(csum - n_runs, k)
+        lo_k, n_k, off_k = jnp.take(lo, k), jnp.take(n, k), jnp.take(offset, k)
+        start = jnp.where(live, lo_k + jnp.maximum(chunk * L - off_k, 0), 0)
+        end = jnp.where(live, lo_k + jnp.minimum((chunk + 1) * L - off_k, n_k),
+                        0)
+    with jax.named_scope("scan"):
+        # lane -> its distance from the first lane of its run: the run starts
+        # are increasing, so a cummax spreads each over its run (R-sized
+        # write, one pass; dead code where every fold is an integer sum)
+        run_start = jax.lax.cummax(jnp.zeros((c,), jnp.int32).at[
+            jnp.where(live, start, c)].set(start, mode="drop"))
+        rank = jnp.arange(c, dtype=jnp.int32) - run_start
+        folded = tuple(
+            _fold_runs(sv, combine, identity, start, end, live, rank, L)
+            for sv, (_, combine, identity) in zip(sorted_vals, folds))
+    return RunFold(key=k, chunk=chunk, length=end - start, live=live,
+                   key_count=n, folded=folded)
+
+
+def _fold_runs(values, combine, identity, start, end, live, rank, run_len):
+    """Per-run fold of ``values`` (sorted order) over the lane ranges
+    ``[start, end)``, none longer than ``run_len``; ``rank`` is each lane's
+    distance from its run's first lane. ``[R, ...]`` leaves."""
+    add = combine is None or combine is jnp.add
+    last = jnp.maximum(end - 1, 0)
+
+    def wrapping_sum(v):
+        cs = jnp.cumsum(v, axis=0, dtype=v.dtype)
+        before = jnp.take(cs, jnp.maximum(start - 1, 0), axis=0)
+        return (jnp.take(cs, last, axis=0)
+                - jnp.where(_bmask(start > 0, before), before, 0))
+
+    def scan(v):
+        op = jnp.add if add else combine
+        d = 1
+        while d < min(run_len, v.shape[0]):
+            pad = jnp.broadcast_to(jnp.asarray(identity, v.dtype),
+                                   (d,) + v.shape[1:])
+            prev = jnp.concatenate([pad, v[:-d]], axis=0)
+            v = jnp.where(_bmask(rank >= d, v), op(prev, v), v)
+            d *= 2
+        return jnp.take(v, last, axis=0)
+
+    def fold(v):
+        exact = add and jnp.issubdtype(v.dtype, jnp.integer)
+        u = wrapping_sum(v) if exact else scan(v)
+        return jnp.where(_bmask(live, u), u, jnp.asarray(identity, u.dtype))
+    return jax.tree.map(fold, values)
 
 
 def segment_prefix_scan(values: Any, keys: jax.Array, valid: jax.Array,
