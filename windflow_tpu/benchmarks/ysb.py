@@ -105,3 +105,16 @@ def make_pipeline(total: int, batch_size: int = 8192,
 def oracle_totals(total: int) -> int:
     """Total view events (the sum of all window counts must equal this)."""
     return len([i for i in range(total) if i % 3 == 0])
+
+
+def window_counts(ad_id, event_type, ts, win_len: int = WIN_LEN) -> np.ndarray:
+    """The plain reference of the window stage, whichever engine implements it
+    (``make_ops`` or ``make_ops_wmr``): views per (campaign, window) straight
+    from the records' three query columns, int64 ``[N_CAMPAIGNS, n_windows]``.
+    A cell of 0 is a window that never fires."""
+    ad_id, ts = np.asarray(ad_id, np.int64), np.asarray(ts, np.int64)
+    view = np.asarray(event_type) == 0
+    n_win = int(ts.max()) // win_len + 1 if ts.size else 0
+    cell = ad_id[view] // ADS_PER_CAMPAIGN * n_win + ts[view] // win_len
+    return np.bincount(cell, minlength=N_CAMPAIGNS * n_win).reshape(
+        N_CAMPAIGNS, n_win)

@@ -1130,6 +1130,12 @@ class MetricsRegistry:
                          "frontier",
             "join_table_version": "applied upsert count of the operator's "
                                   "join table",
+            "archive_overwrites": "window-archive slots overwritten while an "
+                                  "unfired window still needed them",
+            "windows_undelivered_at_eos": "open windows the EOS flush left "
+                                          "undelivered",
+            "archive_slots": "window-archive ring slots per key",
+            "fired_window_budget": "fired windows one batch may emit",
         }
         for c in STAGE_COUNTERS + STAGE_GAUGES:
             rows = [r for r in snap["operators"]

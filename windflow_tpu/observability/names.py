@@ -201,6 +201,11 @@ STAGE_COUNTERS = (
     # rows spilled to the outbox, cold rows re-admitted on probe miss, and
     # host-store rows retired by watermark compaction
     "state_spills", "state_readmits", "state_compactions",
+    # operators/win_seq.py (the archive engine, and the patterns built on it):
+    # ring slots overwritten while an unfired window still needed their tuple,
+    # and open windows the last EOS flush call left behind (0 once the driver
+    # has flushed until None)
+    "archive_overwrites", "windows_undelivered_at_eos",
 )
 
 #: per-stage gauges (same surface, ``windflow_stage_<name>`` gauge form)
@@ -214,6 +219,9 @@ STAGE_GAUGES = (
     # the (key, pane) runs one batch may hold (the size the sorted-order
     # insert compacts to and writes), the keys and the ring slots per key
     "ffat_run_budget", "ffat_keys", "ffat_pane_slots",
+    # operators/win_seq.py, set at bind_geometry: the archive ring's slots per
+    # key and the fired windows one batch may emit
+    "archive_slots", "fired_window_budget",
 )
 
 #: per-operator event-time gauges of the watermark propagation map

@@ -137,7 +137,8 @@ def _drop_total(snap) -> float:
     tot = float((snap.get("totals") or {}).get("tuples_dropped_old", 0))
     for row in snap.get("operators", []):
         for k, v in (row.get("counters") or {}).items():
-            if k in ("overflow_drops", "match_drops", "arch_drops"):
+            if k in ("overflow_drops", "match_drops", "arch_drops",
+                     "archive_overwrites"):
                 tot += v
     ctl = (snap.get("control") or {}).get("counters") or {}
     return tot + float(ctl.get("shed_tuples", 0))

@@ -164,6 +164,12 @@ class Nested_Farm(Basic_Operator):
     def flush(self, state):
         return self.inner.flush(state)
 
+    def collect_stats(self, state=None) -> None:
+        self.inner.collect_stats(state)
+
+    def stage_counters(self) -> dict:
+        return self.inner.stage_counters()
+
     def set_window_sharding(self, mesh, axis: str) -> None:
         if hasattr(self.inner, "set_window_sharding"):
             self.inner.set_window_sharding(mesh, axis)
@@ -207,6 +213,7 @@ class Pane_Farm(Basic_Operator):
             wlq_spec = WindowSpec(spec.win_len, spec.slide, spec.wtype)
         self.wlq = Win_Seq(wlq_fn, wlq_spec, num_keys=num_keys, role=role_t.WLQ,
                            name=f"{name}_wlq")
+        self.plq.scope_op = self.wlq.scope_op = self
         self._wlq_id_fix = spec.is_cb
 
     def bind_geometry(self, batch_capacity: int) -> None:
@@ -276,6 +283,7 @@ class Win_MapReduce(Basic_Operator):
         # does partition-map + reduce inside the per-window vmap
         self.engine = Win_Seq(self._window_fn, spec, num_keys=num_keys,
                               name=f"{name}_engine", role=role_t.MAP, **kw)
+        self.engine.scope_op = self
 
     def _window_fn(self, wid, it: Iterable):
         M = self.M
@@ -287,19 +295,21 @@ class Win_MapReduce(Basic_Operator):
             # round-robin: partition p gets positions p, p+M, p+2M, ...
             # (WinMap_Emitter scatter): reshape [PM] -> [P, M] -> transpose [M, P]
             return jnp.swapaxes(a.reshape((P, M) + a.shape[1:]), 0, 1)
-        sub = Iterable(data=jax.tree.map(part, it.data), ids=part(it.ids),
-                       ts=part(it.ts), mask=part(it.mask))
-        partials = jax.vmap(lambda s: self.map_fn(wid, s))(sub)
+        with jax.named_scope("map"):
+            sub = Iterable(data=jax.tree.map(part, it.data), ids=part(it.ids),
+                           ts=part(it.ts), mask=part(it.mask))
+            partials = jax.vmap(lambda s: self.map_fn(wid, s))(sub)
         # REDUCE over the M partials (CB window of length M in the reference,
         # wf/win_mapreduce.hpp:180-230). A partition that received no tuples
         # contributes no partial — mask it out so identity values (e.g. 0 from an
         # empty sum) can't poison non-sum reduces like min.
-        red_it = Iterable(
-            data=partials,
-            ids=jnp.arange(M, dtype=CTRL_DTYPE),
-            ts=jnp.broadcast_to(jnp.asarray(0, CTRL_DTYPE), (M,)),
-            mask=jnp.any(part(it.mask), axis=1))
-        return self.reduce_fn(wid, red_it)
+        with jax.named_scope("reduce"):
+            red_it = Iterable(
+                data=partials,
+                ids=jnp.arange(M, dtype=CTRL_DTYPE),
+                ts=jnp.broadcast_to(jnp.asarray(0, CTRL_DTYPE), (M,)),
+                mask=jnp.any(part(it.mask), axis=1))
+            return self.reduce_fn(wid, red_it)
 
     def bind_geometry(self, batch_capacity: int) -> None:
         self.engine.bind_geometry(batch_capacity)
@@ -321,3 +331,15 @@ class Win_MapReduce(Basic_Operator):
 
     def flush(self, state):
         return self.engine.flush(state)
+
+    # the engine's device counters and budgets are this operator's
+    def collect_stats(self, state=None) -> None:
+        self.engine.collect_stats(state)
+        self._stats[0].tuples_dropped_old = \
+            self.engine.get_StatsRecords()[0].tuples_dropped_old
+
+    def stage_counters(self) -> dict:
+        return self.engine.stage_counters()
+
+    def drop_counters(self, state=None) -> dict:
+        return self.engine.drop_counters(state)

@@ -20,6 +20,16 @@ progressive ids, ``wf/basic.hpp:129``); TB windows index timestamps with per-key
 watermarks and ``delay`` lateness. Windows whose turn exceeds the per-batch ``max_wins``
 budget defer to the next batch (``next_win`` only advances past emitted windows).
 
+Budgets. A time-based ring holds, per key, the tuples of the open windows plus one
+batch's: how many that is depends on the stream's rate and key spread, which the
+engine cannot see, so a deployment passes ``tb_capacity`` (slots per key) and
+``max_wins`` (fired windows a batch); the default ``2 * batch`` slots per key is the
+worst case of a whole batch on one key. A ring too small overwrites tuples that an
+unfired window still needs: the state counts them (``overwrites``), and the OLD drops
+(``dropped_old``), and ``collect_stats`` publishes both with the two budgets
+(``archive_overwrites``, ``old_drops``, ``archive_slots``, ``fired_window_budget``);
+``flush`` adds ``windows_undelivered_at_eos``.
+
 Emission order is per-key ascending window id — the ordered-collector guarantee of
 ``WF_Collector`` (``wf/wf_nodes.hpp:253-318``) by construction.
 """
@@ -50,6 +60,8 @@ class WinSeqState:
     count: jax.Array      # i32[K] tuples archived per key
     wm: jax.Array         # i32[K] per-key max ts seen
     next_win: jax.Array   # i32[K] next window id to fire
+    overwrites: jax.Array   # i32[] live slots (an unfired window's) overwritten
+    dropped_old: jax.Array  # i32[] TB tuples dropped as OLD (behind the horizon)
 
 
 class Win_Seq(Basic_Operator):
@@ -103,6 +115,9 @@ class Win_Seq(Basic_Operator):
         self.max_wins = max_wins       # resolved at first apply if None
         self._w = None
         self._wshard = None            # (mesh, axis): shard the fired-window W axis
+        #: the operator whose ``Class:name`` scope the chain opens around this
+        #: engine's ``apply``: itself, or the pattern that owns it
+        self.scope_op = self
         self.bind_geometry(256)        # provisional; compiler re-binds with real C
 
     def bind_geometry(self, batch_capacity: int) -> None:
@@ -115,6 +130,8 @@ class Win_Seq(Basic_Operator):
             self.A = _next_pow2(L + batch_capacity)
         else:
             self.A = _next_pow2(self._tb_capacity or 2 * batch_capacity)
+        self._publish_stage_counters({**self.stage_counters(),
+                                      **self._budget_gauges()})
 
     # ------------------------------------------------------------------ state
 
@@ -130,6 +147,8 @@ class Win_Seq(Basic_Operator):
             count=jnp.zeros((K,), CTRL_DTYPE),
             wm=jnp.full((K,), -1, CTRL_DTYPE),
             next_win=jnp.zeros((K,), CTRL_DTYPE),
+            overwrites=jnp.zeros((), CTRL_DTYPE),
+            dropped_old=jnp.zeros((), CTRL_DTYPE),
         )
 
     def out_spec(self, payload_spec: Any) -> Any:
@@ -156,31 +175,52 @@ class Win_Seq(Basic_Operator):
         from ..ops.lookup import table_lookup
         K, A = self.num_keys, self.A
         valid = batch.valid
-        if not self.spec.is_cb:
-            # drop OLD tuples: they precede the purge horizon (already-fired windows)
-            horizon = table_lookup(state.next_win, batch.key) * self.spec.slide
-            valid = valid & (batch.ts >= horizon)
-        rank = segment_rank(batch.key, valid)
-        pos = table_lookup(state.count, batch.key) + rank
-        slot = pos % A
-        flat = jnp.where(valid, batch.key * A + slot, K * A)  # OOB -> dropped
+        dropped_old = state.dropped_old
+        with jax.named_scope("rank"):
+            if not self.spec.is_cb:
+                # drop OLD tuples: they precede the purge horizon (already-fired
+                # windows)
+                horizon = table_lookup(state.next_win, batch.key) * self.spec.slide
+                fresh = valid & (batch.ts >= horizon)
+                dropped_old = dropped_old + jnp.sum(valid & ~fresh, dtype=CTRL_DTYPE)
+                valid = fresh
+            rank = segment_rank(batch.key, valid)
+            pos = table_lookup(state.count, batch.key) + rank
+            slot = pos % A
+            flat = jnp.where(valid, batch.key * A + slot, K * A)  # OOB -> dropped
 
         def scat(tbl, v):
             return tbl.reshape((K * A,) + tbl.shape[2:]).at[flat].set(
                 v, mode="drop").reshape(tbl.shape)
 
-        counts_add = segment_reduce(valid.astype(CTRL_DTYPE), batch.key, valid, K)
-        ts_max = segment_reduce(batch.ts, batch.key, valid, K,
-                                combine=jnp.maximum, identity=-1)
-        return dataclasses.replace(
-            state,
-            arch_payload=jax.tree.map(scat, state.arch_payload, batch.payload),
-            arch_id=scat(state.arch_id, batch.id),
-            arch_ts=scat(state.arch_ts, batch.ts),
-            arch_pos=scat(state.arch_pos, pos),
-            count=state.count + counts_add,
-            wm=jnp.maximum(state.wm, ts_max),
-        )
+        with jax.named_scope("count"):
+            counts_add = segment_reduce(valid.astype(CTRL_DTYPE), batch.key, valid, K)
+            ts_max = segment_reduce(batch.ts, batch.key, valid, K,
+                                    combine=jnp.maximum, identity=-1)
+            count = state.count + counts_add
+            # this batch writes over every slot that holds a position below
+            # count - A. One that an unfired window still needs (at or past the
+            # purge horizon, by position for CB and by ts for TB) is a lost tuple,
+            # and so is a tuple of this batch that a later one of it overwrites:
+            # K*A- and K-sized work, no per-lane read of the ring.
+            stamp = state.arch_pos if self.spec.is_cb else state.arch_ts
+            lost = ((state.arch_pos >= 0)
+                    & (stamp >= (state.next_win * self.spec.slide)[:, None])
+                    & (state.arch_pos < (count - A)[:, None]))
+            overwrites = (state.overwrites + jnp.sum(lost, dtype=CTRL_DTYPE)
+                          + jnp.sum(jnp.maximum(counts_add - A, 0)))
+        with jax.named_scope("write"):
+            return dataclasses.replace(
+                state,
+                arch_payload=jax.tree.map(scat, state.arch_payload, batch.payload),
+                arch_id=scat(state.arch_id, batch.id),
+                arch_ts=scat(state.arch_ts, batch.ts),
+                arch_pos=scat(state.arch_pos, pos),
+                count=count,
+                wm=jnp.maximum(state.wm, ts_max),
+                overwrites=overwrites,
+                dropped_old=dropped_old,
+            )
 
     # ------------------------------------------------------------------ fire
 
@@ -231,54 +271,56 @@ class Win_Seq(Basic_Operator):
         """Emit up to W fired windows (per-key ascending wid). Returns (state, Batch)."""
         K, A = self.num_keys, self.A
         s = self.spec
-        lo, hi = self._fired_range(state, flush)
-        n_f = hi - lo
-        csum = jnp.cumsum(n_f)
-        off = csum - n_f
-        total = csum[-1] if K > 0 else jnp.asarray(0, CTRL_DTYPE)
-        w_idx = self._wsc(jnp.arange(W, dtype=CTRL_DTYPE))
-        k_of = jnp.searchsorted(csum, w_idx, side="right").astype(CTRL_DTYPE)
-        k_safe = self._wsc(jnp.minimum(k_of, K - 1))
-        wid = self._wsc(jnp.take(lo, k_safe) + (w_idx - jnp.take(off, k_safe)))
-        valid_w = self._wsc(w_idx < jnp.minimum(total, W))
+        with jax.named_scope("range"):
+            lo, hi = self._fired_range(state, flush)
+            n_f = hi - lo
+            csum = jnp.cumsum(n_f)
+            off = csum - n_f
+            total = csum[-1] if K > 0 else jnp.asarray(0, CTRL_DTYPE)
+            w_idx = self._wsc(jnp.arange(W, dtype=CTRL_DTYPE))
+            k_of = jnp.searchsorted(csum, w_idx, side="right").astype(CTRL_DTYPE)
+            k_safe = self._wsc(jnp.minimum(k_of, K - 1))
+            wid = self._wsc(jnp.take(lo, k_safe) + (w_idx - jnp.take(off, k_safe)))
+            valid_w = self._wsc(w_idx < jnp.minimum(total, W))
 
-        # advance next_win past emitted windows
-        emitted_k = jnp.clip(jnp.minimum(total, W) - off, 0, n_f)
-        new_next = lo + emitted_k
+            # advance next_win past emitted windows
+            emitted_k = jnp.clip(jnp.minimum(total, W) - off, 0, n_f)
+            new_next = lo + emitted_k
 
-        if s.is_cb:
-            L = s.win_len
-            p = wid[:, None] * s.slide + jnp.arange(L, dtype=CTRL_DTYPE)[None, :]
-            slot = p % A
-            gflat = k_safe[:, None] * A + slot                         # [W, L]
-            def gat(tbl):
-                return jnp.take(tbl.reshape((K * A,) + tbl.shape[2:]), gflat, axis=0)
-            content_mask = (p < jnp.take(state.count, k_safe)[:, None]) & valid_w[:, None]
-            # stale-slot guard: the slot must actually hold position p
-            content_mask &= gat(state.arch_pos) == p
-            data = jax.tree.map(gat, state.arch_payload)
-            ids, tss = gat(state.arch_id), gat(state.arch_ts)
-            res_ts = jnp.max(jnp.where(content_mask, tss, -1), axis=1)
-        else:
-            # TB: full-ring rows masked by ts-in-range
-            def gat(tbl):
-                return jnp.take(tbl, k_safe, axis=0)                   # [W, A, ...]
-            tss = gat(state.arch_ts)
-            poss = gat(state.arch_pos)
-            w_start = (wid * s.slide)[:, None]
-            content_mask = ((poss >= 0) & (tss >= w_start)
-                            & (tss < w_start + s.win_len) & valid_w[:, None])
-            # ring-overwrite guard: slot must hold a live (not yet overwritten) pos
-            cnt = jnp.take(state.count, k_safe)[:, None]
-            content_mask &= poss >= jnp.maximum(0, cnt - A)
-            data = jax.tree.map(gat, state.arch_payload)
-            ids = gat(state.arch_id)
-            res_ts = wid * s.slide + (s.win_len - 1)
+        with jax.named_scope("gather"):
+            if s.is_cb:
+                L = s.win_len
+                p = wid[:, None] * s.slide + jnp.arange(L, dtype=CTRL_DTYPE)[None, :]
+                slot = p % A
+                gflat = k_safe[:, None] * A + slot                         # [W, L]
+                def gat(tbl):
+                    return jnp.take(tbl.reshape((K * A,) + tbl.shape[2:]), gflat, axis=0)
+                content_mask = (p < jnp.take(state.count, k_safe)[:, None]) & valid_w[:, None]
+                # stale-slot guard: the slot must actually hold position p
+                content_mask &= gat(state.arch_pos) == p
+                data = jax.tree.map(gat, state.arch_payload)
+                ids, tss = gat(state.arch_id), gat(state.arch_ts)
+                res_ts = jnp.max(jnp.where(content_mask, tss, -1), axis=1)
+            else:
+                # TB: full-ring rows masked by ts-in-range
+                def gat(tbl):
+                    return jnp.take(tbl, k_safe, axis=0)                   # [W, A, ...]
+                tss = gat(state.arch_ts)
+                poss = gat(state.arch_pos)
+                w_start = (wid * s.slide)[:, None]
+                content_mask = ((poss >= 0) & (tss >= w_start)
+                                & (tss < w_start + s.win_len) & valid_w[:, None])
+                # ring-overwrite guard: slot must hold a live (not yet overwritten) pos
+                cnt = jnp.take(state.count, k_safe)[:, None]
+                content_mask &= poss >= jnp.maximum(0, cnt - A)
+                data = jax.tree.map(gat, state.arch_payload)
+                ids = gat(state.arch_id)
+                res_ts = wid * s.slide + (s.win_len - 1)
 
-        if not s.is_cb:
-            # TB: a window with no content never fires in the reference (Triggerer_TB
-            # only triggers on tuples); filter empty windows from the emission
-            valid_w = valid_w & jnp.any(content_mask, axis=1)
+            if not s.is_cb:
+                # TB: a window with no content never fires in the reference (Triggerer_TB
+                # only triggers on tuples); filter empty windows from the emission
+                valid_w = valid_w & jnp.any(content_mask, axis=1)
 
         it = Iterable(data=jax.tree.map(self._wsc, data), ids=self._wsc(ids),
                       ts=self._wsc(tss), mask=self._wsc(content_mask))
@@ -299,19 +341,66 @@ class Win_Seq(Basic_Operator):
         return self._resolve_w(in_capacity)
 
     def apply(self, state: WinSeqState, batch: Batch):
+        """One scope per phase, as ``Win_SeqFFAT.apply`` has them: ``insert``
+        (``rank``, ``count``, ``write``) and ``emit`` (``range``, ``gather``, then
+        the window function), directly under the scope the chain opened for the
+        operator (a pattern built on this engine opens none for it)."""
         W = self._resolve_w(batch.capacity)
         self._w = W
-        state = self._insert(state, batch)
-        return self._emit(state, W, flush=False)
+        with jax.named_scope("insert"):
+            state = self._insert(state, batch)
+        with jax.named_scope("emit"):
+            return self._emit(state, W, flush=False)
 
     def flush(self, state: WinSeqState):
+        """One batch of up to W open windows, None once none is left: the drivers
+        call until None (``CompiledChain.flush``). Windows a TB key skipped are
+        empty and never delivered, so a batch of them alone is passed over, not
+        taken for the end."""
         W = self._w or self._resolve_w(256)
         if not hasattr(self, "_flush_jit"):
-            self._flush_jit = jax.jit(lambda st: self._emit(st, W, flush=True))
-        state, out = self._flush_jit(state)
-        if not bool(jnp.any(out.valid)):
-            return state, None
-        return state, out
+            def flush_emit(st):
+                with jax.named_scope(self.scope_op.scope_name()), \
+                        jax.named_scope("emit"):
+                    st, out = self._emit(st, W, flush=True)
+                    lo, hi = self._fired_range(st, True)
+                    return st, out, jnp.any(out.valid), jnp.sum(hi - lo)
+            self._flush_jit = jax.jit(flush_emit)
+        while True:
+            state, out, any_valid, left = self._flush_jit(state)
+            any_valid, left = bool(any_valid), int(left)
+            if any_valid or left == 0:
+                break
+        self.collect_stats(state)
+        self._publish_stage_counters({**self.stage_counters(),
+                                      "windows_undelivered_at_eos": left})
+        return state, (out if any_valid else None)
+
+    def _budget_gauges(self) -> dict:
+        """The two static budgets: ring slots per key, fired windows a batch (once
+        ``max_wins`` or the first ``apply`` has settled it)."""
+        W = self.max_wins if self.max_wins is not None else self._w
+        return {"archive_slots": self.A,
+                **({} if W is None else {"fired_window_budget": W})}
+
+    def collect_stats(self, state=None) -> None:
+        """Sync the device-resident counters into the stage counters (monitoring
+        snapshot / EOS: two scalar D2H reads, off the hot path)."""
+        if state is None or not hasattr(state, "overwrites"):
+            return
+        import numpy as np
+        old = int(np.asarray(state.dropped_old))
+        self._stats[0].tuples_dropped_old = old
+        self._publish_stage_counters({
+            **self.stage_counters(), **self._budget_gauges(),
+            "archive_overwrites": int(np.asarray(state.overwrites)),
+            "old_drops": old})
+
+    def drop_counters(self, state=None) -> dict:
+        if state is None or not hasattr(state, "dropped_old"):
+            return {}
+        import numpy as np
+        return {"old_drops": int(np.asarray(state.dropped_old))}
 
 
 def _fold_windows(fn, wids, it: Iterable, init_acc):
