@@ -285,7 +285,18 @@ def cost(compiled):
 NO_EQUATIONS = {"BatchMap:ysb_project"}
 
 
-@pytest.mark.parametrize("name", ["ysb", "kcb"])
+#: what each window engine's insert says its work is
+INSERT_SCOPES = {
+    "ysb": (),
+    # ops/segment.py::segment_run_fold and the run-sized table writes
+    "kcb": ("rank/sort", "rank/runs", "rank/scan", "fold/write"),
+    # the archive engine (operators/win_seq.py::_insert): one sort, the rows
+    # it cuts, the per-key counts and the row moves
+    "ysb_wmr": ("rank/sort", "rank/runs", "count", "write"),
+}
+
+
+@pytest.mark.parametrize("name", ["ysb", "kcb", "ysb_wmr"])
 def test_lowered_chain_names_every_operator_and_phase(name, monkeypatch):
     ops, lowered = lowered_chain(name, True, monkeypatch)
     hlo = lowered.as_text(debug_info=True)
@@ -293,21 +304,18 @@ def test_lowered_chain_names_every_operator_and_phase(name, monkeypatch):
         if op.scope_name() not in NO_EQUATIONS:
             assert f"/{op.scope_name()}/" in hlo, op.scope_name()
     window = ops[-1].scope_name()
-    assert window.startswith("Key_FFAT:")
+    engine = "Win_MapReduce:" if name == "ysb_wmr" else "Key_FFAT:"
+    assert window.startswith(engine)
     for phase in ("insert", "emit"):
         assert f"/{window}/{phase}/" in hlo, phase
-    if name == "kcb":
-        assert f"/{window}/insert/rank/" in hlo
-        assert f"/{window}/insert/fold/" in hlo
-        # the count-based insert's own split (ops/segment.py::segment_run_fold)
-        for sub in ("rank/sort", "rank/runs", "rank/scan", "fold/write"):
-            assert f"/{window}/insert/{sub}/" in hlo, sub
+    for sub in INSERT_SCOPES[name]:
+        assert f"/{window}/insert/{sub}/" in hlo, sub
     # the compiled program keeps the scopes as op_name metadata and nothing
     # else of it moved
     compiled = lowered.compile()
     assert f'op_name="jit(step)/{window}/insert/' in compiled.as_text()
     _, unscoped = lowered_chain(name, False, monkeypatch)
-    assert "Key_FFAT:" not in unscoped.as_text(debug_info=True)
+    assert engine not in unscoped.as_text(debug_info=True)
     assert cost(compiled) == cost(unscoped.compile())
 
 

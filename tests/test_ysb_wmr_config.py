@@ -11,6 +11,7 @@ import os
 import subprocess
 import sys
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -141,8 +142,12 @@ def test_budgets_come_from_the_deployment():
     assert 6829 < slots <= 8192 and max_wins == 200
     window = mod.build_ops(published, 1 << 20)[-1]
     assert isinstance(window, Win_MapReduce) and window.M == 4
-    assert window.stage_counters() == {"archive_slots": 8192,
-                                       "fired_window_budget": 200}
+    # the insert moves the rings as 456 rows of 4,096 slots a table a batch
+    # (100 head rows, 256 + 100 body rows) in place of 1,048,576 lanes
+    window.bind_geometry(1 << 20)               # as the compiled chain does
+    assert window.stage_counters() == {
+        "archive_slots": 8192, "fired_window_budget": 200,
+        "archive_run_len": 4096, "archive_run_rows": 456}
     assert window.engine.A * window.num_keys * 4 * 4 < 14e6   # four tables
 
 
@@ -196,9 +201,12 @@ def test_eos_flush_delivers_more_open_windows_than_the_budget(pattern):
                            spec, map_parallelism=2, **kw)
     got = run_engine(op, keys, ts, batch=32)
     assert got == [(k, w, 2) for k in range(K) for w in (0, 9)]
+    # two batches of 32 lanes, two a key: a head row of 2 slots a key a batch
     assert op.stage_counters() == {
         "archive_slots": 8, "fired_window_budget": 4, "archive_overwrites": 0,
-        "old_drops": 0, "windows_undelivered_at_eos": 0}
+        "old_drops": 0, "windows_undelivered_at_eos": 0,
+        "archive_run_len": 2, "archive_run_rows": 48,
+        "archive_runs_written": 32}
 
 
 def test_old_drops_and_overwrites_are_counted_on_the_device():
@@ -221,19 +229,25 @@ def test_old_drops_and_overwrites_are_counted_on_the_device():
     assert small.stage_counters()["archive_overwrites"] == lost
 
 
-def test_lowered_step_carries_the_engine_phases():
-    mod, cfg = load_config("ysb_wmr")
-    ops = mod.build_ops(cfg, BATCH)
+def chain_step(cfg, mod, batch_capacity):
+    ops = mod.build_ops(cfg, batch_capacity)
     src = wf.RecordSource(lambda: iter(()), mod.RECORD,
                           key_field=mod.KEY_FIELD, ts_field=mod.TS_FIELD)
-    chain = CompiledChain(ops, src.payload_spec(), batch_capacity=BATCH)
-    batch = Batch.empty(BATCH, chain.specs[0])
-    hlo = chain._step_fn(0).lower(tuple(chain.states), batch).as_text(
-        debug_info=True)
+    chain = CompiledChain(ops, src.payload_spec(),
+                          batch_capacity=batch_capacity)
+    batch = Batch.empty(batch_capacity, chain.specs[0])
+    return ops, chain._step_fn(0), (tuple(chain.states), batch)
+
+
+def test_lowered_step_carries_the_engine_phases():
+    mod, cfg = load_config("ysb_wmr")
+    ops, step, args = chain_step(cfg, mod, BATCH)
+    hlo = step.lower(*args).as_text(debug_info=True)
     window = ops[-1].scope_name()
     assert window == "Win_MapReduce:ysb_window_wmr"
     # (a scope opened under ``vmap`` is recorded as ``vmap(<scope>)``)
-    for sub in ("insert/rank", "insert/count", "insert/write", "emit/range",
+    for sub in ("insert/rank", "insert/rank/sort", "insert/rank/runs",
+                "insert/count", "insert/write", "emit/range",
                 "emit/gather", "emit/vmap(map)", "emit/vmap(reduce)"):
         assert f"/{window}/{sub}/" in hlo, sub
     # the inner engine opens no operator scope of its own: the readers take
@@ -243,6 +257,50 @@ def test_lowered_step_carries_the_engine_phases():
     assert span_reduce.scope_of(path + ":scatter") == (path, window, "insert")
     path = f"jit(step)/{window}/emit/vmap(map)/vmap()/reduce_sum"
     assert span_reduce.scope_of(path + ":reduce")[1:] == (window, "emit")
+
+
+def equations(jaxpr, scope=""):
+    """(equation, its whole scope path) through every nested jaxpr."""
+    for eqn in jaxpr.eqns:
+        own = str(eqn.source_info.name_stack)
+        path = "/".join(p for p in (scope, own) if p)
+        yield eqn, path
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from equations(sub, path)
+
+
+def test_the_insert_at_the_published_size_moves_rows_not_lanes():
+    """C = 1,048,576, K = 100, A = 8,192 as the cell builds them: under
+    ``insert`` no scatter and no gather has an index for every lane (the
+    largest has one for each of the 356 body rows), the sort is the only
+    operation of its kind, and the phase still sits right under the pattern's
+    scope."""
+    mod, _ = load_config("ysb_wmr")
+    with open(os.path.join(BENCH, "configs", "ysb_wmr.json")) as f:
+        published = json.load(f)
+    C = 1 << 20
+    ops, step, args = chain_step(published, mod, C)
+    window = ops[-1].scope_name()
+    assert (ops[-1].num_keys, ops[-1].engine.A) == (100, 8192)
+    insert = [(eqn, path) for eqn, path in
+              equations(jax.make_jaxpr(step)(*args).jaxpr)
+              if f"{window}/insert" in path]
+    assert all(path.startswith(f"{window}/insert") for _, path in insert)
+    moves = [(eqn.primitive.name, int(np.prod(eqn.invars[1].aval.shape)), path)
+             for eqn, path in insert
+             if eqn.primitive.name.startswith(("scatter", "gather"))]
+    assert len(moves) > 20
+    assert max(n for _, n, _ in moves) == 356, sorted(moves)[-3:]
+    assert {name for name, _, path in moves if "/write" in path} == {
+        "gather", "scatter"}
+    sorts = [path for eqn, path in insert if eqn.primitive.name == "sort"]
+    assert sorts == [f"{window}/insert/rank/sort"]
+    assert not [eqn.primitive.name for eqn, _ in insert
+                if eqn.primitive.name.startswith("cum")
+                and eqn.invars[0].aval.shape[0] >= C]
 
 
 @pytest.mark.parametrize("name,phase", [("archive_insert_device_ms", "insert"),

@@ -1136,6 +1136,12 @@ class MetricsRegistry:
                                           "undelivered",
             "archive_slots": "window-archive ring slots per key",
             "fired_window_budget": "fired windows one batch may emit",
+            "archive_run_len": "slots of one window-archive ring row as the "
+                               "insert moves it",
+            "archive_run_rows": "window-archive ring rows one batch may "
+                                "write per table",
+            "archive_runs_written": "window-archive ring rows written per "
+                                    "table",
         }
         for c in STAGE_COUNTERS + STAGE_GAUGES:
             rows = [r for r in snap["operators"]

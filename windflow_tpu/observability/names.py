@@ -204,8 +204,9 @@ STAGE_COUNTERS = (
     # operators/win_seq.py (the archive engine, and the patterns built on it):
     # ring slots overwritten while an unfired window still needed their tuple,
     # and open windows the last EOS flush call left behind (0 once the driver
-    # has flushed until None)
-    "archive_overwrites", "windows_undelivered_at_eos",
+    # has flushed until None); ring rows the sorted-order inserts wrote, per
+    # table (beside tuples_in: how many row writes replaced how many lanes)
+    "archive_overwrites", "windows_undelivered_at_eos", "archive_runs_written",
 )
 
 #: per-stage gauges (same surface, ``windflow_stage_<name>`` gauge form)
@@ -220,8 +221,9 @@ STAGE_GAUGES = (
     # insert compacts to and writes), the keys and the ring slots per key
     "ffat_run_budget", "ffat_keys", "ffat_pane_slots",
     # operators/win_seq.py, set at bind_geometry: the archive ring's slots per
-    # key and the fired windows one batch may emit
-    "archive_slots", "fired_window_budget",
+    # key and the fired windows one batch may emit; the slots of one ring row
+    # as the insert moves them, and the rows one batch may write per table
+    "archive_slots", "fired_window_budget", "archive_run_len", "archive_run_rows",
 )
 
 #: per-operator event-time gauges of the watermark propagation map

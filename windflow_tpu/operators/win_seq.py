@@ -2,8 +2,10 @@
 
 Counterpart of ``wf/win_seq.hpp:56-567`` (svc ``:304-465``, EOS flush ``:468-529``)
 with ``StreamArchive`` (``wf/stream_archive.hpp``) fused in: per-key archives live as
-HBM ring buffers ``[K, A]``; each micro-batch (1) scatters its tuples into the rings,
-(2) advances per-key counts/watermarks, (3) computes the FIRED window range per key
+HBM ring buffers ``[K, A]``; each micro-batch (1) sorts its tuples by key once and
+moves them into the rings as whole rows of ``run_len`` slots (``_insert``: no scatter
+or gather with an index per lane), (2) advances per-key counts/watermarks from the
+sorted order's key boundaries, (3) computes the FIRED window range per key
 with batch-level triggerer arithmetic (``window.py``), (4) gathers up to ``max_wins``
 fired windows as rows ``[W, L]`` and (5) applies the user window function across the
 window axis with ``vmap`` — the direct TPU generalization of the reference GPU engine's
@@ -28,7 +30,10 @@ worst case of a whole batch on one key. A ring too small overwrites tuples that 
 unfired window still needs: the state counts them (``overwrites``), and the OLD drops
 (``dropped_old``), and ``collect_stats`` publishes both with the two budgets
 (``archive_overwrites``, ``old_drops``, ``archive_slots``, ``fired_window_budget``);
-``flush`` adds ``windows_undelivered_at_eos``.
+``flush`` adds ``windows_undelivered_at_eos``. The insert's row geometry follows the
+shapes (``_row_geometry``: batch capacity, keys, ring slots) and is published with
+them: ``archive_run_len``, ``archive_run_rows``, and ``archive_runs_written`` counts
+the rows the inserts wrote per table (beside the tuples they archived).
 
 Emission order is per-key ascending window id — the ordered-collector guarantee of
 ``WF_Collector`` (``wf/wf_nodes.hpp:253-318``) by construction.
@@ -45,7 +50,8 @@ import jax.numpy as jnp
 from ..basic import routing_modes_t, role_t, DEFAULT_MAX_KEYS
 from ..batch import Batch, CTRL_DTYPE, TupleRef
 from ..meta import classify_window, classify_winupdate
-from ..ops.segment import segment_rank, segment_reduce
+from ..ops.segment import (enumerate_runs, range_max, run_budget, sort_segments,
+                           take_windows)
 from .base import Basic_Operator
 from .window import Iterable, WindowSpec
 
@@ -62,6 +68,7 @@ class WinSeqState:
     next_win: jax.Array   # i32[K] next window id to fire
     overwrites: jax.Array   # i32[] live slots (an unfired window's) overwritten
     dropped_old: jax.Array  # i32[] TB tuples dropped as OLD (behind the horizon)
+    runs_written: jax.Array  # i32[] ring rows the inserts wrote, per table
 
 
 class Win_Seq(Basic_Operator):
@@ -130,8 +137,22 @@ class Win_Seq(Basic_Operator):
             self.A = _next_pow2(L + batch_capacity)
         else:
             self.A = _next_pow2(self._tb_capacity or 2 * batch_capacity)
+        self.run_len, self.run_rows = self._row_geometry(batch_capacity)
         self._publish_stage_counters({**self.stage_counters(),
                                       **self._budget_gauges()})
+
+    def _row_geometry(self, capacity: int):
+        """How ``_insert`` cuts a batch of ``capacity`` lanes: ``(T, rows)``.
+        The rings move as rows of ``T`` slots, the largest power of two, up to
+        ``A``, at which the rows one batch may touch (``run_budget``) hold no
+        more than twice its lanes; ``rows`` bounds the runs after a key's
+        first (each key has at most ``n // T + 1`` of them, and fewer than
+        ``n``)."""
+        K, T = self.num_keys, 1
+        while (2 * T <= self.A
+               and run_budget(capacity, K, 2 * T) * 2 * T <= 2 * capacity):
+            T *= 2
+        return T, min(capacity, capacity // T + min(K, capacity))
 
     # ------------------------------------------------------------------ state
 
@@ -149,6 +170,7 @@ class Win_Seq(Basic_Operator):
             next_win=jnp.zeros((K,), CTRL_DTYPE),
             overwrites=jnp.zeros((), CTRL_DTYPE),
             dropped_old=jnp.zeros((), CTRL_DTYPE),
+            runs_written=jnp.zeros((), CTRL_DTYPE),
         )
 
     def out_spec(self, payload_spec: Any) -> Any:
@@ -172,8 +194,29 @@ class Win_Seq(Basic_Operator):
     # ------------------------------------------------------------------ insert
 
     def _insert(self, state: WinSeqState, batch: Batch) -> WinSeqState:
+        """Archive a batch in the order of one stable sort by key.
+
+        A key's live lanes of one batch take consecutive arrival positions,
+        ``count[key]`` onward, so in sorted order they are one contiguous lane
+        range that fills consecutive ring slots. Cut the positions every
+        ``T = run_len`` slots: each (key, chunk) run is then one aligned row of
+        the ring viewed as ``[K * A / T, T]`` and one ``T``-wide window of the
+        sorted column. Per table the touched rows are read, each slot takes its
+        lane where "the slot's position is written by this batch" holds, and
+        the rows go back: R- and K-sized index arrays, never one entry a lane
+        (on one v5e a 1 M-lane scatter costs 4.8-9.2 ms, the sort of the same
+        lanes about 2: PERF.md section 6, PR 26 and 28).
+
+        Only the last ``A`` lanes of a key are written (``overwrites`` counts
+        the rest), so the written positions ``[begin, end)`` cover at most
+        ``A / T + 1`` chunks. The chunks after the first are distinct ring rows
+        (the body, ``enumerate_runs``); the first may share its row with the
+        last, so it is written in a pass of its own (the head, one row a key):
+        no pass holds a ring row twice, and the two write disjoint slots."""
         from ..ops.lookup import table_lookup
         K, A = self.num_keys, self.A
+        T, body_rows = self._row_geometry(batch.capacity)
+        per_key = A // T                                 # ring rows a key
         valid = batch.valid
         dropped_old = state.dropped_old
         with jax.named_scope("rank"):
@@ -184,20 +227,28 @@ class Win_Seq(Basic_Operator):
                 fresh = valid & (batch.ts >= horizon)
                 dropped_old = dropped_old + jnp.sum(valid & ~fresh, dtype=CTRL_DTYPE)
                 valid = fresh
-            rank = segment_rank(batch.key, valid)
-            pos = table_lookup(state.count, batch.key) + rank
-            slot = pos % A
-            flat = jnp.where(valid, batch.key * A + slot, K * A)  # OOB -> dropped
-
-        def scat(tbl, v):
-            return tbl.reshape((K * A,) + tbl.shape[2:]).at[flat].set(
-                v, mode="drop").reshape(tbl.shape)
+            with jax.named_scope("sort"):
+                columns, first, n = sort_segments(
+                    (batch.payload, batch.id, batch.ts), batch.key, valid, K)
+            with jax.named_scope("runs"):
+                count = state.count + n
+                begin = jnp.maximum(state.count, count - A)
+                head = begin // T
+                n_body = jnp.where(n > 0, (count - 1) // T - head, 0)
+                body_key, body_i, body_live = enumerate_runs(n_body, body_rows)
+                passes = (
+                    (jnp.arange(K, dtype=CTRL_DTYPE), head, n > 0),
+                    (body_key, jnp.take(head, body_key) + 1 + body_i, body_live))
+                # a window may start up to T - 1 lanes before a key's first
+                # lane and end as many after its last: pad, do not clamp
+                padded = jax.tree.map(
+                    lambda c: jnp.pad(c, [(T, T)] + [(0, 0)] * (c.ndim - 1)),
+                    columns)
+                lane0 = first - state.count + T          # padded lane of position 0
 
         with jax.named_scope("count"):
-            counts_add = segment_reduce(valid.astype(CTRL_DTYPE), batch.key, valid, K)
-            ts_max = segment_reduce(batch.ts, batch.key, valid, K,
-                                    combine=jnp.maximum, identity=-1)
-            count = state.count + counts_add
+            # the per-key watermark is over all n lanes, the overwritten ones too
+            ts_max = range_max(columns[2], first, n, -1)
             # this batch writes over every slot that holds a position below
             # count - A. One that an unfired window still needs (at or past the
             # purge horizon, by position for CB and by ts for TB) is a lost tuple,
@@ -208,18 +259,48 @@ class Win_Seq(Basic_Operator):
                     & (stamp >= (state.next_win * self.spec.slide)[:, None])
                     & (state.arch_pos < (count - A)[:, None]))
             overwrites = (state.overwrites + jnp.sum(lost, dtype=CTRL_DTYPE)
-                          + jnp.sum(jnp.maximum(counts_add - A, 0)))
+                          + jnp.sum(jnp.maximum(n - A, 0)))
+            runs_written = (state.runs_written
+                            + jnp.sum(n > 0, dtype=CTRL_DTYPE)
+                            + jnp.sum(n_body, dtype=CTRL_DTYPE))
+
+        def fill(tables, key, chunk, live):
+            """One pass: the rows (key, chunk) of every table, read, filled
+            with the batch's lanes and written back."""
+            row = jnp.where(live, key * per_key + chunk % per_key, K * per_key)
+            pos = chunk[:, None] * T + jnp.arange(T, dtype=CTRL_DTYPE)[None, :]
+            written = (live[:, None] & (pos >= jnp.take(begin, key)[:, None])
+                       & (pos < jnp.take(count, key)[:, None]))
+            lane = jnp.take(lane0, key) + chunk * T
+
+            def put(tbl, new):
+                ring = tbl.reshape((K * per_key, T) + tbl.shape[2:])
+                rows = jnp.where(
+                    written.reshape(written.shape + (1,) * (new.ndim - 2)),
+                    new, jnp.take(ring, row, axis=0, mode="clip"))
+                return ring.at[row].set(rows, mode="drop").reshape(tbl.shape)
+
+            return (*jax.tree.map(
+                lambda tbl, column: put(tbl, take_windows(column, lane, T)),
+                tables[:3], padded), put(tables[3], pos))
+
         with jax.named_scope("write"):
+            tables = (state.arch_payload, state.arch_id, state.arch_ts,
+                      state.arch_pos)
+            for key, chunk, live in passes:
+                tables = fill(tables, key, chunk, live)
+            arch_payload, arch_id, arch_ts, arch_pos = tables
             return dataclasses.replace(
                 state,
-                arch_payload=jax.tree.map(scat, state.arch_payload, batch.payload),
-                arch_id=scat(state.arch_id, batch.id),
-                arch_ts=scat(state.arch_ts, batch.ts),
-                arch_pos=scat(state.arch_pos, pos),
+                arch_payload=arch_payload,
+                arch_id=arch_id,
+                arch_ts=arch_ts,
+                arch_pos=arch_pos,
                 count=count,
                 wm=jnp.maximum(state.wm, ts_max),
                 overwrites=overwrites,
                 dropped_old=dropped_old,
+                runs_written=runs_written,
             )
 
     # ------------------------------------------------------------------ fire
@@ -342,9 +423,10 @@ class Win_Seq(Basic_Operator):
 
     def apply(self, state: WinSeqState, batch: Batch):
         """One scope per phase, as ``Win_SeqFFAT.apply`` has them: ``insert``
-        (``rank``, ``count``, ``write``) and ``emit`` (``range``, ``gather``, then
-        the window function), directly under the scope the chain opened for the
-        operator (a pattern built on this engine opens none for it)."""
+        (``rank`` with ``sort`` and ``runs``, ``count``, ``write``) and ``emit``
+        (``range``, ``gather``, then the window function), directly under the
+        scope the chain opened for the operator (a pattern built on this engine
+        opens none for it)."""
         W = self._resolve_w(batch.capacity)
         self._w = W
         with jax.named_scope("insert"):
@@ -377,15 +459,18 @@ class Win_Seq(Basic_Operator):
         return state, (out if any_valid else None)
 
     def _budget_gauges(self) -> dict:
-        """The two static budgets: ring slots per key, fired windows a batch (once
-        ``max_wins`` or the first ``apply`` has settled it)."""
+        """The static budgets: ring slots per key, the insert's row length and
+        the rows one batch may write per table (a head row a key and the
+        listed ones), fired windows a batch (once ``max_wins`` or the first
+        ``apply`` has settled it)."""
         W = self.max_wins if self.max_wins is not None else self._w
-        return {"archive_slots": self.A,
+        return {"archive_slots": self.A, "archive_run_len": self.run_len,
+                "archive_run_rows": self.num_keys + self.run_rows,
                 **({} if W is None else {"fired_window_budget": W})}
 
     def collect_stats(self, state=None) -> None:
         """Sync the device-resident counters into the stage counters (monitoring
-        snapshot / EOS: two scalar D2H reads, off the hot path)."""
+        snapshot / EOS: three scalar D2H reads, off the hot path)."""
         if state is None or not hasattr(state, "overwrites"):
             return
         import numpy as np
@@ -394,6 +479,7 @@ class Win_Seq(Basic_Operator):
         self._publish_stage_counters({
             **self.stage_counters(), **self._budget_gauges(),
             "archive_overwrites": int(np.asarray(state.overwrites)),
+            "archive_runs_written": int(np.asarray(state.runs_written)),
             "old_drops": old})
 
     def drop_counters(self, state=None) -> dict:

@@ -1,11 +1,9 @@
 """Segmented (per-key) reductions and scans over micro-batches.
 
-This is the device-side replacement for the reference's KEYBY routing
-(``wf/standard_emitter.hpp:85-110``: hash(key) -> replica queue): instead of scattering
-tuples to per-key threads, a whole batch stays on device and per-key semantics are
-recovered with segment operations. The reference's own GPU scattering study found
-sort-by-key the winning strategy at high fan-out
-(``src/GPU_Tests/scattering/results_scattering.org``) — which is exactly the plan here.
+The device-side replacement for the reference's KEYBY routing (hash(key) -> replica
+queue, ``wf/standard_emitter.hpp:85-110``): a whole batch stays on device and per-key
+semantics come from segment operations. Sort-by-key is the plan, as the reference's own
+GPU scattering study found (``src/GPU_Tests/scattering/results_scattering.org``).
 
 TPU cost discipline (docs/ARCHITECTURE.md §5): permutation gathers cost ~5.6 ns/elem,
 so sorting carries companion arrays through multi-operand ``lax.sort`` (one fused sort,
@@ -15,10 +13,12 @@ the same lanes with three companions costs 1.7 ms (PERF.md section 6, PR 26). So
 what only needs per-group results stays in sorted order (:func:`segment_run_fold`:
 closed-form run boundaries, a ``cumsum`` or a segmented scan, run-sized writes), and
 only what a caller needs lane by lane (:func:`segment_rank`,
-:func:`segment_prefix_scan`) returns to stream order, with a single scatter.
+:func:`segment_prefix_scan`) returns to stream order, with a single scatter. Lanes
+that have to land in a keyed table go there from sorted order too, as whole rows
+(:func:`sort_segments`, :func:`enumerate_runs`, :func:`take_windows`,
+:func:`range_max`: the ``Win_Seq`` archives, PR 28).
 
-All functions are mask-aware: invalid lanes contribute the combine identity.
-"""
+All functions are mask-aware: invalid lanes contribute the combine identity."""
 
 from __future__ import annotations
 
@@ -410,6 +410,75 @@ def segment_prefix_scan(values: Any, keys: jax.Array, valid: jax.Array,
         out = jax.tree.map(
             lambda v, t: combine(table_lookup(t, keys), v), out, carry_in)
     return out
+
+
+# ------------------------------------------------ rows in sorted order
+
+def sort_segments(arrays: Any, keys: jax.Array, valid: jax.Array,
+                  num_keys: int):
+    """``arrays`` (a pytree of ``[C, ...]`` leaves) in the order of one stable
+    sort by (dead, key), with every key's lane range in it: returns
+    ``(sorted arrays, first[K], n[K])``; key k's live lanes are
+    ``[first[k], first[k] + n[k])``, in stream order. Live lanes are ``valid``
+    with a key in ``[0, num_keys)``, as in :func:`segment_run_fold`."""
+    K = int(num_keys)
+    ok = valid & (keys >= 0) & (keys < K)
+    sorted_keys, _, sorted_arrays = _sort_by_key(keys, ok, arrays)
+    edges = jnp.searchsorted(sorted_keys, jnp.arange(K + 1, dtype=keys.dtype),
+                             side="left").astype(jnp.int32)
+    return sorted_arrays, edges[:-1], edges[1:] - edges[:-1]
+
+
+def enumerate_runs(n_runs: jax.Array, budget: int):
+    """List ``n_runs[k]`` runs for every key k, key by key, in ``budget``
+    rows: returns ``(key[budget], index[budget], live[budget])`` with
+    ``index`` counting a key's runs from 0. K- and budget-sized work; rows
+    past the total are dead (``key`` clipped to K - 1)."""
+    csum = jnp.cumsum(n_runs)
+    r = jnp.arange(budget, dtype=jnp.int32)
+    k = jnp.minimum(jnp.searchsorted(csum, r, side="right"),
+                    n_runs.shape[0] - 1).astype(jnp.int32)
+    return k, r - jnp.take(csum - n_runs, k), r < csum[-1]
+
+
+#: lanes of one aligned block of :func:`range_max`
+RANGE_BLOCK = 1024
+
+
+def range_max(values: jax.Array, first: jax.Array, n: jax.Array,
+              identity) -> jax.Array:
+    """``max(values[first[k] : first[k] + n[k]])`` for every k (``identity``
+    where ``n[k]`` is 0), without a scan over the lanes: of each range, the
+    two aligned blocks of :data:`RANGE_BLOCK` lanes it starts and ends in are
+    read as rows (K-row gathers of the column viewed ``[C / B, B]``) and
+    masked; the whole blocks between them are a range of the block maxima,
+    which are a column ``B`` times shorter, taken the same way."""
+    B = RANGE_BLOCK
+    fill = jnp.asarray(identity, values.dtype)
+    lo, hi = first, first + n
+    out = jnp.full(first.shape, fill)
+    while True:
+        size = values.shape[0]
+        blocks = jnp.pad(values, (0, -size % B),
+                         constant_values=fill).reshape(-1, B)
+        for block in (lo // B, (hi - 1) // B):
+            lane = block[:, None] * B + jnp.arange(B, dtype=lo.dtype)[None, :]
+            inside = (lane >= lo[:, None]) & (lane < hi[:, None])
+            rows = jnp.take(blocks, block, axis=0, mode="clip")
+            out = jnp.maximum(out, jnp.max(jnp.where(inside, rows, fill), axis=1))
+        if size <= B:
+            return out
+        values = jnp.max(blocks, axis=1)
+        lo, hi = lo // B + 1, (hi - 1) // B
+
+
+def take_windows(column: jax.Array, start: jax.Array, length: int) -> jax.Array:
+    """``column[start[r] : start[r] + length]`` for every r as ``[R, length,
+    ...]``: one gather of R contiguous slices (R indices, not one a lane).
+    The caller keeps every window inside the column (``dynamic_slice`` would
+    shift one that is not)."""
+    return jax.vmap(
+        lambda s: jax.lax.dynamic_slice_in_dim(column, s, length, axis=0))(start)
 
 
 # ------------------------------------------------------------- registration
