@@ -515,47 +515,6 @@ def bench_adaptive(total_batches: int = 240, base_batch: int = None):
     }
 
 
-def bench_dispatch(total_batches: int = 96, base_batch: int = None,
-                   k: int = None):
-    """Scan dispatch through the real Pipeline driver: the SAME chain driven
-    per-batch (dispatch off) and K-fused (``dispatch=k``), launch counts read
-    from the entry op's own Stats_Record (``num_kernels`` vs
-    ``batches_received`` — the attribution CompiledChain.push_many makes: K
-    batches, ONE kernel). The dispatch-amortization evidence next to the
-    throughput it buys."""
-    import jax.numpy as jnp
-    import windflow_tpu as wf
-    from windflow_tpu.operators.source import DeviceSource
-
-    base = base_batch or max(BATCH // 4, 1 << 12)
-    k = k or int(os.environ.get("WF_DISPATCH_K", "8") or "8")
-
-    def run(dispatch):
-        src = DeviceSource(lambda i: {"v": (i % 1000).astype(jnp.float32)},
-                           total=total_batches * base, num_keys=512)
-        pipe = wf.Pipeline(src, [wf.Map(lambda t: {"v": t.v * 2.0 + 1.0}),
-                                 wf.Filter(lambda t: t.v > 100.0),
-                                 wf.ReduceSink(lambda t: t.v)],
-                           batch_size=base, dispatch=dispatch)
-        t0 = time.perf_counter()
-        pipe.run()
-        dt = time.perf_counter() - t0
-        rec = pipe.chain.ops[0].get_StatsRecords()[0]
-        return {"tps": round(total_batches * base / dt),
-                "batches": rec.batches_received,
-                "launches": rec.num_kernels,
-                "launches_per_batch": round(rec.num_kernels
-                                            / max(rec.batches_received, 1), 4)}
-
-    fused = run(k)
-    per_batch = run(False)
-    return {
-        "dispatch_k": k, "base_capacity": base,
-        "fused": fused, "per_batch": per_batch,
-        "speedup": round(fused["tps"] / max(per_batch["tps"], 1), 3),
-    }
-
-
 def bench_keyed_stateful(num_keys: int):
     """MapGPU-stateful analogue (BASELINE.md rows 3-5): keyed map with a per-key
     running state folded in stream order (the reference keeps a per-key device
@@ -989,7 +948,6 @@ def bench_native_ring_raw():
 DEFAULT_ROWS = [
     ("ysb", bench_ysb),
     ("stateless", bench_stateless),
-    ("dispatch", bench_dispatch),
     ("nexmark", bench_nexmark),
     ("keyed_cb", bench_keyed_cb),
     ("native_ring", bench_native_ring_raw),

@@ -15,11 +15,8 @@ the fault-free controlled baseline byte-for-byte; backpressure governor on
 the threaded driver). Controller + injection must neither diverge nor
 livelock the supervisor's backoff.
 
---dispatch K runs every CHAOS run with scan dispatch (K-fused push_many)
-while the fault-free baselines stay per-batch — asserting the dispatch
-byte-identity claim and the recovery machinery in one sweep. The graph_det
-driver (DETERMINISTIC merge) keeps the Ordering_Node's async counts
-readback in every sweep, dispatch or not.
+The graph_det driver (DETERMINISTIC merge) keeps the Ordering_Node's async
+counts readback in every sweep.
 
 --shards N runs the two SUPERVISED drivers (pipeline + graph) through the
 shard-local supervision layer (N ShardSupervisor units) and widens each
@@ -27,7 +24,6 @@ seed's plan with shard-kill and torn reshard-handoff injection; the
 fault-free baselines stay UNSHARDED, so every seed asserts shard-count
 invariance AND shard-local recovery byte-identity at once. The sharded
 pipeline run additionally carries a mid-stream N -> 2N live reshard.
-(--shards excludes --dispatch on the supervised drivers: WF115.)
 
 --remediate closes the loop: the supervised PIPELINE runs (baseline AND
 chaos) carry barrier remediation (``remediation=True`` + deterministic
@@ -50,7 +46,6 @@ duplicate frame deduped (the peer-kill-degrades-to-replay contract).
 
     JAX_PLATFORMS=cpu python scripts/chaos_sweep.py --seeds 5 --total 400
     JAX_PLATFORMS=cpu python scripts/chaos_sweep.py --seeds 5 --controller
-    JAX_PLATFORMS=cpu python scripts/chaos_sweep.py --seeds 5 --dispatch 4
     JAX_PLATFORMS=cpu python scripts/chaos_sweep.py --seeds 5 --shards 4
     JAX_PLATFORMS=cpu python scripts/chaos_sweep.py --seeds 3 --remediate
     JAX_PLATFORMS=cpu python scripts/chaos_sweep.py --seeds 3 --serve
@@ -101,7 +96,7 @@ def collect(acc):
     return cb
 
 
-def run_pipeline(total, batch, faults=None, controller=False, dispatch=False,
+def run_pipeline(total, batch, faults=None, controller=False,
                  shards=0, remediate=False):
     got = []
     src = wf.Source(lambda i: {"v": (i % 13).astype(jnp.float32)},
@@ -111,8 +106,7 @@ def run_pipeline(total, batch, faults=None, controller=False, dispatch=False,
     SupervisedPipeline(src, [op], wf.Sink(collect(got)), batch_size=batch,
                        checkpoint_every=3, max_restarts=8,
                        backoff_base=0.001, backoff_cap=0.01,
-                       faults=faults, dispatch=dispatch,
-                       shards=shards or 1,
+                       faults=faults, shards=shards or 1,
                        # sharded runs also cross a live N -> 2N reshard at
                        # the first barrier past 1/3 of the stream — chaos
                        # seeds then hit shard kills AND torn handoffs
@@ -130,12 +124,11 @@ def run_pipeline(total, batch, faults=None, controller=False, dispatch=False,
     return sorted(got)
 
 
-def run_graph(total, batch, faults=None, controller=False, dispatch=False,
+def run_graph(total, batch, faults=None, controller=False,
               mode=None, shards=0):
     from windflow_tpu.basic import Mode
     got = []
-    g = PipeGraph("sweep", batch_size=batch,
-                  mode=mode or Mode.DEFAULT, dispatch=dispatch)
+    g = PipeGraph("sweep", batch_size=batch, mode=mode or Mode.DEFAULT)
     a = g.add_source(wf.Source(lambda i: {"v": (i % 9).astype(jnp.float32)},
                                total=total, num_keys=3, name="a"))
     b = g.add_source(wf.Source(lambda i: {"v": (i % 7).astype(jnp.float32)},
@@ -155,19 +148,16 @@ def run_graph(total, batch, faults=None, controller=False, dispatch=False,
     return sorted(got)
 
 
-def run_graph_det(total, batch, faults=None, controller=False,
-                  dispatch=False, shards=0):
+def run_graph_det(total, batch, faults=None, controller=False, shards=0):
     # DETERMINISTIC merge: every root push drives the Ordering_Node's
     # async [n_released, n_kept] readback — the sync-free hot path under
-    # chaos (and under fused dispatch when --dispatch is on)
+    # chaos
     from windflow_tpu.basic import Mode
     return run_graph(total, batch, faults=faults, controller=controller,
-                     dispatch=dispatch, mode=Mode.DETERMINISTIC,
-                     shards=shards)
+                     mode=Mode.DETERMINISTIC, shards=shards)
 
 
-def run_threaded(total, batch, faults=None, controller=False,
-                 dispatch=False):
+def run_threaded(total, batch, faults=None, controller=False):
     got = []
     src = wf.Source(lambda i: {"v": i.astype(jnp.float32)}, total=total)
     ThreadedPipeline(src, [[wf.Map(lambda t: {"v": t.v * 3})],
@@ -177,7 +167,7 @@ def run_threaded(total, batch, faults=None, controller=False,
                              np.asarray(v["payload"]["v"]).tolist()))
                          if v is not None else None),
                      batch_size=batch, pin=False, heartbeat_timeout=0.25,
-                     faults=faults, dispatch=dispatch,
+                     faults=faults,
                      control=thr_control() if controller else False).run()
     return sorted(got)
 
@@ -437,11 +427,6 @@ def main():
                     help="run every driver with the adaptive control plane "
                     "active (admission/backpressure; baselines use the same "
                     "controller, so shedding must stay deterministic)")
-    ap.add_argument("--dispatch", type=int, default=0, metavar="K",
-                    help="run every CHAOS run with scan dispatch (K-fused "
-                    "push_many) while the baselines stay per-batch — the "
-                    "fused path must match the per-batch fault-free oracle "
-                    "byte-for-byte")
     ap.add_argument("--shards", type=int, default=0, metavar="N",
                     help="run the supervised drivers (pipeline + graph) "
                     "through N-way shard-local supervision (plus a live "
@@ -484,10 +469,6 @@ def main():
         print("PASS: all serving chaos runs byte-identical to the "
               "RecordSource oracle")
         return 0
-    if args.shards and args.dispatch:
-        ap.error("--shards excludes --dispatch on the supervised drivers "
-                 "(WF115: a fused group failure has no single shard's "
-                 "replay extent)")
 
     #: drivers that route through the sharded supervisors under --shards
     sharded_drivers = {"pipeline", "graph", "graph_det"}
@@ -515,9 +496,7 @@ def main():
                 if args.remediate and name == "pipeline":
                     kw["remediate"] = True
                 out = fn(args.total, args.batch, faults=inj,
-                         controller=args.controller,
-                         dispatch=args.dispatch,   # 0 = off (every driver)
-                         **kw)
+                         controller=args.controller, **kw)
             except Exception as e:          # noqa: BLE001
                 print(f"[seed {seed}] {name}: RUN FAILED {type(e).__name__}: "
                       f"{e} ({len(inj.fired)} faults injected)")

@@ -183,7 +183,6 @@ def compile_report(snap, journal):
                 cost = (f"  {e['flops'] / 1e6:.2f} Mflop"
                         f"/{(e.get('bytes_accessed') or 0) / 1e6:.2f} MB")
             shape = f" cap={e['capacity']}" if e.get("capacity") else ""
-            shape += f" k={e['k']}" if e.get("k") else ""
             kind = ("RETRACE" if e.get("retrace")
                     else ("UNEXPECTED" if e.get("unexpected") else "compile"))
             lines.append(

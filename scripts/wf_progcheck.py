@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """wf_progcheck — the device-program analyzer (WF3xx) over this repository.
 
-Traces the closed jaxprs of every registered audit target's step/scan
+Traces the closed jaxprs of every registered audit target's step
 programs (``windflow_tpu/analysis/progcheck.py`` — zero FLOPs, zero device)
 and gates on the WF300-WF305 findings:
 
@@ -198,8 +198,7 @@ def main(argv=None) -> int:
             fresh = pc.apply_baseline(findings, counts)
             fresh_ids = {id(x) for x in fresh}
             suppressed = [x for x in findings if id(x) not in fresh_ids]
-        fps = ([{"target": p.target, "kind": p.kind, "k": p.k,
-                 "shards": p.shards, "capacity": p.capacity,
+        fps = ([{"target": p.target, "shards": p.shards, "capacity": p.capacity,
                  "fingerprint": pc.program_fingerprint(p.closed)}
                 for p in programs] if args.fingerprints else None)
     except Exception as e:  # noqa: BLE001 — a broken analyzer must exit 2,
@@ -219,7 +218,7 @@ def main(argv=None) -> int:
     else:
         if fps is not None:
             for row in fps:
-                print(f"{row['target']}/{row['kind']} k={row['k']} "
+                print(f"{row['target']}/step "
                       f"shards={row['shards']} cap={row['capacity']}  "
                       f"{row['fingerprint']}")
         for x in fresh:

@@ -315,77 +315,3 @@ def test_report_json_roundtrip():
     j = validate(g).to_json()
     assert j["diagnostics"][0]["code"] == "WF100"
     assert j["target"].startswith("PipeGraph")
-
-
-# ----------------------------------------------------------- WF110 dispatch
-
-
-def test_wf110_sequence_ids_with_dispatch_under_supervision():
-    from windflow_tpu.observability import TraceConfig
-    p = wf.Pipeline(_src(), [wf.Map(lambda t: {"v": t.v})], _sink(),
-                    batch_size=32, dispatch=4)
-    rep = validate(p, supervised=True, trace=TraceConfig(ids="sequence"))
-    assert {"WF108", "WF110"} <= set(rep.codes())
-    [d] = [d for d in rep.diagnostics if d.code == "WF110"]
-    assert d.severity == "error" and "sequence" in d.message
-    # position ids (the default) are legal with dispatch under supervision
-    assert "WF110" not in validate(
-        p, supervised=True, trace=TraceConfig(ids="position")).codes()
-    # and sequence ids are fine live (no supervision)
-    assert "WF110" not in validate(
-        p, trace=TraceConfig(ids="sequence")).codes()
-
-
-def test_wf110_wall_clock_admission_with_dispatch_under_supervision():
-    from windflow_tpu.control import ControlConfig
-    p = wf.Pipeline(_src(), [wf.Map(lambda t: {"v": t.v})], _sink(),
-                    batch_size=32, dispatch=8)
-    cfg = ControlConfig(admission=True, rate_tps=50.0, autotune=False,
-                        backpressure=False)
-    rep = validate(p, control=cfg, supervised=True)
-    codes = rep.codes()
-    assert "WF105" in codes and "WF110" in codes      # both name the hazard
-    det = ControlConfig(admission=True, refill_per_batch=32.0,
-                        autotune=False, backpressure=False)
-    assert "WF110" not in validate(p, control=det, supervised=True).codes()
-
-
-def test_wf110_k_exceeds_ring_capacity_warns():
-    tp = wf.ThreadedPipeline(_src(), [[wf.Map(lambda t: {"v": t.v})]],
-                             _sink(), batch_size=32, queue_capacity=4,
-                             dispatch=16, control=False)
-    hits = [d for d in validate(tp).diagnostics if d.code == "WF110"]
-    assert hits and all(d.severity == "warning" for d in hits)
-    assert any("16" in d.message and "4" in d.message for d in hits)
-    # K within every ring is clean
-    tp2 = wf.ThreadedPipeline(_src(), [[wf.Map(lambda t: {"v": t.v})]],
-                              _sink(), batch_size=32, queue_capacity=8,
-                              dispatch=4, control=False)
-    assert "WF110" not in validate(tp2).codes()
-
-
-def test_wf110_unresolvable_config_is_an_error():
-    p = wf.Pipeline(_src(), [wf.Map(lambda t: {"v": t.v})], _sink(),
-                    batch_size=32, dispatch={"k": -2})
-    [d] = [d for d in validate(p).diagnostics if d.code == "WF110"]
-    assert d.severity == "error" and "resolve" in d.message
-
-
-def test_wf110_k1_and_off_are_silent():
-    p = wf.Pipeline(_src(), [wf.Map(lambda t: {"v": t.v})], _sink(),
-                    batch_size=32, dispatch=1)
-    assert "WF110" not in validate(p, supervised=True).codes()
-    p2 = wf.Pipeline(_src(), [wf.Map(lambda t: {"v": t.v})], _sink(),
-                    batch_size=32)
-    assert "WF110" not in validate(p2, supervised=True).codes()
-
-
-def test_wf110_explicit_dispatch_overrides_stored():
-    p = wf.Pipeline(_src(), [wf.Map(lambda t: {"v": t.v})], _sink(),
-                    batch_size=32)                     # no stored dispatch
-    tp_cfg = {"k": 16}
-    rep = validate(p, supervised=True, dispatch=tp_cfg,
-                   control=wf.ControlConfig(admission=True, rate_tps=10.0,
-                                            autotune=False,
-                                            backpressure=False))
-    assert "WF110" in rep.codes()

@@ -45,7 +45,7 @@ def test_baselined_wf26x_entries_carry_a_rationale():
 
 
 def test_driver_only_contracts_are_annotation_enforced():
-    """The three formerly docstring-only contracts are now declared in the
+    """The two formerly docstring-only contracts are now declared in the
     checked annotation grammar (and the inference actually classifies them
     — their inferred roles stay inside the declared set)."""
     roles = conc.inferred_roles(ROOT)
@@ -57,7 +57,6 @@ def test_driver_only_contracts_are_annotation_enforced():
 
     assert roles_of("Ordering_Node.settle") <= {"driver", "stage"}
     assert roles_of("TieredTable.maintain") <= {"driver", "stage"}
-    assert roles_of("MicrobatchAccumulator.feed") <= {"driver", "stage"}
     # and the spawned roles landed where the annotations say they do
     assert "reporter" in roles_of("Reporter._run")
     assert "watchdog" in roles_of("ThreadedPipeline._watchdog_body")

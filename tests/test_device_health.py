@@ -135,9 +135,8 @@ def test_health_snapshot_journal_prometheus(tmp_path):
     comps = [e for e in ev if e["event"] == "compile"]
     assert len(comps) == h["compile"]["compiles"]
     for e in comps:
-        assert e["cause"] in ("push", "push_many", "warm", "warm_scan",
-                              "autotune_prewarm")
-        assert e["kind"] in ("step", "scan")
+        assert e["cause"] in ("push", "warm", "autotune_prewarm")
+        assert e["kind"] == "step"
         assert e["cache_key"] and e["compile_s"] > 0
         # AOT cost columns land on the CPU backend
         assert e["flops"] >= 0 and e["bytes_accessed"] > 0
@@ -184,22 +183,6 @@ def test_retrace_counters_and_detector(tmp_path):
     # same cache key for the unexpected retrace as the original compile
     comp_keys = [e["cache_key"] for e in ev if e["event"] == "compile"]
     assert comp_keys[0] == comp_keys[2]
-
-
-def test_scan_compile_carries_k(tmp_path):
-    led = dh.HealthLedger(cost_analysis=False)
-    dh.set_active(led)
-    j = EventJournal(str(tmp_path / "events.jsonl"))
-    set_journal(j)
-    src, chain = _small_chain()
-    it = iter(src.batches(64))
-    chain.push_many([next(it) for _ in range(4)])
-    j.close()
-    ev = read_journal(str(tmp_path / "events.jsonl"))
-    scans = [e for e in ev if e["event"] == "compile" and e["kind"] == "scan"]
-    assert len(scans) == 1
-    assert scans[0]["k"] == 4 and scans[0]["capacity"] == 64
-    assert scans[0]["cause"] == "push_many"
 
 
 def test_autotune_prewarm_cause_overrides():

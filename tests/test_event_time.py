@@ -1,10 +1,10 @@
 """Event-time observability: telemetry-on byte-identity across all four
 drivers (plain / threaded / supervised / graph-supervised, under FaultPlan
-restarts and fused ``WF_DISPATCH``), the watermark/occupancy/lateness
+restarts), the watermark/occupancy/lateness
 snapshot + Prometheus + topology surfaces, ``recommend_delay`` driving a
 skewed stream's OLD drops to zero end-to-end through ``wf_state.py``, the
-fused-dispatch trace apportionment, and the ``wf_state.py`` 0/2 exit
-contract without JAX."""
+trace report's per-batch service attribution, and the ``wf_state.py`` 0/2
+exit contract without JAX."""
 
 import json
 import os
@@ -150,12 +150,6 @@ def test_event_time_on_byte_identical_under_faultplan(name, tmp_path,
     assert run_query(name, "graph-supervised",
                      monitoring=_cfg(tmp_path, "graph"),
                      faults=plan) == base
-
-
-def test_event_time_on_byte_identical_under_wf_dispatch(tmp_path):
-    name = "q3_enrich_join"
-    base = run_query(name)
-    assert run_query(name, monitoring=_cfg(tmp_path), dispatch=4) == base
 
 
 # -------------------------------------------------- snapshot surfaces
@@ -388,48 +382,22 @@ def test_wf_state_exit_2_on_bad_quantile(tmp_path):
     assert out.returncode == 2
 
 
-# ----------------------------------- fused-dispatch trace apportionment
+# ----------------------------------- per-batch service attribution
 
-def test_fused_spans_apportion_service_across_members():
+def test_spans_charge_each_batch_its_own_service_time():
     from windflow_tpu.observability.tracing import _batch_lifecycles
     recs = []
-    # a fused group of 4: four spans over the SAME 8 ms launch, k-marked
-    for i, tid in enumerate((11, 12, 13, 14)):
-        recs.append({"t": 0.0 + i * 1e-6, "tid": tid, "stage": "chain",
-                     "kind": "begin", "k": 4})
-    for i, tid in enumerate((11, 12, 13, 14)):
-        recs.append({"t": 0.008 + i * 1e-6, "tid": tid, "stage": "chain",
-                     "kind": "end"})
-    # an unfused span: full duration charged
+    # two batches through the same stage, then a second visit of the first
+    recs.append({"t": 0.000, "tid": 11, "stage": "chain", "kind": "begin"})
+    recs.append({"t": 0.008, "tid": 11, "stage": "chain", "kind": "end"})
     recs.append({"t": 0.020, "tid": 15, "stage": "chain", "kind": "begin"})
     recs.append({"t": 0.024, "tid": 15, "stage": "chain", "kind": "end"})
+    recs.append({"t": 0.030, "tid": 11, "stage": "chain", "kind": "begin"})
+    recs.append({"t": 0.031, "tid": 11, "stage": "chain", "kind": "end"})
     lives = _batch_lifecycles(recs)
-    for tid in (11, 12, 13, 14):
-        assert lives[tid]["service"]["chain"] == pytest.approx(0.002,
-                                                               rel=1e-3)
-        assert lives[tid]["fused"] == 1
+    assert lives[11]["service"]["chain"] == pytest.approx(0.009, rel=1e-6)
+    assert lives[11]["attempts"]["chain"] == 2
     assert lives[15]["service"]["chain"] == pytest.approx(0.004, rel=1e-6)
-    assert lives[15]["fused"] == 0
-
-
-def test_fused_push_marks_k_on_begin_records(tmp_path):
-    from windflow_tpu.observability import TraceConfig, Tracer, tracing
-    src, ops = make_query("q3_enrich_join", TOTAL)
-    rows = []
-    p = wf.Pipeline(src, ops, wf.Sink(lambda v: rows.append(1)),
-                    batch_size=64,
-                    trace=TraceConfig(out_dir=str(tmp_path / "tr")),
-                    dispatch=4)
-    p.run()
-    records, meta = tracing.load_flight(str(tmp_path / "tr"))
-    fused_begins = [r for r in records
-                    if r["kind"] == "begin" and r.get("k")]
-    assert fused_begins, "no k-marked begin records under dispatch=4"
-    assert all(r["k"] > 1 for r in fused_begins)
-    # chrome export annotates the fused spans
-    trace = tracing.to_chrome_trace(records, [], meta)
-    assert any(e.get("args", {}).get("fused_k")
-               for e in trace["traceEvents"] if e["ph"] == "B")
 
 
 def test_wf_trace_report_renders_lateness_drops(tmp_path):

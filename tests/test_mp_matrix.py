@@ -22,7 +22,11 @@ TOTAL, K = 240, 3
 rng = np.random.default_rng(7)
 
 
-def run_case(make_op, batch_size):
+def build_case(make_op):
+    """One case's stream, operators and collector: ``(src, ops, results,
+    cb)`` — ``cb`` appends each delivered ``(key, id, value)`` to ``results``
+    in delivery order.  Shared with the driver-matrix files
+    (tests/test_driver_matrix_*.py)."""
     src = wf.Source(lambda i: {"v": ((i * 13) % 23).astype(jnp.float32)},
                     total=TOTAL, num_keys=K)
     results = []
@@ -37,8 +41,29 @@ def run_case(make_op, batch_size):
     ops = make_op()
     if not isinstance(ops, (list, tuple)):
         ops = [ops]
-    wf.Pipeline(src, list(ops), wf.Sink(cb), batch_size=batch_size).run()
-    return sorted(results)
+    return src, list(ops), results, cb
+
+
+def delivered(make_op, drive):
+    """What ``drive(src, ops, sink)`` delivers for one case, in delivery
+    order."""
+    src, ops, results, cb = build_case(make_op)
+    drive(src, ops, wf.Sink(cb))
+    return results
+
+
+#: the batch size of the driver-matrix files: six batches of the stream
+DRIVER_BATCH = 40
+
+
+def pipeline_delivered(make_op, batch_size=DRIVER_BATCH):
+    """The reference of the driver matrix: what ``wf.Pipeline`` delivers."""
+    return delivered(make_op, lambda src, ops, sink: wf.Pipeline(
+        src, ops, sink, batch_size=batch_size).run())
+
+
+def run_case(make_op, batch_size):
+    return sorted(pipeline_delivered(make_op, batch_size))
 
 
 CASES = {

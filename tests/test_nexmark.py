@@ -1,9 +1,8 @@
 """Nexmark suite correctness: every query passes its dense oracle (exact
 expected outputs, the ``test_ysb.py`` style) invariant under batch size; the
 interval-join and session queries are byte-identical across the plain /
-threaded / supervised drivers, under FaultPlan injection with mid-upsert
-checkpoints (both supervised drivers), and under fused scan dispatch
-(``WF_DISPATCH``); the join-table state replays byte-identically through a
+threaded / supervised drivers and under FaultPlan injection with mid-upsert
+checkpoints (both supervised drivers); the join-table state replays byte-identically through a
 restart that lands between an upsert's ingestion and its watermark
 application."""
 
@@ -174,18 +173,6 @@ def test_join_table_replay_with_mid_upsert_checkpoint():
     # ring mid-flight (delay=3 keeps recent upserts unapplied)
     got = run(FaultPlan([FaultSpec("chain.step", at=[3])], seed=11))
     assert got == base
-
-
-# ------------------------------------------------------ fused dispatch
-
-@pytest.mark.parametrize("name", ["q3_enrich_join", "q4_interval_join",
-                                  "q5_session"])
-def test_join_and_session_byte_identical_under_wf_dispatch(name, monkeypatch):
-    base = run_query(name, 50)
-    assert run_query(name, 50, dispatch=4) == base
-    monkeypatch.setenv("WF_DISPATCH", "1")
-    monkeypatch.setenv("WF_DISPATCH_K", "3")
-    assert run_query(name, 50) == base
 
 
 # ------------------------------------------------------------- wiring

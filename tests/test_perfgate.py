@@ -186,66 +186,18 @@ def test_stage_costs_rows_per_operator():
     assert rows[0]["capacity"] == cap
 
 
-# ------------------------------------------------------- scan dispatch
+# ----------------------------------------------- proxy family coverage
 
 
-def test_scan_workload_pinned_and_in_measurement(measurement):
-    """The ysb_scan_k8 workload (the K-fused _scan_fn program AOT-lowered)
-    is measured and pinned beside the per-batch steps, carrying its K."""
-    row = measurement["workloads"]["ysb_scan_k8"]
-    assert row["flops"] > 0 and row["bytes_accessed"] > 0
-    assert row["k"] == 8 and row["capacity"] == 2048
-    pinned = perfgate.load_baseline(perfgate.baseline_path(ROOT))
-    assert "ysb_scan_k8" in pinned["workloads"]
-
-
-def test_scan_body_cost_parity_no_per_step_regression(measurement):
-    """XLA's cost model counts a lax.scan body ONCE, so the scanned
-    program's flops must match the single chain step's within tolerance —
-    fusing K steps into one program must not bloat the per-step program
-    (a fusion break inside the scan body fails here)."""
-    scan = measurement["workloads"]["ysb_scan_k8"]
-    single = perfgate.chain_step_cost("ysb")
-    assert scan["flops"] <= single["flops"] * 1.05
-    assert scan["flops"] >= single["flops"] * 0.5    # it IS the same body
-
-
-def test_scan_k_drift_is_a_finding():
-    cur = {"workloads": {"ysb_scan_k8": {"flops": 10.0,
-                                         "bytes_accessed": 5.0,
-                                         "capacity": 2048, "k": 16}}}
-    base = {"workloads": {"ysb_scan_k8": {"flops": 10.0,
-                                          "bytes_accessed": 5.0,
-                                          "capacity": 2048, "k": 8}}}
-    [f] = perfgate.compare(cur, base)
-    assert f["kind"] == "capacity-drift" and "K changed" in f["message"]
-
-
-def test_dispatch_proxy_row_and_coverage(measurement):
-    """The 'dispatch' proxy family (names.py::PERF_PROXY_FAMILIES) is
+def test_proxy_family_row_and_coverage(measurement):
+    """A non-kernel proxy family (names.py::PERF_PROXY_FAMILIES) is
     measured — and dropping it is a coverage finding, the KERNELS
     convention."""
-    row = measurement["proxy"]["dispatch"]
+    row = measurement["proxy"]["join"]
     assert row["ns_per_elem"] > 0
-    assert row["launches"] * row["k"] >= row["batches"]
     pruned = {"workloads": measurement["workloads"],
               "proxy": {k: v for k, v in measurement["proxy"].items()
-                        if k != "dispatch"}}
+                        if k != "join"}}
     findings = perfgate.compare(pruned, pruned)
-    assert any(f["kind"] == "proxy-coverage" and f["workload"] == "dispatch"
+    assert any(f["kind"] == "proxy-coverage" and f["workload"] == "join"
                for f in findings)
-
-
-def test_dispatch_launch_counts_amortization():
-    """push_many issues ONE executable call per K batches (partial tail
-    included): launches == ceil(batches / K), measured at the jit boundary
-    by wrapping the chain's cached executables — the >= Kx
-    fewer-invocations-per-batch claim of the scan dispatcher."""
-    import math
-    for k, n in ((8, 20), (4, 16), (3, 7)):
-        row = perfgate.dispatch_launch_counts(k=k, capacity=256, n_batches=n)
-        assert row["batches"] == n
-        assert row["launches"] == math.ceil(n / k), row
-    # the K=1 degenerate rung is exactly today's per-batch dispatch
-    row = perfgate.dispatch_launch_counts(k=1, capacity=256, n_batches=5)
-    assert row["launches"] == row["batches"] == 5

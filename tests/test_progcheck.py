@@ -26,12 +26,12 @@ F8 = S((8,), jnp.float32)
 I8 = S((8,), jnp.int32)
 
 
-def prog(fn, *args, k=1, replay=False, shards=1):
+def prog(fn, *args, replay=False, shards=1):
     """A fixture Program: trace ``fn`` abstractly, wrap with the given
     execution context."""
-    return pc.Program(target="fx", kind="step",
+    return pc.Program(target="fx",
                       closed=jax.make_jaxpr(fn)(*args), capacity=8,
-                      k=k, shards=shards, replay=replay)
+                      shards=shards, replay=replay)
 
 
 def codes(p):
@@ -88,7 +88,7 @@ def test_wf302_names_the_callback_and_ranks_fusion():
 
 
 def test_wf303_weak_typed_program_input():
-    bad = pc.Program(target="fx", kind="step",
+    bad = pc.Program(target="fx",
                      closed=jax.make_jaxpr(lambda x: x * 2)(3.0),
                      capacity=8)
     ok = prog(lambda x: x * 2, F8)
@@ -105,12 +105,10 @@ def test_wf304_donated_input_read_after_donation():
 
 
 def test_wf305_float_reduction_under_composition():
-    under_k = prog(lambda v: jnp.sum(v), F8, k=2)
     under_shards = prog(lambda v: jnp.sum(v), F8, shards=2)
-    integer = prog(lambda v: jnp.sum(v), I8, k=2)
-    solo = prog(lambda v: jnp.sum(v), F8, k=1)
-    exact_max = prog(lambda v: jnp.max(v), F8, k=2)
-    assert codes(under_k) == ["WF305"]
+    integer = prog(lambda v: jnp.sum(v), I8, shards=2)
+    solo = prog(lambda v: jnp.sum(v), F8)
+    exact_max = prog(lambda v: jnp.max(v), F8, shards=2)
     assert codes(under_shards) == ["WF305"]
     assert codes(integer) == []
     assert codes(solo) == []
@@ -123,14 +121,14 @@ def test_walker_recurses_into_scan_and_cond():
     def body(c, v):
         return c, jnp.sum(v)               # float reduce inside the scan
     bad = prog(lambda vs: jax.lax.scan(body, 0.0, vs),
-               S((4, 8), jnp.float32), k=2)
+               S((4, 8), jnp.float32), shards=2)
     hits = [f for f in pc.analyze_program(bad) if f.code == "WF305"]
     assert hits and any("scan" in f.text for f in hits)
 
     def branch(x):
         return jnp.sum(x)
     bad2 = prog(lambda p, x: jax.lax.cond(p, branch, lambda x: x[0], x),
-                S((), jnp.bool_), F8, k=2)
+                S((), jnp.bool_), F8, shards=2)
     hits2 = [f for f in pc.analyze_program(bad2) if f.code == "WF305"]
     assert hits2 and any("cond" in f.text for f in hits2)
 
@@ -417,19 +415,3 @@ def test_audit_targets_trace(target):
     counts, problems = pc.load_baseline(pc.baseline_path())
     assert problems == []
     assert pc.apply_baseline(findings, counts) == []
-
-
-def test_wf115_pairing_demo_no_order_variant_reductions():
-    """ROADMAP item 1 evidence (the satellite demo, pinned): the
-    currently-forbidden dispatch K>1 x tiered-state pairing has NO
-    order-variant float reductions in its fused scan program — the exact
-    record the next composition arc needs. Only the designed tiered host
-    exchange (WF302) appears."""
-    from windflow_tpu.nexmark import queries as q
-    src, ops = q.q3_enrich_join(512, tiered=True)
-    chain = pc._mk_chain(src, ops, 64)
-    programs = pc.chain_programs(chain, capacity=64, k=4, replay=True,
-                                 target="demo:q3_tiered_k4")
-    findings = pc.analyze_programs(programs)
-    assert [f.code for f in findings] == ["WF302", "WF302"]
-    assert not [f for f in findings if f.code == "WF305"]

@@ -93,20 +93,3 @@ def test_event_time_on_changes_program(monkeypatch):
     monkeypatch.setenv("WF_MONITORING", "1")
     monkeypatch.setenv("WF_MONITORING_EVENT_TIME", "1")
     assert _fingerprint("q5_session") != base
-
-
-def test_scan_program_toggle_off_identical(monkeypatch):
-    """The K-fused scan program rides the same gate: monitoring on must
-    not perturb the fused dispatch path either."""
-    for env in _TOGGLE_ENVS:
-        monkeypatch.delenv(env, raising=False)
-
-    def scan_fp():
-        src, ops = q.make_query("q1_currency", total=512)
-        chain = pc._mk_chain(src, ops, 64)
-        return pc.program_fingerprint(pc.trace_scan(chain, 4, 64))
-
-    base = scan_fp()
-    monkeypatch.setenv("WF_MONITORING", "1")
-    monkeypatch.setenv("WF_SLO", "1")
-    assert scan_fp() == base

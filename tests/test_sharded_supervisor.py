@@ -478,11 +478,6 @@ def test_shard_range_rejects_reshard():
 # ------------------------------------------------- composition guards
 
 
-def test_shards_reject_dispatch_fusion():
-    with pytest.raises(ValueError, match="scan dispatch"):
-        run_build(shards=4, dispatch=4)
-
-
 def test_shard_key_follows_rekeyed_stream():
     """A KeyBy re-key under sharding: ownership must follow the KeyBy's
     key (shard_key=), and the validator errors without it."""
@@ -901,10 +896,6 @@ def test_wf115_pins():
     errs = validate(mk(shards=4),
                     reshard={"new_shards": 4, "moves": [[2, 9]]}).errors
     assert any(d.code == "WF115" and "does not exist" in d.message
-               for d in errs)
-    # dispatch K>1 under shards: error
-    errs = validate(mk(shards=4), dispatch=4).errors
-    assert any(d.code == "WF115" and "scan dispatch" in d.message
                for d in errs)
     # wall-clock admission under shards: error (the WF105 mirror)
     errs = validate(mk(shards=4),

@@ -282,7 +282,7 @@ class _Func:
         #: ``upsert`` lock); filled by _effective_held
         self.entry_held: frozenset = frozenset()
         #: local var -> repo class name, from in-body constructor bindings
-        #: (``acc = MicrobatchAccumulator(...)``) and with-as bindings —
+        #: (``reb = Rebatcher(...)``) and with-as bindings —
         #: consulted by _resolve_call for ``obj.m()`` receivers
         self.local_types: Dict[str, str] = {}
         #: call sites RESOLVED once per index build (``_indexed``):
@@ -843,8 +843,8 @@ def _resolve_call(idx: _Index, caller: _Func, spec) -> List["_Func"]:
             got = _class_method(idx, caller.cls, meth)
             return [got] if got is not None else []
         if base is not None:
-            # a constructor-typed local resolves precisely (`acc =
-            # MicrobatchAccumulator(...); acc.drain()`)
+            # a constructor-typed local resolves precisely (`reb =
+            # Rebatcher(...); reb.drain()`)
             t = caller.local_types.get(base)
             if t is not None and t in idx.classes:
                 got = _class_method(idx, t, meth)
@@ -1100,8 +1100,8 @@ def _rule_ordered_effects(idx: _Index, replay: Set[str]) -> None:
                     "WF262", "error", fn.file, node.lineno,
                     "io_callback in a deterministic-replay module must "
                     "pass a LITERAL ordered=True — an unordered host "
-                    "callback reorders side effects under scan-fused "
-                    "dispatch and breaks byte-identical replay")
+                    "callback reorders side effects across pushes "
+                    "and breaks byte-identical replay")
             if not _resolve_target(idx, fn, target):
                 idx.finding(
                     "WF262", "error", fn.file, node.lineno,

@@ -178,7 +178,7 @@ RULES: Dict[str, Tuple[str, str]] = {
                        "in a deterministic-replay program"),
     "WF301": ("error", "unordered host effect (io_callback/debug_callback "
                        "without ordered=True) reachable from a compiled "
-                       "step/scan body — the jaxpr-level complement of "
+                       "step body — the jaxpr-level complement of "
                        "WF262"),
     "WF302": ("warning", "host-sync in the per-push hot path: a callback "
                          "primitive forcing a blocking D2H round trip "
@@ -191,10 +191,9 @@ RULES: Dict[str, Tuple[str, str]] = {
     "WF304": ("error", "donated-buffer aliasing: a donated input read "
                        "after the equation XLA aliases it into, or "
                        "aliased into two outputs"),
-    "WF305": ("warning", "shard/K-variant float reduction: accumulation "
-                         "grouping that can change with shard count or "
-                         "dispatch K (the static evidence for retiring "
-                         "WF115 pairings)"),
+    "WF305": ("warning", "shard-variant float reduction: accumulation "
+                         "grouping that can change with shard count (the "
+                         "static evidence for retiring WF115 pairings)"),
 }
 
 

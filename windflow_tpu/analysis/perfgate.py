@@ -58,12 +58,6 @@ WORKLOAD_CAPACITY = {"ysb": 2048, "mp_matrix": 1024,
                      # io_callback lowers to a host custom-call)
                      "tiered_probe_miss": 512}
 
-#: scan-dispatch workloads: (base workload, K) — the K-fused
-#: ``CompiledChain._scan_fn`` program AOT-lowered and pinned beside the
-#: per-batch step, so a change that breaks the scan body's fusion (or makes
-#: the fused program cost more than K x the single step) fails tier-1
-SCAN_WORKLOADS = {"ysb_scan_k8": ("ysb", 8)}
-
 
 # ------------------------------------------------------------- workloads
 
@@ -201,20 +195,6 @@ def workload_cost(name: str) -> Dict[str, float]:
     return out
 
 
-def chain_step_cost(name: str) -> Dict[str, float]:
-    """Cost of ONE chain-only batch step (``CompiledChain._step_fn``, no
-    source framing) — the denominator of the scan amortization check."""
-    import jax
-    from ..batch import Batch
-    chain, _, cap = WORKLOADS[name]()
-    bspec = jax.eval_shape(lambda: Batch.empty(cap, chain.specs[0]))
-    sspec = _arg_specs(tuple(chain.states))
-    compiled = chain._step_fn(0).lower(sspec, bspec).compile()
-    out = _cost_of(compiled)
-    out["capacity"] = cap
-    return out
-
-
 #: reshard_pack pin geometry: one batch split into N masked per-shard
 #: sub-batches (``parallel/sharding.py::ShardAssignment.split_fn`` — the
 #: only per-batch program the sharded supervisors add, and the pack step of
@@ -240,28 +220,6 @@ def reshard_pack_cost() -> Dict[str, float]:
     out = _cost_of(compiled)
     out["capacity"] = cap
     out["shards"] = RESHARD_PACK_SHARDS
-    return out
-
-
-def workload_scan_cost(name: str) -> Dict[str, float]:
-    """AOT cost of the K-fused scan-dispatch program for one
-    ``SCAN_WORKLOADS`` entry: ``CompiledChain._scan_fn`` (the ``lax.scan``
-    over the per-batch step with states as carry) lowered for a
-    ``[K, C, ...]`` stacked batch — zero execution, CPU backend. The pin
-    guards the scanned step the same way the per-batch pins guard ``push``:
-    a fusion break INSIDE the scan body moves this number."""
-    import jax
-    from ..batch import Batch
-    base, k = SCAN_WORKLOADS[name]
-    chain, _, cap = WORKLOADS[base]()
-    bspec = jax.eval_shape(lambda: Batch.empty(cap, chain.specs[0]))
-    stacked = jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct((k,) + s.shape, s.dtype), bspec)
-    sspec = _arg_specs(tuple(chain.states))
-    compiled = chain._scan_fn(0).lower(sspec, stacked).compile()
-    out = _cost_of(compiled)
-    out["capacity"] = cap
-    out["k"] = k
     return out
 
 
@@ -405,18 +363,6 @@ def proxy_microbench(reps: int = 3) -> Dict[str, dict]:
     f = jax.jit(lambda s: join_table_tier_evict(s, KT2 // 2))
     out["spill"] = {"elems": KT2, "seconds": _bench_one(f, ts0, reps=reps)}
 
-    # dispatch: K batches through ONE fused push_many scan launch (the
-    # runtime/dispatch.py hot path) — time per tuple of the fused call, with
-    # the jit-boundary launch counts riding along as evidence
-    KD, CD = 8, 1024
-    chain_d, group = _dispatch_chain(KD, CD)
-    chain_d.warm_scan(KD, CD)                 # compile outside the timing
-    row = {"elems": KD * CD,
-           "seconds": _bench_one(lambda g: chain_d.push_many(g), group,
-                                 reps=reps)}
-    row.update(dispatch_launch_counts(k=KD, capacity=CD))
-    out["dispatch"] = row
-
     # shard: the sharded supervisors' key-ownership splitter (one batch ->
     # N masked sub-batches, parallel/sharding.py) — the only per-batch cost
     # shard-local supervision adds; also the pack step of a reshard handoff
@@ -432,59 +378,6 @@ def proxy_microbench(reps: int = 3) -> Dict[str, dict]:
     for row in out.values():
         row["ns_per_elem"] = round(row.pop("seconds") / row["elems"] * 1e9, 3)
     return out
-
-
-def _dispatch_chain(k: int, capacity: int):
-    """A tiny stateless map+filter chain + exactly ``k`` capacity-C batches
-    for the scan-dispatch proxy/count instruments."""
-    import jax.numpy as jnp
-    from ..operators.filter import Filter
-    from ..operators.map import Map
-    from ..operators.source import Source
-    from ..runtime.pipeline import CompiledChain
-    src = Source(lambda i: {"v": (i % 97).astype(jnp.float32)},
-                 total=k * capacity, num_keys=8)
-    ops = [Map(lambda t: {"v": t.v * 2.0 + 1.0}),
-           Filter(lambda t: t.v > 3.0)]
-    chain = CompiledChain(ops, src.payload_spec(), batch_capacity=capacity,
-                          event_time=False)
-    return chain, list(src.batches(capacity))
-
-
-def dispatch_launch_counts(k: int = 8, capacity: int = 1024,
-                           n_batches: Optional[int] = None) -> Dict[str, int]:
-    """Count jit-boundary executable dispatches for ``n_batches`` batches
-    driven through a ``MicrobatchAccumulator(k)`` + ``push_many`` (tail
-    drained short, the driver shape) by wrapping the chain's cached
-    executables — the dispatch-amortization claim measured, not assumed:
-    one launch per full K group, one per partial tail, so
-    ``launches == ceil(batches / k)`` and the per-batch path would have paid
-    ``batches``. Device-free (CPU backend)."""
-    from ..runtime.dispatch import MicrobatchAccumulator
-    n = int(n_batches) if n_batches else 2 * k + max(1, k // 2)
-    chain, batches = _dispatch_chain(n, capacity)
-    calls = {"n": 0}
-    for name in ("_scan_fn", "_step_fn"):
-        orig = getattr(chain, name)
-
-        def counting(i, _orig=orig):
-            f = _orig(i)
-
-            def call(*a, **kw):
-                calls["n"] += 1
-                return f(*a, **kw)
-            return call
-        setattr(chain, name, counting)
-    acc = MicrobatchAccumulator(k)
-    fed = 0
-    for b in batches:
-        fed += 1
-        for group in acc.feed(b):
-            chain.push_many(group)
-    tail = acc.drain()
-    if tail:
-        chain.push_many(tail)
-    return {"k": int(k), "batches": fed, "launches": calls["n"]}
 
 
 # --------------------------------------------------------------- baseline
@@ -525,8 +418,6 @@ def measure(skip_proxy: bool = False, reps: int = 3) -> dict:
     """The gate's current measurement: cost pins for every workload (+
     advisory proxy timings)."""
     report = {"workloads": {name: workload_cost(name) for name in WORKLOADS}}
-    for name in SCAN_WORKLOADS:
-        report["workloads"][name] = workload_scan_cost(name)
     report["workloads"]["reshard_pack"] = reshard_pack_cost()
     if not skip_proxy:
         report["proxy"] = proxy_microbench(reps=reps)
@@ -563,16 +454,6 @@ def compare(current: dict, baseline: Optional[dict],
                                    f"comparable — re-pin with "
                                    f"--update-baseline"})
             continue
-        if int(pin.get("k", 1)) != int(cur.get("k", 1)):
-            # scan workloads carry the fused K beside the capacity — a K
-            # change re-scales every cost, same incomparability as capacity
-            out.append({"kind": "capacity-drift", "workload": name,
-                        "message": f"{name}: scan dispatch K changed "
-                                   f"({pin.get('k', 1)} -> "
-                                   f"{cur.get('k', 1)}); costs are not "
-                                   f"comparable — re-pin with "
-                                   f"--update-baseline"})
-            continue
         for metric in ("flops", "bytes_accessed"):
             c, p = float(cur.get(metric, 0.0)), float(pin.get(metric, 0.0))
             if p <= 0.0:
@@ -601,8 +482,7 @@ def compare(current: dict, baseline: Optional[dict],
                                    f"the gate no longer measures — remove "
                                    f"via --update-baseline"})
     # proxy coverage: every registry kernel family + every extra gate family
-    # (names.py::PERF_PROXY_FAMILIES — the scan "dispatch" row) must have a
-    # proxy microbenchmark
+    # (names.py::PERF_PROXY_FAMILIES) must have a proxy microbenchmark
     if "proxy" in current:
         from ..observability.names import KERNELS, PERF_PROXY_FAMILIES
         for k in KERNELS + PERF_PROXY_FAMILIES:

@@ -3,12 +3,12 @@
 Every other static gate reasons about Python *source* (WF1xx config/spec
 validation, WF2xx invariant lint, WF26x concurrency).  This one walks the
 closed jaxprs of the programs that actually run on the chip — obtained via
-``jax.make_jaxpr`` over the same step/scan bodies ``CompiledChain.warm`` /
-``warm_scan`` trace (zero FLOPs, zero device: inputs are
-``jax.ShapeDtypeStruct``), recursing through ``scan``/``cond``/``while``/
-``pjit`` sub-jaxprs — and checks the invariants the whole system rests on
-(byte-identical replay, ordered effects inside scan bodies, "OFF path is
-byte-for-byte") where they actually live: in the traced equations.
+``jax.make_jaxpr`` over the same step body ``CompiledChain.warm`` traces
+(zero FLOPs, zero device: inputs are ``jax.ShapeDtypeStruct``), recursing
+through ``scan``/``cond``/``while``/``pjit`` sub-jaxprs — and checks the
+invariants the whole system rests on (byte-identical replay, ordered host
+effects, "OFF path is byte-for-byte") where they actually live: in the
+traced equations.
 
 ====== ========= =====================================================
 code   severity  invariant
@@ -24,9 +24,9 @@ WF300  error     order-dependent float accumulation in a deterministic-
                  or a sort-then-segment formulation
 WF301  error     unordered host effect in a compiled body: an
                  ``io_callback`` without a literal ``ordered=True`` (or
-                 a ``debug_callback`` without ``ordered=True``)
-                 reachable from a step/scan program — under scan-fused
-                 dispatch the K bodies' effects interleave freely; the
+                 a ``debug_callback``/``debug_print`` without
+                 ``ordered=True``) reachable from a step program — the
+                 effects of consecutive pushes interleave freely; the
                  jaxpr-level complement of the AST-only WF262, catching
                  aliased imports and wrapped call sites
 WF302  warning   host-sync in the per-push hot path: a callback
@@ -46,10 +46,10 @@ WF304  error     donated-buffer aliasing: a donated input is read by a
                  later equation (or returned) after the equation XLA
                  will alias it into, or is aliased into two outputs —
                  the classic donate_argnums use-after-free
-WF305  warning   shard/K-variant float reduction: a float-dtype
+WF305  warning   shard-variant float reduction: a float-dtype
                  ``reduce_sum``/``reduce_prod``/``cumsum``/
-                 ``dot_general`` in a program analyzed under dispatch
-                 K>1 or shards>1 — float addition is non-associative,
+                 ``dot_general`` in a program analyzed under
+                 shards>1 — float addition is non-associative,
                  so the reduction's grouping (and therefore the bytes)
                  can change with the composition geometry; the precise
                  static evidence needed to retire WF115 pairings one by
@@ -98,17 +98,15 @@ class Program:
     for — the unit every WF3xx rule runs over."""
 
     target: str              # audit-target label, e.g. "nexmark:q3"
-    kind: str                # "step" | "scan"
     closed: Any              # jax ClosedJaxpr
     capacity: int
-    k: int = 1               # fused dispatch K (kind == "scan")
     shards: int = 1          # shard count the program will run under
     replay: bool = False     # deterministic-replay (supervised) context
 
     @property
     def path(self) -> str:
         """Baseline identity path (the lint Finding ``path`` slot)."""
-        return f"{self.target}/{self.kind}"
+        return f"{self.target}/step"
 
 
 def abstract_batch(capacity: int, payload_spec) -> Any:
@@ -151,46 +149,17 @@ def trace_step(chain, capacity: int):
     return jax.make_jaxpr(step)(states, b)
 
 
-def trace_scan(chain, k: int, capacity: int):
-    """Closed jaxpr of the K-fused scan program — the same body
-    ``CompiledChain._scan_fn(0)`` jits (``lax.scan`` over the per-batch
-    step with operator states as carry), traced abstractly."""
-    states = _abstract_states(chain)
-    b = abstract_batch(capacity, chain.specs[0])
-    stacked = jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct((int(k),) + tuple(s.shape), s.dtype),
-        b)
-
-    def scan_step(states, stacked):
-        def body(carry, batch):
-            carry = list(carry)
-            for j in range(len(chain.ops)):
-                carry[j], batch = chain.ops[j].apply(carry[j], batch)
-            return tuple(carry), batch
-        return jax.lax.scan(body, tuple(states), stacked)
-
-    return jax.make_jaxpr(scan_step)(states, stacked)
-
-
-def chain_programs(chain, capacity: int = None, k: int = 1,
+def chain_programs(chain, capacity: int = None,
                    shards: int = 1, replay: bool = False,
                    target: str = "chain") -> List[Program]:
     """The programs a driver will actually dispatch for ``chain`` under the
-    given config: the per-push step, plus the K-fused scan when scan
-    dispatch is on (k > 1) — the ``warm``/``warm_scan`` surface."""
+    given config: the per-push step — the ``warm`` surface."""
     if capacity is None:
         from ..basic import DEFAULT_BATCH_SIZE
         from ..runtime.pipeline import resolve_batch_hint
         capacity = resolve_batch_hint(chain.ops) or DEFAULT_BATCH_SIZE
-    out = [Program(target=target, kind="step",
-                   closed=trace_step(chain, capacity),
-                   capacity=capacity, k=1, shards=shards, replay=replay)]
-    if k and int(k) > 1:
-        out.append(Program(target=target, kind="scan",
-                           closed=trace_scan(chain, int(k), capacity),
-                           capacity=capacity, k=int(k), shards=shards,
-                           replay=replay))
-    return out
+    return [Program(target=target, closed=trace_step(chain, capacity),
+                    capacity=capacity, shards=shards, replay=replay)]
 
 
 # ----------------------------------------------------------- jaxpr walking
@@ -246,7 +215,10 @@ def _aval_str(v) -> str:
 
 
 #: callback primitives that force a host round trip inside a compiled body
-_CALLBACK_PRIMS = ("io_callback", "debug_callback", "pure_callback")
+#: (``debug_print``: what ``jax.debug.print`` binds since jax 0.9; before, it
+#: was a ``debug_callback``)
+_CALLBACK_PRIMS = ("io_callback", "debug_callback", "debug_print",
+                   "pure_callback")
 
 #: float reductions whose result depends on accumulation grouping
 #: (max/min/and/or are associative-exact and never flagged)
@@ -291,23 +263,25 @@ def analyze_program(prog: Program) -> List[Finding]:
             out.append(_finding(
                 prog, "WF301", "error", n,
                 f"io_callback without ordered=True ({at}) in a compiled "
-                f"{prog.kind} body: under scan-fused dispatch the K "
-                f"bodies' host effects interleave freely, breaking "
-                f"byte-identical replay — pass ordered=True (the "
+                f"step body: the host effects of consecutive pushes "
+                f"interleave freely, breaking byte-identical replay — "
+                f"pass ordered=True (the "
                 f"jaxpr-level complement of WF262, which only sees "
                 f"direct AST call sites)",
                 text=f"io_callback unordered {at}"))
-        elif name == "debug_callback" \
-                and "OrderedDebug" not in str(eqn.params.get("effect", "")):
+        elif (name == "debug_callback"
+              and "OrderedDebug" not in str(eqn.params.get("effect", ""))) \
+                or (name == "debug_print"
+                    and eqn.params.get("ordered") is not True):
             flagged_301.add(id(eqn))
             out.append(_finding(
                 prog, "WF301", "error", n,
-                f"debug_callback without ordered=True ({at}) in a "
-                f"compiled {prog.kind} body: effect order is unspecified "
-                f"across fused scan iterations — pass "
+                f"{name} without ordered=True ({at}) in a "
+                f"compiled step body: effect order is unspecified "
+                f"across pushes — pass "
                 f"jax.debug.print(..., ordered=True) or drop it from the "
                 f"compiled path",
-                text=f"debug_callback unordered {at}"))
+                text=f"{name} unordered {at}"))
 
         # WF302 — host sync in the per-push hot path (skip eqns already
         # carrying the stronger WF301 verdict)
@@ -341,17 +315,15 @@ def analyze_program(prog: Program) -> List[Finding]:
                      f"{eqn.params.get('new_dtype')} {at}"))
 
         # WF305 — grouping-variant float reductions under composition
-        if (prog.k > 1 or prog.shards > 1) \
+        if prog.shards > 1 \
                 and name in _GROUPING_REDUCTIONS \
                 and any(_is_inexact(o.aval) for o in eqn.outvars):
-            geom = (f"dispatch K={prog.k}" if prog.k > 1 else "") + \
-                   (" and " if prog.k > 1 and prog.shards > 1 else "") + \
-                   (f"shards={prog.shards}" if prog.shards > 1 else "")
             out.append(_finding(
                 prog, "WF305", "warning", n,
                 f"{name} on {_aval_str(eqn.outvars[0])} ({at}) in a "
-                f"program composed under {geom}: float accumulation is "
-                f"non-associative, so a grouping change with the "
+                f"program composed under shards={prog.shards}: float "
+                f"accumulation is non-associative, so a grouping change "
+                f"with the "
                 f"composition geometry can change the bytes — the exact "
                 f"evidence WF115 retirement needs (prove the grouping "
                 f"fixed, cast to integer, or keep the pairing rejected)",
@@ -673,19 +645,18 @@ def _mk_chain(src, ops, capacity: int):
 
 
 def _nexmark_programs() -> List[Program]:
-    """The Nexmark query set: every query's step program, the K-fused scan
-    for the dispatch surface, and the q3 tiered variant (the host-callback
-    production path), all under replay semantics (every query runs under
-    the supervised drivers in tier-1)."""
+    """The Nexmark query set: every query's step program and the q3 tiered
+    variant (the host-callback production path), all under replay semantics
+    (every query runs under the supervised drivers in tier-1)."""
     from ..nexmark import queries as q
     out: List[Program] = []
     for name in q.QUERIES:
         src, ops = q.make_query(name, total=512)
         chain = _mk_chain(src, ops, 64)
-        out += chain_programs(chain, capacity=64, k=4, replay=True,
+        out += chain_programs(chain, capacity=64, replay=True,
                               target=f"nexmark:{name}")
     src, ops = q.q3_enrich_join(512, tiered=True)
-    out += chain_programs(_mk_chain(src, ops, 64), capacity=64, k=1,
+    out += chain_programs(_mk_chain(src, ops, 64), capacity=64,
                           replay=True, target="nexmark:q3_tiered")
     return out
 
@@ -696,7 +667,7 @@ def _ysb_programs() -> List[Program]:
     for label, mk in (("ysb", ysb.make_ops), ("ysb_wmr", ysb.make_ops_wmr)):
         src = ysb.make_source(total=2048)
         chain = _mk_chain(src, mk(), 1024)
-        out += chain_programs(chain, capacity=1024, k=4, replay=True,
+        out += chain_programs(chain, capacity=1024, replay=True,
                               target=f"bench:{label}")
     return out
 
@@ -747,7 +718,7 @@ def _mp_matrix_programs() -> List[Program]:
         if not isinstance(ops, (list, tuple)):
             ops = [ops]
         chain = _mk_chain(src, list(ops), 48)
-        out += chain_programs(chain, capacity=48, k=1, replay=True,
+        out += chain_programs(chain, capacity=48, replay=True,
                               target=f"mp:{label}")
     return out
 
@@ -777,7 +748,7 @@ def _example_programs() -> List[Program]:
            wf.KeyBy(lambda t: t.word, num_keys=VOCAB),
            wf.Accumulator(lambda t: t.data["one"], init_value=0,
                           num_keys=VOCAB)]
-    out += chain_programs(_mk_chain(src, ops, 64), capacity=64, k=1,
+    out += chain_programs(_mk_chain(src, ops, 64), capacity=64,
                           replay=True, target="example:wordcount")
     # 02 rides the YSB chains and 06 the nexmark q1 chain already audited;
     # 03/05 use the Key_FFAT/Win_Seq topologies the mp-matrix target owns.
@@ -789,7 +760,7 @@ def _example_programs() -> List[Program]:
                     total=4096, num_keys=8)
     op = wf.Key_FFAT(lambda t: t.v, jnp.add,
                      spec=WindowSpec(8, 4, win_type_t.CB), num_keys=8)
-    out += chain_programs(_mk_chain(src, [op], 256), capacity=256, k=1,
+    out += chain_programs(_mk_chain(src, [op], 256), capacity=256,
                           shards=2, replay=True, target="example:multichip")
     # 06 is the serving wrapper around a Pipeline chain, audited here via
     # its default echo graph
@@ -797,7 +768,7 @@ def _example_programs() -> List[Program]:
                     num_keys=8)
     out += chain_programs(
         _mk_chain(src, [wf.Map(lambda t: {"v": t.v * 2})], 64),
-        capacity=64, k=1, replay=True, target="example:serving_echo")
+        capacity=64, replay=True, target="example:serving_echo")
     return out
 
 

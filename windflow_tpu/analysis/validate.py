@@ -134,24 +134,12 @@ WF114  warn/err  tiered keyed state (``windflow_tpu/state``) combined
                  the probe kernel's blockable geometry (warning — the
                  ``_pallas_block`` gate routes the fused probe to the
                  XLA reference inside the call)
-WF110  warn/err  scan dispatch (K > 1) combined with a configuration
-                 the fused launch cannot honor: an unresolvable
-                 ``dispatch=``/``WF_DISPATCH`` (error);
-                 ``ids="sequence"`` tracing or a wall-clock admission
-                 bucket under supervision (error — the re-formed
-                 groups of a replay would fuse different batches /
-                 mint fresh ids, mirroring WF105/WF108); K exceeding
-                 a ring's capacity (warning, the WF106 shape — a full
-                 fused group can never be ring-resident, so the
-                 consumer always flushes short on the linger)
 WF115  warn/err  shard-local supervision (``shards=``/``WF_SHARDS``)
                  combined with a configuration its per-shard recovery
                  contract cannot honor: an unresolvable shard count or
-                 re-sharding plan (error); scan dispatch K > 1 (error
-                 — a fused group failure has no single shard's replay
-                 extent); tiered keyed state (error — one process-wide
-                 HostStore per operator, a shard restore could roll
-                 back peers); wall-clock admission or sequence-id
+                 re-sharding plan (error); tiered keyed state (error
+                 — one process-wide HostStore per operator, a shard
+                 restore could roll back peers); wall-clock admission or sequence-id
                  tracing under sharded supervision (error, the
                  WF105/WF108 mirror); a re-sharding plan whose move
                  targets a nonexistent shard (error); more shards than
@@ -416,8 +404,8 @@ _SHARD_SITES = frozenset({"shard.kill", "reshard.handoff"})
 
 
 def _check_shards(report, shards_arg, reshard_arg, ops, cfg, trace,
-                  stored_trace, dispatch, stored_dispatch, faults,
-                  where: str, shard_key=None) -> None:
+                  stored_trace, faults, where: str,
+                  shard_key=None) -> None:
     """WF115: shard-local supervision (``runtime/supervisor.py``
     ``ShardedSupervisor``) against configurations its per-shard recovery /
     deterministic re-sharding contracts cannot honor."""
@@ -472,20 +460,6 @@ def _check_shards(report, shards_arg, reshard_arg, ops, cfg, trace,
                             "runs only under sharded supervision")
         return
     # -- sharded: composition checks --------------------------------------
-    from ..runtime.dispatch import DispatchConfig
-    try:
-        dcfg = (DispatchConfig.resolve(dispatch) if dispatch is not None
-                else DispatchConfig.resolve(stored_dispatch))
-    except (ValueError, TypeError):
-        dcfg = None                        # already a WF110 error
-    if dcfg is not None and dcfg.k > 1:
-        report.add(
-            "WF115", "error", f"{where}:shards",
-            f"shards={n} does not compose with scan dispatch (K={dcfg.k}): "
-            f"a fused group failure cannot be attributed to one shard's "
-            f"replay extent",
-            hint="drop dispatch=/WF_DISPATCH (per-shard pushes amortize "
-                 "dispatch across shards already), or run shards=1")
     tiered = [op.getName() for op in ops
               if getattr(op, "_tier_cfg", None) is not None]
     if tiered:
@@ -1084,70 +1058,6 @@ def _check_prefetch(report, prefetch: int, first_edge) -> None:
             hint="size prefetch <= the src edge's queue_capacity")
 
 
-def _check_dispatch(report, dispatch, stored_arg, cfg, trace, stored_trace,
-                    supervised: bool, edges=None) -> None:
-    """WF110: scan dispatch (``runtime/dispatch.py``) against configurations
-    the K-fused launch cannot honor — resolved exactly as the driver will
-    (explicit ``dispatch=`` wins, else the object's stored argument /
-    ``WF_DISPATCH``), the WF105/WF108 convention."""
-    from ..runtime.dispatch import DispatchConfig
-    try:
-        dcfg = DispatchConfig.resolve(dispatch if dispatch is not None
-                                      else stored_arg)
-    except (ValueError, TypeError, OSError) as e:
-        report.add("WF110", "error", "dispatch",
-                   f"dispatch config does not resolve: "
-                   f"{type(e).__name__}: {e}",
-                   hint="dispatch= accepts None/bool/int K/dict/"
-                        "DispatchConfig; WF_DISPATCH_K must be a positive "
-                        "integer")
-        return
-    if dcfg is None or dcfg.k <= 1:
-        return
-    if supervised:
-        from ..observability.tracing import TraceConfig
-        try:
-            tcfg = TraceConfig.resolve(trace if trace is not None
-                                       else stored_trace)
-        except (ValueError, TypeError):
-            tcfg = None                # already diagnosed as WF108
-        if tcfg is not None and tcfg.ids != "position":
-            report.add(
-                "WF110", "error", "dispatch",
-                f"dispatch k={dcfg.k} with trace ids={tcfg.ids!r} under "
-                f"supervision: per-batch spans are synthesized from each "
-                f"fused launch, and sequence ids come from a process-global "
-                f"counter — a replay after restore re-forms the groups but "
-                f"mints fresh ids for them, orphaning every exemplar "
-                f"recorded before the failure",
-                hint="use TraceConfig(ids='position') (the default) so span "
-                     "ids are a pure function of stream position, the same "
-                     "contract the accumulator's count-based flush follows")
-        if (cfg is not None and cfg.admission
-                and cfg.refill_per_batch is None):
-            report.add(
-                "WF110", "error", "dispatch",
-                f"dispatch k={dcfg.k} with wall-clock admission (rate_tps) "
-                f"under supervision: group boundaries are count-based so "
-                f"replay re-forms them, but the wall-clock refill timeline "
-                f"shifts on restore — the re-formed groups would fuse "
-                f"DIFFERENT batches than the original run",
-                hint="use ControlConfig(refill_per_batch=...) — positional "
-                     "admission keeps the admitted stream (and therefore "
-                     "every fused group) a pure function of position")
-    for label, cap in (edges or []):
-        if dcfg.k > cap:
-            report.add(
-                "WF110", "warning", f"edge[{label}]",
-                f"dispatch k={dcfg.k} exceeds ring capacity {cap}: a full "
-                f"fused group can never be resident in the ring at once, so "
-                f"the consumer flushes short on the linger nearly every "
-                f"group — the (K, capacity) executable is traced and warmed "
-                f"but rarely runs at full K",
-                hint="size queue_capacity >= dispatch k (room for one full "
-                     "group) or lower k for this topology")
-
-
 def _check_tiered(report, ops, cfg, trace, stored_trace,
                   supervised: bool, where_prefix: str) -> None:
     """WF114: tiered keyed state (``windflow_tpu/state``) against
@@ -1394,7 +1304,7 @@ def _validate_chain_ops(report, ops, in_spec, in_cap, where: str,
 
 
 def _validate_pipeline(report, p, faults, control, supervised,
-                       trace=None, dispatch=None) -> None:
+                       trace=None) -> None:
     cfg = _resolve_control(control, getattr(p, "_control", None))
     in_spec = _source_spec(report, p.source, f"source:{p.source.getName()}")
     if in_spec is None:
@@ -1417,12 +1327,10 @@ def _validate_pipeline(report, p, faults, control, supervised,
     _check_remediation(report, getattr(p, "_monitoring_arg", None), cfg)
     _check_serving(report, getattr(p, "_serving_arg", None),
                    getattr(p, "_monitoring_arg", None), supervised)
-    _check_dispatch(report, dispatch, getattr(p, "_dispatch_arg", None), cfg,
-                    trace, getattr(p, "_trace_arg", None), supervised)
 
 
 def _validate_supervised(report, sp, faults, control, trace=None,
-                         dispatch=None, shards=None, reshard=None,
+                         shards=None, reshard=None,
                          shard_key=None) -> None:
     cfg = _resolve_control(control, getattr(sp, "_control", None))
     in_spec = _source_spec(report, sp.source,
@@ -1446,15 +1354,12 @@ def _validate_supervised(report, sp, faults, control, trace=None,
     _check_remediation_supervised(report, sp)
     _check_serving(report, getattr(sp, "_serving_arg", None),
                    getattr(sp, "_monitoring_arg", None), True)
-    _check_dispatch(report, dispatch, getattr(sp, "_dispatch_arg", None),
-                    cfg, trace, getattr(sp, "_trace_arg", None), True)
     _check_shards(report,
                   shards if shards is not None
                   else getattr(sp, "_shards", None),
                   reshard if reshard is not None
                   else getattr(sp, "_reshard_arg", None),
                   sp.chain.ops, cfg, trace, getattr(sp, "_trace_arg", None),
-                  dispatch, getattr(sp, "_dispatch_arg", None),
                   faults if faults is not None
                   else getattr(sp, "_faults_arg", None), "supervised",
                   shard_key=(shard_key if shard_key is not None
@@ -1462,7 +1367,7 @@ def _validate_supervised(report, sp, faults, control, trace=None,
 
 
 def _validate_threaded(report, tp, faults, control, supervised,
-                       trace=None, dispatch=None) -> None:
+                       trace=None) -> None:
     cfg = _resolve_control(control, getattr(tp, "_control", None))
     spec = _source_spec(report, tp.source,
                         f"source:{tp.source.getName()}")
@@ -1505,9 +1410,6 @@ def _validate_threaded(report, tp, faults, control, supervised,
     _check_remediation(report, getattr(tp, "_monitoring_arg", None), cfg)
     _check_serving(report, getattr(tp, "_serving_arg", None),
                    getattr(tp, "_monitoring_arg", None), supervised)
-    _check_dispatch(report, dispatch, getattr(tp, "_dispatch_arg", None),
-                    cfg, trace, getattr(tp, "_trace_arg", None), supervised,
-                    edges=edges)
 
 
 def _graph_edges(g):
@@ -1538,7 +1440,7 @@ def _check_graph_edges(report, g, cfg) -> None:
 
 
 def _validate_graph(report, g, faults, control, supervised,
-                    threaded, trace=None, dispatch=None, shards=None,
+                    threaded, trace=None, shards=None,
                     reshard=None, shard_key=None) -> None:
     from ..basic import DEFAULT_BATCH_SIZE
     from ..control import ControlConfig
@@ -1622,23 +1524,13 @@ def _validate_graph(report, g, faults, control, supervised,
     _check_remediation(report, getattr(g, "_monitoring_arg", None), cfg)
     _check_serving(report, getattr(g, "_serving_arg", None),
                    getattr(g, "_monitoring_arg", None), supervised)
-    dedges = None
-    if threaded:
-        try:
-            dedges = _graph_edges(g)
-        except Exception:  # noqa: BLE001 — already a WF104 error above
-            dedges = None
-    _check_dispatch(report, dispatch, getattr(g, "_dispatch_arg", None),
-                    cfg, trace, getattr(g, "_trace_arg", None), supervised,
-                    edges=dedges)
     if supervised:
         # run unconditionally: shards=None consults WF_SHARDS inside
         # _check_shards (the run_graph_supervised resolution) — an
         # env-driven sharded run must get the same WF115 coverage as an
         # explicit one
         _check_shards(report, shards, reshard, g._operators, cfg, trace,
-                      getattr(g, "_trace_arg", None), dispatch,
-                      getattr(g, "_dispatch_arg", None), faults, "graph",
+                      getattr(g, "_trace_arg", None), faults, "graph",
                       shard_key=shard_key)
 
 
@@ -1654,9 +1546,8 @@ def _validate_compiled_chain(report, chain, faults, control,
         _check_trace(report, trace, None, supervised)
 
 
-def _check_progcheck(report, obj, progcheck, dispatch, supervised,
-                     shards) -> None:
-    """WF300-WF305: trace the driver's built-but-not-run step/scan
+def _check_progcheck(report, obj, progcheck, supervised, shards) -> None:
+    """WF300-WF305: trace the driver's built-but-not-run step
     programs (``analysis/progcheck.py`` — zero FLOPs, zero device) and
     append the device-program findings, baseline-suppressed like the CLI.
 
@@ -1671,7 +1562,6 @@ def _check_progcheck(report, obj, progcheck, dispatch, supervised,
         return
     try:
         from . import progcheck as pc
-        from ..runtime.dispatch import DispatchConfig
         chains = []
         if getattr(obj, "chain", None) is not None:
             chains.append(("chain", obj.chain))
@@ -1682,17 +1572,13 @@ def _check_progcheck(report, obj, progcheck, dispatch, supervised,
             chains.append(("chain", obj))        # a raw CompiledChain
         if not chains:
             return
-        dcfg = DispatchConfig.resolve(
-            dispatch if dispatch is not None
-            else getattr(obj, "_dispatch_arg", None))
-        k = dcfg.k if dcfg is not None else 1
         from ..parallel.sharding import resolve_shards
         n_shards = resolve_shards(shards if shards is not None
                                   else getattr(obj, "_shards", None)) or 1
         programs = []
         for label, chain in chains:
             programs += pc.chain_programs(
-                chain, k=k, shards=n_shards,
+                chain, shards=n_shards,
                 replay=bool(supervised), target=label)
         findings = pc.analyze_programs(programs)
         counts, _problems = pc.load_baseline(pc.baseline_path())
@@ -1702,8 +1588,8 @@ def _check_progcheck(report, obj, progcheck, dispatch, supervised,
         return
 
 
-def _validate_serving_runtime(report, rt, faults, control, trace=None,
-                              dispatch=None) -> None:
+def _validate_serving_runtime(report, rt, faults, control,
+                              trace=None) -> None:
     """A ServingRuntime is a Pipeline to the spec-flow checks, plus the
     WF119 serving checks over its ALREADY-resolved config (construction
     raised on fatal problems; the report re-derives them for tooling) and
@@ -1735,7 +1621,7 @@ def _validate_serving_runtime(report, rt, faults, control, trace=None,
 
 
 def validate(obj, *, faults=None, control=None, supervised: bool = None,
-             threaded: bool = False, trace=None, dispatch=None,
+             threaded: bool = False, trace=None,
              shards=None, reshard=None, shard_key=None,
              progcheck: bool = None) -> ValidationReport:
     """Validate a built-but-not-run driver object; returns a
@@ -1761,11 +1647,6 @@ def validate(obj, *, faults=None, control=None, supervised: bool = None,
     stored ``trace=`` argument for the WF108 determinism checks; ``None``
     consults the stored argument and ``WF_TRACE`` (mirroring the drivers).
 
-    ``dispatch``: a ``DispatchConfig``/bool/int K/dict overriding the
-    object's own stored ``dispatch=`` argument for the WF110 scan-dispatch
-    checks; ``None`` consults the stored argument and ``WF_DISPATCH``
-    (mirroring the drivers).
-
     ``shards``/``reshard``/``shard_key``: the shard count, re-sharding
     plan, and ownership-key override destined for the sharded supervisors,
     for the WF115 checks — a ``SupervisedPipeline`` consults its own
@@ -1775,9 +1656,8 @@ def validate(obj, *, faults=None, control=None, supervised: bool = None,
 
     ``progcheck``: run the device-program analyzer (WF300-WF305,
     ``analysis/progcheck.py``) over the object's built-but-not-run
-    step/scan programs under the resolved dispatch K / shard / supervision
-    config; ``None`` consults ``WF_PROGCHECK`` (default on, ``'0'``
-    disables). Skipped when the report already has errors."""
+    step programs under the resolved shard / supervision config; ``None``
+    consults ``WF_PROGCHECK`` (default on, ``'0'`` disables). Skipped when the report already has errors."""
     from ..runtime.pipegraph import PipeGraph
     from ..runtime.pipeline import CompiledChain, Pipeline
     from ..runtime.supervisor import SupervisedPipeline
@@ -1786,25 +1666,23 @@ def validate(obj, *, faults=None, control=None, supervised: bool = None,
 
     if isinstance(obj, ServingRuntime):
         report = ValidationReport("ServingRuntime")
-        _validate_serving_runtime(report, obj, faults, control,
-                                  trace, dispatch)
+        _validate_serving_runtime(report, obj, faults, control, trace)
     elif isinstance(obj, PipeGraph):
         report = ValidationReport(f"PipeGraph({obj.name!r})")
         _validate_graph(report, obj, faults, control, bool(supervised),
-                        threaded, trace, dispatch, shards, reshard,
-                        shard_key)
+                        threaded, trace, shards, reshard, shard_key)
     elif isinstance(obj, SupervisedPipeline):
         report = ValidationReport("SupervisedPipeline")
-        _validate_supervised(report, obj, faults, control, trace, dispatch,
+        _validate_supervised(report, obj, faults, control, trace,
                              shards, reshard, shard_key)
     elif isinstance(obj, ThreadedPipeline):
         report = ValidationReport("ThreadedPipeline")
         _validate_threaded(report, obj, faults, control, bool(supervised),
-                           trace, dispatch)
+                           trace)
     elif isinstance(obj, Pipeline):
         report = ValidationReport("Pipeline")
         _validate_pipeline(report, obj, faults, control, bool(supervised),
-                           trace, dispatch)
+                           trace)
     elif isinstance(obj, CompiledChain):
         report = ValidationReport("CompiledChain")
         _validate_compiled_chain(report, obj, faults, control,
@@ -1817,7 +1695,7 @@ def validate(obj, *, faults=None, control=None, supervised: bool = None,
                    f"SupervisedPipeline, ServingRuntime, or CompiledChain")
         return report
     _check_kernel_records(report)
-    _check_progcheck(report, obj, progcheck, dispatch,
+    _check_progcheck(report, obj, progcheck,
                      supervised if supervised is not None
                      else isinstance(obj, SupervisedPipeline),
                      shards)

@@ -233,39 +233,6 @@ def concat_batches(a: Batch, b: Batch) -> Batch:
     )
 
 
-def stack_batches(batches) -> Batch:
-    """Stack K same-capacity batches along a NEW leading axis: every leaf
-    ``[C, ...]`` becomes ``[K, C, ...]``. The scan-dispatch transport
-    (``CompiledChain.push_many``): the stacked pytree is the ``xs`` of a
-    ``lax.scan`` over the per-batch step, so K batches ride ONE host dispatch.
-    Inverse of :func:`unstack_batches`; lane content is preserved verbatim
-    (stack/unstack is a pure reshape-move, so scanned results are
-    byte-identical to K sequential pushes). The host-side trace sidecar does
-    NOT ride along (same stance as ``split_batch``/``concat_batches``):
-    drivers re-attach ids to the unstacked outputs with ``tracing.carry``."""
-    batches = list(batches)
-    if not batches:
-        raise ValueError("stack_batches: need at least one batch")
-    c0 = batches[0].capacity
-    for b in batches[1:]:
-        if b.capacity != c0:
-            raise ValueError(
-                f"stack_batches: mixed capacities {c0} vs {b.capacity} — a "
-                f"scanned executable is traced for ONE (K, capacity) shape; "
-                f"the MicrobatchAccumulator groups same-capacity runs")
-    return jax.tree.map(lambda *xs: jnp.stack(xs, axis=0), *batches)
-
-
-def unstack_batches(stacked: Batch, k: int = None) -> list:
-    """Split a stacked batch (leaves ``[K, C, ...]``) back into K capacity-C
-    batches — the inverse of :func:`stack_batches`, applied to the stacked
-    ``ys`` a scanned dispatch returns."""
-    leaves = jax.tree.leaves(stacked)
-    if k is None:
-        k = leaves[0].shape[0]
-    return [jax.tree.map(lambda a: a[i], stacked) for i in range(k)]
-
-
 def split_batch(batch: Batch, capacity: int) -> list:
     """Slice a batch into ``capacity``-sized pieces along the capacity axis —
     the inverse of :func:`concat_batches` and the counterpart of the reference

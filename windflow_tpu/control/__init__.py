@@ -44,7 +44,7 @@ from .admission import (AdmissionController, PositionBucket, TokenBucket,
                         bucket_from_config)
 from .autotune import (CapacityAutotuner, Rebatcher, TuningCache,
                        build_ladder, chain_signature, device_kind,
-                       dispatch_tuning_key, payload_signature, tuning_key)
+                       payload_signature, tuning_key)
 from .config import ControlConfig
 from .governor import BackpressureGovernor, governor_from_config
 from .remediation import (ACTUATORS, BarrierRemediation, RemediationAction,
@@ -57,7 +57,7 @@ __all__ = [
     "ControlConfig", "AdmissionController", "TokenBucket", "PositionBucket",
     "BackpressureGovernor", "CapacityAutotuner", "Rebatcher", "TuningCache",
     "build_ladder", "chain_signature", "payload_signature", "device_kind",
-    "tuning_key", "dispatch_tuning_key", "admission_from_config",
+    "tuning_key", "admission_from_config",
     "admission_group", "bucket_from_config", "governor_from_config",
     "RemediationAction", "RemediationPolicy", "RemediationEngine",
     "BarrierRemediation", "ACTUATORS", "default_policy", "resolve_policy",

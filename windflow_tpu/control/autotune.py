@@ -173,14 +173,6 @@ def kernel_tuning_key(kernel: str, spec_key: str, device: str) -> str:
     return tuning_key(f"kernel:{kernel}", spec_key, device)
 
 
-def dispatch_tuning_key(chain_sig: str, payload_sig: str, device: str) -> str:
-    """Cache key for the scan-dispatch K winner (``runtime/dispatch.py``):
-    the capacity key's (chain, payload, device) coordinates under a
-    ``dispatch:`` namespace, so K plans and capacity plans for the SAME chain
-    live side by side in one cache file."""
-    return tuning_key(f"dispatch:{chain_sig}", payload_sig, device)
-
-
 class TuningCache:
     """JSON file of winning plans, read-merge-atomic-replace on ``put``; a
     corrupt/missing file reads empty. Two entry kinds share the store:
@@ -246,8 +238,7 @@ class CapacityAutotuner:
                  decide_every: int = 8, settle_batches: int = 2,
                  improve_threshold: float = 0.05, clock=time.monotonic,
                  cache: Optional[TuningCache] = None,
-                 cache_key: Optional[str] = None, name: str = "",
-                 gauge: str = "chosen_capacity"):
+                 cache_key: Optional[str] = None, name: str = ""):
         if not ladder:
             raise ValueError("empty capacity ladder")
         self.ladder = sorted(int(c) for c in ladder)
@@ -258,10 +249,6 @@ class CapacityAutotuner:
         self.cache = cache
         self.cache_key = cache_key
         self.name = name
-        #: control gauge this tuner publishes its chosen rung under — the
-        #: capacity ladder's "chosen_capacity", or "dispatch_k" when the SAME
-        #: hill-climber is pointed at a scan-dispatch K ladder
-        self.gauge = gauge
         self.converged = False
         self.decisions = 0
         self._rates = {}                      # capacity -> tuples/s
@@ -279,7 +266,7 @@ class CapacityAutotuner:
                                 capacity=seed, key=cache_key)
         self.capacity = seed
         self._seed = seed
-        _state.set_gauge(self.gauge, self.capacity)
+        _state.set_gauge("chosen_capacity", self.capacity)
         # measurement window
         self._settle = self.settle_batches
         self._win_batches = 0
@@ -327,7 +314,7 @@ class CapacityAutotuner:
             return None
         self.capacity = capacity
         _state.bump("capacity_switches")
-        _state.set_gauge(self.gauge, capacity)
+        _state.set_gauge("chosen_capacity", capacity)
         _journal.record("capacity_switch", tuner=self.name, capacity=capacity)
         self._settle = self.settle_batches
         return capacity

@@ -15,14 +15,14 @@ premise of arXiv:2306.11686). Four pieces:
   snapshot's ``health`` section and the ``windflow_hbm_headroom_bytes``
   Prometheus gauge.
 - **Compile/retrace ledger** (:class:`HealthLedger`): every trace of a
-  ``CompiledChain`` step/scan program is journaled (``compile`` events with
+  ``CompiledChain`` step program is journaled (``compile`` events with
   cause, cache key, compile duration, AOT cost-analysis flops/bytes), with an
   unexpected-retrace detector — a re-trace under an already-traced cache key
   means a warm executable was silently recompiled (the live complement of the
   WF102 weak-type and WF109 stale-impl diagnostics) and raises a counter plus
   a ``retrace_unexpected`` journal event.
 - **Device-time attribution**: the sampled ``block_until_ready`` points in
-  ``CompiledChain.push``/``push_many`` split each sample into host-dispatch
+  ``CompiledChain.push`` split each sample into host-dispatch
   time vs device time per stage label; the per-stage ratio is the
   *dispatch-bound classifier* that names fusion candidates for whole-graph
   single-dispatch (ROADMAP item 2).
@@ -129,8 +129,8 @@ class HealthLedger:
 
     def set_cause(self, cause: str) -> None:
         """Default cause for compiles noted on this thread (``push`` /
-        ``push_many`` / ``warm`` / ``warm_scan``); a :func:`cause` context
-        override (``autotune_prewarm``) wins."""
+        ``warm``); a :func:`cause` context override (``autotune_prewarm``)
+        wins."""
         self._tls.cause = cause
 
     def _current_cause(self) -> str:
@@ -147,9 +147,8 @@ class HealthLedger:
             + (1 if on else -1)
 
     def note_trace(self, label: str, from_op: int, kind: str, sig: str,
-                   capacity: Optional[int] = None,
-                   k: Optional[int] = None) -> None:
-        """One jit trace of a chain step/scan program observed.  Classifies
+                   capacity: Optional[int] = None) -> None:
+        """One jit trace of a chain step program observed.  Classifies
         it (fresh compile / shape retrace / unexpected same-signature
         retrace), journals the detector event, and parks a pending record
         for the caller to finish with duration + AOT cost once the traced
@@ -173,8 +172,6 @@ class HealthLedger:
                "retrace": retrace, "unexpected": unexpected}
         if capacity is not None:
             rec["capacity"] = int(capacity)
-        if k is not None and int(k) > 1:
-            rec["k"] = int(k)
         if unexpected:
             # the detector event fires immediately (the compile record
             # follows once the call returns with its duration): a warm

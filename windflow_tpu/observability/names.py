@@ -26,11 +26,8 @@ from __future__ import annotations
 JOURNAL_EVENTS = (
     # observability lifecycle (observability/__init__.py Monitor)
     "monitoring_start", "monitoring_end",
-    # compiled-chain hot path (runtime/pipeline.py, sampled): per-batch
-    # "launch", and "dispatch_fused" for a sampled K-batch scan dispatch
-    # (runtime/dispatch.py scan dispatcher; k= says how many batches rode
-    # the one compiled program)
-    "launch", "dispatch_fused",
+    # compiled-chain hot path (runtime/pipeline.py): a sampled push
+    "launch",
     # EOS protocol (runtime/pipeline.py, runtime/pipegraph.py)
     "eos", "eos_flush", "eos_propagate",
     # ordering buffer (parallel/ordering.py, via its _journal_release wrapper)
@@ -56,7 +53,7 @@ JOURNAL_EVENTS = (
     "lateness_drop",
     # runtime health ledger (observability/device_health.py, health
     # monitoring only): "compile" = one jit trace of a CompiledChain
-    # step/scan program (cause, cache key, compile duration, AOT cost
+    # step program (cause, cache key, compile duration, AOT cost
     # flops/bytes); "retrace_unexpected" = the live retrace detector — a
     # warm executable re-traced under an ALREADY-TRACED signature (jit
     # cache eviction/clear, the WF102/WF109 hazard caught at runtime);
@@ -157,10 +154,6 @@ CONTROL_COUNTERS = (
 #: ``windflow_control_<name>``)
 CONTROL_GAUGES = (
     "chosen_capacity",
-    # scan dispatch (runtime/dispatch.py MicrobatchAccumulator + the
-    # autotuner's K ladder): batches buffered awaiting a fused launch, and
-    # the K rung the dispatch tuner currently runs
-    "dispatch_linger_depth", "dispatch_k",
     # versioned join-state table (ops/lookup.py join_table_*): applied
     # upsert count of the most recently synced table (last-write-wins
     # across tables, the chosen_capacity convention)
@@ -379,12 +372,8 @@ KERNELS = (
 
 #: non-kernel proxy-microbench families the hermetic perf gate must ALSO
 #: cover (``analysis/perfgate.py::compare``: a family without a proxy row is
-#: a coverage finding, the KERNELS convention). "dispatch" times the scan
-#: dispatcher's fused ``push_many`` launch and carries its jit-boundary
-#: launch counts — the 1-executable-call-per-K-batches amortization claim
-#: ``tests/test_perfgate.py`` asserts.
+#: a coverage finding, the KERNELS convention).
 PERF_PROXY_FAMILIES = (
-    "dispatch",
     # "join" times the full versioned JoinTable step (upsert + registry
     # probe, ops/lookup.py join_table_*) — the probe kernels keep their
     # microbench or tests/test_perfgate.py fails coverage

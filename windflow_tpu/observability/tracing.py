@@ -376,23 +376,14 @@ class Tracer:
             return
         self._seg().add((time.perf_counter(), tid, stage, kind, None))
 
-    def service(self, batch, stage: str,
-                k: Optional[int] = None) -> Optional[_ServiceSpan]:
+    def service(self, batch, stage: str) -> Optional[_ServiceSpan]:
         """Open a service span for a traced batch; the caller invokes
-        ``.done()`` after the stage's work.  None for untraced batches.
-
-        ``k`` marks FUSED-GROUP membership (scan dispatch, ``WF_DISPATCH``
-        with K>1): all K member spans cover the same one compiled launch, so
-        the begin record carries ``k`` and the report's per-batch service
-        attribution divides the span by it — without the marker a fused
-        group would charge its whole service span to every member and the
-        stage breakdown would overcount K-fold."""
+        ``.done()`` after the stage's work.  None for untraced batches."""
         tid = getattr(batch, TRACE_META_ATTR, None)
         if tid is None:
             return None
         seg = self._seg()
-        extra = {"k": int(k)} if k is not None and k > 1 else None
-        seg.add((time.perf_counter(), tid, stage, K_BEGIN, extra))
+        seg.add((time.perf_counter(), tid, stage, K_BEGIN, None))
         seg.open_spans.append((tid, stage))
         return _ServiceSpan(self, seg, tid, stage)
 
@@ -489,11 +480,10 @@ def event(batch, stage: str, kind: str) -> None:
         tr.event(batch, stage, kind)
 
 
-def service(batch, stage: str, k: Optional[int] = None
-            ) -> Optional[_ServiceSpan]:
+def service(batch, stage: str) -> Optional[_ServiceSpan]:
     tr = _active
     if tr is not None:
-        return tr.service(batch, stage, k=k)
+        return tr.service(batch, stage)
     return None
 
 
@@ -669,8 +659,6 @@ def to_chrome_trace(records: List[dict], journal_events: Optional[list] = None,
             if b is None:
                 continue                  # end without begin (ring wrapped)
             args: Dict[str, Any] = {"trace_id": hex(tid)}
-            if b.get("k"):
-                args["fused_k"] = b["k"]  # scan-dispatch group membership
             if r.get("aborted"):
                 args["aborted"] = r["aborted"]
             tk = track(stage)
@@ -751,13 +739,7 @@ def to_chrome_trace(records: List[dict], journal_events: Optional[list] = None,
 
 def _batch_lifecycles(records: List[dict]) -> Dict[int, dict]:
     """Fold records into per-trace-id lifecycles: ingest time, end time,
-    per-stage service durations, per-edge queue waits, aborted-span count.
-
-    Fused-dispatch apportionment: a span whose begin record carries ``k``
-    (scan dispatch, K>1) covers ONE compiled launch shared by K group
-    members, so each member is charged ``span / k`` — the per-batch drill-
-    down stays honest under ``WF_DISPATCH`` instead of charging the whole
-    group service span to every member."""
+    per-stage service durations, per-edge queue waits, aborted-span count."""
     out: Dict[int, dict] = {}
 
     def life(tid):
@@ -766,14 +748,14 @@ def _batch_lifecycles(records: List[dict]) -> Dict[int, dict]:
             lc = out[tid] = {"tid": tid, "pos": None, "stream": None,
                              "t_ingest": None, "t_end": None,
                              "service": {}, "queue": {}, "aborts": 0,
-                             "attempts": {}, "fused": 0,
+                             "attempts": {},
                              # wire-to-sink coordinates (serving ingest
                              # extras; None for non-serving drivers)
                              "tenant": None, "seq": None,
                              "wire_ms": None, "queue_ms": None}
         return lc
 
-    open_begin: Dict[tuple, tuple] = {}    # (tid, stage) -> (t, k or None)
+    open_begin: Dict[tuple, float] = {}    # (tid, stage) -> t
     enq_at: Dict[tuple, float] = {}
     for r in sorted(records, key=lambda x: x["t"]):
         tid, stage, kind, t = r["tid"], r["stage"], r["kind"], r["t"]
@@ -791,17 +773,13 @@ def _batch_lifecycles(records: List[dict]) -> Dict[int, dict]:
                 lc["wire_ms"] = r.get("wire_ms")
                 lc["queue_ms"] = r.get("queue_ms")
         elif kind == K_BEGIN:
-            open_begin[(tid, stage)] = (t, r.get("k"))
+            open_begin[(tid, stage)] = t
             lc["attempts"][stage] = lc["attempts"].get(stage, 0) + 1
         elif kind == K_END:
-            b = open_begin.pop((tid, stage), None)
-            if b is not None:
-                t0, k = b
-                dur = t - t0
-                if k and int(k) > 1:
-                    dur /= int(k)         # fused group: this batch's share
-                    lc["fused"] += 1
-                lc["service"][stage] = lc["service"].get(stage, 0.0) + dur
+            t0 = open_begin.pop((tid, stage), None)
+            if t0 is not None:
+                lc["service"][stage] = (lc["service"].get(stage, 0.0)
+                                        + t - t0)
             if r.get("aborted"):
                 lc["aborts"] += 1
         elif kind == K_ENQ:
@@ -1020,9 +998,6 @@ def critical_path_report(records: List[dict],
 
     def flags(lc) -> str:
         f = []
-        if lc.get("fused"):
-            # service figures are the batch's 1/k share of fused launches
-            f.append("FUSED")
         if _is_shed(lc):
             f.append("SHED")
         if lc["pos"] in dead_pos:

@@ -638,9 +638,9 @@ def join_table_tier_resolve(state: dict, keys: jax.Array, ok: jax.Array,
     ob_m = (jnp.take(state["over"], oidxc), jnp.take(state["ovid"], oidxc),
             jnp.take(state["ovseq"], oidxc))
     # cold-tier lookup: ONE ordered host callback for the still-missing
-    # keys (ordered => scan-fused dispatch and supervised replay walk the
-    # identical sequence; an all-False mask is a host no-op, so warm()'s
-    # functional dry-runs never touch the store). Duplicate lanes look up
+    # keys (ordered => supervised replay walks the identical sequence; an
+    # all-False mask is a host no-op, so warm()'s functional dry-runs never
+    # touch the store). Duplicate lanes look up
     # independently (same row) — only ADMISSION dedups.
     need_host = need & ~in_ob
     shapes = ([jax.ShapeDtypeStruct((R,), jnp.bool_)]

@@ -287,7 +287,7 @@ class PipeGraph:
 
     def __init__(self, name: str = "pipegraph", mode: Mode = Mode.DEFAULT,
                  batch_size: int = None, monitoring=None, control=None,
-                 queue_capacity=8, trace=None, dispatch=None):
+                 queue_capacity=8, trace=None):
         self.name = name
         self.mode = mode
         #: None = resolve at start(): min withBatch hint over registered
@@ -322,15 +322,6 @@ class PipeGraph:
         #: int for all, a dict keyed by edge label ("src->2", "0->1", by
         #: consumer pipe index), or a callable (label, index) -> int.
         self.queue_capacity = queue_capacity
-        #: scan-dispatch opt-in (mirrors monitoring=/control=): None =
-        #: consult WF_DISPATCH; resolved at start(). The push driver buffers
-        #: root batches in arrival order, fuses each root's run as one
-        #: compiled scan (up to K), and delivers outputs in the original
-        #: interleave — downstream split/merge hops stay per-batch, in the
-        #: per-batch order.
-        self._dispatch_arg = dispatch
-        # resolved by start() on the driver before any body thread spawns
-        self._dispatch = None         # wf-lint: single-writer[driver]
         self._e2e_t0 = None           # in-flight e2e latency sample start
         # graph build is driver-only; bodies and the reporter only iterate
         self._roots: List[MultiPipe] = []  # wf-lint: single-writer[driver]
@@ -380,9 +371,6 @@ class PipeGraph:
         if self._control is None:
             from ..control import ControlConfig
             self._control = ControlConfig.resolve(self._control_arg)
-        if self._dispatch is None:
-            from .dispatch import DispatchConfig
-            self._dispatch = DispatchConfig.resolve(self._dispatch_arg)
         if self._tracer is None:
             from ..observability import TraceConfig, Tracer
             tcfg = TraceConfig.resolve(self._trace_arg)
@@ -512,32 +500,9 @@ class PipeGraph:
             onode = (self._ordering_of(mp)
                      if self.mode == Mode.DETERMINISTIC and mp.merge_inputs
                      else None)
-            # scan dispatch: each pipe thread gathers up to K same-capacity
-            # batches (ThreadedPipeline's segment shape — bounded linger when
-            # its rings run dry) and runs them as ONE compiled scan; ordering
-            # releases flow through the same accumulator, a capacity switch
-            # between chunk shapes flushing the buffered run short
-            acc = None
-            if self._dispatch is not None and self._dispatch.k > 1:
-                from .dispatch import MicrobatchAccumulator
-                # per-pipe-thread accumulator: no global linger gauge (the
-                # threaded.py convention — N threads would stomp it)
-                acc = MicrobatchAccumulator(self._dispatch.k,
-                                            self._dispatch.linger_s,
-                                            publish_gauge=False)
-            from .dispatch import fused_push
-
-            def run_group(group):
-                chain = mp._compile(group[0].capacity)
-                for out in fused_push(chain, group, self._trace_label(mp)):
-                    deliver(mp, out)
 
             def run_batch(item):
-                if acc is None:
-                    run_group([item])
-                else:
-                    for g in acc.feed(item):
-                        run_group(g)
+                deliver(mp, self._compute(mp, item))
 
             live = list(in_queues[id(mp)])
             try:
@@ -545,10 +510,6 @@ class PipeGraph:
                     for q in list(live):
                         ok, item = q.pop(spin=64, max_yields=0)
                         if not ok:
-                            # ring dry: a lingering partial group goes out
-                            # short rather than hold latency hostage
-                            if acc is not None and acc.expired():
-                                run_group(acc.take())
                             continue
                         if item is EOS:
                             live.remove(q)
@@ -571,10 +532,6 @@ class PipeGraph:
                     for piece in self._chunks(onode.flush(),
                                               onode.last_release_count):
                         run_batch(piece)
-                if acc is not None:
-                    tail = acc.drain()          # partial tail < K at EOS
-                    if tail:
-                        run_group(tail)
                 if mp._chain is not None:
                     for out in mp._chain.flush():
                         deliver(mp, out)
@@ -674,53 +631,14 @@ class PipeGraph:
             # position) — the same coordinates the supervised driver replays
             root_idx = {id(mp): i for i, mp in enumerate(self._roots)}
             offered = {id(mp): 0 for mp in self._roots}
-            # scan dispatch: batches buffer in ARRIVAL order across ALL
-            # roots and flush together the moment any root holds K — each
-            # root's run dispatches as ONE fused scan, but outputs deliver
-            # in the original round-robin interleave, so every downstream
-            # merge sees byte-identically the per-batch arrival order. (A
-            # per-root flush would reorder the merged stream: K batches of
-            # root a would land before the interleaved batches of root b.)
-            # The pull loop is synchronous — no linger; a partial run only
-            # exists at a flush triggered by a sibling root or at EOS.
-            dk = (self._dispatch.k
-                  if self._dispatch is not None and self._dispatch.k > 1
-                  else 0)
-            from ..control import _state as _cstate
-            buf = []          # (mp, batch, e2e t0 | None) in arrival order
-            buf_n = {}        # root id -> batches buffered
-
-            def flush_buf():
-                if not buf:
-                    return
-                outs = {}
-                for mp2 in self._roots:
-                    run = [b for m, b, _ in buf if m is mp2]
-                    if run:
-                        outs[id(mp2)] = iter(self._compute_many(mp2, run, dk))
-                for m, b, t0 in buf:
-                    self._e2e_t0 = t0
-                    self._deliver(m, next(outs[id(m)]))
-                    self._e2e_t0 = None
-                buf.clear()
-                buf_n.clear()
-                _cstate.set_gauge("dispatch_linger_depth", 0)
 
             def ingest(mp, ab, sampled):
-                if not dk:
-                    if sampled:
-                        # e2e latency sample: source framing -> first sink's
-                        # host receipt (recorded in _deliver after consume)
-                        self._e2e_t0 = _time.perf_counter()
-                    self._push(mp, ab)
-                    self._e2e_t0 = None
-                    return
-                buf.append((mp, ab,
-                            _time.perf_counter() if sampled else None))
-                buf_n[id(mp)] = buf_n.get(id(mp), 0) + 1
-                _cstate.set_gauge("dispatch_linger_depth", len(buf))
-                if buf_n[id(mp)] >= dk:
-                    flush_buf()
+                if sampled:
+                    # e2e latency sample: source framing -> first sink's
+                    # host receipt (recorded in _deliver after consume)
+                    self._e2e_t0 = _time.perf_counter()
+                self._push(mp, ab)
+                self._e2e_t0 = None
 
             while live:
                 mp, it = live[round_robin_pos % len(live)]
@@ -732,9 +650,6 @@ class PipeGraph:
                     if adm is not None:
                         for ab in adm.drain():  # bounded held tail
                             ingest(mp, ab, False)
-                    # buffered batches (every root's) must land before this
-                    # root's chain flushes downstream
-                    flush_buf()
                     self._exhaust(mp)
                     continue
                 record_source_launch(mp.source, batch)
@@ -870,8 +785,8 @@ class PipeGraph:
             visit(p)
         return order
 
-    def _push(self, mp: MultiPipe, batch: Batch):
-        """Push one batch through mp's chain and onward through split/merge edges."""
+    def _compute(self, mp: MultiPipe, batch: Batch) -> Batch:
+        """One batch through mp's chain, under the pipe's trace span."""
         chain = mp._compile(batch.capacity)
         tr = _tracing.get_active()
         span = tr.service(batch, self._trace_label(mp)) if tr is not None \
@@ -880,28 +795,11 @@ class PipeGraph:
         if span is not None:
             span.done()
             _tracing.carry(batch, out)
-        self._deliver(mp, out)
+        return out
 
-    def _compute_many(self, mp: MultiPipe, batches, k: int):
-        """Outputs for a buffered run of mp's batches WITHOUT delivering:
-        same-capacity runs of up to ``k`` dispatch as ONE compiled scan
-        (``CompiledChain.push_many``), singletons as today's per-batch push
-        — byte-identical to len(batches) sequential :meth:`_push` computes,
-        per-batch trace spans synthesized from each fused launch in batch
-        order. The caller interleaves delivery with its sibling roots'
-        outputs so downstream merge order is untouched."""
-        from .dispatch import MicrobatchAccumulator, fused_push
-        acc = MicrobatchAccumulator(max(int(k), 1), publish_gauge=False)
-        groups = []
-        for b in batches:
-            groups += acc.feed(b)
-        if len(acc):
-            groups.append(acc.drain())
-        outs = []
-        for g in groups:
-            outs += fused_push(mp._compile(g[0].capacity), g,
-                               self._trace_label(mp))
-        return outs
+    def _push(self, mp: MultiPipe, batch: Batch):
+        """Push one batch through mp's chain and onward through split/merge edges."""
+        self._deliver(mp, self._compute(mp, batch))
 
     def _ordering_of(self, merged: MultiPipe):
         """Per-merge Ordering_Node (DETERMINISTIC mode): holds tuples back to the

@@ -26,11 +26,10 @@ from typing import Any, List, Optional, Sequence
 import jax
 
 from ..basic import DEFAULT_BATCH_SIZE
-from ..batch import Batch, stack_batches, unstack_batches
+from ..batch import Batch
 from ..observability import device_health as _dh
 from ..observability import journal as _journal
 from ..observability import tracing as _tracing
-from . import dispatch as _dispatch
 from ..operators.base import Basic_Operator
 from ..operators.sink import ReduceSink, Sink
 from ..operators.source import SourceBase
@@ -160,7 +159,6 @@ class CompiledChain:  # wf-lint: single-writer[driver, stage]
                           if op.tier_controllers()]
         self._steps = {}
         self._push_count = 0
-        self._fused_count = 0       # push_many launches (scan dispatch)
         self._nbytes_cache = {}     # (from_op, in capacity) -> (in, out bytes)
         #: stage label for the health ledger's compile + device-time
         #: attribution (the flight-recorder stage convention): drivers
@@ -180,7 +178,7 @@ class CompiledChain:  # wf-lint: single-writer[driver, stage]
             b = jax.device_put(b, self.device)
         hl, t0c = self._health_begin("warm")
         self._step_fn(0)(tuple(self.states), b)
-        self._health_end(hl, t0c, 0, "step", b)
+        self._health_end(hl, t0c, 0, b)
 
     def reset_states(self) -> None:
         """Re-initialize every operator's state (supervised replay of a chain
@@ -194,19 +192,11 @@ class CompiledChain:  # wf-lint: single-writer[driver, stage]
     def out_spec(self):
         return self.specs[-1]
 
-    def _apply_from(self, i: int, states, batch):
-        """ops[i:] over one batch — the body both the per-batch step and the
-        scan body trace.  Each operator's ``apply`` runs under its
-        ``jax.named_scope`` (``Class:name``): HLO metadata only, so the
-        profiler's device operations name their operator while executables
-        and cost pins stay as they were."""
-        states = list(states)
-        for j in range(i, len(self.ops)):
-            with jax.named_scope(self.ops[j].scope_name()):
-                states[j], batch = self.ops[j].apply(states[j], batch)
-        return tuple(states), batch
-
     def _step_fn(self, i: int):
+        """The jitted step over ops[i:].  Each operator's ``apply`` runs
+        under its ``jax.named_scope`` (``Class:name``): HLO metadata only, so
+        the profiler's device operations name their operator while
+        executables and cost pins stay as they were."""
         if i not in self._steps:
             def step(states, batch):
                 # compile-ledger hook: this line runs at TRACE time only
@@ -219,51 +209,13 @@ class CompiledChain:  # wf-lint: single-writer[driver, stage]
                     hl.note_trace(self.label, i, "step", _health_sig(batch),
                                   capacity=jax.tree.leaves(batch)[0].shape[0]
                                   if jax.tree.leaves(batch) else None)
-                return self._apply_from(i, states, batch)
+                states = list(states)
+                for j in range(i, len(self.ops)):
+                    with jax.named_scope(self.ops[j].scope_name()):
+                        states[j], batch = self.ops[j].apply(states[j], batch)
+                return tuple(states), batch
             self._steps[i] = jax.jit(step)
         return self._steps[i]
-
-    def _scan_fn(self, i: int):
-        """The scan-dispatch core: ONE jitted program running K consecutive
-        batch steps via ``lax.scan`` over the per-op ``apply`` with operator
-        states as carry. The body is the SAME per-batch step ``_step_fn``
-        traces, so a fused launch is byte-identical to K sequential pushes;
-        jax.jit caches one executable per stacked input shape — one trace,
-        one executable per (K, capacity), one host dispatch per K batches."""
-        key = ("scan", i)
-        if key not in self._steps:
-            def scan_step(states, stacked):
-                # compile-ledger hook — trace-time only, in the OUTER fn
-                # (lax.scan may trace `body` more than once; that is one
-                # executable, so it must count as one compile)
-                hl = _dh.get_active()
-                if hl is not None:
-                    leaves = jax.tree.leaves(stacked)
-                    hl.note_trace(
-                        self.label, i, "scan", _health_sig(stacked),
-                        capacity=leaves[0].shape[1] if leaves else None,
-                        k=leaves[0].shape[0] if leaves else None)
-
-                def body(carry, batch):
-                    return self._apply_from(i, carry, batch)
-                return jax.lax.scan(body, tuple(states), stacked)
-            self._steps[key] = jax.jit(scan_step)
-        return self._steps[key]
-
-    def warm_scan(self, k: int, capacity: int) -> None:
-        """Trace + compile the K-fused scan executable for ``(k, capacity)``
-        WITHOUT touching operator state (the :meth:`warm` discipline): the
-        dispatch autotuner pre-warms every K rung so a rung switch on the hot
-        path selects a cached executable, never a trace."""
-        if k <= 1:
-            return self.warm(capacity)
-        b = Batch.empty(capacity, self.specs[0])
-        if self.device is not None:
-            b = jax.device_put(b, self.device)
-        stacked = stack_batches([b] * int(k))
-        hl, t0c = self._health_begin("warm_scan")
-        self._scan_fn(0)(tuple(self.states), stacked)
-        self._health_end(hl, t0c, 0, "scan", stacked)
 
     # -- runtime-health ledger (MonitoringConfig.health) --------------------
 
@@ -278,8 +230,7 @@ class CompiledChain:  # wf-lint: single-writer[driver, stage]
         hl.set_cause(cause)
         return hl, time.perf_counter()
 
-    def _health_end(self, hl, t0c: float, from_op: int, kind: str,
-                    example) -> None:
+    def _health_end(self, hl, t0c: float, from_op: int, example) -> None:
         """Commit any trace notes the invocation parked: duration = the
         whole first call (trace + XLA compile + first execution — the
         honest number a user waits for), cost = AOT cost/memory analysis of
@@ -290,14 +241,14 @@ class CompiledChain:  # wf-lint: single-writer[driver, stage]
         pending = hl.take_pending()
         if not pending:
             return
-        cost = self._health_cost(hl, from_op, kind, example)
+        cost = self._health_cost(hl, from_op, example)
         hl.commit_pending(time.perf_counter() - t0c, cost,
                           op=self.ops[from_op].getName() if self.ops else "",
                           notes=pending)
 
-    def _health_cost(self, hl, from_op: int, kind: str, example) -> dict:
+    def _health_cost(self, hl, from_op: int, example) -> dict:
         """AOT cost-analysis flops/bytes + executable memory footprint of
-        the program just compiled for (from_op, kind, example's shapes).
+        the program just compiled for (from_op, example's shapes).
         One extra lowering on the health path only (``hl.cost_analysis``
         gates it); every failure degrades to an empty dict — the compile
         event then simply carries no cost columns."""
@@ -305,7 +256,7 @@ class CompiledChain:  # wf-lint: single-writer[driver, stage]
             return {}
         hl._suppress(True)
         try:
-            fn = self._steps[from_op if kind == "step" else ("scan", from_op)]
+            fn = self._steps[from_op]
             compiled = fn.lower(tuple(self.states), example).compile()
             ca = compiled.cost_analysis()
             if isinstance(ca, (list, tuple)):
@@ -335,7 +286,7 @@ class CompiledChain:  # wf-lint: single-writer[driver, stage]
         spill pipeline (start/consume ``copy_to_host_async`` copies, apply
         settled prefixes to the host stores, one cached clear executable
         when a prefix settled) + the compaction cadence. Called by
-        ``push``/``push_many`` right after the state update — the cadence
+        ``push`` right after the state update — the cadence
         is therefore a pure function of stream position, so supervised
         replay re-walks it exactly."""
         for j in self._tier_ops:
@@ -423,100 +374,6 @@ class CompiledChain:  # wf-lint: single-writer[driver, stage]
             out[name] = out.get(name, 0) + n
         return out
 
-    def push_many(self, batches: Sequence[Batch],
-                  from_op: int = 0) -> List[Batch]:
-        """Run K same-capacity batches through ops[from_op:] as ONE compiled
-        scan dispatch; updates states; returns the K out batches in order —
-        byte-identical to K sequential :meth:`push` calls. Stats attribute
-        the launch the way one fused program deserves: K batches counted per
-        op, ONE kernel launch on the entry op. K = 1 degenerates to
-        :meth:`push` (same executable, same sampling path)."""
-        batches = list(batches)
-        if len(batches) == 1:
-            return [self.push(batches[0], from_op=from_op)]
-        # per-LAUNCH sampling (the push-path predicate over launch count):
-        # every Nth fused dispatch is timed to completion, the other N-1 keep
-        # the async queue full
-        self._fused_count += 1
-        sampled = self._sampled(self._fused_count)
-        with _tracing.span("wf.chain.push", pos=_tracing.pos_of(batches[0]),
-                           k=len(batches), sampled=int(sampled)):
-            return self._push_many(batches, from_op, sampled)
-
-    def _push_many(self, batches: List[Batch], from_op: int,
-                   sampled: bool) -> List[Batch]:
-        k = len(batches)
-        stacked = stack_batches(batches)
-        if self.device is not None:
-            stacked = jax.device_put(stacked, self.device)
-        c = self._fused_count
-        hl, t0c = self._health_begin("push_many")
-        t0 = time.perf_counter() if sampled else 0.0
-        states, outs_stacked = self._scan_fn(from_op)(tuple(self.states),
-                                                      stacked)
-        if sampled:
-            # device-time attribution (health): the dispatch call above
-            # already returned asynchronously, so the split between "host
-            # dispatch" and "device completion" is one extra perf_counter
-            # on a path that pays a block_until_ready anyway
-            t_disp = time.perf_counter()
-            with _tracing.span("wf.chain.sync"):
-                jax.block_until_ready(outs_stacked)
-            t_done = time.perf_counter()
-            service_s = t_done - t0
-            # never attribute a launch that COMPILED (pending trace notes):
-            # its "dispatch" span is trace+XLA time, and the sums never
-            # decay — one such sample would mis-flag the stage forever
-            if (hl is not None and not hl.has_pending()
-                    and hl.service_sample()):
-                hl.note_service(self.label, dispatch_s=t_disp - t0,
-                                device_s=t_done - t_disp)
-            if _journal.get_active() is not None:
-                _journal.record(
-                    "dispatch_fused",
-                    op=self.ops[from_op].getName() if self.ops else "",
-                    from_op=from_op, k=k, launch=c,
-                    service_s=round(service_s, 6))
-        else:
-            service_s = None
-        if hl is not None:
-            # after the timed window, so the cost-analysis lowering of a
-            # compile event can never inflate the service sample
-            self._health_end(hl, t0c, from_op, "scan", stacked)
-        self.states = list(states)
-        if self._tier_ops:
-            self._tier_maintain()
-        if sampled:
-            # the fused launch is already synced: fold the event-time drop
-            # readback into it (coordinates = the group's first traced batch)
-            self._journal_drops(next(
-                (b for b in batches if _tracing.tid_of(b) is not None), None))
-        self._push_count += k
-        outs = unstack_batches(outs_stacked, k)
-        # batch/byte counters mirror push: K batches per op, static shapes
-        ck = (from_op, batches[0].capacity)
-        if ck in self._nbytes_cache:
-            in_bytes, out_bytes = self._nbytes_cache[ck]
-        else:
-            in_bytes, out_bytes = (_batch_nbytes(batches[0]),
-                                   _batch_nbytes(outs[0]))
-            self._nbytes_cache[ck] = (in_bytes, out_bytes)
-        for j in range(from_op, len(self.ops)):
-            rec = self.ops[j].get_StatsRecords()[0]
-            rec.batches_received += k
-            rec.batches_sent += k
-            rec.bytes_received += k * in_bytes
-            rec.bytes_sent += k * out_bytes
-        if self.ops:
-            # ONE launch for K batches — the dispatch-amortization claim the
-            # perf gate asserts (num_kernels vs batches_received)
-            tid = next((t for t in map(_tracing.tid_of, batches)
-                        if t is not None), None)
-            self.ops[from_op].get_StatsRecords()[0].record_launch(
-                service_s,
-                exemplar=None if service_s is None else tid)
-        return outs
-
     def push(self, batch: Batch, from_op: int = 0) -> Batch:
         """Run one batch through ops[from_op:]; updates states; returns the out batch."""
         self._push_count += 1
@@ -570,7 +427,7 @@ class CompiledChain:  # wf-lint: single-writer[driver, stage]
         if hl is not None:
             # after the timed window, so the cost-analysis lowering of a
             # compile event can never inflate the service sample
-            self._health_end(hl, t0c, from_op, "step", batch)
+            self._health_end(hl, t0c, from_op, batch)
         self.states = list(states)
         if self._tier_ops:
             self._tier_maintain()
@@ -685,7 +542,7 @@ class Pipeline:
     def __init__(self, source: SourceBase, ops: Sequence[Basic_Operator],
                  sink: Optional[Sink] = None, *,
                  batch_size: Optional[int] = None, prefetch: int = 0,
-                 monitoring=None, control=None, trace=None, dispatch=None):
+                 monitoring=None, control=None, trace=None):
         self.source = source
         self.sink = sink
         if batch_size is None:
@@ -728,10 +585,6 @@ class Pipeline:
         #: observability.tracing.TraceConfig.resolve) — same lazy resolution
         self._trace_arg = trace
         self._tracer = None
-        #: scan dispatch (None = consult WF_DISPATCH; see
-        #: runtime.dispatch.DispatchConfig.resolve) — off by default: with
-        #: dispatch off the drive loop runs today's exact per-batch path
-        self._dispatch_arg = dispatch
 
     def _make_controller(self):
         """Assemble the run-scoped control pieces from the resolved config:
@@ -776,54 +629,6 @@ class Pipeline:
         admission = admission_from_config(cfg, base, driver="pipeline")
         return tuner, rebatcher, admission
 
-    def _make_dispatcher(self):
-        """Resolve ``dispatch=``/``WF_DISPATCH`` into (accumulator, K-tuner)
-        — both None when scan dispatch is off. The K tuner is the SAME
-        hill-climber class the capacity ladder uses, pointed at a power-of-two
-        K ladder (1 included — the degenerate rung IS per-batch push), its
-        winner persisted in the shared TuningCache under a dispatch key."""
-        from .dispatch import DispatchConfig, MicrobatchAccumulator, \
-            build_k_ladder
-        dcfg = DispatchConfig.resolve(self._dispatch_arg)
-        if dcfg is None:
-            return None, None
-        acc = MicrobatchAccumulator(dcfg.k, dcfg.linger_s)
-        ktuner = None
-        cfg = self._control
-        base = getattr(self.source, "out_capacity",
-                       lambda b: b)(self.batch_size)
-        if (dcfg.autotune_k and cfg is not None and cfg.autotune
-                and dcfg.k > 1):
-            from ..control import (CapacityAutotuner, TuningCache,
-                                   chain_signature, device_kind,
-                                   dispatch_tuning_key, payload_signature)
-            ladder = build_k_ladder(dcfg.k)
-            cache = key = None
-            if cfg.cache_path:
-                cache = TuningCache(cfg.cache_path)
-                key = dispatch_tuning_key(
-                    chain_signature(self.chain.ops),
-                    payload_signature(self.chain.specs[0]), device_kind())
-            ktuner = CapacityAutotuner(
-                ladder, start_capacity=dcfg.k,
-                decide_every=cfg.decide_every,
-                settle_batches=cfg.settle_batches,
-                improve_threshold=cfg.improve_threshold,
-                cache=cache, cache_key=key,
-                name=self.source.getName() + "-dispatch-k",
-                gauge="dispatch_k")
-            acc.set_k(ktuner.capacity)
-            if dcfg.prewarm:
-                warm_ks = ({ktuner.capacity, 1} if ktuner.converged
-                           else ladder)
-                with _dh.cause("autotune_prewarm"):
-                    for kr in sorted(warm_ks):
-                        self.chain.warm_scan(kr, base)
-        elif dcfg.prewarm and dcfg.k > 1:
-            with _dh.cause("autotune_prewarm"):
-                self.chain.warm_scan(dcfg.k, base)
-        return acc, ktuner
-
     def run(self):
         import time as _time
         from ..observability import Monitor, MonitoringConfig, TraceConfig, \
@@ -839,12 +644,9 @@ class Pipeline:
             self._tracer = Tracer(tcfg,
                                   self.source.getName() + "-pipeline").start()
         tuner, rebatcher, admission = self._make_controller()
-        acc, ktuner = self._make_dispatcher()
         if mon is not None and tuner is not None:
             mon.registry.attach_gauge("control_chosen_capacity",
                                       lambda: tuner.capacity)
-        if mon is not None and acc is not None:
-            mon.registry.attach_gauge("dispatch_k", lambda: acc.k)
         if mon is not None and mon.remediation is not None:
             # bind the actuators THIS run owns (control/remediation.py):
             # unbound actuators skip loudly.  scale_rate is lock-guarded;
@@ -855,14 +657,10 @@ class Pipeline:
                     "admission_rate",
                     lambda a, _adm=admission: _adm.scale_rate(a.factor,
                                                               a.floor))
-            if tuner is not None or ktuner is not None:
-                def _reclimb(_a, _t=tuner, _k=ktuner):
-                    names = []
-                    for t in (_t, _k):
-                        if t is not None:
-                            t.request_reclimb()
-                            names.append(t.name)
-                    return {"tuners": names}
+            if tuner is not None:
+                def _reclimb(_a, _t=tuner):
+                    _t.request_reclimb()
+                    return {"tuners": [_t.name]}
                 mon.remediation.bind("autotune_reclimb", _reclimb)
         try:
             batches = (self.source.batches_prefetched(
@@ -899,49 +697,6 @@ class Pipeline:
                     newcap = tuner.on_batch(b.capacity)
                     if newcap is not None:
                         rebatcher.set_target(newcap)
-                if ktuner is not None:
-                    newk = ktuner.on_batch(b.capacity)
-                    if newk is not None:
-                        acc.set_k(newk)
-
-            def drive_many(group):
-                # K batches, ONE compiled scan dispatch: per-batch sink
-                # delivery, trace spans, e2e samples, and tuner accounting
-                # are synthesized from the one launch, in batch order
-                nonlocal n
-                if len(group) == 1:
-                    drive(group[0])
-                    return
-                sampled_any = (mon is not None and self.sink is not None
-                               and any(mon.config.should_sample_e2e(n + i)
-                                       for i in range(len(group))))
-                t0 = _time.perf_counter() if sampled_any else 0.0
-                outs = _dispatch.fused_push(self.chain, group, "chain")
-                for b, out in zip(group, outs):
-                    if self.sink is not None:
-                        with _tracing.span("sink", out):
-                            self.sink.consume(out)
-                    if (mon is not None and self.sink is not None
-                            and mon.config.should_sample_e2e(n)):
-                        mon.registry.record_e2e(_time.perf_counter() - t0,
-                                                exemplar=_tracing.tid_of(b))
-                    n += 1
-                    if tuner is not None:
-                        newcap = tuner.on_batch(b.capacity)
-                        if newcap is not None:
-                            rebatcher.set_target(newcap)
-                    if ktuner is not None:
-                        newk = ktuner.on_batch(b.capacity)
-                        if newk is not None:
-                            acc.set_k(newk)
-
-            def feed(rb):
-                # with dispatch off this IS drive(rb) — today's exact path
-                if acc is None:
-                    drive(rb)
-                else:
-                    for g in acc.feed(rb):
-                        drive_many(g)
 
             n_offered = 0
             for batch in batches:
@@ -956,20 +711,16 @@ class Pipeline:
                 for ab in admitted:
                     for rb in (rebatcher.feed(ab) if rebatcher is not None
                                else (ab,)):
-                        feed(rb)
+                        drive(rb)
             _journal.record("eos", pipeline=self.source.getName())
             if admission is not None:
                 for ab in admission.drain():      # bounded held tail
                     for rb in (rebatcher.feed(ab) if rebatcher is not None
                                else (ab,)):
-                        feed(rb)
+                        drive(rb)
             if rebatcher is not None:
                 for rb in rebatcher.drain():      # partial up-rung buffer
-                    feed(rb)
-            if acc is not None:
-                tail = acc.drain()                # partial tail < K at EOS
-                if tail:
-                    drive_many(tail)
+                    drive(rb)
             for out in self.chain.flush():
                 if self.sink is not None:
                     self.sink.consume(out)

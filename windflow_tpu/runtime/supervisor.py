@@ -206,7 +206,7 @@ class SupervisedPipeline:
                  backoff_base: float = 0.05, backoff_cap: float = 2.0,
                  dead_letter=None, poison_threshold: int = 3,
                  step_timeout: Optional[float] = None, faults=None,
-                 control=None, trace=None, dispatch=None, shards=None,
+                 control=None, trace=None, shards=None,
                  reshard=None, shard_key=None, shard_range=None,
                  remediation=None):
         self.source = source
@@ -241,14 +241,6 @@ class SupervisedPipeline:
                 "stream and the fleet would emit every output once per "
                 "host — pass the global shard count alongside the slice")
         self._sharded = None          # last run's ShardedSupervisor (report)
-        #: scan-dispatch opt-in (dispatch= kwarg or WF_DISPATCH env). The
-        #: supervised driver flushes the accumulator before EVERY checkpoint
-        #: commit (the snapshot must reflect every read position) and ignores
-        #: the wall-clock linger — flush boundaries are count-based, a pure
-        #: function of stream position, so replay reproduces them. A fused
-        #: group failure degrades the replay to per-batch through the
-        #: group's range, so poison quarantine keys the exact batch.
-        self._dispatch_arg = dispatch
         #: causal tracing opt-in (position-mode ids only — see
         #: _supervised_tracer); resolved at run()
         self._trace_arg = trace
@@ -358,14 +350,6 @@ class SupervisedPipeline:
             else {}
 
     def _run(self):
-        from .dispatch import DispatchConfig, MicrobatchAccumulator
-        dcfg = DispatchConfig.resolve(self._dispatch_arg)
-        acc = acc_pos = None
-        if dcfg is not None and dcfg.k > 1:
-            # count-based flush ONLY (no linger poll): group boundaries are a
-            # pure function of stream position, so replay re-forms them
-            acc = MicrobatchAccumulator(dcfg.k)
-            acc_pos = []                      # positions of buffered batches
         snap = self._snapshot()
         snap_pos = 0                          # batches fully committed
         snap_cursor = None                    # source resume token at commit
@@ -401,67 +385,24 @@ class SupervisedPipeline:
                 self.sink.consume(outbox[0])
                 outbox.pop(0)
 
-        degrade_hi = None             # per-batch replay bound after a fused
-        #                               group failure (exact poison
-        #                               attribution; None = fuse normally)
-
-        def push_group(group, positions):
-            # K buffered batches, ONE compiled scan dispatch (K=1 delegates
-            # to the per-batch push executable). A fused-group failure is
-            # only attributable to the group's HEAD position — so on failure
-            # the replay degrades to per-batch through the group's range,
-            # where the failure re-manifests at its true position and the
-            # poison-quarantine machinery keys the exact batch (never an
-            # innocent group-mate), exactly like the per-batch path.
-            nonlocal cur_pos, degrade_hi
-            if len(group) > 1:
-                try:
-                    return _push_group_inner(group, positions)
-                except BaseException:
-                    degrade_hi = positions[-1]
-                    raise
-            return _push_group_inner(group, positions)
-
-        def _push_group_inner(group, positions):
-            nonlocal cur_pos
-            cur_pos = positions[0]
-            # k marks fused-group membership so the trace report apportions
-            # the one launch across the K member ids (wf_trace.py)
-            spans = [_tracing.service(b, "chain", k=len(group))
-                     for b in group]
-            p0 = positions[0]
-            # a singleton group takes the plain per-batch push — the exact
-            # seed call (so step stubs/wrappers around chain.push still see
-            # every batch when dispatch is off or the tail is 1)
-            step = (lambda: [self.chain.push(group[0])] if len(group) == 1
-                    else self.chain.push_many(group))
-            outs = _faults.call_with_timeout(
-                step, self.step_timeout,
+        def push_batch(batch, pos):
+            # the span opens on the driver thread BEFORE the step-watchdog
+            # worker runs the push
+            span = _tracing.service(batch, "chain")
+            out = _faults.call_with_timeout(
+                lambda: self.chain.push(batch), self.step_timeout,
                 stage="chain.step",
-                pre=lambda: _faults.fire("chain.step", pos=p0))
-            for b, out, span in zip(group, outs, spans):
-                if span is not None:
-                    span.done()
-                    _tracing.carry(b, out)
-                # materialize now: surfaces async device faults HERE, before
-                # the output could ever reach the sink
-                pending.append(jax.tree.map(np.asarray, out)
-                               if self.sink is not None else out)
-            cur_pos = None
-
-        def flush_acc():
-            # a checkpoint boundary (or EOS) lands mid-accumulator: the
-            # partial group dispatches short so the snapshot reflects every
-            # position already read — commit() below sees fully-pushed state
-            if acc is None or not len(acc):
-                return
-            tail = acc.drain()
-            positions, acc_pos[:] = acc_pos[:len(tail)], []
-            push_group(tail, positions)
+                pre=lambda: _faults.fire("chain.step", pos=pos))
+            if span is not None:
+                span.done()
+                _tracing.carry(batch, out)
+            # materialize now: surfaces async device faults HERE, before
+            # the output could ever reach the sink
+            pending.append(jax.tree.map(np.asarray, out)
+                           if self.sink is not None else out)
 
         def commit(pos):
             nonlocal snap, snap_pos, snap_cursor, snap_adm, budget
-            flush_acc()
             with _journal.span("checkpoint", at_batch=pos,
                                outputs_committed=len(pending)):
                 # fallible work FIRST: a failure here leaves the previous
@@ -538,18 +479,7 @@ class SupervisedPipeline:
                         if pos - snap_pos >= self.checkpoint_every:
                             commit(pos)
                         continue
-                    if degrade_hi is not None and pos > degrade_hi:
-                        degrade_hi = None     # past the failed group's range:
-                        #                       resume fusing
-                    if acc is None or degrade_hi is not None:
-                        # per-batch through a failed group's replay range
-                        push_group([batch], [pos])
-                    else:
-                        acc_pos.append(pos)
-                        for group in acc.feed(batch):
-                            positions = acc_pos[:len(group)]
-                            del acc_pos[:len(group)]
-                            push_group(group, positions)
+                    push_batch(batch, pos)
                     pos += 1
                     cur_pos = None
                     if pos - snap_pos >= self.checkpoint_every:
@@ -557,7 +487,6 @@ class SupervisedPipeline:
                 # EOS: flush, commit everything (inside the recovery loop — a
                 # fault during flush or the final commit replays like any other)
                 cur_pos = None
-                flush_acc()                   # partial tail < K at EOS
                 for out in self.chain.flush():
                     pending.append(jax.tree.map(np.asarray, out)
                                    if self.sink is not None else out)
@@ -620,9 +549,6 @@ class SupervisedPipeline:
                             adm.set_rate(float(
                                 rst.get("rate", adm.current_rate())))
                 pending.clear()               # uncommitted: regenerated by replay
-                if acc is not None:
-                    acc.clear()               # replay re-feeds dropped batches
-                    acc_pos.clear()
                 pos = snap_pos                # replay uncommitted batches
                 prev_sleep = _faults.backoff_sleep(
                     rng, prev_sleep, self.backoff_base, self.backoff_cap,
@@ -1032,14 +958,6 @@ class ShardedSupervisor:
         from ..parallel.sharding import (ReshardPlan, ShardAssignment,
                                          make_splitter)
         self._make_splitter = make_splitter
-        from .dispatch import DispatchConfig
-        dcfg = DispatchConfig.resolve(p._dispatch_arg)
-        if dcfg is not None and dcfg.k > 1:
-            raise ValueError(
-                "shards > 1 does not compose with scan dispatch (K > 1): a "
-                "fused group failure could not be attributed to one shard's "
-                "replay extent — drop dispatch= (or run shards=1); the "
-                "validator's WF115 catches this pre-run")
         self.p = p
         self.chain = p.chain
         self.source = p.source
@@ -1625,7 +1543,7 @@ def run_graph_supervised(graph, *, checkpoint_every: int = 8,
                          backoff_base: float = 0.05, backoff_cap: float = 2.0,
                          dead_letter=None, poison_threshold: int = 3,
                          step_timeout: Optional[float] = None, faults=None,
-                         control=None, dispatch=None, shards=None,
+                         control=None, shards=None,
                          reshard=None, shard_key=None, shard_range=None):
     """Supervised push-driver execution of a whole PipeGraph DAG: aligned
     checkpoints over every pipe's state (chains, Ordering_Nodes, the exhausted
@@ -1634,11 +1552,7 @@ def run_graph_supervised(graph, *, checkpoint_every: int = 8,
     :class:`SupervisedPipeline` (the reference exits on failure, SURVEY §5) —
     with the same hardening: backoff with decorrelated jitter between restart
     attempts, poison-batch quarantine to ``dead_letter``, and a ``step_timeout``
-    watchdog over each push. ``dispatch=`` (explicit wins over the graph's
-    own ``dispatch=``/``WF_DISPATCH``) fuses K root batches per compiled scan
-    with the SupervisedPipeline contract: count-based group boundaries only
-    (a pure function of per-root position, so replay re-forms them), the
-    accumulators flushed before every commit, cleared on restore."""
+    watchdog over each push."""
     from ..parallel.sharding import resolve_shards
     if shard_range is not None:
         raise ValueError(
@@ -1658,20 +1572,18 @@ def run_graph_supervised(graph, *, checkpoint_every: int = 8,
                 max_restarts=max_restarts, backoff_base=backoff_base,
                 backoff_cap=backoff_cap, dead_letter=dead_letter,
                 poison_threshold=poison_threshold,
-                step_timeout=step_timeout, control=control,
-                dispatch=dispatch)
+                step_timeout=step_timeout, control=control)
         return _run_graph_supervised(
             graph, checkpoint_every=checkpoint_every,
             max_restarts=max_restarts, backoff_base=backoff_base,
             backoff_cap=backoff_cap, dead_letter=dead_letter,
             poison_threshold=poison_threshold, step_timeout=step_timeout,
-            control=control, dispatch=dispatch)
+            control=control)
 
 
 def _run_graph_supervised(graph, *, checkpoint_every, max_restarts,
                           backoff_base, backoff_cap, dead_letter,
-                          poison_threshold, step_timeout, control=None,
-                          dispatch=None):
+                          poison_threshold, step_timeout, control=None):
     # reject illegal trace configs BEFORE start() builds anything: raising
     # after start() would leak the just-activated tracer and the running
     # reporter thread (their finish lives in this function's finally, which
@@ -1695,23 +1607,6 @@ def _run_graph_supervised(graph, *, checkpoint_every, max_restarts,
     # limiting whose shed pattern is a pure function of the (deterministic)
     # round-robin offer order — replay-stable with the state snapshotted below
     adm = _supervised_admission(cfg, graph.batch_size, "supervised-graph")
-    # scan dispatch (explicit dispatch= wins over the graph's own resolved
-    # config): batches buffer in ARRIVAL order across ALL roots and flush
-    # together the moment any root holds K — fused compute per root, but
-    # delivery in the original round-robin interleave, so downstream merges
-    # see byte-identically the per-batch order (the push driver's shape).
-    # Boundaries are count-based, a pure function of the deterministic offer
-    # order, so a replay after restore re-forms the same groups.
-    from .dispatch import DispatchConfig
-    dcfg = (DispatchConfig.resolve(dispatch) if dispatch is not None
-            else graph._dispatch)
-    dk = dcfg.k if dcfg is not None and dcfg.k > 1 else 0
-    buf = []                         # (mp, batch, pos, e2e t0) arrival order
-    buf_n = {}                       # root id -> batches buffered
-    degrade = {}                     # root id -> per-batch replay bound after
-    #                                  a fused run failed (exact poison
-    #                                  attribution, the SupervisedPipeline
-    #                                  degrade_hi convention)
     pipes = graph._all_pipes()
     originals = {}
     for mp in pipes:
@@ -1738,9 +1633,6 @@ def _run_graph_supervised(graph, *, checkpoint_every, max_restarts,
 
     def commit(pos):
         nonlocal snap, snap_adm, committed_pos, committed_cursor, budget
-        # a commit boundary lands mid-accumulator: partial groups dispatch
-        # short FIRST so the snapshot reflects every position already read
-        flush_accs()
         with _journal.span("checkpoint", graph=graph.name,
                            batches_committed=sum(pos.values()),
                            outputs_pending=sum(len(sh.pending)
@@ -1767,77 +1659,16 @@ def _run_graph_supervised(graph, *, checkpoint_every, max_restarts,
     # list scan) — the same coordinates the push driver uses
     root_idx = {id(mp): i for i, mp in enumerate(graph._roots)}
 
-    def flush_accs():
-        # compute per root (fused; a failed run flags its root for per-batch
-        # replay through the range, so quarantine keys the exact batch —
-        # the SupervisedPipeline degrade convention), then deliver in the
-        # original arrival order
-        nonlocal cur_key
-        if not buf:
-            return
-        outs = {}
-        for mp2 in graph._roots:
-            entries = [(b, p) for m, b, p, _ in buf if m is mp2]
-            if not entries:
-                continue
-            run = [b for b, _ in entries]
-            p0 = entries[0][1]
-            cur_key = (id(mp2), p0)
-            try:
-                outs[id(mp2)] = iter(_faults.call_with_timeout(
-                    lambda mp2=mp2, run=run: graph._compute_many(
-                        mp2, run, dk),
-                    step_timeout, stage="graph.step",
-                    pre=lambda p0=p0: _faults.fire("chain.step", pos=p0)))
-            except BaseException:
-                if len(run) > 1:
-                    # replay this root per-batch through the failed run's
-                    # range: the failure re-manifests at its true position,
-                    # so quarantine keys the exact batch, not the run's head
-                    degrade[id(mp2)] = entries[-1][1]
-                raise
-        for m, b, p, t0 in buf:
-            cur_key = (id(m), p)      # delivery failure attribution
-            graph._e2e_t0 = t0
-            out = next(outs[id(m)])
-            # delivery (downstream merged-chain pushes, ordering work, sink
-            # consume) stays under the step watchdog, like the per-batch
-            # path's whole-_push wrapper — a hung downstream op must raise
-            # WatchdogTimeout and replay, not wedge the supervisor
-            _faults.call_with_timeout(
-                lambda m=m, out=out: graph._deliver(m, out),
-                step_timeout, stage="graph.step")
-            graph._e2e_t0 = None
-        buf.clear()
-        buf_n.clear()
-        cur_key = None
-
     def ingest(mp, batch, p, sampled):
-        hi = degrade.get(id(mp))
-        if hi is not None and p > hi:
-            degrade.pop(id(mp))       # past the failed run: resume fusing
-        # degrade is GLOBAL while any root is inside a failed run's range:
-        # per-batch ingest delivers in round-robin arrival order for every
-        # root — mixing one degraded (immediate) root with buffered siblings
-        # would reorder the merged stream vs both the fused and the
-        # per-batch paths
-        if not dk or degrade:
-            if sampled:
-                # e2e latency sample — telemetry only, never replayed data
-                graph._e2e_t0 = \
-                    _time.perf_counter()  # wf-lint: allow[wall-clock]
-            _faults.call_with_timeout(
-                lambda: graph._push(mp, batch), step_timeout,
-                stage="graph.step",
-                pre=lambda: _faults.fire("chain.step", pos=p))
-            graph._e2e_t0 = None
-            return
-        buf.append((mp, batch, p,
-                    _time.perf_counter()  # wf-lint: allow[wall-clock]
-                    if sampled else None))
-        buf_n[id(mp)] = buf_n.get(id(mp), 0) + 1
-        if buf_n[id(mp)] >= dk:
-            flush_accs()
+        if sampled:
+            # e2e latency sample — telemetry only, never replayed data
+            graph._e2e_t0 = \
+                _time.perf_counter()  # wf-lint: allow[wall-clock]
+        _faults.call_with_timeout(
+            lambda: graph._push(mp, batch), step_timeout,
+            stage="graph.step",
+            pre=lambda: _faults.fire("chain.step", pos=p))
+        graph._e2e_t0 = None
 
     try:
         while True:
@@ -1868,7 +1699,6 @@ def _run_graph_supervised(graph, *, checkpoint_every, max_restarts,
                             fail_key, fail_streak = None, 0
                             if eos:
                                 live.remove(mp)
-                                flush_accs()
                                 graph._exhaust(mp)
                                 continue
                             pos[id(mp)] += 1
@@ -1889,9 +1719,6 @@ def _run_graph_supervised(graph, *, checkpoint_every, max_restarts,
                         except StopIteration:
                             live.remove(mp)
                             cur_key = None
-                            # buffered batches (every root's) land before
-                            # this root's chain flushes downstream
-                            flush_accs()
                             graph._exhaust(mp)
                             continue
                         # position-derived ids (same (stream, pos) coordinates
@@ -1980,8 +1807,6 @@ def _run_graph_supervised(graph, *, checkpoint_every, max_restarts,
                     _graph_restore(graph, snap)
                     if adm is not None:
                         adm.set_state(snap_adm)
-                buf.clear()           # replay re-feeds the dropped batches
-                buf_n.clear()
                 for sh in shims:
                     sh.discard()
                 prev_sleep = _faults.backoff_sleep(
@@ -2200,20 +2025,13 @@ class _ShardedGraphRun:
 
     def __init__(self, graph, *, shards, reshard, checkpoint_every,
                  max_restarts, backoff_base, backoff_cap, dead_letter,
-                 poison_threshold, step_timeout, control, dispatch,
+                 poison_threshold, step_timeout, control,
                  shard_key=None):
         from ..parallel.sharding import (ReshardPlan, ShardAssignment,
                                          make_splitter)
         self._make_splitter = make_splitter
         self._shard_key = shard_key
-        from .dispatch import DispatchConfig
         self.graph = graph
-        dcfg = (DispatchConfig.resolve(dispatch) if dispatch is not None
-                else graph._dispatch)
-        if dcfg is not None and dcfg.k > 1:
-            raise ValueError(
-                "shards > 1 does not compose with scan dispatch (K > 1) on "
-                "the supervised graph driver — drop dispatch= (WF115)")
         if any(getattr(op, "_tier_cfg", None) is not None
                for op in graph._operators):
             raise ValueError(
@@ -2624,12 +2442,12 @@ class _ShardedGraphRun:
 
 def _run_graph_sharded(graph, *, shards, reshard, checkpoint_every,
                        max_restarts, backoff_base, backoff_cap, dead_letter,
-                       poison_threshold, step_timeout, control, dispatch,
+                       poison_threshold, step_timeout, control,
                        shard_key=None):
     run = _ShardedGraphRun(
         graph, shards=shards, reshard=reshard, shard_key=shard_key,
         checkpoint_every=checkpoint_every, max_restarts=max_restarts,
         backoff_base=backoff_base, backoff_cap=backoff_cap,
         dead_letter=dead_letter, poison_threshold=poison_threshold,
-        step_timeout=step_timeout, control=control, dispatch=dispatch)
+        step_timeout=step_timeout, control=control)
     return run.run()

@@ -84,7 +84,7 @@ class ThreadedPipeline:
                  batch_size: int = DEFAULT_BATCH_SIZE,
                  queue_capacity=8, pin: bool = True,
                  heartbeat_timeout: Optional[float] = None, faults=None,
-                 prefetch: int = 0, control=None, trace=None, dispatch=None,
+                 prefetch: int = 0, control=None, trace=None,
                  monitoring=None):
         self.source = source
         self.sink = sink
@@ -106,14 +106,6 @@ class ThreadedPipeline:
         #: per-batch causal tracing opt-in (trace= kwarg or WF_TRACE env)
         self._trace_arg = trace
         self._tracer = None
-        #: scan dispatch opt-in (dispatch= kwarg or WF_DISPATCH env); each
-        #: segment thread gathers up to K popped batches — flushing short on
-        #: the bounded linger when its input ring runs dry — and runs them as
-        #: ONE compiled scan
-        self._dispatch_arg = dispatch
-        # resolved in run() BEFORE the stage threads start (happens-before
-        # via Thread.start); stages only read
-        self._dispatch = None               # wf-lint: single-writer[driver]
         self.batch_size = batch_size
         self.pin = pin
         self.heartbeat_timeout = heartbeat_timeout
@@ -248,53 +240,28 @@ class ThreadedPipeline:
         stage = f"seg{i}"
         self._beats[stage] = time.monotonic()
         eos_seen = False
-        dcfg = self._dispatch
-        acc = None
-        if dcfg is not None and dcfg.k > 1:
-            from .dispatch import MicrobatchAccumulator
-            # per-segment accumulator: the global linger gauge stays with
-            # the single-threaded ingest accumulators (N segment threads
-            # stomping one gauge would report a random thread's depth)
-            acc = MicrobatchAccumulator(dcfg.k, dcfg.linger_s,
-                                        publish_gauge=False)
-        from .dispatch import fused_push
-
-        def run_group(group):
-            # K popped batches, ONE scan dispatch; per-batch spans + ring
-            # records synthesized from the one launch, in pop order
-            outs = fused_push(chain, group, stage)
-            for out in outs:
-                _tracing.event(out, edge_out, "enq")   # no-op untraced
-                q_out.push(out)
-
         try:
             n = 0
             while True:
                 self._beats[stage] = time.monotonic()
                 ok, item = q_in.pop(spin=256, max_yields=1024)
                 if not ok:
-                    # input ring ran dry: a lingering partial group goes out
-                    # short rather than hold latency hostage
-                    if acc is not None and acc.expired():
-                        run_group(acc.take())
                     continue
                 if item is _EOS:
                     eos_seen = True
-                    if acc is not None:
-                        tail = acc.drain()      # partial tail < K at EOS
-                        if tail:
-                            run_group(tail)
                     for out in chain.flush():
                         q_out.push(out)
                     break
                 _faults.fire("queue.stall", stage=stage, pos=n)
                 _faults.fire("chain.step", stage=stage, pos=n)
                 _tracing.event(item, edge_in, "deq")
-                if acc is None:
-                    run_group([item])
-                else:
-                    for group in acc.feed(item):
-                        run_group(group)
+                span = _tracing.service(item, stage)
+                out = chain.push(item)
+                if span is not None:
+                    span.done()
+                    _tracing.carry(item, out)
+                _tracing.event(out, edge_out, "enq")   # no-op untraced
+                q_out.push(out)
                 n += 1
         except BaseException as e:          # noqa: BLE001
             self._errors.append(e)
@@ -372,8 +339,6 @@ class ThreadedPipeline:
 
     def run(self):
         injector = _faults.resolve(self._faults_arg)
-        from .dispatch import DispatchConfig
-        self._dispatch = DispatchConfig.resolve(self._dispatch_arg)
         from ..observability import Monitor, MonitoringConfig, TraceConfig, \
             Tracer
         mcfg = MonitoringConfig.resolve(self._monitoring_arg)

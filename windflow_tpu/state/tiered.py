@@ -56,7 +56,7 @@ class TierConfig:
 
     #: device-resident hot-table slots (None = the operator's own
     #: ``num_slots``/``num_keys`` — today's geometry). ``WF_STATE_HOT_CAPACITY``
-    #: overrides for every tiered operator (the WF_DISPATCH_K convention).
+    #: overrides for every tiered operator (the WF_TRACE_SAMPLE convention).
     hot_capacity: Optional[int] = None
     #: spill-outbox slots (None = auto: 4x the operator's per-batch
     #: admission bound, absorbing the 3-phase async drain latency)
