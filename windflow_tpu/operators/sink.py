@@ -19,7 +19,6 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..basic import routing_modes_t
 from ..batch import Batch, tuple_refs
@@ -33,13 +32,18 @@ class Sink(Basic_Operator):
     """Host-callback sink. The callback receives a dict with numpy ``key/id/ts``,
     payload leaves restricted to live lanes.
 
+    A result batch crosses to the host in one round trip: ``consume`` starts the
+    copy of every leaf before it reads any (``Batch.to_host``), so the leaves
+    travel together instead of one blocking fetch after another, and delivers
+    before it returns.
+
     ``async_depth > 0`` routes batches through an
-    :class:`~windflow_tpu.runtime.async_sink.AsyncResultShipper`: the
-    device->host copy starts immediately and the callback fires once the copy of
-    a batch ``async_depth`` ships old has landed — result transfer overlaps
-    device compute instead of paying a blocking round trip per batch (the
-    reference GPU D2H overlap, ``wf/win_seq_gpu.hpp:243-260,524``). Callback
-    order stays FIFO; EOS (``None``) drains everything first."""
+    :class:`~windflow_tpu.runtime.async_sink.AsyncResultShipper` instead: the
+    callback fires once the copy of a batch ``async_depth`` ships old has
+    landed, so a batch's delivery is deferred past later pushes and result
+    transfer overlaps device compute (the reference GPU D2H overlap,
+    ``wf/win_seq_gpu.hpp:243-260,524``). Callback order stays FIFO; EOS
+    (``None``) drains everything first."""
 
     def __init__(self, fn: Callable, *, name: str = "sink", parallelism: int = 1,
                  keyed: bool = False, async_depth: int = 0,
@@ -103,9 +107,10 @@ class Sink(Basic_Operator):
         if nbytes is None:
             nbytes = self._nbytes_by_cap[batch.capacity] = sum(
                 a.nbytes for a in jax.tree.leaves(batch))
-        # waits for the device to finish the batch, then copies it back
+        # waits for the device to finish the batch and for its leaves' copies,
+        # all started before the first is read: one round trip, not one a leaf
         with _tracing.span("wf.sink.d2h", pos=pos, bytes=nbytes):
-            host = jax.tree.map(np.asarray, batch)
+            host = batch.to_host()
         self._deliver_host(host, pos)
 
 

@@ -398,8 +398,7 @@ class SupervisedPipeline:
                 _tracing.carry(batch, out)
             # materialize now: surfaces async device faults HERE, before
             # the output could ever reach the sink
-            pending.append(jax.tree.map(np.asarray, out)
-                           if self.sink is not None else out)
+            pending.append(out.to_host() if self.sink is not None else out)
 
         def commit(pos):
             nonlocal snap, snap_pos, snap_cursor, snap_adm, budget
@@ -488,7 +487,7 @@ class SupervisedPipeline:
                 # fault during flush or the final commit replays like any other)
                 cur_pos = None
                 for out in self.chain.flush():
-                    pending.append(jax.tree.map(np.asarray, out)
+                    pending.append(out.to_host()
                                    if self.sink is not None else out)
                 commit(pos)
                 break
@@ -841,7 +840,7 @@ class ShardSupervisor(_ShardRecoveryBase):
                 if span is not None:
                     span.done()
                     _tracing.carry(sub, out)
-                self.pending.append(jax.tree.map(np.asarray, out)
+                self.pending.append(out.to_host()
                                     if self.co.sink is not None else out)
                 i += 1
             except (KeyboardInterrupt, SystemExit):
@@ -886,7 +885,7 @@ class ShardSupervisor(_ShardRecoveryBase):
                     stage=f"shard{self.idx}.flush")
                 self.states = self.chain.states
                 for out in outs:
-                    self.pending.append(jax.tree.map(np.asarray, out)
+                    self.pending.append(out.to_host()
                                         if self.co.sink is not None else out)
                 return
             except (KeyboardInterrupt, SystemExit):
@@ -1441,7 +1440,7 @@ class _CommitBufferSink:
             return                      # EOS delivered by the supervisor at the end
         if self.mute:
             return
-        host = jax.tree.map(np.asarray, batch)
+        host = batch.to_host()
         if self.shard is None:
             self.pending.append(host)
         else:
