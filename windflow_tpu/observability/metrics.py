@@ -1118,7 +1118,8 @@ class MetricsRegistry:
         # STAGE_GAUGES — only registered names render, the WF240/241
         # discipline), with HELP lines: these are the PR 8 operator counters
         # promoted to a uniform per-operator exposition
-        from .names import STAGE_COUNTERS, STAGE_GAUGES
+        from .names import (ARCHIVE_ENGINE_COUNTERS, ARCHIVE_ENGINE_GAUGES,
+                            PANE_STAGES, STAGE_COUNTERS, STAGE_GAUGES)
         stage_help = {
             "sessions_closed": "sessions closed by the session triggerer",
             "topn_evictions": "leaderboard candidates evicted by the top-N "
@@ -1143,6 +1144,11 @@ class MetricsRegistry:
             "archive_runs_written": "window-archive ring rows written per "
                                     "table",
         }
+        # a pattern of two archive engines publishes each one's under its stage
+        for stage in PANE_STAGES:
+            for c in ARCHIVE_ENGINE_COUNTERS + ARCHIVE_ENGINE_GAUGES:
+                stage_help[f"{stage}_{c}"] = (
+                    f"{stage_help[c]} ({stage.upper()} engine of a Pane_Farm)")
         for c in STAGE_COUNTERS + STAGE_GAUGES:
             rows = [r for r in snap["operators"]
                     if c in (r.get("counters") or {})]

@@ -174,6 +174,17 @@ CONTROL_GAUGES = (
     "remediation_hot_capacity", "remediation_recommended_delay",
 )
 
+#: what the archive engine (``operators/win_seq.py``) publishes, and the stages
+#: of a pattern that runs two of them (``Pane_Farm``) and publishes each
+#: engine's under ``<stage>_<name>``; ``ARCHIVE_ENGINE_DROPS`` are the ones
+#: that count lost tuples (``slo.py::_drop_total``)
+ARCHIVE_ENGINE_COUNTERS = ("archive_overwrites", "old_drops",
+                           "windows_undelivered_at_eos", "archive_runs_written")
+ARCHIVE_ENGINE_GAUGES = ("archive_slots", "fired_window_budget",
+                         "archive_run_len", "archive_run_rows")
+ARCHIVE_ENGINE_DROPS = ("archive_overwrites",)
+PANE_STAGES = ("plq", "wlq")
+
 #: per-STAGE counters exported in the metrics snapshot's operator rows
 #: (``row["counters"]``) and in Prometheus as
 #: ``windflow_stage_<name>_total`` with HELP/TYPE lines — the PR 8 operator
@@ -200,6 +211,10 @@ STAGE_COUNTERS = (
     # has flushed until None); ring rows the sorted-order inserts wrote, per
     # table (beside tuples_in: how many row writes replaced how many lanes)
     "archive_overwrites", "windows_undelivered_at_eos", "archive_runs_written",
+    # operators/win_patterns.py::Pane_Farm: its two engines' counters, each
+    # under its stage's prefix (``plq_old_drops``, ``wlq_archive_overwrites``)
+    *(f"{stage}_{counter}" for stage in PANE_STAGES
+      for counter in ARCHIVE_ENGINE_COUNTERS),
 )
 
 #: per-stage gauges (same surface, ``windflow_stage_<name>`` gauge form)
@@ -217,6 +232,9 @@ STAGE_GAUGES = (
     # key and the fired windows one batch may emit; the slots of one ring row
     # as the insert moves them, and the rows one batch may write per table
     "archive_slots", "fired_window_budget", "archive_run_len", "archive_run_rows",
+    # Pane_Farm's two engines' budgets, a prefix a stage
+    *(f"{stage}_{gauge}" for stage in PANE_STAGES
+      for gauge in ARCHIVE_ENGINE_GAUGES),
 )
 
 #: per-operator event-time gauges of the watermark propagation map

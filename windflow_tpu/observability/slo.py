@@ -59,6 +59,7 @@ import time
 from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple
 
 from . import journal as _journal
+from .names import ARCHIVE_ENGINE_DROPS, PANE_STAGES
 
 #: health states, worst-last (the merge folds per-SLO state by code MAX)
 STATE_OK, STATE_WARN, STATE_PAGE = "ok", "warn", "page"
@@ -133,12 +134,17 @@ def _sig_watermark_lag(snap, prev) -> Optional[float]:
     return float(max(vals))
 
 
+#: per-stage counters that count lost tuples (OLD drops come with the totals)
+_DROP_COUNTERS = frozenset((
+    "overflow_drops", "match_drops", "arch_drops", *ARCHIVE_ENGINE_DROPS,
+    *(f"{s}_{c}" for s in PANE_STAGES for c in ARCHIVE_ENGINE_DROPS)))
+
+
 def _drop_total(snap) -> float:
     tot = float((snap.get("totals") or {}).get("tuples_dropped_old", 0))
     for row in snap.get("operators", []):
         for k, v in (row.get("counters") or {}).items():
-            if k in ("overflow_drops", "match_drops", "arch_drops",
-                     "archive_overwrites"):
+            if k in _DROP_COUNTERS:
                 tot += v
     ctl = (snap.get("control") or {}).get("counters") or {}
     return tot + float(ctl.get("shed_tuples", 0))

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 
 from ..basic import routing_modes_t, role_t, pattern_t, DEFAULT_MAX_KEYS
 from ..batch import Batch, CTRL_DTYPE, TupleRef
+from ..observability.names import PANE_STAGES
 from .base import Basic_Operator
 from .window import Iterable, WindowSpec
 from .win_seq import Win_Seq
@@ -181,19 +182,46 @@ class Pane_Farm(Basic_Operator):
     ``plq_fn(pane_id, iterable) -> pane_result`` runs once per pane;
     ``wlq_fn(wid, iterable_of_pane_results) -> result`` combines the panes of each
     window. Sliding windows only (slide < win_len, enforced like ``:170-173``).
-    Composed of two vectorized engines executing in the same program."""
+    Composed of two vectorized engines executing in the same program, each under
+    its own scope (``plq`` / ``wlq``) inside the pattern's.
+
+    Budgets, one pair a stage and one form for count- and time-based panes:
+    ``plq_slots`` / ``wlq_slots`` are ring slots per key (tuples of an open pane
+    plus a batch's share; pane results of an open window plus a batch's),
+    ``plq_max_wins`` / ``wlq_max_wins`` the panes and windows one batch may fire
+    over all keys. Left out, the PLQ takes ``Win_Seq``'s defaults (and its own
+    ``max_wins=`` / ``archive_capacity=`` / ``tb_capacity=``, which ``**kw``
+    still hands it with ``incremental=`` and the rest) and the WLQ sizes itself
+    from the pane results a batch can bring, ``plq_max_wins`` of them: the panes
+    of a window plus all of those on one key, and a window fired for every
+    slide's worth. At a large batch both defaults exceed ``Win_Seq``'s ``[W, L]``
+    gather guard, which raises and asks for the stage's budget."""
 
     routing = routing_modes_t.KEYBY
     pattern = pattern_t.PF_CPU
+    #: the two engines, as ``stage_counters`` prefixes and as scopes
+    STAGES = PANE_STAGES
 
     def __init__(self, plq_fn: Callable, wlq_fn: Callable, spec: WindowSpec, *,
                  num_keys: int = DEFAULT_MAX_KEYS, name: str = "pane_farm",
-                 plq_parallelism: int = 1, wlq_parallelism: int = 1, **kw):
+                 plq_parallelism: int = 1, wlq_parallelism: int = 1,
+                 plq_slots: int = None, plq_max_wins: int = None,
+                 wlq_slots: int = None, wlq_max_wins: int = None, **kw):
         import math
         super().__init__(name, max(plq_parallelism, wlq_parallelism))
         if spec.slide >= spec.win_len:
             raise ValueError("Pane_Farm requires sliding windows (slide < win_len), "
                              "wf/pane_farm.hpp:170-173")
+        for stage_kw, value, engine_kws in (
+                ("plq_slots", plq_slots, ("archive_capacity", "tb_capacity")),
+                ("plq_max_wins", plq_max_wins, ("max_wins",))):
+            if value is None:
+                continue
+            for engine_kw in engine_kws:
+                if engine_kw in kw:
+                    raise TypeError(f"{name}: the PLQ's budget is given twice "
+                                    f"({stage_kw}= and {engine_kw}=)")
+            kw[engine_kws[0]] = value
         self.spec = spec
         self.num_keys = num_keys
         self.shard_axis = "key"
@@ -206,22 +234,46 @@ class Pane_Farm(Basic_Operator):
                            name=f"{name}_plq", **kw)
         # WLQ consumes the pane-result stream: CB windows counted in pane results
         # (panes arrive per key in ascending order without gaps for CB; for TB, pane
-        # results carry ts = pane end time and WLQ windows stay time-based)
+        # results carry ts = pane end time and WLQ windows stay time-based: one
+        # result a pane at most, which is what sizes the WLQ's default budgets)
         if spec.is_cb:
-            wlq_spec = WindowSpec(self.wpanes, self.spanes)
+            wlq_spec, stride = WindowSpec(self.wpanes, self.spanes), None
         else:
             wlq_spec = WindowSpec(spec.win_len, spec.slide, spec.wtype)
+            stride = self.pane_len
         self.wlq = Win_Seq(wlq_fn, wlq_spec, num_keys=num_keys, role=role_t.WLQ,
-                           name=f"{name}_wlq")
-        self.plq.scope_op = self.wlq.scope_op = self
-        self._wlq_id_fix = spec.is_cb
+                           name=f"{name}_wlq", archive_capacity=wlq_slots,
+                           max_wins=wlq_max_wins, ts_stride=stride)
+        for stage, engine in self.engines():
+            engine.scope_op, engine.scope_stage = self, (stage,)
+
+        def cascade(st_w, panes):
+            with jax.named_scope(self.scope_name()), jax.named_scope("wlq"):
+                return self.wlq.apply(st_w, panes)
+        #: a batch of the PLQ's EOS panes through the WLQ: outside the chain's
+        #: step, so compiled and scoped here
+        self._cascade = jax.jit(cascade)
+
+    def engines(self):
+        """``(stage, engine)`` in the order a batch passes them."""
+        return zip(self.STAGES, (self.plq, self.wlq))
+
+    def _fired_budget(self, stage: str, engine: Win_Seq, capacity: int) -> int:
+        try:
+            return engine.out_capacity(capacity)
+        except ValueError as e:
+            raise ValueError(f"{e} (this engine is {self.name}'s {stage.upper()}: "
+                             f"{stage}_max_wins= is that budget and {stage}_slots= "
+                             f"bounds a time-based L)") from None
 
     def bind_geometry(self, batch_capacity: int) -> None:
         self.plq.bind_geometry(batch_capacity)
-        self.wlq.bind_geometry(self.plq.out_capacity(batch_capacity))
+        self.wlq.bind_geometry(
+            self._fired_budget("plq", self.plq, batch_capacity))
 
     def out_capacity(self, in_capacity: int) -> int:
-        return self.wlq.out_capacity(self.plq.out_capacity(in_capacity))
+        return self._fired_budget(
+            "wlq", self.wlq, self._fired_budget("plq", self.plq, in_capacity))
 
     def init_state(self, payload_spec: Any):
         return {"plq": self.plq.init_state(payload_spec),
@@ -238,17 +290,43 @@ class Pane_Farm(Basic_Operator):
     # with the pane close time, so no ts fix-up is needed between the stages.
 
     def apply(self, state, batch: Batch):
-        st_p, panes = self.plq.apply(state["plq"], batch)
-        st_w, out = self.wlq.apply(state["wlq"], panes)
+        with jax.named_scope("plq"):
+            st_p, panes = self.plq.apply(state["plq"], batch)
+        with jax.named_scope("wlq"):
+            st_w, out = self.wlq.apply(state["wlq"], panes)
         return {"plq": st_p, "wlq": st_w}, out
 
     def flush(self, state):
+        """One batch a call, None at the end (``CompiledChain.flush`` calls until
+        then): first the PLQ's open panes, partial, each batch of them through
+        the WLQ as a batch of any other panes; then the WLQ's open windows."""
         st_p, panes = self.plq.flush(state["plq"])
-        if panes is not None:
-            st_w, out = self.wlq.apply(state["wlq"], panes)
+        if panes is None:
+            st_w, out = self.wlq.flush(state["wlq"])
             return {"plq": st_p, "wlq": st_w}, out
-        st_w, out = self.wlq.flush(state["wlq"])
+        st_w, out = self._cascade(state["wlq"], panes)
         return {"plq": st_p, "wlq": st_w}, out
+
+    # both engines' device counters and budgets are this operator's, each under
+    # its stage's prefix (``plq_archive_overwrites``, ``wlq_fired_window_budget``)
+    def collect_stats(self, state=None) -> None:
+        if state is None:
+            return
+        for stage, engine in self.engines():
+            engine.collect_stats(state[stage])
+        self._stats[0].tuples_dropped_old = sum(
+            engine.get_StatsRecords()[0].tuples_dropped_old
+            for _, engine in self.engines())
+
+    def stage_counters(self) -> dict:
+        return {f"{stage}_{name}": value for stage, engine in self.engines()
+                for name, value in engine.stage_counters().items()}
+
+    def drop_counters(self, state=None) -> dict:
+        if state is None:
+            return {}
+        return {f"{stage}_{name}": value for stage, engine in self.engines()
+                for name, value in engine.drop_counters(state[stage]).items()}
 
 
 class Win_MapReduce(Basic_Operator):

@@ -26,7 +26,10 @@ Budgets. A time-based ring holds, per key, the tuples of the open windows plus o
 batch's: how many that is depends on the stream's rate and key spread, which the
 engine cannot see, so a deployment passes ``tb_capacity`` (slots per key) and
 ``max_wins`` (fired windows a batch); the default ``2 * batch`` slots per key is the
-worst case of a whole batch on one key. A ring too small overwrites tuples that an
+worst case of a whole batch on one key. A pattern that feeds the engine its own results
+does know their spacing and says so (``ts_stride``: ``Pane_Farm``'s WLQ gets one pane
+result a pane at most), and the defaults then follow the batch as the count-based ones
+do. A ring too small overwrites tuples that an
 unfired window still needs: the state counts them (``overwrites``), and the OLD drops
 (``dropped_old``), and ``collect_stats`` publishes both with the two budgets
 (``archive_overwrites``, ``old_drops``, ``archive_slots``, ``fired_window_budget``);
@@ -41,6 +44,7 @@ Emission order is per-key ascending window id — the ordered-collector guarante
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Callable, Optional
 
@@ -78,6 +82,7 @@ class Win_Seq(Basic_Operator):
                  incremental: Optional[bool] = None, init_acc: Any = None,
                  num_keys: int = DEFAULT_MAX_KEYS, archive_capacity: int = None,
                  max_wins: int = None, tb_capacity: int = None,
+                 ts_stride: int = None,
                  name: str = "win_seq", parallelism: int = 1,
                  role: role_t = role_t.SEQ, context=None):
         super().__init__(name, parallelism)
@@ -118,13 +123,22 @@ class Win_Seq(Basic_Operator):
         self.role = role
         self._archive_capacity = archive_capacity
         self._tb_capacity = tb_capacity
+        #: time-based only: a key's tuples are known to lie at least this many
+        #: ticks apart (a pattern's pane results, one a pane). A window then
+        #: holds ``win_len // ts_stride`` lanes and a slide ``slide //
+        #: ts_stride``, and the default budgets follow the batch as the
+        #: count-based ones do, not the ``2 * batch`` of an unknown rate.
+        self._ts_stride = ts_stride
         self.A = None                  # resolved in bind_geometry
         self.max_wins = max_wins       # resolved at first apply if None
         self._w = None
         self._wshard = None            # (mesh, axis): shard the fired-window W axis
         #: the operator whose ``Class:name`` scope the chain opens around this
-        #: engine's ``apply``: itself, or the pattern that owns it
+        #: engine's ``apply``: itself, or the pattern that owns it; and the
+        #: scopes that pattern opens between its own and the engine's phases
+        #: (``Pane_Farm``: ``plq`` or ``wlq``)
         self.scope_op = self
+        self.scope_stage = ()
         self.bind_geometry(256)        # provisional; compiler re-binds with real C
 
     def bind_geometry(self, batch_capacity: int) -> None:
@@ -135,6 +149,8 @@ class Win_Seq(Basic_Operator):
             # ring must survive one whole batch landing on a single key before the
             # fire phase runs, plus the open-window span
             self.A = _next_pow2(L + batch_capacity)
+        elif self._tb_capacity is None and self._ts_stride:
+            self.A = _next_pow2(L // self._ts_stride + batch_capacity)
         else:
             self.A = _next_pow2(self._tb_capacity or 2 * batch_capacity)
         self.run_len, self.run_rows = self._row_geometry(batch_capacity)
@@ -308,7 +324,10 @@ class Win_Seq(Basic_Operator):
     def _resolve_w(self, capacity: int) -> int:
         if self.max_wins is not None:
             return self.max_wins
-        W = max(16, -(-capacity // self.spec.slide) + 64)
+        slide = self.spec.slide
+        if not self.spec.is_cb and self._ts_stride:
+            slide = max(1, slide // self._ts_stride)      # in lanes, as CB's
+        W = max(16, -(-capacity // slide) + 64)
         L = self.spec.win_len if self.spec.is_cb else self.A
         if W * L > (1 << 22):
             # adversarial slide (e.g. slide=1 at large batch) would imply a [W, L]
@@ -388,15 +407,18 @@ class Win_Seq(Basic_Operator):
                     return jnp.take(tbl, k_safe, axis=0)                   # [W, A, ...]
                 tss = gat(state.arch_ts)
                 poss = gat(state.arch_pos)
+                # (the window's end may lie past int32's: compare the offset)
                 w_start = (wid * s.slide)[:, None]
                 content_mask = ((poss >= 0) & (tss >= w_start)
-                                & (tss < w_start + s.win_len) & valid_w[:, None])
+                                & (tss - w_start < s.win_len) & valid_w[:, None])
                 # ring-overwrite guard: slot must hold a live (not yet overwritten) pos
                 cnt = jnp.take(state.count, k_safe)[:, None]
                 content_mask &= poss >= jnp.maximum(0, cnt - A)
                 data = jax.tree.map(gat, state.arch_payload)
                 ids = gat(state.arch_id)
-                res_ts = wid * s.slide + (s.win_len - 1)
+                # the window's last tick, or int32's last where it ends later
+                res_ts = (jnp.minimum(wid * s.slide, _TS_MAX - (s.win_len - 1))
+                          + (s.win_len - 1))
 
             if not s.is_cb:
                 # TB: a window with no content never fires in the reference (Triggerer_TB
@@ -442,8 +464,10 @@ class Win_Seq(Basic_Operator):
         W = self._w or self._resolve_w(256)
         if not hasattr(self, "_flush_jit"):
             def flush_emit(st):
-                with jax.named_scope(self.scope_op.scope_name()), \
-                        jax.named_scope("emit"):
+                with contextlib.ExitStack() as scopes:
+                    for scope in (self.scope_op.scope_name(),
+                                  *self.scope_stage, "emit"):
+                        scopes.enter_context(jax.named_scope(scope))
                     st, out = self._emit(st, W, flush=True)
                     lo, hi = self._fired_range(st, True)
                     return st, out, jnp.any(out.valid), jnp.sum(hi - lo)
@@ -506,6 +530,9 @@ def _fold_windows(fn, wids, it: Iterable, init_acc):
         return acc
 
     return jax.vmap(one)(wids, it.data, it.ids, it.ts, it.mask)
+
+
+_TS_MAX = int(jnp.iinfo(CTRL_DTYPE).max)
 
 
 def _next_pow2(n: int) -> int:
