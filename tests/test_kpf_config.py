@@ -11,6 +11,7 @@ import os
 import subprocess
 import sys
 
+import jax
 import numpy as np
 import pytest
 
@@ -125,6 +126,19 @@ def test_budgets_come_from_the_deployment():
         "plq_archive_slots": 4096, "plq_fired_window_budget": 8704,
         "wlq_archive_slots": 64, "wlq_fired_window_budget": 8704}
     assert window.out_capacity(1 << 20) == 8704
+    # how a batch is cut: the PLQ moves 2,048 ring rows of 1,024 slots a table
+    # (512 head rows, 1,024 + 512 body rows); the WLQ moves a key's whole ring
+    # of 64 slots, 1,160 rows (512 + 136 + 512) where rows of 8 were 2,112
+    assert {k: counters[k] for k in (
+        "plq_archive_run_len", "plq_archive_run_rows",
+        "wlq_archive_run_len", "wlq_archive_run_rows")} == {
+        "plq_archive_run_len": 1024, "plq_archive_run_rows": 2048,
+        "wlq_archive_run_len": 64, "wlq_archive_run_rows": 1160}
+    # and once the step is traced: payload, id and ts of a row in one gather
+    ops, step, args = chain_step(published, mod, 1 << 20)
+    jax.eval_shape(step, *args)
+    for _, engine in ops[-1].engines():
+        assert engine._budget_gauges()["archive_run_groups"] == 1
     # four int32 tables a stage: 33.5 MB and 0.5 MB, not 17 GB
     assert window.plq.A * 512 * 4 * 4 == 33_554_432
     assert window.wlq.A * 512 * 4 * 4 == 524_288

@@ -1,6 +1,8 @@
 """``Win_Seq._insert`` archives a batch in the order one stable sort makes and
 moves the rings as rows (``ops/segment.py``: ``sort_segments``,
-``enumerate_runs``, ``take_windows``). The formulation it replaced — rank back
+``enumerate_runs``, ``take_windows``), each row's window of the sorted columns
+taken once for all the columns that share a buffer, the rows as long as a
+slice's price buys (``_row_geometry``). The formulation it replaced — rank back
 in stream order, one per-lane scatter per table, two per-lane reductions per
 key — is kept HERE as the reference: both run over the same consecutive
 batches and must agree bit for bit on every state leaf after every batch and
@@ -13,15 +15,18 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from test_ysb_wmr_config import equations
 from windflow_tpu.basic import win_type_t
 from windflow_tpu.batch import Batch, CTRL_DTYPE
 from windflow_tpu.observability.names import STAGE_COUNTERS, STAGE_GAUGES
+from windflow_tpu.operators import win_seq
 from windflow_tpu.operators.win_seq import Win_Seq
 from windflow_tpu.operators.window import WindowSpec
 from windflow_tpu.ops.lookup import table_lookup
-from windflow_tpu.ops.segment import (enumerate_runs, run_budget, segment_rank,
-                                      segment_reduce, sort_segments,
-                                      take_windows)
+from windflow_tpu.ops.segment import (SLICE_GBPS, SLICE_US, enumerate_runs,
+                                      segment_rank, segment_reduce,
+                                      sort_segments, take_windows,
+                                      window_groups)
 
 #: the leaves the per-lane form had (``runs_written`` came with the rows)
 LEAVES = ("arch_payload", "arch_id", "arch_ts", "arch_pos", "count", "wm",
@@ -78,6 +83,10 @@ def keys_one_hot(rng, c, k, j):
     return np.full(c, 3 % k)
 
 
+def keys_uniform(rng, c, k, j):
+    return rng.integers(0, k, c)
+
+
 def keys_zipf(rng, c, k, j):
     return np.minimum(rng.zipf(1.3, c) - 1, k - 1)
 
@@ -96,6 +105,10 @@ def valid_all(rng, c, j):
 
 def valid_holes(rng, c, j):
     return rng.random(c) < 0.7
+
+
+def valid_a_third(rng, c, j):
+    return rng.random(c) < 1 / 3
 
 
 def valid_holes_and_an_empty_batch(rng, c, j):
@@ -123,6 +136,17 @@ def payload_2d_leaf(rng, c):
             "m": rng.random((c, 3)).astype(np.float32)}
 
 
+def payload_mixed_leaves(rng, c):
+    """Two 32-bit columns of different dtypes (they share a buffer with id
+    and ts, the floats as their bit patterns: a NaN's payload and the sign of
+    a zero survive) and a rank-2 leaf (a gather of its own)."""
+    f = rng.standard_normal(c).astype(np.float32)
+    f[::7], f[3::11] = -0.0, np.float32(np.nan)
+    f.view(np.uint32)[5::13] = 0x7FC12345            # a NaN with a payload
+    return {"v": rng.integers(0, 97, c).astype(np.int32), "f": f,
+            "m": rng.random((c, 3)).astype(np.float32)}
+
+
 @dataclasses.dataclass(frozen=True)
 class Case:
     name: str
@@ -141,10 +165,16 @@ class Case:
     want_T: int = None            # the row length bind_geometry must arrive at
     loses: bool = False           # the ring is too small on purpose
     bind: bool = True             # bind_geometry(C) before the first batch
+    groups: int = 1               # take_windows gathers a pass
+    slice_us: float = None        # SLICE_US for the case (None: the chip's)
 
 
 CB, TB = win_type_t.CB, win_type_t.TB
-CASES = [
+#: at the chip's price of a slice these small batches would move as one or
+#: two long rows a key; on a chip where a slice is nearly free (SLICE_US
+#: 0.01) they are cut into many short ones, and every way a key's lanes can
+#: lie across ring rows occurs
+MANY_SHORT_ROWS = [
     Case("cb_round_robin", want_T=16),
     Case("tb_round_robin", TB, win=400, slide=200, capacity=256, want_T=16),
     # one key takes every batch: C / T + 2 rows at most; 240 lanes a batch
@@ -152,12 +182,12 @@ CASES = [
     Case("cb_one_hot_key", keys=keys_one_hot, C=240, K=8),
     Case("tb_one_hot_key", TB, keys=keys_one_hot, C=240, K=8, win=400,
          slide=200, capacity=1024),
-    # K = 200 > C / T = 256 / 2: most keys hold a lane or two, and the rows
-    # are as many as the lanes
+    # K = 200 > C: most keys hold a lane or two, the rows are as many as the
+    # lanes whatever their length, so they are one slot long
     Case("cb_zipf_many_keys", keys=keys_zipf, K=200, C=256, win=8, slide=4,
-         want_T=2),
+         want_T=1),
     Case("tb_zipf_many_keys", TB, keys=keys_zipf, K=200, C=256, win=300,
-         slide=100, capacity=512, want_T=2),
+         slide=100, capacity=512, want_T=1),
     Case("cb_invalid_lanes", keys=keys_zipf, valid=valid_holes, K=16),
     Case("tb_invalid_lanes_and_an_empty_batch", TB, keys=keys_zipf,
          valid=valid_holes_and_an_empty_batch, K=16, win=400, slide=200,
@@ -183,19 +213,19 @@ CASES = [
     Case("tb_more_than_a_ring_in_one_batch", TB, keys=keys_zipf, C=256, K=5,
          win=400, slide=200, capacity=32, loses=True, max_wins=64),
     Case("cb_2d_payload_leaf", keys=keys_zipf, valid=valid_holes, K=9,
-         payload=payload_2d_leaf),
+         payload=payload_2d_leaf, groups=2),
     Case("tb_2d_payload_leaf", TB, keys=keys_zipf, valid=valid_holes, K=9,
-         payload=payload_2d_leaf, win=400, slide=200, capacity=512),
+         payload=payload_2d_leaf, win=400, slide=200, capacity=512, groups=2),
     Case("cb_keys_beyond_the_table", keys=keys_beyond_the_table, K=7),
     Case("tb_keys_beyond_the_table", TB, keys=keys_beyond_the_table, K=7,
          win=400, slide=200, capacity=512),
-    # more keys than lanes: never more than a run a lane, rows of two slots
+    # more keys than lanes: never more than a run a lane, rows of one slot
     Case("cb_more_keys_than_lanes", keys=keys_zipf, K=64, C=16, win=4,
-         slide=2, batches=10, want_T=2),
+         slide=2, batches=10, want_T=1),
     Case("tb_more_keys_than_lanes", TB, keys=keys_round_robin, K=64, C=16,
-         win=40, slide=20, capacity=8, batches=10, max_wins=64, want_T=2),
-    # the ring is shorter than the row length the batch alone would choose
-    # (C / 2K = 64): T = A = 4
+         win=40, slide=20, capacity=8, batches=10, max_wins=64, want_T=1),
+    # the ring is shorter than the row length the batch alone would choose:
+    # T = A = 4
     Case("tb_ring_smaller_than_a_row", TB, K=2, C=256, win=8, slide=8,
          capacity=4, loses=True, max_wins=64, want_T=4),
     Case("cb_ring_smaller_than_a_row", K=2, C=256, win=4, slide=4,
@@ -205,6 +235,39 @@ CASES = [
     Case("tb_batch_larger_than_the_bound_geometry", TB, keys=keys_one_hot,
          K=4, C=1024, win=400, slide=200, capacity=2048, bind=False),
 ]
+#: the shapes the rule meets at the chip's prices
+AT_THE_CHIPS_PRICES = [
+    # kpf's WLQ: 512 keys, rings of 64 slots, 8,704 pane results a batch, 17
+    # a key: T = A, a key's ring is one row, and the head and the last chunk
+    # are that same row whenever a key's count passes a multiple of 64
+    Case("tb_wlq_shape_whole_rings", TB, K=512, C=8704, win=4000, slide=1000,
+         capacity=64, max_wins=2048, batches=5, want_T=64),
+    # kpf's PLQ (512 keys, rings of 4,096) and ysb_wmr's engine (100 keys,
+    # rings of 8,192, a third of the lanes live) at a sixty-fourth and a
+    # thirty-second of their batch
+    Case("tb_plq_shape_reduced_batch", TB, K=512, C=16384, win=512, slide=512,
+         capacity=4096, max_wins=1100, batches=4, want_T=128),
+    Case("tb_ysb_wmr_shape_reduced_batch", TB, keys=keys_uniform,
+         valid=valid_a_third, K=100, C=32768, win=2000, slide=2000,
+         capacity=8192, max_wins=256, batches=4, want_T=512),
+    # 250 lanes of one key into a ring that is one row of 64: the last 64 are
+    # written, through the head and through one body chunk of the same row
+    Case("cb_more_than_a_ring_of_one_key_whole_rings", keys=keys_one_hot,
+         C=250, K=8, win=16, slide=8, capacity=64, loses=True, want_T=64),
+    Case("tb_more_than_a_ring_of_one_key_whole_rings", TB, keys=keys_one_hot,
+         C=250, K=8, win=60, slide=30, capacity=64, loses=True, max_wins=64,
+         want_T=64),
+    Case("tb_an_empty_batch_long_rows", TB, keys=keys_zipf,
+         valid=valid_holes_and_an_empty_batch, K=16, win=400, slide=200,
+         capacity=512, want_T=64),
+    Case("cb_mixed_leaves_two_groups", keys=keys_zipf, valid=valid_holes, K=9,
+         payload=payload_mixed_leaves, groups=2, want_T=128),
+    Case("tb_mixed_leaves_two_groups", TB, keys=keys_zipf, valid=valid_holes,
+         K=9, payload=payload_mixed_leaves, win=400, slide=200, capacity=512,
+         groups=2, want_T=128),
+]
+CASES = ([dataclasses.replace(case, slice_us=0.01) for case in MANY_SHORT_ROWS]
+         + AT_THE_CHIPS_PRICES)
 
 
 def make_op(case, fn=None):
@@ -234,12 +297,16 @@ def stream(case, seed=11):
 
 
 def assert_same(got, want, what):
+    """Bit for bit: floats are compared as the words they are."""
     got_l, tree_g = jax.tree.flatten(got)
     want_l, tree_w = jax.tree.flatten(want)
     assert tree_g == tree_w, what
     for g, w in zip(got_l, want_l):
         assert g.dtype == w.dtype and g.shape == w.shape, what
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), what)
+        g, w = np.asarray(g), np.asarray(w)
+        if g.dtype.kind == "f":
+            g, w = g.view(np.uint32), w.view(np.uint32)
+        np.testing.assert_array_equal(g, w, what)
 
 
 def live_rows(out):
@@ -257,7 +324,9 @@ def window_fn(wid, it):
 
 
 @pytest.mark.parametrize("case", CASES, ids=lambda c: c.name)
-def test_sorted_insert_equals_the_per_lane_scatters(case):
+def test_sorted_insert_equals_the_per_lane_scatters(case, monkeypatch):
+    if case.slice_us is not None:
+        monkeypatch.setattr(win_seq, "SLICE_US", case.slice_us)
     op = make_op(case, window_fn)
     assert case.want_T in (None, op.run_len)
     assert op.A % op.run_len == 0
@@ -288,6 +357,8 @@ def test_sorted_insert_equals_the_per_lane_scatters(case):
     assert_same(live_rows(flush_new), live_rows(flush_old), "flush")
     assert emitted + int(np.asarray(flush_new.valid).sum()) > 0
     assert (int(np.asarray(s_new.overwrites)) > 0) == case.loses
+    op.collect_stats(s_new)
+    assert op.stage_counters()["archive_run_groups"] == case.groups
     if case.ts is ts_with_stragglers:
         assert int(np.asarray(s_new.dropped_old)) > 0
 
@@ -330,18 +401,58 @@ def test_keys_outside_the_table_are_dropped_like_invalid_lanes():
 
 
 @pytest.mark.parametrize("c,k,a,want", [
-    (1_048_576, 100, 8_192, 4_096),       # the ysb_wmr cell: 456 rows of 4,096
-    (1_048_576, 512, 2_097_152, 1_024), (256, 8, 512, 16), (16, 1024, 32, 2),
-    (256, 2, 4, 4), (1, 1, 2, 2), (1, 1, 1, 1)])
+    (1_048_576, 100, 8_192, 2_048),       # ysb_wmr: 712 rows of 2,048
+    (1_048_576, 512, 4_096, 1_024),       # kpf's PLQ: 2,048 rows of 1,024
+    (8_704, 512, 64, 64),                 # kpf's WLQ: 1,160 whole rings
+    (1_048_576, 512, 2_097_152, 1_024), (256, 8, 512, 128), (16, 1024, 32, 1),
+    (256, 2, 4, 4), (1, 1, 2, 1), (1, 1, 1, 1)])
 def test_row_length_follows_the_shapes(c, k, a, want):
+    """The row length is the cheapest for a batch's two passes at the chip's
+    two prices, and the longer where two cost the same."""
     op = Win_Seq(lambda wid, it: it.sum("v"), WindowSpec(4, 4, TB), num_keys=k,
                  tb_capacity=a)
     op.bind_geometry(c)
     T = op.run_len
     assert T == want and a % T == 0
-    assert run_budget(c, k, T) * T <= 2 * c or T == 1
-    assert T == a or run_budget(c, k, 2 * T) * 2 * T > 2 * c
-    assert op.run_rows <= c
+
+    def cost(t):
+        rows = k + min(c, c // t + min(k, c))
+        return rows * (SLICE_US + t * 16 / (SLICE_GBPS * 1e3))
+    lengths = [1 << e for e in range(a.bit_length())]
+    assert lengths[-1] == a and T in lengths
+    assert all(cost(T) <= cost(t) for t in lengths)
+    assert all(cost(T) < cost(t) for t in lengths if t > T)
+    assert op.run_rows == min(c, c // T + min(k, c))
+    assert op.stage_counters()["archive_run_rows"] == k + op.run_rows
+
+
+@pytest.mark.parametrize("payload,columns,groups", [
+    (payload_scalar, 3, 1), (payload_mixed_leaves, 5, 2)])
+def test_a_pass_takes_each_row_window_once_for_all_the_columns(
+        payload, columns, groups):
+    """The traced ``_insert``: what reads the sorted, padded columns is
+    ``archive_run_groups`` gathers a pass (two passes), one start a ring row
+    each, not one gather a column; the buffers they read hold every column
+    between them."""
+    case = Case("takes", TB, keys=keys_zipf, K=16, C=256, win=400, slide=200,
+                capacity=512, payload=payload)
+    op = make_op(case)
+    batch = next(stream(case))
+    state = op.init_state(jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), batch.payload))
+    jaxpr = jax.make_jaxpr(op._insert)(state, batch).jaxpr
+    T, rows = op.run_len, op.run_rows
+    lanes = case.C + 2 * T
+    takes = [eqn for eqn, _ in equations(jaxpr)
+             if eqn.primitive.name == "gather"
+             and eqn.invars[0].aval.shape[1:2] == (lanes,)]
+    assert len(jax.tree.leaves((batch.payload, batch.id, batch.ts))) == columns
+    assert op._budget_gauges()["archive_run_groups"] == groups
+    assert len(takes) == 2 * groups
+    # a start a row: the head pass has a row a key, the body pass the listed
+    assert sorted(eqn.invars[1].aval.shape[0] for eqn in takes) == sorted(
+        [case.K, rows] * groups)
+    assert sum(eqn.invars[0].aval.shape[0] for eqn in takes) == 2 * columns
 
 
 def test_sorted_order_primitives():
@@ -358,6 +469,18 @@ def test_sorted_order_primitives():
     assert np.asarray(i)[:5].tolist() == [0, 1, 0, 1, 2]
     win = take_windows(jnp.arange(10), jnp.asarray([0, 3, 7]), 3)
     assert np.asarray(win).tolist() == [[0, 1, 2], [3, 4, 5], [7, 8, 9]]
+    # a pytree of columns: the 32-bit ones of rank 1 share a gather whatever
+    # their dtype, the others ride with their like
+    columns = {"i": jnp.arange(10), "f": -jnp.arange(10, dtype=jnp.float32),
+               "u": jnp.arange(10, dtype=jnp.uint32), "b": jnp.arange(10) % 2 > 0,
+               "m": jnp.arange(20).reshape(10, 2), "h": jnp.arange(10, dtype=jnp.int16)}
+    leaves = jax.tree.leaves(columns)                 # b, f, h, i, m, u
+    assert list(window_groups(leaves).values()) == [[0], [1, 3, 5], [2], [4]]
+    wins = jax.jit(lambda c: take_windows(c, jnp.asarray([0, 3, 7]), 3))(columns)
+    for name, column in columns.items():
+        want = np.stack([np.asarray(column)[s:s + 3] for s in (0, 3, 7)])
+        assert wins[name].dtype == column.dtype
+        np.testing.assert_array_equal(np.asarray(wins[name]), want)
 
 
 @pytest.mark.parametrize("c,block", [(100, 4), (1000, 8), (64, 64), (5000, 1024)])
@@ -377,16 +500,18 @@ def test_range_max_equals_a_loop(c, block, monkeypatch):
     assert np.asarray(got).tolist() == want
 
 
-def test_the_rows_are_counted():
+def test_the_rows_are_counted(monkeypatch):
     """``archive_run_len`` and ``archive_run_rows`` at ``bind_geometry``,
+    ``archive_run_groups`` once an insert has seen the payload,
     ``archive_runs_written`` after a run: 8 keys round-robin, 32 lanes a key
     a batch into rows of 16 from an aligned count, so 2 rows a key a batch."""
     case = CASES[0]
+    monkeypatch.setattr(win_seq, "SLICE_US", case.slice_us)
     op = make_op(case)
     gauges = {"archive_slots": op.A, "archive_run_len": 16,
               "archive_run_rows": 8 + 256 // 16 + 8}
     assert op.stage_counters() == gauges
-    assert set(gauges) <= set(STAGE_GAUGES)
+    assert set(gauges) | {"archive_run_groups"} <= set(STAGE_GAUGES)
     assert "archive_runs_written" in STAGE_COUNTERS
     step = jax.jit(op.apply)
     st = op.init_state({"v": jax.ShapeDtypeStruct((), jnp.int32)})
@@ -396,4 +521,6 @@ def test_the_rows_are_counted():
     counters = op.stage_counters()
     assert counters["archive_runs_written"] == case.batches * 8 * 2
     assert counters["archive_run_len"] == 16
+    # payload, id and ts in one gather a pass: 2 x 32 slices a batch, not 6 x
+    assert counters["archive_run_groups"] == 1
     assert counters["archive_overwrites"] == 0

@@ -142,12 +142,12 @@ def test_budgets_come_from_the_deployment():
     assert 6829 < slots <= 8192 and max_wins == 200
     window = mod.build_ops(published, 1 << 20)[-1]
     assert isinstance(window, Win_MapReduce) and window.M == 4
-    # the insert moves the rings as 456 rows of 4,096 slots a table a batch
-    # (100 head rows, 256 + 100 body rows) in place of 1,048,576 lanes
+    # the insert moves the rings as 712 rows of 2,048 slots a table a batch
+    # (100 head rows, 512 + 100 body rows) in place of 1,048,576 lanes
     window.bind_geometry(1 << 20)               # as the compiled chain does
     assert window.stage_counters() == {
         "archive_slots": 8192, "fired_window_budget": 200,
-        "archive_run_len": 4096, "archive_run_rows": 456}
+        "archive_run_len": 2048, "archive_run_rows": 712}
     assert window.engine.A * window.num_keys * 4 * 4 < 14e6   # four tables
 
 
@@ -201,11 +201,13 @@ def test_eos_flush_delivers_more_open_windows_than_the_budget(pattern):
                            spec, map_parallelism=2, **kw)
     got = run_engine(op, keys, ts, batch=32)
     assert got == [(k, w, 2) for k in range(K) for w in (0, 9)]
-    # two batches of 32 lanes, two a key: a head row of 2 slots a key a batch
+    # two batches of 32 lanes, two a key: a head row a key a batch, a key's
+    # whole ring of 8 slots (20 rows a pass cost less than 32 of 2 slots),
+    # the payload, id and ts of a row in one gather
     assert op.stage_counters() == {
         "archive_slots": 8, "fired_window_budget": 4, "archive_overwrites": 0,
         "old_drops": 0, "windows_undelivered_at_eos": 0,
-        "archive_run_len": 2, "archive_run_rows": 48,
+        "archive_run_len": 8, "archive_run_rows": 36, "archive_run_groups": 1,
         "archive_runs_written": 32}
 
 
@@ -259,6 +261,31 @@ def test_lowered_step_carries_the_engine_phases():
     assert span_reduce.scope_of(path + ":reduce")[1:] == (window, "emit")
 
 
+@pytest.mark.parametrize("name", ["ysb", "kcb"])
+def test_the_other_cells_step_programs_do_not_reach_the_archive_engine(name):
+    """``ysb`` and ``kcb`` window through ``Key_FFAT``: nothing in their
+    lowered step comes from ``win_seq.py`` or from ``ops/segment.py`` at or
+    below ``take_windows`` and its prices, so a change there leaves their
+    programs, and the compile cache's keys for them (which take each
+    operation's source line), as they were."""
+    import inspect
+    import re
+    from windflow_tpu.ops import segment
+    # (a library function jitted on its own, ``jnp.searchsorted`` say, keeps
+    # the call site it was first traced from: start as the cell's process does)
+    jax.clear_caches()
+    mod, cfg = load_config(name)
+    _, step, args = chain_step(cfg, mod, 8192)
+    text = step.lower(*args).as_text(debug_info=True)
+    assert "win_seqffat.py" in text                    # the locations are there
+    assert "win_seq.py" not in text and "take_windows" not in text
+    above, first = inspect.getsourcelines(segment.range_max)
+    fence = first + len(above)              # what follows range_max moved
+    lines = [int(n) for n in re.findall(r'ops/segment\.py":(\d+)', text)]
+    assert (name == "kcb") == bool(lines)        # its run folds live above
+    assert all(n < fence for n in lines), (max(lines), fence)
+
+
 def equations(jaxpr, scope=""):
     """(equation, its whole scope path) through every nested jaxpr."""
     for eqn in jaxpr.eqns:
@@ -275,7 +302,7 @@ def equations(jaxpr, scope=""):
 def test_the_insert_at_the_published_size_moves_rows_not_lanes():
     """C = 1,048,576, K = 100, A = 8,192 as the cell builds them: under
     ``insert`` no scatter and no gather has an index for every lane (the
-    largest has one for each of the 356 body rows), the sort is the only
+    largest has one for each of the 612 body rows), the sort is the only
     operation of its kind, and the phase still sits right under the pattern's
     scope."""
     mod, _ = load_config("ysb_wmr")
@@ -289,13 +316,23 @@ def test_the_insert_at_the_published_size_moves_rows_not_lanes():
               equations(jax.make_jaxpr(step)(*args).jaxpr)
               if f"{window}/insert" in path]
     assert all(path.startswith(f"{window}/insert") for _, path in insert)
-    moves = [(eqn.primitive.name, int(np.prod(eqn.invars[1].aval.shape)), path)
+    moves = [(eqn.primitive.name, eqn.invars[1].aval.shape[0], path)
              for eqn, path in insert
              if eqn.primitive.name.startswith(("scatter", "gather"))]
     assert len(moves) > 20
-    assert max(n for _, n, _ in moves) == 356, sorted(moves)[-3:]
+    assert max(n for _, n, _ in moves) == 612, sorted(moves)[-3:]
     assert {name for name, _, path in moves if "/write" in path} == {
         "gather", "scatter"}
+    # cmp, id and ts ride one buffer: a gather of row windows a pass, not one
+    # a column, and the engine says so
+    takes = [eqn.invars[1].aval.shape[0] for eqn, path in insert
+             if eqn.primitive.name == "gather"
+             and eqn.invars[0].aval.shape == (3, C + 2 * 2048)]
+    assert sorted(takes) == [100, 612], sorted(moves)
+    assert ops[-1].engine._budget_gauges() == {
+        "archive_slots": 8192, "fired_window_budget": 200,
+        "archive_run_len": 2048, "archive_run_rows": 712,
+        "archive_run_groups": 1}
     sorts = [path for eqn, path in insert if eqn.primitive.name == "sort"]
     assert sorts == [f"{window}/insert/rank/sort"]
     assert not [eqn.primitive.name for eqn, _ in insert

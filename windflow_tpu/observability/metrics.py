@@ -1141,6 +1141,9 @@ class MetricsRegistry:
                                "insert moves it",
             "archive_run_rows": "window-archive ring rows one batch may "
                                 "write per table",
+            "archive_run_groups": "gathers of the sorted columns one pass "
+                                  "of the window-archive insert issues, "
+                                  "one slice a ring row each",
             "archive_runs_written": "window-archive ring rows written per "
                                     "table",
         }

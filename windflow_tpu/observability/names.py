@@ -181,7 +181,8 @@ CONTROL_GAUGES = (
 ARCHIVE_ENGINE_COUNTERS = ("archive_overwrites", "old_drops",
                            "windows_undelivered_at_eos", "archive_runs_written")
 ARCHIVE_ENGINE_GAUGES = ("archive_slots", "fired_window_budget",
-                         "archive_run_len", "archive_run_rows")
+                         "archive_run_len", "archive_run_rows",
+                         "archive_run_groups")
 ARCHIVE_ENGINE_DROPS = ("archive_overwrites",)
 PANE_STAGES = ("plq", "wlq")
 
@@ -230,8 +231,11 @@ STAGE_GAUGES = (
     "ffat_run_budget", "ffat_keys", "ffat_pane_slots",
     # operators/win_seq.py, set at bind_geometry: the archive ring's slots per
     # key and the fired windows one batch may emit; the slots of one ring row
-    # as the insert moves them, and the rows one batch may write per table
+    # as the insert moves them, and the rows one batch may write per table; at
+    # the first insert: the gathers of the sorted columns a pass issues (one
+    # slice a row each; 1 where every column shares a buffer)
     "archive_slots", "fired_window_budget", "archive_run_len", "archive_run_rows",
+    "archive_run_groups",
     # Pane_Farm's two engines' budgets, a prefix a stage
     *(f"{stage}_{gauge}" for stage in PANE_STAGES
       for gauge in ARCHIVE_ENGINE_GAUGES),

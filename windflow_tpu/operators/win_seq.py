@@ -35,8 +35,10 @@ unfired window still needs: the state counts them (``overwrites``), and the OLD 
 (``archive_overwrites``, ``old_drops``, ``archive_slots``, ``fired_window_budget``);
 ``flush`` adds ``windows_undelivered_at_eos``. The insert's row geometry follows the
 shapes (``_row_geometry``: batch capacity, keys, ring slots) and is published with
-them: ``archive_run_len``, ``archive_run_rows``, and ``archive_runs_written`` counts
-the rows the inserts wrote per table (beside the tuples they archived).
+them: ``archive_run_len``, ``archive_run_rows``, ``archive_run_groups`` (the gathers
+of the sorted columns a pass issues, one slice a row each), and
+``archive_runs_written`` counts the rows the inserts wrote per table (beside the
+tuples they archived).
 
 Emission order is per-key ascending window id — the ordered-collector guarantee of
 ``WF_Collector`` (``wf/wf_nodes.hpp:253-318``) by construction.
@@ -54,8 +56,8 @@ import jax.numpy as jnp
 from ..basic import routing_modes_t, role_t, DEFAULT_MAX_KEYS
 from ..batch import Batch, CTRL_DTYPE, TupleRef
 from ..meta import classify_window, classify_winupdate
-from ..ops.segment import (enumerate_runs, range_max, run_budget, sort_segments,
-                           take_windows)
+from ..ops.segment import (SLICE_GBPS, SLICE_US, enumerate_runs, range_max,
+                           sort_segments, take_windows, window_groups)
 from .base import Basic_Operator
 from .window import Iterable, WindowSpec
 
@@ -132,6 +134,7 @@ class Win_Seq(Basic_Operator):
         self.A = None                  # resolved in bind_geometry
         self.max_wins = max_wins       # resolved at first apply if None
         self._w = None
+        self._run_groups = None        # settled by the first _insert
         self._wshard = None            # (mesh, axis): shard the fired-window W axis
         #: the operator whose ``Class:name`` scope the chain opens around this
         #: engine's ``apply``: itself, or the pattern that owns it; and the
@@ -159,16 +162,26 @@ class Win_Seq(Basic_Operator):
 
     def _row_geometry(self, capacity: int):
         """How ``_insert`` cuts a batch of ``capacity`` lanes: ``(T, rows)``.
-        The rings move as rows of ``T`` slots, the largest power of two, up to
-        ``A``, at which the rows one batch may touch (``run_budget``) hold no
-        more than twice its lanes; ``rows`` bounds the runs after a key's
-        first (each key has at most ``n // T + 1`` of them, and fewer than
-        ``n``)."""
-        K, T = self.num_keys, 1
-        while (2 * T <= self.A
-               and run_budget(capacity, K, 2 * T) * 2 * T <= 2 * capacity):
-            T *= 2
-        return T, min(capacity, capacity // T + min(K, capacity))
+        The rings move as rows of ``T`` slots, the power of two up to ``A``
+        at which a batch's two passes cost least (the longer where two cost
+        the same). A pass pays by the row, a head row a key and the ``rows``
+        that bound the runs after a key's first (each key has at most ``n //
+        T + 1`` of them, and fewer than ``n``): one ``take_windows`` slice
+        (``SLICE_US``, whatever it holds) and the row's ``T`` lanes of id, ts,
+        position and a payload word at ``SLICE_GBPS``. A small ring so moves
+        as whole rings (``T = A``: 64 slots cost what 8 do), a large one in
+        rows about as long as a slice's price buys."""
+        K = self.num_keys
+
+        def rows(T):
+            return min(capacity, capacity // T + min(K, capacity))
+
+        def cost_us(T):
+            return (K + rows(T)) * (SLICE_US + T * 16 / (SLICE_GBPS * 1e3))
+
+        T = min((1 << e for e in range(self.A.bit_length())),
+                key=lambda T: (cost_us(T), -T))
+        return T, rows(T)
 
     # ------------------------------------------------------------------ state
 
@@ -227,8 +240,13 @@ class Win_Seq(Basic_Operator):
         the rest), so the written positions ``[begin, end)`` cover at most
         ``A / T + 1`` chunks. The chunks after the first are distinct ring rows
         (the body, ``enumerate_runs``); the first may share its row with the
-        last, so it is written in a pass of its own (the head, one row a key):
-        no pass holds a ring row twice, and the two write disjoint slots."""
+        last (and always does where ``T = A``: a key's ring is one row), so it
+        is written in a pass of its own (the head, one row a key): no pass
+        holds a ring row twice, and the two write disjoint slots. A pass reads
+        each row's window of the sorted columns once for all the columns that
+        can share a buffer (``take_windows``; ``archive_run_groups`` such
+        gathers a pass, 1 where payload, id and ts are all 32-bit columns),
+        because what a window costs there does not depend on what it holds."""
         from ..ops.lookup import table_lookup
         K, A = self.num_keys, self.A
         T, body_rows = self._row_geometry(batch.capacity)
@@ -296,11 +314,12 @@ class Win_Seq(Basic_Operator):
                     new, jnp.take(ring, row, axis=0, mode="clip"))
                 return ring.at[row].set(rows, mode="drop").reshape(tbl.shape)
 
-            return (*jax.tree.map(
-                lambda tbl, column: put(tbl, take_windows(column, lane, T)),
-                tables[:3], padded), put(tables[3], pos))
+            return (*jax.tree.map(put, tables[:3],
+                                  take_windows(padded, lane, T)),
+                    put(tables[3], pos))
 
         with jax.named_scope("write"):
+            self._run_groups = len(window_groups(jax.tree.leaves(padded)))
             tables = (state.arch_payload, state.arch_id, state.arch_ts,
                       state.arch_pos)
             for key, chunk, live in passes:
@@ -485,11 +504,15 @@ class Win_Seq(Basic_Operator):
     def _budget_gauges(self) -> dict:
         """The static budgets: ring slots per key, the insert's row length and
         the rows one batch may write per table (a head row a key and the
-        listed ones), fired windows a batch (once ``max_wins`` or the first
+        listed ones), the ``take_windows`` gathers a pass issues (each of
+        them one slice a row; once the first ``_insert`` has seen the
+        payload), fired windows a batch (once ``max_wins`` or the first
         ``apply`` has settled it)."""
         W = self.max_wins if self.max_wins is not None else self._w
         return {"archive_slots": self.A, "archive_run_len": self.run_len,
                 "archive_run_rows": self.num_keys + self.run_rows,
+                **({} if self._run_groups is None
+                   else {"archive_run_groups": self._run_groups}),
                 **({} if W is None else {"fired_window_budget": W})}
 
     def collect_stats(self, state=None) -> None:

@@ -472,13 +472,55 @@ def range_max(values: jax.Array, first: jax.Array, n: jax.Array,
         lo, hi = lo // B + 1, (hi - 1) // B
 
 
-def take_windows(column: jax.Array, start: jax.Array, length: int) -> jax.Array:
-    """``column[start[r] : start[r] + length]`` for every r as ``[R, length,
-    ...]``: one gather of R contiguous slices (R indices, not one a lane).
-    The caller keeps every window inside the column (``dynamic_slice`` would
-    shift one that is not)."""
-    return jax.vmap(
-        lambda s: jax.lax.dynamic_slice_in_dim(column, s, length, axis=0))(start)
+#: what :func:`take_windows` pays on one v5e (PERF.md section 6, PR 33): XLA:TPU
+#: runs the gather as a loop of one ``dynamic-slice`` a window, and a row of an
+#: insert pass, that step and the row's trip through the ring tables, costs
+#: ``SLICE_US`` microseconds whatever it holds (0.97 by ``Win_Seq._insert`` at
+#: 5,120, 3,072 and 2,048 rows a batch; 0.80-0.95 for the slice alone at 8 to
+#: 512 lanes of three columns). ``SLICE_GBPS``: the rate, in 16-byte lanes
+#: (payload word, id, ts, position), at which ``_insert`` moves the lanes it
+#: adds between rows of 1,024 and of 4,096 slots (15.3 at 512 keys, 13.7 at
+#: 100): under 0.4 ns a lane up to 2,048 slots and 1.4-1.7 beyond, where a
+#: slice of three stacked columns goes from 1.18 us to 2.89 (5.74 at 8,192)
+SLICE_US = 0.97
+SLICE_GBPS = 15.0
+
+
+def window_groups(leaves) -> dict:
+    """Which ``[C, ...]`` leaves share one :func:`take_windows` gather:
+    ``{(carrier dtype, trailing shape): [their indices]}``. 32-bit numbers
+    ride as int32 (bit patterns, never converted), anything else as itself."""
+    groups = {}
+    for i, leaf in enumerate(leaves):
+        dtype = jnp.dtype(leaf.dtype)
+        if dtype.itemsize == 4 and dtype.kind in "iuf":
+            dtype = jnp.dtype(jnp.int32)
+        groups.setdefault((dtype, tuple(leaf.shape[1:])), []).append(i)
+    return groups
+
+
+def take_windows(columns: Any, start: jax.Array, length: int) -> Any:
+    """``column[start[r] : start[r] + length]`` for every r and every ``[C,
+    ...]`` leaf of ``columns``, as the same pytree of ``[R, length, ...]``:
+    gathers of R contiguous slices (R indices, not one a lane). XLA:TPU runs
+    such a gather as a loop of R ``dynamic-slice``s that cost the same
+    whatever they hold (``SLICE_US``), so the leaves of one
+    :func:`window_groups` group are stacked ``[n, C, ...]`` and every window
+    is taken once for all of them; floats ride as their bit patterns, so the
+    result is exact. The caller keeps every window inside the columns
+    (``dynamic_slice`` would shift one that is not)."""
+    def bits_as(x, dtype):
+        return x if x.dtype == dtype else jax.lax.bitcast_convert_type(x, dtype)
+
+    leaves, treedef = jax.tree.flatten(columns)
+    out = [None] * len(leaves)
+    for (carrier, _), members in window_groups(leaves).items():
+        stacked = jnp.stack([bits_as(leaves[i], carrier) for i in members])
+        windows = jax.vmap(lambda s: jax.lax.dynamic_slice_in_dim(
+            stacked, s, length, axis=1))(start)          # [R, n, length, ...]
+        for j, i in enumerate(members):
+            out[i] = bits_as(windows[:, j], leaves[i].dtype)
+    return treedef.unflatten(out)
 
 
 # ------------------------------------------------------------- registration
