@@ -329,4 +329,7 @@ def test_geometry_gauges_are_published_at_bind_geometry():
         "ffat_pane_slots": op.P, "old_drops": 0}
     tb = Win_SeqFFAT(Lifts.value, jnp.add, spec=WindowSpec(32, 16, win_type_t.TB),
                      num_keys=8, global_time=False)
-    assert tb.stage_counters() == {}
+    # a time-based spec has no run budget; its fired-window budget joins the
+    # ring's sizes once max_wins= or the first apply has settled it (the
+    # global-time path knows it from the ring: tests/test_kff_config.py)
+    assert tb.stage_counters() == {"ffat_keys": 8, "ffat_pane_slots": tb.P}

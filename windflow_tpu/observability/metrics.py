@@ -1135,6 +1135,12 @@ class MetricsRegistry:
                                   "unfired window still needed them",
             "windows_undelivered_at_eos": "open windows the EOS flush left "
                                           "undelivered",
+            "ffat_ring_overruns": "tuples folded into a pane-ring slot whose "
+                                  "pane had not fired yet",
+            "ffat_run_budget": "(key, pane) runs one batch may hold in the "
+                               "count-based pane fold",
+            "ffat_keys": "keys of the pane ring",
+            "ffat_pane_slots": "pane-ring slots per key",
             "archive_slots": "window-archive ring slots per key",
             "fired_window_budget": "fired windows one batch may emit",
             "archive_run_len": "slots of one window-archive ring row as the "

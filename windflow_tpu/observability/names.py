@@ -212,6 +212,10 @@ STAGE_COUNTERS = (
     # has flushed until None); ring rows the sorted-order inserts wrote, per
     # table (beside tuples_in: how many row writes replaced how many lanes)
     "archive_overwrites", "windows_undelivered_at_eos", "archive_runs_written",
+    # operators/win_seqffat.py, global-time path: lanes folded into a ring slot
+    # whose pane had not fired (their pane lay ffat_pane_slots or more past
+    # the first unfired one); it publishes windows_undelivered_at_eos too
+    "ffat_ring_overruns",
     # operators/win_patterns.py::Pane_Farm: its two engines' counters, each
     # under its stage's prefix (``plq_old_drops``, ``wlq_archive_overwrites``)
     *(f"{stage}_{counter}" for stage in PANE_STAGES
@@ -225,12 +229,14 @@ STAGE_GAUGES = (
     # key count — the per-operator tier_occupancy pair wf_state.py trends
     # and wf_health.py cross-references against the HBM headroom gauge
     "tier_hot_used", "tier_cold_keys",
-    # operators/win_seqffat.py, count-based windows, set at bind_geometry:
-    # the (key, pane) runs one batch may hold (the size the sorted-order
-    # insert compacts to and writes), the keys and the ring slots per key
+    # operators/win_seqffat.py, set at bind_geometry: the keys and the ring
+    # slots per key; count-based windows: the (key, pane) runs one batch may
+    # hold (the size the sorted-order insert compacts to and writes);
+    # time-based windows: fired_window_budget (below)
     "ffat_run_budget", "ffat_keys", "ffat_pane_slots",
     # operators/win_seq.py, set at bind_geometry: the archive ring's slots per
-    # key and the fired windows one batch may emit; the slots of one ring row
+    # key and the fired windows one batch may emit (win_seqffat.py, time-based:
+    # a key on the global-time path); the slots of one ring row
     # as the insert moves them, and the rows one batch may write per table; at
     # the first insert: the gathers of the sorted columns a pass issues (one
     # slice a row each; 1 where every column shares a buffer)

@@ -136,7 +136,8 @@ def _sig_watermark_lag(snap, prev) -> Optional[float]:
 
 #: per-stage counters that count lost tuples (OLD drops come with the totals)
 _DROP_COUNTERS = frozenset((
-    "overflow_drops", "match_drops", "arch_drops", *ARCHIVE_ENGINE_DROPS,
+    "overflow_drops", "match_drops", "arch_drops", "ffat_ring_overruns",
+    *ARCHIVE_ENGINE_DROPS,
     *(f"{s}_{c}" for s in PANE_STAGES for c in ARCHIVE_ENGINE_DROPS)))
 
 

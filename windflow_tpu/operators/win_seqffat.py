@@ -67,11 +67,36 @@ class GFFATState:
     wm: jax.Array         # i32[] global max ts seen
     next_win: jax.Array   # i32[] next window id to fire (global)
     dropped_old: jax.Array  # i32[] tuples dropped as OLD (pane < fired horizon)
+    #: i32[] lanes folded into a ring slot that an unfired pane still held: their
+    #: pane lay P or more past the first unfired one (counted where the fold
+    #: goes by slot; the count-lift branch passes it through)
+    ring_overruns: jax.Array
     #: i32[NB] observed-lateness histogram (event-time monitoring only)
     lat_hist: Any = None
 
 
 class Win_SeqFFAT(Basic_Operator):
+    """The pane-partial window engine (``Key_FFAT`` is this class with its
+    pattern tag).
+
+    Budgets: ``pane_capacity=`` ring slots per key (rounded up to a power of
+    two) and ``max_wins=`` fired windows a step (a key on the global-time
+    path); the time-based defaults follow the batch, not the deployment.
+
+    What it publishes (``stage_counters()``): at ``bind_geometry`` the static
+    sizes, ``ffat_keys``, ``ffat_pane_slots``, for count-based specs
+    ``ffat_run_budget``, for time-based ones ``fired_window_budget`` once it
+    is known (``max_wins=``, the ring on the global-time path, else the first
+    ``apply``); at ``collect_stats`` ``old_drops`` and, on the global-time
+    path with a lift that reads the tuple, ``ffat_ring_overruns`` (lanes
+    whose pane lay ``P`` or more past the first unfired pane: they were folded
+    into a slot that an unfired pane still held; the count-lift branch folds no
+    value by slot and publishes none); at ``flush``
+    ``windows_undelivered_at_eos``.
+
+    ``flush`` returns one batch of open windows a call and None once none is
+    left; a pass that holds only windows without a tuple is passed over."""
+
     routing = routing_modes_t.KEYBY
 
     def __init__(self, lift: Callable, combine: Callable, *, spec: WindowSpec,
@@ -131,14 +156,25 @@ class Win_SeqFFAT(Basic_Operator):
             # very bursty timestamp distributions).
             self.P = _next_pow2(self.wpanes
                                 + max(64, batch_capacity // self.pane_len) + 2)
+        # the static sizes the step is compiled for: keys, ring slots per key
+        gauges = {"ffat_keys": self.num_keys, "ffat_pane_slots": self.P}
         if self.spec.is_cb:
-            # the static sizes the insert is compiled for: (key, pane) runs a
-            # batch may hold, keys, ring slots per key
+            # the (key, pane) runs a batch may hold
             from ..ops.segment import run_budget
-            self._publish_stage_counters({
-                "ffat_run_budget": run_budget(batch_capacity, self.num_keys,
-                                              self.pane_len),
-                "ffat_keys": self.num_keys, "ffat_pane_slots": self.P})
+            gauges["ffat_run_budget"] = run_budget(
+                batch_capacity, self.num_keys, self.pane_len)
+        else:
+            gauges.update(self._fired_budget_gauge())
+        self._publish_stage_counters(gauges)
+
+    def _fired_budget_gauge(self) -> dict:
+        """Fired windows one step may emit (a key on the global-time path, in
+        all otherwise), time-based specs: known from ``max_wins=`` or the
+        ring (global time), else once the first ``apply`` has settled it."""
+        W = self.max_wins if self.max_wins is not None else self._w
+        if W is None and self.global_time:
+            W = self._resolve_w(0)
+        return {} if W is None else {"fired_window_budget": W}
 
     def out_capacity(self, in_capacity: int) -> int:
         if self.global_time:
@@ -170,6 +206,7 @@ class Win_SeqFFAT(Basic_Operator):
                 wm=jnp.asarray(-1, CTRL_DTYPE),
                 next_win=jnp.asarray(0, CTRL_DTYPE),
                 dropped_old=jnp.zeros((), CTRL_DTYPE),
+                ring_overruns=jnp.zeros((), CTRL_DTYPE),
                 lat_hist=lat,
             )
         return FFATState(
@@ -203,7 +240,12 @@ class Win_SeqFFAT(Basic_Operator):
         ``WF_KERNEL_IMPL`` with no code change here. Slot cleanliness is
         maintained by clear-on-fire in ``_g_emit`` so no pane-id bookkeeping is
         needed; OLD tuples (pane already fired) are dropped with a scalar
-        horizon compare."""
+        horizon compare. The ring holds the ``P`` panes from the first unfired
+        one: a lane further ahead shares its slot with a pane that has not
+        fired, and where the partials are folded by slot such lanes are
+        counted (``ring_overruns`` -> ``ffat_ring_overruns``; size the ring
+        with ``pane_capacity=``). Scopes: ``hist`` (the occupancy
+        histogram), ``fold`` (lift, segment fold, the add into the ring)."""
         from ..ops.histogram import keyed_pane_histogram
         K, P = self.num_keys, self.P
         pane = batch.ts // self.pane_len
@@ -212,30 +254,40 @@ class Win_SeqFFAT(Basic_Operator):
         # stragglers behind the fired horizon are DROPPED, not merely delayed
         # (global clock: per-key skew > delay loses tuples) — count them
         n_dropped = jnp.sum((batch.valid & ~valid).astype(CTRL_DTYPE))
-        cnt_upd = keyed_pane_histogram(batch.key, pane, valid, K, P)
-        cnt = state.cnt + cnt_upd
+        with jax.named_scope("hist"):
+            cnt_upd = keyed_pane_histogram(batch.key, pane, valid, K, P)
+            cnt = state.cnt + cnt_upd
         if self.count_lift is None:
             self.count_lift = _detect_count_lift(self.lift, batch)
-        if self.count_lift and self.combine is jnp.add:
-            # lift == 1: the value histogram IS the count histogram
-            panes = jax.tree.map(
-                lambda t: t + cnt_upd.astype(t.dtype), state.panes)
-        else:
-            slot = pane % P
-            seg = jnp.where(valid, batch.key * P + slot, K * P)
-            lifted = jax.vmap(self.lift)(TupleRef(
-                key=batch.key, id=batch.id, ts=batch.ts, data=batch.payload))
-            if self.combine is jnp.add:
-                upd = segment_reduce(lifted, seg, valid, K * P)
+        ring_overruns = state.ring_overruns
+        with jax.named_scope("fold"):
+            if self._hist_is_fold():
+                # lift == 1: the value histogram IS the count histogram
                 panes = jax.tree.map(
-                    lambda t, u: t + u.reshape((K, P) + u.shape[1:]),
-                    state.panes, upd)
+                    lambda t: t + cnt_upd.astype(t.dtype), state.panes)
             else:
-                upd = segment_reduce(lifted, seg, valid, K * P,
-                                     combine=self.combine, identity=self.identity)
-                panes = jax.tree.map(
-                    lambda t, u: self.combine(t, u.reshape((K, P) + u.shape[1:])),
-                    state.panes, upd)
+                slot = pane % P
+                seg = jnp.where(valid, batch.key * P + slot, K * P)
+                # the ring holds panes [horizon, horizon + P): a lane further
+                # ahead lands in the slot of a pane that has not fired yet
+                ring_overruns = ring_overruns + jnp.sum(
+                    (valid & (pane >= horizon + P)).astype(CTRL_DTYPE))
+                lifted = jax.vmap(self.lift)(TupleRef(
+                    key=batch.key, id=batch.id, ts=batch.ts,
+                    data=batch.payload))
+                if self.combine is jnp.add:
+                    upd = segment_reduce(lifted, seg, valid, K * P)
+                    panes = jax.tree.map(
+                        lambda t, u: t + u.reshape((K, P) + u.shape[1:]),
+                        state.panes, upd)
+                else:
+                    upd = segment_reduce(lifted, seg, valid, K * P,
+                                         combine=self.combine,
+                                         identity=self.identity)
+                    panes = jax.tree.map(
+                        lambda t, u: self.combine(
+                            t, u.reshape((K, P) + u.shape[1:])),
+                        state.panes, upd)
         wm_new = jnp.maximum(state.wm,
                              jnp.max(jnp.where(batch.valid, batch.ts, -1)))
         lat = state.lat_hist
@@ -251,21 +303,26 @@ class Win_SeqFFAT(Basic_Operator):
             cnt=cnt,
             wm=wm_new,
             dropped_old=state.dropped_old + n_dropped,
+            ring_overruns=ring_overruns,
             lat_hist=lat,
         )
+
+    def _hist_is_fold(self) -> bool:
+        """A count lift under an additive combine: the occupancy histogram is
+        the value fold, and nothing is folded by ring slot."""
+        return bool(self.count_lift) and self.combine is jnp.add
 
     def _g_emit(self, state: GFFATState, W_n: int, flush: bool):
         """Grid emission: the fired window range [lo, hi) is shared by every key, so
         the output is a [W_n, K] grid flattened — no searchsorted, no index math.
         Fired panes are cleared back to identity (ring hygiene) with an elementwise
-        cyclic-interval mask over the [K, P] table — no scatter."""
+        cyclic-interval mask over the [K, P] table — no scatter. Scopes:
+        ``gather`` (the windows' panes out of both tables), ``reduce`` (a
+        window's panes into its result), ``clear``."""
         K, P = self.num_keys, self.P
         s = self.spec
         lo = state.next_win
-        if flush:
-            hi = jnp.maximum(lo, state.wm // s.slide + 1)
-        else:
-            hi = jnp.maximum(lo, (state.wm - s.delay - s.win_len) // s.slide + 1)
+        hi = jnp.maximum(lo, self._due_hi(state, flush))
         hi = jnp.minimum(hi, lo + W_n)
         n_w = hi - lo
 
@@ -293,14 +350,19 @@ class Win_SeqFFAT(Basic_Operator):
             def gat(tbl):                                     # tbl [K, P, ...]
                 g = jnp.take(tbl, slot.reshape(-1), axis=1)   # [K, W_n*wpanes, ...]
                 return g.reshape((K, W_n, self.wpanes) + tbl.shape[2:])
-        cnts = gat(state.cnt)                                 # [K, W_n, wpanes]
-        win_cnt = jnp.sum(cnts, axis=2)                       # [K, W_n]
+        with jax.named_scope("gather"):
+            cnts = gat(state.cnt)                             # [K, W_n, wpanes]
+        with jax.named_scope("reduce"):
+            win_cnt = jnp.sum(cnts, axis=2)                   # [K, W_n]
+
         def reduce_w(tbl):
-            g = gat(tbl)                                      # [K, W_n, wpanes, ...]
-            if self.combine is jnp.add:
-                m = (cnts > 0).reshape(cnts.shape + (1,) * (g.ndim - 3))
-                return jnp.sum(jnp.where(m, g, 0), axis=2)
-            return _tree_reduce(self.combine, g, axis=2)
+            with jax.named_scope("gather"):
+                g = gat(tbl)                                  # [K, W_n, wpanes, ...]
+            with jax.named_scope("reduce"):
+                if self.combine is jnp.add:
+                    m = (cnts > 0).reshape(cnts.shape + (1,) * (g.ndim - 3))
+                    return jnp.sum(jnp.where(m, g, 0), axis=2)
+                return _tree_reduce(self.combine, g, axis=2)
         results = jax.tree.map(reduce_w, state.panes)         # [K, W_n, ...]
 
         valid = (win_cnt > 0) & w_valid[None, :]              # empty windows not emitted
@@ -315,17 +377,19 @@ class Win_SeqFFAT(Basic_Operator):
             valid=flat(valid),
         )
         # clear fired panes [lo*spanes, hi*spanes) — cyclic interval mask over [P]
-        first, last = lo * self.spanes, hi * self.spanes      # clear [first, last)
-        pos = jnp.arange(P, dtype=CTRL_DTYPE)
-        # slot s holds a fired pane iff exists p in [first,last) with p % P == s;
-        # since last-first <= P, that is a cyclic interval test
-        rel = (pos - first % P) % P
-        clear = rel < (last - first)
-        panes = jax.tree.map(
-            lambda t: jnp.where(clear.reshape((1, P) + (1,) * (t.ndim - 2)),
-                                jnp.asarray(self.identity, t.dtype), t),
-            state.panes)
-        cnt = jnp.where(clear[None, :], 0, state.cnt)
+        with jax.named_scope("clear"):
+            first, last = lo * self.spanes, hi * self.spanes  # clear [first, last)
+            pos = jnp.arange(P, dtype=CTRL_DTYPE)
+            # slot s holds a fired pane iff exists p in [first,last) with
+            # p % P == s; since last-first <= P, that is a cyclic interval test
+            rel = (pos - first % P) % P
+            clear = rel < (last - first)
+            panes = jax.tree.map(
+                lambda t: jnp.where(
+                    clear.reshape((1, P) + (1,) * (t.ndim - 2)),
+                    jnp.asarray(self.identity, t.dtype), t),
+                state.panes)
+            cnt = jnp.where(clear[None, :], 0, state.cnt)
         return dataclasses.replace(state, panes=panes, cnt=cnt, next_win=hi), out
 
     # ------------------------------------------------------------------ insert
@@ -457,14 +521,8 @@ class Win_SeqFFAT(Basic_Operator):
     def _emit(self, state: FFATState, W: int, flush: bool):
         K, P = self.num_keys, self.P
         s = self.spec
-        if s.is_cb:
-            hi = (jnp.where(state.count > 0, (state.count - 1) // s.slide + 1, 0)
-                  if flush else jnp.maximum(0, (state.count - s.win_len) // s.slide + 1))
-        else:
-            hi = (jnp.where(state.count > 0, state.wm // s.slide + 1, 0)
-                  if flush else jnp.maximum(0, (state.wm - s.delay - s.win_len) // s.slide + 1))
         lo = state.next_win
-        hi = jnp.maximum(hi, lo)
+        hi = jnp.maximum(self._due_hi(state, flush), lo)
         n_f = hi - lo
         csum = jnp.cumsum(n_f)
         off = csum - n_f
@@ -501,6 +559,22 @@ class Win_SeqFFAT(Basic_Operator):
 
     # ------------------------------------------------------------------ operator API
 
+    def _due_hi(self, state, flush: bool):
+        """One past the last window id that is due (scalar on the global-time
+        path, per key otherwise): whole windows behind the watermark or the
+        count, every window with a tuple at EOS. May lie below ``next_win``."""
+        s = self.spec
+        if self.global_time:
+            return (state.wm // s.slide + 1 if flush
+                    else (state.wm - s.delay - s.win_len) // s.slide + 1)
+        if s.is_cb:
+            return (jnp.where(state.count > 0, (state.count - 1) // s.slide + 1, 0)
+                    if flush else
+                    jnp.maximum(0, (state.count - s.win_len) // s.slide + 1))
+        return (jnp.where(state.count > 0, state.wm // s.slide + 1, 0)
+                if flush else
+                jnp.maximum(0, (state.wm - s.delay - s.win_len) // s.slide + 1))
+
     def _resolve_w(self, capacity):
         if self.max_wins is not None:
             return self.max_wins
@@ -519,10 +593,12 @@ class Win_SeqFFAT(Basic_Operator):
         return W
 
     def apply(self, state, batch: Batch):
-        """One scope per phase (``insert``, ``emit``; inside ``insert`` the
-        ``rank`` and the ``fold``), under the operator's own scope that the
-        chain opens: a profile's device operations say which part of the
-        engine they belong to."""
+        """One scope per phase (``insert``, ``emit``), under the operator's own
+        scope that the chain opens, and below them ``insert/rank`` and
+        ``insert/fold`` (count-based and per-key windows), ``insert/hist``,
+        ``insert/fold``, ``emit/gather``, ``emit/reduce`` and ``emit/clear``
+        (the global-time path, in the step and in the EOS flush): a profile's
+        device operations say which part of the engine they belong to."""
         W = self._resolve_w(batch.capacity)
         self._w = W
         insert, emit = ((self._g_insert, self._g_emit) if self.global_time
@@ -533,6 +609,13 @@ class Win_SeqFFAT(Basic_Operator):
             return emit(state, W, flush=False)
 
     def flush(self, state):
+        """One batch of up to W open windows (W a key on the global-time path),
+        None once none is left: the drivers call until None
+        (``CompiledChain.flush``). A time-based window without a tuple is
+        never delivered, so a pass of such windows alone is passed over, not
+        taken for the end. Publishes ``windows_undelivered_at_eos``: the window
+        ids still open after the call (a key's summed over the keys; one count
+        for all keys on the global-time path), 0 once flushed until None."""
         W = self._w or self._resolve_w(256)
         if not hasattr(self, "_flush_jit"):
             emit = self._g_emit if self.global_time else self._emit
@@ -540,24 +623,41 @@ class Win_SeqFFAT(Basic_Operator):
             def flush_emit(st):
                 with jax.named_scope(self.scope_name()), \
                         jax.named_scope("emit"):
-                    return emit(st, W, flush=True)
+                    st, out = emit(st, W, flush=True)
+                    left = jnp.sum(jnp.maximum(
+                        self._due_hi(st, True) - st.next_win, 0))
+                    return st, out, jnp.any(out.valid), left
             self._flush_jit = jax.jit(flush_emit)
-        state, out = self._flush_jit(state)
+        while True:
+            state, out, any_valid, left = self._flush_jit(state)
+            any_valid, left = bool(any_valid), int(left)
+            if any_valid or left == 0:
+                break
         self.collect_stats(state)
-        if not bool(jnp.any(out.valid)):
-            return state, None
-        return state, out
+        self._publish_stage_counters({**self.stage_counters(),
+                                      "windows_undelivered_at_eos": left})
+        return state, (out if any_valid else None)
 
     def collect_stats(self, state=None) -> None:
-        """Sync the device-resident OLD-drop counter into the Stats_Record
-        (monitoring snapshot / EOS — one scalar D2H read, off the hot path)."""
+        """Sync the device-resident counters into the Stats_Record and the stage
+        counters (monitoring snapshot / EOS — scalar D2H reads, off the hot
+        path): ``old_drops``; on the global-time path ``ffat_ring_overruns``
+        where the fold counts them (a lift that reads the tuple: the count-lift
+        branch folds no value by slot and publishes none); for time-based
+        specs the fired-window budget once it is settled."""
         if state is None or not hasattr(state, "dropped_old"):
             return
         import numpy as np
         old = int(np.asarray(state.dropped_old))
         self._stats[0].tuples_dropped_old = old
-        self._publish_stage_counters({**self.stage_counters(),
-                                      "old_drops": old})
+        counters = {**self.stage_counters(), "old_drops": old}
+        if not self.spec.is_cb:
+            counters.update(self._fired_budget_gauge())
+        if (self.global_time and self.count_lift is not None
+                and not self._hist_is_fold()):
+            counters["ffat_ring_overruns"] = int(
+                np.asarray(state.ring_overruns))
+        self._publish_stage_counters(counters)
 
     def drop_counters(self, state=None) -> dict:
         if state is None or not hasattr(state, "dropped_old"):
