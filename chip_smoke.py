@@ -250,6 +250,31 @@ def _case_histogram(args, rng):
             ref)
 
 
+def _case_pane_fold(args, rng):
+    """``kff``'s geometry with values across the whole int32 range (the
+    cell's own lie in [0, 96]: one limb); the first quarter of the batch is
+    one key's one pane, so a limb column of that cell passes 2^24."""
+    import jax
+    import jax.numpy as jnp
+    from windflow_tpu.ops.histogram import keyed_pane_fold
+    C, K, P = args.lanes, 512, 256
+    lane = np.arange(C)
+    key = np.where(lane < C // 4, 7, lane % K).astype(np.int32)
+    pane = (P - 3 + lane // max(256, C // 64)).astype(np.int32)  # wraps
+    pane[:C // 4] = P - 3
+    vals = rng.integers(-(1 << 31), 1 << 31, C, dtype=np.int64).astype(np.int32)
+    ok = rng.random(C) < 0.97
+    counts = np.zeros((K, P), np.int32)
+    np.add.at(counts, (key[ok], pane[ok] % P), 1)
+    sums = np.zeros((K, P), np.int64)
+    np.add.at(sums, (key[ok], pane[ok] % P), vals[ok].astype(np.int64))
+    dev = tuple(map(jnp.asarray, (key, pane, ok, vals)))
+    return (f"pane_fold[C={C},K={K},P={P},i32 full range,one-cell quarter]",
+            {"xla": lambda: jax.jit(
+                lambda k, p, v, x: keyed_pane_fold(k, p, v, x, K, P))(*dev)},
+            (counts, sums.astype(np.int32), np.array(True)))
+
+
 def _cases_lookup(args, rng):
     """The YSB join's table size, values over the documented exact domain."""
     import jax
@@ -341,6 +366,7 @@ def _kernel_cases(args, rng):
     compiles and runs one form on the default device."""
     yield _case_segment_fold(args, rng)
     yield _case_histogram(args, rng)
+    yield _case_pane_fold(args, rng)
     yield from _cases_lookup(args, rng)
     yield _case_join_probe(args, rng)
     yield _case_ordering_merge(args, rng)

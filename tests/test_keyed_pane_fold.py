@@ -1,0 +1,265 @@
+"""``ops/histogram.py::keyed_pane_fold``: the occupancy counts and an additive
+fold of integers in one chunk-local one-hot contraction, bit for bit
+``jax.ops.segment_sum`` whatever the batch holds, and the one call site,
+``Win_SeqFFAT._g_insert``: which lifts ride the contraction (the code sees it
+in the lift's result), which keep the scatter, and the counter of the batches
+that fell back."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from test_histogram_lookup import tpu_default_dot  # noqa: F401 - a fixture
+from windflow_tpu.basic import win_type_t
+from windflow_tpu.batch import Batch
+from windflow_tpu.observability.names import STAGE_COUNTERS
+from windflow_tpu.ops.histogram import (K_TILE, _place_group, keyed_pane_fold,
+                                        pane_fold_applies)
+from windflow_tpu.operators.win_patterns import Key_FFAT
+from windflow_tpu.operators.window import WindowSpec
+
+
+def in_order_panes(C, lanes_a_pane, first=0):
+    return (np.arange(C) // lanes_a_pane + first).astype(np.int32)
+
+
+def full_range(rng, dtype, C):
+    info = np.iinfo(dtype)
+    v = rng.integers(info.min, info.max, C, endpoint=True).astype(dtype)
+    v[:4] = info.min, info.max, info.min, info.max
+    return v
+
+
+def one_cell(value):
+    """Every lane of 131,072 in one (key, pane): a limb column sums to
+    255 x 131,072 > 2^24 in one cell, past what one f32 placement dot holds
+    (8,192 lanes would not reach it). ``value`` None: the top 2^20 values of
+    int32 at random, so that the chunks' limb sums are odd numbers whose
+    total f32 would round (equal values sum to multiples of 2^10, which f32
+    holds far past 2^24: the placement in one dot passes those by luck)."""
+    def make(rng):
+        C = 131072
+        assert 255 * C > 1 << 24 and _place_group(C // 1024, 1024) == 64
+        values = (np.full(C, value) if value is not None
+                  else rng.integers((1 << 31) - (1 << 20), 1 << 31, C))
+        return dict(key=np.ones(C, np.int32), pane=np.full(C, 5, np.int32),
+                    valid=np.ones(C, bool), values=values.astype(np.int32),
+                    K=2, P=16)
+    return make
+
+
+def of_dtype(dtype, K=5, P=32, C=8192, live=0.7):
+    def make(rng):
+        return dict(key=rng.integers(0, K, C).astype(np.int32),
+                    pane=in_order_panes(C, 200, first=P - 3),   # wraps the ring
+                    valid=rng.random(C) < live,
+                    values=full_range(rng, dtype, C), K=K, P=P)
+    return make
+
+
+def sums_that_wrap(rng):
+    C = 8192
+    return dict(key=rng.integers(0, 3, C).astype(np.int32),
+                pane=in_order_panes(C, 4096),
+                valid=np.ones(C, bool),
+                values=rng.integers((1 << 31) - 1000, 1 << 31, C).astype(
+                    np.int32), K=3, P=8)
+
+
+def masked_lanes(rng):
+    case = of_dtype(np.int32, live=0.3)(rng)
+    case["valid"][1024:2048] = False            # a chunk with no live lane
+    return case
+
+
+def two_leaves(rng):
+    case = of_dtype(np.int32)(rng)
+    case["values"] = {"a": case["values"],
+                      "b": full_range(rng, np.int16, 8192)}
+    return case
+
+
+def breaks_locality(rng):
+    C = 4096
+    return dict(key=rng.integers(0, 11, C).astype(np.int32),
+                pane=rng.integers(0, 1000, C).astype(np.int32),
+                valid=rng.random(C) < 0.5,
+                values=full_range(rng, np.int32, C), K=11, P=64,
+                in_bounds=False)
+
+
+CASES = {
+    "int32_full_domain": of_dtype(np.int32),
+    "int32_sums_that_wrap": sums_that_wrap,
+    "int8": of_dtype(np.int8),
+    "int16": of_dtype(np.int16),
+    "uint8": of_dtype(np.uint8),
+    "uint16": of_dtype(np.uint16),
+    "uint32": of_dtype(np.uint32),
+    "masked_lanes": masked_lanes,
+    "one_cell_at_int32_max": one_cell((1 << 31) - 1),
+    "one_cell_at_int32_min": one_cell(-(1 << 31)),
+    "one_cell_of_odd_sums": one_cell(None),
+    "keys_above_the_tile": of_dtype(np.int32, K=K_TILE + 188, C=4096),
+    "keys_below_the_tile": of_dtype(np.int32, K=7),
+    "ring_smaller_than_locality": of_dtype(np.int32, P=4),
+    "two_leaves": two_leaves,
+    "breaks_locality": breaks_locality,
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_fold_equals_segment_sum_bit_for_bit(tpu_default_dot, name):  # noqa: F811
+    case = CASES[name](np.random.default_rng(sorted(CASES).index(name)))
+    K, P = case["K"], case["P"]
+    key, pane, valid = (jnp.asarray(case[f]) for f in ("key", "pane", "valid"))
+    values = jax.tree.map(jnp.asarray, case["values"])
+    assert pane_fold_applies(values)
+    counts, folds, in_bounds = jax.jit(
+        lambda *a: keyed_pane_fold(*a, K, P))(key, pane, valid, values)
+    assert bool(in_bounds) is case.get("in_bounds", True)
+    seg = jnp.where(valid, key * P + pane % P, K * P)
+
+    def want(v):
+        return jax.ops.segment_sum(jnp.where(valid, v, 0), seg,
+                                   num_segments=K * P).reshape(K, P)
+    np.testing.assert_array_equal(counts, want(jnp.ones_like(key)))
+    assert counts.dtype == jnp.int32
+    for got, v in zip(jax.tree.leaves(folds), jax.tree.leaves(values)):
+        assert got.dtype == v.dtype
+        np.testing.assert_array_equal(got, want(v))
+    assert jax.tree.structure(folds) == jax.tree.structure(values)
+    if name.startswith("one_cell_at"):
+        assert int(folds[1, 5]) == (-131072 if name.endswith("max") else 0)
+        assert int(counts[1, 5]) == 131072
+
+
+# ---- the call site: Win_SeqFFAT._g_insert --------------------------------
+
+C, K = 4096, 4
+
+
+def engine(lift, combine=jnp.add, identity=0):
+    op = Key_FFAT(lift, combine, identity=identity,
+                  spec=WindowSpec(64, 16, win_type_t.TB), num_keys=K,
+                  pane_capacity=512, max_wins=8)
+    op.bind_geometry(C)
+    return op
+
+
+def batch_of(ts, capacity=C, seed=0):
+    rng = np.random.default_rng(seed)
+    return Batch.of(
+        {"i": rng.integers(-(1 << 31), 1 << 31, capacity).astype(np.int32),
+         "f": rng.standard_normal(capacity).astype(np.float32),
+         "m": rng.integers(0, 9, (capacity, 3)).astype(np.int32)},
+        key=rng.integers(0, K, capacity), id=np.arange(capacity),
+        ts=np.asarray(ts)[:capacity])
+
+
+def scatters(jaxpr, branch=None):
+    """(primitive, index of the ``cond`` branch it lies in, None outside any)
+    of every scatter equation, nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name.startswith("scatter"):
+            yield eqn.primitive.name, branch
+        for name, param in eqn.params.items():
+            for i, sub in enumerate(
+                    param if isinstance(param, (list, tuple)) else [param]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    inside = (i if eqn.primitive.name == "cond"
+                              and name == "branches" and branch is None
+                              else branch)
+                    yield from scatters(sub, inside)
+
+
+def pane_sums(batch, leaf, P, pane_len=16):
+    """numpy: wrapping int32 sums per (key, pane % P)."""
+    want = np.zeros((K, P), np.int64)
+    np.add.at(want, (np.asarray(batch.key),
+                     (np.asarray(batch.ts) // pane_len) % P),
+              np.asarray(batch.payload[leaf]).astype(np.int64))
+    return want.astype(np.int32)                    # wraps
+
+
+IN_ORDER = np.arange(C) // 16         # 256 lanes a pane, 4 panes a chunk
+
+
+def test_an_integer_lift_rides_the_contraction_and_counts_its_fallbacks():
+    op = engine(lambda t: t.data["i"])
+    state = op.init_state(jax.eval_shape(
+        lambda b: jax.tree.map(lambda x: x[0], b.payload), batch_of(IN_ORDER)))
+    insert = jax.jit(op._g_insert)
+    first = batch_of(IN_ORDER)
+    # every scatter of the program lies in the cond's fallback branch
+    found = list(scatters(jax.make_jaxpr(op._g_insert)(state, first).jaxpr))
+    assert found and all(branch == 0 for _, branch in found), found
+    state = insert(state, first)
+    np.testing.assert_array_equal(state.panes, pane_sums(first, "i", op.P))
+    assert int(state.cnt.sum()) == C
+    op.collect_stats(state)
+    assert op.stage_counters()["ffat_fold_fallbacks"] == 0
+    assert op.stage_counters()["ffat_ring_overruns"] == 0
+    assert "ffat_fold_fallbacks" in STAGE_COUNTERS
+    # ticks shuffled over 16 panes: the locality test fails, the scatters
+    # run, the answer is the same and the batch is counted
+    shuffled = batch_of(np.random.default_rng(7).permutation(IN_ORDER), seed=1)
+    state = insert(state, shuffled)
+    np.testing.assert_array_equal(
+        state.panes,
+        pane_sums(first, "i", op.P) + pane_sums(shuffled, "i", op.P))
+    assert int(state.cnt.sum()) == 2 * C
+    op.collect_stats(state)
+    assert op.stage_counters()["ffat_fold_fallbacks"] == 1
+
+
+@pytest.mark.parametrize("name,lift,combine,identity,capacity", [
+    ("float_lift", lambda t: t.data["f"], jnp.add, 0.0, C),
+    ("maximum_combine", lambda t: t.data["i"], jnp.maximum,
+     -(1 << 31), C),
+    ("rank_2_leaf", lambda t: t.data["m"], jnp.add, 0, C),
+    ("odd_capacity", lambda t: t.data["i"], jnp.add, 0, C - 96),
+])
+def test_other_lifts_and_combines_keep_the_scatter_path(name, lift, combine,
+                                                        identity, capacity):
+    op = engine(lift, combine, identity)
+    batch = batch_of(IN_ORDER, capacity)
+    state = op.init_state(jax.eval_shape(
+        lambda b: jax.tree.map(lambda x: x[0], b.payload), batch))
+    found = list(scatters(jax.make_jaxpr(op._g_insert)(state, batch).jaxpr))
+    # the value fold's scatter stands outside any cond, as before
+    assert any(branch is None for _, branch in found), found
+    state = jax.jit(op._g_insert)(state, batch)
+    assert int(state.fold_fallbacks) == 0
+    op.collect_stats(state)
+    counters = op.stage_counters()
+    assert "ffat_fold_fallbacks" not in counters      # absent, not 0
+    assert counters["ffat_ring_overruns"] == 0
+    if name == "odd_capacity":
+        np.testing.assert_array_equal(state.panes,
+                                      pane_sums(batch, "i", op.P))
+
+
+def test_a_count_lift_passes_the_new_leaf_through():
+    """``ysb``'s branch: both counters leave ``_g_insert`` as the variables
+    that went in (no operation), and neither fallbacks are published."""
+    op = engine(lambda t: 1)
+    batch = batch_of(IN_ORDER)
+    state = op.init_state(jax.eval_shape(
+        lambda b: jax.tree.map(lambda x: x[0], b.payload), batch))
+    jaxpr = jax.make_jaxpr(op._g_insert)(state, batch).jaxpr
+    fields = [f.name for f in dataclasses.fields(state)
+              if getattr(state, f.name) is not None]
+    assert len(fields) == len(jax.tree.leaves(state))     # a leaf a field
+    came_in = dict(zip(fields, jaxpr.invars))
+    went_out = dict(zip(fields, jaxpr.outvars))
+    for leaf in ("fold_fallbacks", "ring_overruns"):
+        assert went_out[leaf] is came_in[leaf], leaf
+    assert went_out["cnt"] is not came_in["cnt"]
+    op.collect_stats(jax.jit(op._g_insert)(state, batch))
+    assert op.count_lift is True
+    assert "ffat_fold_fallbacks" not in op.stage_counters()

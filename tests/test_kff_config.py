@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from test_keyed_pane_fold import scatters
 from test_ysb_wmr_config import (BATCH, BENCH, ROOT, as_grid, chain_step,
                                  equations, load_config, run_config,
                                  run_engine)
@@ -292,11 +293,16 @@ def test_lowered_step_and_flush_carry_the_five_new_scopes():
     assert window == "Key_FFAT:kff_window"
     for sub in NEW_SCOPES:
         assert f"/{window}/{sub}/" in hlo, sub
-    # the segment fold's scatter lies under insert/fold, the histogram's dots
-    # under insert/hist, and nothing of the engine under a phase alone but
-    # index arithmetic and the out batch
+    # the segment fold's scatter lies under insert/fold (since PR 35 in the
+    # fallback branch of the cond that the contraction's dots share with it,
+    # the counts' included: ISSUE 35 puts the one contraction under fold and
+    # leaves insert/hist the add into ``cnt``, so the dots this line looked
+    # for under insert/hist are under insert/fold now), and nothing of the
+    # engine under a phase alone but index arithmetic and the out batch
     assert re.search(rf'/{window}/insert/fold/[^"]*scatter', hlo)
-    assert re.search(rf'/{window}/insert/hist/[^"]*dot_general', hlo)
+    assert re.search(rf'/{window}/insert/fold/[^"]*dot_general', hlo)
+    assert re.search(rf'/{window}/insert/hist/add"', hlo)
+    assert not re.search(rf'/{window}/insert/hist/[^"]*(dot_general|cond)', hlo)
     assert not re.search(
         rf'/{window}/(insert|emit)/(scatter[\w-]*|gather|dot_general)"', hlo)
     path = f"jit(step)/{window}/insert/fold/scatter-add"
@@ -412,3 +418,35 @@ PARENT_STEPS = {
 @pytest.mark.parametrize("name", sorted(PARENT_STEPS))
 def test_the_older_cells_step_programs_are_the_parents(name):
     assert step_operations(name) == PARENT_STEPS[name]
+
+
+#: PR 35 changed this program on purpose: ``kff``'s integer value fold rides
+#: the occupancy histogram's one-hot contraction (``keyed_pane_fold``: one
+#: ``cond`` for counts and values, the two scatters its fallback branch). At
+#: PR 34's commit (8ecdb84) the step read (331, "b0625d6fe9e9746a...").
+CHANGED_STEPS = {
+    "kff": (371, "e00cd3c7b7dbb22f7883d1be3444b39a"
+                 "f70365d3e1a250be5cc2ad7b0997aa72"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CHANGED_STEPS))
+def test_the_step_programs_changed_on_purpose_are_as_recorded(name):
+    assert step_operations(name) == CHANGED_STEPS[name]
+
+
+def test_kffs_step_scatters_nowhere_but_in_the_fallback_branch():
+    """At rehearsal size: the step's only per-lane scatters are the two of
+    ``keyed_pane_fold``'s fallback (counts and values), inside the one
+    ``cond`` of the window's insert; the branch an in-order stream takes has
+    the two dots and no scatter, and the served path never left it."""
+    mod, cfg = load_config("kff")
+    _, step, args = chain_step(cfg, mod, BATCH)
+    jaxpr = jax.make_jaxpr(step)(*args).jaxpr
+    assert sum(e.primitive.name == "cond" for e, _ in equations(jaxpr)) == 1
+    # branch 0 is the cond's false side: the locality test failed
+    assert list(scatters(jaxpr)) == [("scatter-add", 0), ("scatter-add", 0)]
+    ops, _ = run_config("kff", make_pool(41, n_batches=3))
+    counters = ops[-1].stage_counters()
+    assert counters["ffat_fold_fallbacks"] == 0
+    assert counters["ffat_ring_overruns"] == 0

@@ -1137,6 +1137,9 @@ class MetricsRegistry:
                                           "undelivered",
             "ffat_ring_overruns": "tuples folded into a pane-ring slot whose "
                                   "pane had not fired yet",
+            "ffat_fold_fallbacks": "batches whose pane value fold took the "
+                                   "scatter branch (ticks out of order "
+                                   "inside a chunk)",
             "ffat_run_budget": "(key, pane) runs one batch may hold in the "
                                "count-based pane fold",
             "ffat_keys": "keys of the pane ring",

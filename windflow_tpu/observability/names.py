@@ -214,8 +214,10 @@ STAGE_COUNTERS = (
     "archive_overwrites", "windows_undelivered_at_eos", "archive_runs_written",
     # operators/win_seqffat.py, global-time path: lanes folded into a ring slot
     # whose pane had not fired (their pane lay ffat_pane_slots or more past
-    # the first unfired one); it publishes windows_undelivered_at_eos too
-    "ffat_ring_overruns",
+    # the first unfired one); it publishes windows_undelivered_at_eos too;
+    # batches whose integer value fold left the histogram's one-hot
+    # contraction for the exact scatters (a chunk spanned too many panes)
+    "ffat_ring_overruns", "ffat_fold_fallbacks",
     # operators/win_patterns.py::Pane_Farm: its two engines' counters, each
     # under its stage's prefix (``plq_old_drops``, ``wlq_archive_overwrites``)
     *(f"{stage}_{counter}" for stage in PANE_STAGES

@@ -60,7 +60,10 @@ class FFATState:
 class GFFATState:
     """State of the global-time TB fast path: the stream shares one event clock, so
     watermark/next-window are scalars and no per-tuple gather from per-key tables is
-    needed — the insert is ONE scatter-add (plus one for occupancy counts)."""
+    needed — the insert is one chunk-local one-hot contraction on the MXU for the
+    occupancy counts and, where the lift is a count or gives integers to add, for
+    the values too (``ops/histogram.py``); other lifts and combines pay one
+    scatter (or sorted scan) a leaf beside the counts' contraction."""
 
     panes: Any            # pytree [K, P, ...] ring of pane partials
     cnt: jax.Array        # i32[K, P] tuples per pane slot (emptiness filter)
@@ -71,6 +74,10 @@ class GFFATState:
     #: pane lay P or more past the first unfired one (counted where the fold
     #: goes by slot; the count-lift branch passes it through)
     ring_overruns: jax.Array
+    #: i32[] batches whose integer value fold took the scatter branch (a chunk
+    #: of the batch spanned more panes than the one-hot holds: ticks far out of
+    #: order); only the fold that rides the histogram's contraction counts
+    fold_fallbacks: jax.Array
     #: i32[NB] observed-lateness histogram (event-time monitoring only)
     lat_hist: Any = None
 
@@ -91,7 +98,10 @@ class Win_SeqFFAT(Basic_Operator):
     path with a lift that reads the tuple, ``ffat_ring_overruns`` (lanes
     whose pane lay ``P`` or more past the first unfired pane: they were folded
     into a slot that an unfired pane still held; the count-lift branch folds no
-    value by slot and publishes none); at ``flush``
+    value by slot and publishes none) and, where an additive integer lift
+    rides the occupancy histogram's contraction, ``ffat_fold_fallbacks``
+    (batches whose ticks were too far out of order for it: they took the
+    exact scatters; 0 for an in-order stream); at ``flush``
     ``windows_undelivered_at_eos``.
 
     ``flush`` returns one batch of open windows a call and None once none is
@@ -110,7 +120,8 @@ class Win_SeqFFAT(Basic_Operator):
         # global_time (TB only): all keys share the event clock — watermark and the
         # fired-window frontier become scalars, removing every per-tuple gather from
         # the hot path (take() costs ~5.6 ns/elem on TPU; scatter-add ~7 — the insert
-        # becomes two scatters total). Default on for TB: streaming benchmarks and
+        # becomes two folds by (key, pane), on the MXU where _g_insert can put
+        # them there). Default on for TB: streaming benchmarks and
         # real event streams share one clock (the reference's TB windows likewise
         # advance on tuple timestamps, wf/window.hpp:83-121). CAVEAT: the frontier
         # advances on the GLOBAL watermark, so a key whose tuples lag more than
@@ -141,6 +152,9 @@ class Win_SeqFFAT(Basic_Operator):
         self.P = None
         self.max_wins = max_wins
         self._w = None
+        #: whether the value fold shares the occupancy histogram's contraction
+        #: (settled by the first trace of ``_g_insert``, from the lift's result)
+        self._fold_rides = False
         self.bind_geometry(256)        # provisional; compiler re-binds with real C
 
     def bind_geometry(self, batch_capacity: int) -> None:
@@ -207,6 +221,7 @@ class Win_SeqFFAT(Basic_Operator):
                 next_win=jnp.asarray(0, CTRL_DTYPE),
                 dropped_old=jnp.zeros((), CTRL_DTYPE),
                 ring_overruns=jnp.zeros((), CTRL_DTYPE),
+                fold_fallbacks=jnp.zeros((), CTRL_DTYPE),
                 lat_hist=lat,
             )
         return FFATState(
@@ -229,24 +244,36 @@ class Win_SeqFFAT(Basic_Operator):
     # ---------------------------------------------------- global-time fast path (TB)
 
     def _g_insert(self, state: GFFATState, batch: Batch):
-        """Fold a batch into the [K, P] pane ring. The occupancy counts — and, for a
-        count-like lift (lift(t) == 1, the YSB/windowed-count case), the partials
-        themselves — go through the MXU histogram (``ops/histogram.py``) instead of a
-        serialized scatter-add; other additive lifts take the segment-fold path
-        (``ops/segment.py::segment_fold``). Both are kernel-registry families
-        (``"histogram"``/``"segment_fold"``, ``ops/registry.py``) — the impl is
-        resolved at trace time per (kernel, shape spec, device), so this fold
-        call site A/Bs between XLA and the fused Pallas kernels via
-        ``WF_KERNEL_IMPL`` with no code change here. Slot cleanliness is
+        """Fold a batch into the [K, P] pane ring. The occupancy counts go
+        through the MXU histogram (``ops/histogram.py``: a chunk-local one-hot
+        contraction with an exact scatter fallback under one ``lax.cond``)
+        instead of a serialized scatter-add, and so do the partials where the
+        lift allows it, which the code sees for itself: a count-like lift
+        (lift(t) == 1, the YSB/windowed-count case) IS the count histogram,
+        and an additive lift whose leaves are ``[C]`` integers of at most 4
+        bytes rides the counts' contraction as 8-bit limbs
+        (``keyed_pane_fold``: one ``cond`` for counts and values, bit for bit
+        ``segment_sum``; ``fold_fallbacks`` -> ``ffat_fold_fallbacks`` counts
+        the batches whose locality test sent it to the scatters). Floats,
+        leaves of higher rank, odd capacities and every other combine take the
+        segment-fold path (``ops/segment.py::segment_reduce``) beside the
+        count histogram. ``"histogram"`` and ``"segment_fold"`` are
+        kernel-registry families (``ops/registry.py``: the impl is resolved at
+        trace time per kernel, shape spec and device, so those call sites A/B
+        between XLA and the Pallas kernels via ``WF_KERNEL_IMPL``);
+        ``keyed_pane_fold`` has the XLA form alone. Slot cleanliness is
         maintained by clear-on-fire in ``_g_emit`` so no pane-id bookkeeping is
         needed; OLD tuples (pane already fired) are dropped with a scalar
         horizon compare. The ring holds the ``P`` panes from the first unfired
         one: a lane further ahead shares its slot with a pane that has not
         fired, and where the partials are folded by slot such lanes are
         counted (``ring_overruns`` -> ``ffat_ring_overruns``; size the ring
-        with ``pane_capacity=``). Scopes: ``hist`` (the occupancy
-        histogram), ``fold`` (lift, segment fold, the add into the ring)."""
-        from ..ops.histogram import keyed_pane_histogram
+        with ``pane_capacity=``). Scopes: ``fold`` (the lift, the value fold
+        and, where the values ride it, the contraction that also counts; the
+        add into the ring), ``hist`` (the occupancy histogram where it runs
+        alone; the add into ``cnt``)."""
+        from ..ops.histogram import (keyed_pane_fold, keyed_pane_histogram,
+                                     pane_fold_applies)
         K, P = self.num_keys, self.P
         pane = batch.ts // self.pane_len
         horizon = state.next_win * self.spanes       # first un-fired pane (global)
@@ -254,28 +281,41 @@ class Win_SeqFFAT(Basic_Operator):
         # stragglers behind the fired horizon are DROPPED, not merely delayed
         # (global clock: per-key skew > delay loses tuples) — count them
         n_dropped = jnp.sum((batch.valid & ~valid).astype(CTRL_DTYPE))
-        with jax.named_scope("hist"):
-            cnt_upd = keyed_pane_histogram(batch.key, pane, valid, K, P)
-            cnt = state.cnt + cnt_upd
         if self.count_lift is None:
             self.count_lift = _detect_count_lift(self.lift, batch)
+        tuples = TupleRef(key=batch.key, id=batch.id, ts=batch.ts,
+                          data=batch.payload)
+        # integers to add: counts and values share one contraction
+        rides = self._fold_rides = (
+            not self._hist_is_fold() and self.combine is jnp.add
+            and pane_fold_applies(jax.eval_shape(jax.vmap(self.lift), tuples)))
+        if not rides:
+            with jax.named_scope("hist"):
+                cnt_upd = keyed_pane_histogram(batch.key, pane, valid, K, P)
+                cnt = state.cnt + cnt_upd
         ring_overruns = state.ring_overruns
+        fold_fallbacks = state.fold_fallbacks
         with jax.named_scope("fold"):
             if self._hist_is_fold():
                 # lift == 1: the value histogram IS the count histogram
                 panes = jax.tree.map(
                     lambda t: t + cnt_upd.astype(t.dtype), state.panes)
             else:
-                slot = pane % P
-                seg = jnp.where(valid, batch.key * P + slot, K * P)
+                if not rides:
+                    slot = pane % P
+                    seg = jnp.where(valid, batch.key * P + slot, K * P)
                 # the ring holds panes [horizon, horizon + P): a lane further
                 # ahead lands in the slot of a pane that has not fired yet
                 ring_overruns = ring_overruns + jnp.sum(
                     (valid & (pane >= horizon + P)).astype(CTRL_DTYPE))
-                lifted = jax.vmap(self.lift)(TupleRef(
-                    key=batch.key, id=batch.id, ts=batch.ts,
-                    data=batch.payload))
-                if self.combine is jnp.add:
+                lifted = jax.vmap(self.lift)(tuples)
+                if rides:
+                    cnt_upd, upd, in_bounds = keyed_pane_fold(
+                        batch.key, pane, valid, lifted, K, P)
+                    fold_fallbacks = fold_fallbacks + (
+                        ~in_bounds).astype(CTRL_DTYPE)
+                    panes = jax.tree.map(jnp.add, state.panes, upd)
+                elif self.combine is jnp.add:
                     upd = segment_reduce(lifted, seg, valid, K * P)
                     panes = jax.tree.map(
                         lambda t, u: t + u.reshape((K, P) + u.shape[1:]),
@@ -288,6 +328,9 @@ class Win_SeqFFAT(Basic_Operator):
                         lambda t, u: self.combine(
                             t, u.reshape((K, P) + u.shape[1:])),
                         state.panes, upd)
+        if rides:
+            with jax.named_scope("hist"):
+                cnt = state.cnt + cnt_upd
         wm_new = jnp.maximum(state.wm,
                              jnp.max(jnp.where(batch.valid, batch.ts, -1)))
         lat = state.lat_hist
@@ -304,6 +347,7 @@ class Win_SeqFFAT(Basic_Operator):
             wm=wm_new,
             dropped_old=state.dropped_old + n_dropped,
             ring_overruns=ring_overruns,
+            fold_fallbacks=fold_fallbacks,
             lat_hist=lat,
         )
 
@@ -643,8 +687,10 @@ class Win_SeqFFAT(Basic_Operator):
         counters (monitoring snapshot / EOS — scalar D2H reads, off the hot
         path): ``old_drops``; on the global-time path ``ffat_ring_overruns``
         where the fold counts them (a lift that reads the tuple: the count-lift
-        branch folds no value by slot and publishes none); for time-based
-        specs the fired-window budget once it is settled."""
+        branch folds no value by slot and publishes none) and
+        ``ffat_fold_fallbacks`` where the value fold rides the histogram's
+        contraction; for time-based specs the fired-window budget once it is
+        settled."""
         if state is None or not hasattr(state, "dropped_old"):
             return
         import numpy as np
@@ -657,6 +703,9 @@ class Win_SeqFFAT(Basic_Operator):
                 and not self._hist_is_fold()):
             counters["ffat_ring_overruns"] = int(
                 np.asarray(state.ring_overruns))
+        if self.global_time and self._fold_rides:
+            counters["ffat_fold_fallbacks"] = int(
+                np.asarray(state.fold_fallbacks))
         self._publish_stage_counters(counters)
 
     def drop_counters(self, state=None) -> dict:

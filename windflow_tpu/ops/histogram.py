@@ -1,4 +1,4 @@
-"""Keyed-pane histograms on the MXU — the FFAT-insert hot path.
+"""Keyed-pane histograms and integer folds on the MXU — the FFAT-insert hot path.
 
 The reference's incremental window engines fold each tuple into a per-(key, pane)
 partial (``wf/flatfat.hpp:134-240`` leaf update; ``wf/win_seqffat.hpp:389-396``).
@@ -24,11 +24,24 @@ Batches that violate the locality bound (a chunk spanning ≥ L panes — wildly
 out-of-order timestamps) are detected on device and routed through the exact
 scatter-add path with ``lax.cond``: the fast path is an optimization, never a
 semantics change.
+
+**Values as well as counts** (:func:`keyed_pane_fold`). An additive fold of
+integers into the same (key, pane) cells rides the same contraction: the local
+pane one-hot is 8 columns wide where the MXU takes 128, so beside the count's
+column group the right-hand operand carries one group per 8-bit limb of each
+value (``v = l3*2^24 + l2*2^16 + l1*2^8 + l0``, the sign in the top limb).
+A limb is an integer of magnitude ≤ 255, exact in bf16, and a chunk's limb sum
+stays under 2^24, exact in f32. The ring placement adds the chunk partials in
+groups small enough for f32 (:func:`_place_group`), the groups add in wrapping
+int32, and the limbs recombine with wrapping shifts: bit for bit
+``jax.ops.segment_sum`` on the integers, overflow included.
 """
 
 from __future__ import annotations
 
+import math
 import os
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +52,58 @@ DEFAULT_CHUNK = 1024
 DEFAULT_L = 8
 #: key-axis tile for the chunk-local one-hot (caps transient memory at ~C*K_TILE B)
 K_TILE = 512
+
+
+def _chunk_locality(pane, valid, R, chunk, locality):
+    """Per chunk of ``chunk`` consecutive lanes: the smallest valid pane
+    ``base`` [R], every lane's distance from it ``local`` [R, chunk], the
+    lanes the chunk-local one-hot can hold ``ok_local``, and whether that is
+    every valid lane of the batch (``in_bounds``: the fast branch is exact)."""
+    pane_r = pane.reshape(R, chunk)
+    valid_r = valid.reshape(R, chunk)
+    big = jnp.iinfo(pane.dtype).max
+    base = jnp.min(jnp.where(valid_r, pane_r, big), axis=1)      # [R]
+    base = jnp.where(base == big, 0, base)
+    local = pane_r - base[:, None]                               # [R, chunk]
+    ok_local = valid_r & (local < locality)
+
+    in_bounds = jnp.all(ok_local == valid_r)
+    return base, local, ok_local, in_bounds
+
+
+def _chunk_contract(key, local, ok_local, K, R, chunk, locality, weights=None):
+    """The chunk-local contraction ``rck,rcm->rkm``: key one-hot against the
+    local-pane one-hot -> ``f32[R, K, L]`` counts. With ``weights``
+    (``bf16[R, chunk, J]``, integers of magnitude <= 255) the right-hand
+    operand holds one column group a weight, the one-hot times it:
+    ``f32[R, K, J * L]``, column ``j * L + l``. Exact: bf16 holds the
+    operands, f32 a chunk's sum (at most 255 x chunk)."""
+    lr = jnp.where(ok_local, local, 0)
+    key_r = key.reshape(R, chunk)
+    ohl = ((lr[:, :, None] == jnp.arange(locality, dtype=lr.dtype))
+           & ok_local[:, :, None]).astype(jnp.bfloat16)
+    if weights is not None:
+        ohl = (weights[:, :, :, None] * ohl[:, :, None, :]).reshape(
+            R, chunk, weights.shape[2] * locality)
+    # tile the key axis: bounds the transient [R, chunk, K_tile] one-hot to
+    # ~C * K_TILE bytes instead of C * K (K can be thousands)
+    tiles = []
+    for k0 in range(0, K, K_TILE):
+        kn = min(K_TILE, K - k0)
+        ohk = ((key_r[:, :, None]
+                == jnp.arange(k0, k0 + kn, dtype=key.dtype))
+               & ok_local[:, :, None]).astype(jnp.bfloat16)
+        tiles.append(jnp.einsum("rck,rcl->rkl", ohk, ohl,
+                                preferred_element_type=jnp.float32))
+    return tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, axis=1)
+
+
+def _ring_onehot(base, locality, P):
+    """``f32[R * L, P]``: chunk r's local pane l goes to ring column
+    ``(base[r] + l) % P``."""
+    slot = (base[:, None] + jnp.arange(locality, dtype=base.dtype)) % P
+    return (slot.reshape(-1)[:, None]
+            == jnp.arange(P, dtype=slot.dtype)).astype(jnp.float32)
 
 
 def keyed_pane_histogram(key: jax.Array, pane: jax.Array, valid: jax.Array,
@@ -74,36 +139,12 @@ def keyed_pane_histogram(key: jax.Array, pane: jax.Array, valid: jax.Array,
     key, pane, valid = jax.lax.optimization_barrier((key, pane, valid))
     R = C // chunk
 
-    pane_r = pane.reshape(R, chunk)
-    valid_r = valid.reshape(R, chunk)
-    big = jnp.iinfo(pane.dtype).max
-    base = jnp.min(jnp.where(valid_r, pane_r, big), axis=1)      # [R]
-    base = jnp.where(base == big, 0, base)
-    local = pane_r - base[:, None]                               # [R, chunk]
-    ok_local = valid_r & (local < locality)
-
-    in_bounds = jnp.all(ok_local == valid_r)
+    base, local, ok_local, in_bounds = _chunk_locality(
+        pane, valid, R, chunk, locality)
 
     def fast(_):
-        lr = jnp.where(ok_local, local, 0)
-        key_r = key.reshape(R, chunk)
-        ohl = ((lr[:, :, None] == jnp.arange(locality, dtype=lr.dtype))
-               & ok_local[:, :, None]).astype(jnp.bfloat16)
-        # tile the key axis: bounds the transient [R, chunk, K_tile] one-hot to
-        # ~C * K_TILE bytes instead of C * K (K can be thousands)
-        tiles = []
-        for k0 in range(0, K, K_TILE):
-            kn = min(K_TILE, K - k0)
-            ohk = ((key_r[:, :, None]
-                    == jnp.arange(k0, k0 + kn, dtype=key.dtype))
-                   & ok_local[:, :, None]).astype(jnp.bfloat16)
-            tiles.append(jnp.einsum("rck,rcl->rkl", ohk, ohl,
-                                    preferred_element_type=jnp.float32))
-        h3 = tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, axis=1)
-        # place chunk histograms into ring columns: one-hot of (base+l) % P
-        slot = (base[:, None] + jnp.arange(locality, dtype=base.dtype)) % P
-        ohp = (slot.reshape(-1)[:, None]
-               == jnp.arange(P, dtype=slot.dtype)).astype(jnp.float32)  # [R*L, P]
+        h3 = _chunk_contract(key, local, ok_local, K, R, chunk, locality)
+        ohp = _ring_onehot(base, locality, P)                    # [R*L, P]
         flat = jnp.transpose(h3, (1, 0, 2)).reshape(K, R * locality)
         # `flat` holds per-chunk COUNTS (up to `chunk`): HIGHEST, because a
         # TPU's default f32 dot is one bf16 pass and rounds counts past 256
@@ -157,6 +198,125 @@ def _scatter_hist(key, pane, valid, K, P):
     seg = jnp.where(valid, key * P + pane % P, K * P)
     return jax.ops.segment_sum(valid.astype(jnp.int32), seg,
                                num_segments=K * P).reshape(K, P)
+
+
+def pane_fold_applies(values: Any, *, chunk: int = DEFAULT_CHUNK) -> bool:
+    """Whether :func:`keyed_pane_fold` takes these lifted values (arrays or
+    their ``ShapeDtypeStruct``): every leaf ``[C]`` of an integer dtype of at
+    most 4 bytes, ``C`` whole chunks. Floats (a matmul re-orders their sum),
+    leaves of higher rank and odd capacities are the scatter path's."""
+    leaves = jax.tree.leaves(values)
+    return bool(leaves) and all(
+        len(v.shape) == 1 and v.shape[0] >= chunk and v.shape[0] % chunk == 0
+        and jnp.issubdtype(v.dtype, jnp.integer)
+        and jnp.dtype(v.dtype).itemsize <= 4 for v in leaves)
+
+
+def _limbs(v):
+    """An integer leaf as 8-bit limbs of an int32 (its own width's bits, sign-
+    or zero-extended), low byte first: ``sum(limb[i] << 8 * i)`` is the value
+    in wrapping int32. The low limbs lie in [0, 255]; the top one carries the
+    sign (an arithmetic shift: [-128, 127], or [0, 255] of a narrower unsigned
+    leaf)."""
+    n = jnp.dtype(v.dtype).itemsize
+    x = (jax.lax.bitcast_convert_type(v, jnp.int32) if v.dtype == jnp.uint32
+         else v.astype(jnp.int32))
+    return [(x >> (8 * i)) & 255 for i in range(n - 1)] + [x >> (8 * (n - 1))]
+
+
+def _from_limbs(sums, dtype):
+    """The limbs' folds back into one value of ``dtype``: wrapping int32
+    shifts and adds, then the leaf's own width of the result."""
+    acc = sums[0]
+    for i, s in enumerate(sums[1:], 1):
+        acc = acc + (s << (8 * i))
+    dtype = jnp.dtype(dtype)
+    if dtype == jnp.uint32:
+        return jax.lax.bitcast_convert_type(acc, jnp.uint32)
+    spare = 32 - 8 * dtype.itemsize
+    if spare:
+        # a narrower sum wraps at its own width: keep the low bits, and
+        # extend them as the dtype reads them
+        acc = ((acc << spare) >> spare if jnp.issubdtype(dtype, jnp.signedinteger)
+               else acc & ((1 << 8 * dtype.itemsize) - 1))
+    return acc.astype(dtype)
+
+
+def _place_group(R: int, chunk: int) -> int:
+    """Chunks whose limb partials one f32 placement dot may add: the largest
+    power of two that divides ``R`` and keeps 255 x chunk x group under 2^24
+    (64 at a chunk of 1,024), so every sum the dot makes is exact whatever the
+    batch holds (all of it one key's one pane at 2^31 - 1 included)."""
+    most = ((1 << 24) - 1) // (255 * chunk)
+    if most < 1:
+        raise ValueError(f"a chunk of {chunk} lanes can sum a limb past 2^24")
+    return math.gcd(R, 1 << (most.bit_length() - 1))
+
+
+def keyed_pane_fold(key: jax.Array, pane: jax.Array, valid: jax.Array,
+                    values: Any, num_keys: int, ring: int, *,
+                    chunk: int = DEFAULT_CHUNK, locality: int = DEFAULT_L):
+    """The occupancy histogram and the additive fold of integer ``values``
+    into the same (key, ``pane % ring``) cells, in ONE chunk-local contraction:
+    ``(counts i32[K, P], folds: the values' pytree of [K, P], in_bounds)``.
+
+    ``values``: a pytree that :func:`pane_fold_applies` accepts. ``folds``
+    equals ``jax.ops.segment_sum`` of each masked leaf bit for bit (wrapping at
+    the leaf's width), ``counts`` equals :func:`keyed_pane_histogram`, for any
+    input: a batch that breaks chunk locality takes the two scatters
+    (:func:`_scatter_hist`, ``ops/segment.py::segment_reduce``) inside the
+    same ``lax.cond``, and ``in_bounds`` (bool[]) says which branch ran.
+
+    The fast branch's right-hand operand holds ``1 + limbs`` column groups of
+    ``locality`` columns (40 for one int32 leaf), under the 128 the MXU takes
+    in one pass, so values cost what counts alone cost. XLA only: the
+    ``"histogram"`` registry family's Pallas forms count and do not fold."""
+    from .segment import segment_reduce
+    leaves, treedef = jax.tree.flatten(values)
+    C = key.shape[0]
+    K, P, L = int(num_keys), int(ring), int(locality)
+    R = C // chunk
+    group = _place_group(R, chunk)
+    # materialize the inputs before the one-hot tiles consume them
+    # (keyed_pane_histogram: a producer re-fused into every tile)
+    key, pane, valid, leaves = jax.lax.optimization_barrier(
+        (key, pane, valid, leaves))
+    base, local, ok_local, in_bounds = _chunk_locality(
+        pane, valid, R, chunk, L)
+
+    def fast(_):
+        limbs = [_limbs(v) for v in leaves]
+        # column group 0 counts (weight 1), then every leaf's limbs; a dead
+        # lane's weights meet an all-zero one-hot row
+        weights = jnp.stack(
+            [jnp.ones((C,), jnp.int32)] + [w for ws in limbs for w in ws],
+            axis=-1).astype(jnp.bfloat16).reshape(R, chunk, -1)
+        J = weights.shape[2]
+        h = _chunk_contract(key, local, ok_local, K, R, chunk, L, weights)
+        # ring placement, `group` chunks a dot: sums under 2^24, exact in f32
+        # (HIGHEST: a TPU's default f32 dot is one bf16 pass)
+        placed = jax.lax.dot_general(                   # gakjl,galp->gkjp
+            h.reshape(R // group, group, K, J, L),
+            _ring_onehot(base, L, P).reshape(R // group, group, L, P),
+            (((1, 4), (1, 2)), ((0,), (0,))),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        sums = jnp.sum(placed.astype(jnp.int32), axis=0)         # [K, J, P]
+        folds, j = [], 1
+        for v, ws in zip(leaves, limbs):
+            folds.append(_from_limbs(
+                [sums[:, j + i] for i in range(len(ws))], v.dtype))
+            j += len(ws)
+        return sums[:, 0], folds
+
+    def scatter(_):
+        seg = jnp.where(valid, key * P + pane % P, K * P)
+        return (_scatter_hist(key, pane, valid, K, P),
+                [u.reshape(K, P)
+                 for u in segment_reduce(leaves, seg, valid, K * P)])
+
+    counts, folds = jax.lax.cond(in_bounds, fast, scatter, None)
+    return counts, jax.tree.unflatten(treedef, folds), in_bounds
 
 
 def keyed_pane_histogram_pallas(key: jax.Array, pane: jax.Array,
