@@ -38,7 +38,8 @@ N_BATCHES = 9
 #: once per batch on the prefetch thread / on the drive thread
 PREFETCH_SPANS = ("wf.source.unpack", "wf.source.frame", "wf.source.h2d",
                   "wf.source.put")
-DRIVE_SPANS = ("wf.chain.push", "wf.sink.consume", "wf.sink.d2h")
+DRIVE_SPANS = ("wf.chain.push", "wf.chain.dispatch", "wf.sink.consume",
+               "wf.sink.d2h")
 
 
 def load_config(name):
@@ -131,7 +132,8 @@ def test_every_span_of_the_table_once_per_batch(traced):
     sampled = [e["stats"]["pos"] for e in spans["wf.chain.push"]
                if e["stats"]["sampled"]]
     assert sampled == [1, 3, 7]
-    assert len(spans["wf.chain.sync"]) == len(sampled)
+    # the completion wait is on its batch's row: it carries the push's pos
+    assert [e["stats"]["pos"] for e in spans["wf.chain.sync"]] == sampled
     # EOS: the flushed batch and the None marker reach the sink without a pos
     flushed = [e for e in spans["wf.sink.consume"] if "pos" not in e["stats"]]
     assert len(flushed) == 2
@@ -151,7 +153,10 @@ def test_counts_ride_on_the_spans(traced):
     # the device holds both in 32 bits (no x64)
     assert {e["stats"]["bytes"] for e in spans["wf.source.h2d"]} == {
         BATCH * (13 + 4 + 4)}
-    assert all(e["stats"]["k"] == 1 for e in spans["wf.chain.push"])
+    # "always 1" since scan dispatch went (PR 29): the argument went too
+    assert all(set(e["stats"]) == {"pos", "sampled"}
+               for e in spans["wf.chain.push"])
+    assert all(set(e["stats"]) == {"pos"} for e in spans["wf.chain.dispatch"])
     assert all(0 <= e["stats"]["queued"] <= 2
                for e in spans["wf.drive.ingest_wait"])
     assert all(e["stats"]["bytes"] > 0 for e in spans["wf.sink.d2h"])
@@ -160,7 +165,8 @@ def test_counts_ride_on_the_spans(traced):
 
 def test_children_inside_parents_on_one_thread(traced):
     _, threads = traced
-    parent_of = {"wf.chain.sync": "wf.chain.push",
+    parent_of = {"wf.chain.dispatch": "wf.chain.push",
+                 "wf.chain.sync": "wf.chain.push",
                  "wf.sink.d2h": "wf.sink.consume",
                  "wf.sink.deliver": "wf.sink.consume"}
     seen = set()
@@ -179,6 +185,26 @@ def test_children_inside_parents_on_one_thread(traced):
                 seen.add(e["name"])
             stack.append(e)
     assert seen == set(parent_of)
+
+
+def test_dispatch_once_a_batch_inside_its_push(traced):
+    """``wf.chain.dispatch`` is the ``jit`` call alone: one a push, on the
+    drive thread, inside that push's span and before its sampled sync."""
+    _, threads = traced
+    (drive,) = [evs for evs in threads.values()
+                if any(e["name"] == "wf.chain.push" for e in evs)]
+    pushes = [e for e in drive if e["name"] == "wf.chain.push"]
+    dispatches = [e for e in drive if e["name"] == "wf.chain.dispatch"]
+    syncs = {e["stats"]["pos"]: e for e in drive
+             if e["name"] == "wf.chain.sync"}
+    assert len(dispatches) == len(pushes) == N_BATCHES
+    for push, dispatch in zip(pushes, dispatches):
+        assert dispatch["stats"]["pos"] == push["stats"]["pos"]
+        assert push["start_ns"] <= dispatch["start_ns"]
+        assert dispatch["end_ns"] <= push["end_ns"]
+        sync = syncs.get(push["stats"]["pos"])
+        if sync is not None:
+            assert dispatch["end_ns"] <= sync["start_ns"]
 
 
 def test_source_spans_on_the_prefetch_thread(traced):

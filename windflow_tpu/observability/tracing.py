@@ -32,9 +32,11 @@ Three pieces:
 
 - **Spans on the profiler's clock**: :func:`span` is the one way a runtime
   site marks an interval.  It always opens a ``jax.profiler.TraceAnnotation``
-  (a TraceMe: recorded only while a profiler session is open, on the same
-  clock as the device plane, so ``wf.xprof_trace`` / ``scripts/wf_profile.py``
-  show the program's own spans beside the device's operations) and, for a
+  (a TraceMe: recorded only while a profiler session is open, in the file
+  that holds the device plane, so ``wf.xprof_trace`` / ``scripts/wf_profile.py``
+  show the program's own spans beside the device's operations; the device
+  plane reads 1.3-2.0 ms early against the host plane's clock, by an offset
+  ``benchmark/timeline_reduce.py`` bounds from causality) and, for a
   traced batch under an active :class:`Tracer`, the flight recorder's
   begin/end rows.  The span names (``wf.source.*``, ``wf.drive.*``,
   ``wf.chain.*``, ``wf.sink.*``) are listed in ``docs/ARCHITECTURE.md``.
@@ -511,7 +513,7 @@ def span(name: str, batch=None, **counts):
     """``with span(name, batch=None, **counts):`` — THE way a runtime site marks
     an interval.  Always a ``jax.profiler.TraceAnnotation(name, **counts)``: a
     TraceMe, recorded only while a profiler session is open (one atomic load
-    otherwise), on the clock the device plane uses; ``counts`` (``pos``,
+    otherwise), on the host plane's clock; ``counts`` (``pos``,
     bytes, queue depths; a None is left out) ride as the event's arguments,
     so ratios are taken at the boundary the span marks.  With ``batch`` given,
     a :class:`Tracer` active and the batch traced, the flight recorder also

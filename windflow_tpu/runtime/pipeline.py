@@ -378,9 +378,9 @@ class CompiledChain:  # wf-lint: single-writer[driver, stage]
         """Run one batch through ops[from_op:]; updates states; returns the out batch."""
         self._push_count += 1
         sampled = self._sampled(self._push_count)
-        with _tracing.span("wf.chain.push", pos=_tracing.pos_of(batch), k=1,
-                           sampled=int(sampled)):
-            return self._push(batch, from_op, sampled)
+        pos = _tracing.pos_of(batch)
+        with _tracing.span("wf.chain.push", pos=pos, sampled=int(sampled)):
+            return self._push(batch, from_op, sampled, pos)
 
     def _sampled(self, c: int) -> bool:
         """Is launch number ``c`` timed to completion?  Never #1 — it would
@@ -392,19 +392,23 @@ class CompiledChain:  # wf-lint: single-writer[driver, stage]
         return ((c % self.SERVICE_SAMPLE_EVERY) == 0
                 or (1 < c < self.SERVICE_SAMPLE_EVERY and (c & (c - 1)) == 0))
 
-    def _push(self, batch: Batch, from_op: int, sampled: bool) -> Batch:
+    def _push(self, batch: Batch, from_op: int, sampled: bool,
+              pos: Optional[int]) -> Batch:
         if self.device is not None:
             batch = jax.device_put(batch, self.device)
         hl, t0c = self._health_begin("push")
         t0 = time.perf_counter() if sampled else 0.0
-        states, out = self._step_fn(from_op)(tuple(self.states), batch)
+        # the jit call alone (argument flattening, PjRt Execute): its start is
+        # the program's lower anchor for the step's start on the device
+        with _tracing.span("wf.chain.dispatch", pos=pos):
+            states, out = self._step_fn(from_op)(tuple(self.states), batch)
         if sampled:
             # device-time attribution (health): dispatch returned async, so
             # t_disp - t0 is host-dispatch overhead and t_done - t_disp the
             # device completion wait — riding the block_until_ready this
             # sampled push already pays
             t_disp = time.perf_counter()
-            with _tracing.span("wf.chain.sync"):
+            with _tracing.span("wf.chain.sync", pos=pos):
                 jax.block_until_ready(out)
             t_done = time.perf_counter()
             service_s = t_done - t0
