@@ -240,7 +240,10 @@ def test_eos_flush_goes_on_past_empty_windows_until_none_is_open(global_time):
         "ffat_keys": K, "ffat_pane_slots": 16,
         "fired_window_budget": 2 if global_time else 2 * K, "old_drops": 0,
         "windows_undelivered_at_eos": 0,
-        **({"ffat_ring_overruns": 0} if global_time else {})}
+        # the global-time path lists no rows; the per-key one finds the key
+        # of its 32 fired windows by comparison with all 16 (PR 37)
+        **({"ffat_ring_overruns": 0} if global_time
+           else {"owner_compare_cells": 2 * K * K})}
     assert op.get_StatsRecords()[0].tuples_dropped_old == 0
 
 
@@ -397,21 +400,16 @@ def step_operations(name, batch_capacity=8192):
     return len(lines), hashlib.sha256("\n".join(lines).encode()).hexdigest()
 
 
-#: ``step_operations`` of the four older cells at PR 33's commit (64560ca),
-#: taken there with this function. PR 34 put scopes below ``insert`` and
-#: ``emit`` on the path ``ysb`` runs and a counter of ring overruns where the
-#: value fold goes by slot (which ``ysb``'s count lift does not take): names
-#: changed, no operation did. A PR that changes a cell's program on purpose
-#: takes the new pair here, and says so.
+#: ``step_operations`` of ``ysb`` at PR 33's commit (64560ca), taken there with
+#: this function (``kcb``, ``ysb_wmr`` and ``kpf`` stood here with it until
+#: PR 37). PR 34 put scopes below ``insert`` and ``emit`` on the path ``ysb``
+#: runs and a counter of ring overruns where the value fold goes by slot
+#: (which ``ysb``'s count lift does not take): names changed, no operation
+#: did. A PR that changes a cell's program on purpose takes the new pair in
+#: ``CHANGED_STEPS``, and says so.
 PARENT_STEPS = {
     "ysb": (288, "3bf2d779d9de5f066e7fcd70e83a3c3c"
                  "106173e6b3e12e5fda01330b69b7dca9"),
-    "kcb": (453, "cf2391ad4be611da1702b973d2a08160"
-                 "d7da0c6451744b75205ca1af51e0447c"),
-    "ysb_wmr": (749, "30da5f53c44d9b80335adb1bb7844e23"
-                     "d285cd8c66e59e11a3f4a708be204554"),
-    "kpf": (1279, "cb2f241e337299e9a55c5eee4dc42485"
-                  "7f170ac78bb1d8a8681ccee6b934a018"),
 }
 
 
@@ -420,19 +418,72 @@ def test_the_older_cells_step_programs_are_the_parents(name):
     assert step_operations(name) == PARENT_STEPS[name]
 
 
-#: PR 35 changed this program on purpose: ``kff``'s integer value fold rides
+#: PR 35 changed ``kff``'s program on purpose: its integer value fold rides
 #: the occupancy histogram's one-hot contraction (``keyed_pane_fold``: one
 #: ``cond`` for counts and values, the two scatters its fallback branch). At
 #: PR 34's commit (8ecdb84) the step read (331, "b0625d6fe9e9746a...").
+#: PR 37 changed ``kcb``, ``ysb_wmr`` and ``kpf`` on purpose: every list of
+#: rows in the two window engines (``Win_Seq``'s body rows and fired windows,
+#: ``segment_run_fold``'s runs, ``Win_SeqFFAT._emit``'s fired windows) finds
+#: its keys through ``ops/segment.py::enumerate_runs``, which at these key
+#: counts compares a row with every key where ``jnp.searchsorted`` looped
+#: (and what a run or a fired window reads of its key's K-sized tables comes
+#: by ``table_lookup``'s select-reduce, not by a take); at PR 36's commit
+#: (992955a) they read (453, "cf2391ad4be611da..."), (749,
+#: "30da5f53c44d9b80...") and (1279, "cb2f241e337299e9..."). ``ysb`` (in
+#: ``PARENT_STEPS``) and ``kff`` (PR 35's pair) list no rows and did not move.
 CHANGED_STEPS = {
     "kff": (371, "e00cd3c7b7dbb22f7883d1be3444b39a"
                  "f70365d3e1a250be5cc2ad7b0997aa72"),
+    "kcb": (445, "03555a722655e4d39e8e56654b7eb342"
+                 "37af879e5058edbe40bcbbc4407047bd"),
+    "ysb_wmr": (729, "9bea6c039a3f24015c46cd932f5776c9"
+                     "b5f4b4f073d0e836b3076763fc2d4405"),
+    "kpf": (1239, "67b249f99659b352f5e55d08e28c5af5"
+                  "72fce9816ad34fd54ed546a86fe7e316"),
 }
 
 
 @pytest.mark.parametrize("name", sorted(CHANGED_STEPS))
 def test_the_step_programs_changed_on_purpose_are_as_recorded(name):
     assert step_operations(name) == CHANGED_STEPS[name]
+
+
+@pytest.mark.parametrize("name", ["kcb", "kpf"])
+def test_no_loop_is_left_over_the_rows_a_step_lists(name):
+    """At rehearsal size: where the engines list their rows (``Win_Seq``'s
+    ``insert/rank/runs`` and ``emit/range``, ``Win_SeqFFAT``'s
+    ``insert/rank/runs`` and ``emit``) no ``while`` and no ``scan`` carries
+    an operand as long as a list (the binary search's rounds over R runs or
+    W fired windows); what loops there still is the search of the K + 1 key
+    edges among the sorted lanes."""
+    mod, cfg = load_config(name)
+    ops, step, args = chain_step(cfg, mod, BATCH)
+    jaxpr = jax.make_jaxpr(step)(*args).jaxpr
+    window = ops[-1]
+    if name == "kpf":
+        lists = {n for _, e in window.engines() for n in (e.run_rows, e._w)}
+        # fired panes and windows, the PLQ's body rows, the WLQ's
+        assert lists == {136, 16 + 8, 2 + 8}, lists
+    else:
+        lists = {window._run_budget, window._w}
+        assert lists == {BATCH // cfg["slide"] + 2 * cfg["n_keys"],
+                         BATCH // cfg["slide"] + 64}, lists
+    K = cfg["n_keys"]
+    loops = [(path, sorted({v.aval.shape[0] for v in eqn.invars
+                            if v.aval.shape}))
+             for eqn, path in equations(jaxpr)
+             if eqn.primitive.name in ("while", "scan")
+             and re.search(r"/(insert/rank/runs|emit)(/|$)", path)]
+    assert all(not lists & set(sizes) for _, sizes in loops), loops
+    # kcb's key edges are searched under insert/rank/runs (kpf's under
+    # insert/rank/sort, in sort_segments): K + 1 queries into the lanes
+    assert [sizes for _, sizes in loops] == (
+        [[K + 1, BATCH]] if name == "kcb" else []), loops
+    window.collect_stats(args[0][-1])
+    cells = {k: v for k, v in window.stage_counters().items()
+             if k.endswith("owner_compare_cells")}
+    assert len(cells) == (2 if name == "kpf" else 1) and all(cells.values())
 
 
 def test_kffs_step_scatters_nowhere_but_in_the_fallback_branch():

@@ -64,6 +64,13 @@ def test_served_path_equals_the_reference_eos_flush_included(seed):
     assert {f"{s}_{c}" for s in mod.STAGES
             for c in mod.ENGINE_COUNTERS} < set(checks)
     assert all(v == 0 and limit == 0 for v, limit in checks.values()), checks
+    # the checks read what they read before PR 37: the six drop and EOS
+    # counters and the two of the structure, not the new gauge, which the
+    # stage publishes for both engines beside them (rehearsal: 8 keys)
+    assert len(checks) == 8 and not any("owner" in c for c in checks)
+    counters = ops[-1].stage_counters()
+    assert counters["plq_owner_compare_cells"] == (24 + 136) * 8
+    assert counters["wlq_owner_compare_cells"] == (10 + 136) * 8
     # straight from the stamped records: the last whole window of key 3
     recs = np.concatenate(pool)
     w = 144 - 17
@@ -134,6 +141,12 @@ def test_budgets_come_from_the_deployment():
         "wlq_archive_run_len", "wlq_archive_run_rows")} == {
         "plq_archive_run_len": 1024, "plq_archive_run_rows": 2048,
         "wlq_archive_run_len": 64, "wlq_archive_run_rows": 1160}
+    # how a listed row finds its key: by comparison with all 512 (PR 37), the
+    # insert's body rows and the fired windows of each engine
+    assert {k: counters[k] for k in (
+        "plq_owner_compare_cells", "wlq_owner_compare_cells")} == {
+        "plq_owner_compare_cells": (1536 + 8704) * 512,
+        "wlq_owner_compare_cells": (648 + 8704) * 512}
     # and once the step is traced: payload, id and ts of a row in one gather
     ops, step, args = chain_step(published, mod, 1 << 20)
     jax.eval_shape(step, *args)

@@ -5,6 +5,7 @@ result invariance vs a sequential run, src/mp_test_cpu suite semantics)."""
 
 import numpy as np
 import jax.numpy as jnp
+import pytest
 
 import windflow_tpu as wf
 from windflow_tpu.operators.window import WindowSpec
@@ -184,3 +185,77 @@ def test_vector_payload_windows():
     want = sorted(tuple([sum(xs[j:j + 8])] * 4)
                   for xs in per_key.values() for j in range(0, len(xs), 8))
     assert noninc == want
+
+
+# ------------------------------------------- owner_compare_cells (PR 37)
+
+def _engine(kind, K, **kw):
+    from windflow_tpu.operators.win_seqffat import Win_SeqFFAT
+    if kind == "win_seq":
+        return Win_Seq(lambda wid, it: it.sum("v"),
+                       WindowSpec(8, 4, win_type_t.CB), num_keys=K, **kw)
+    return Win_SeqFFAT(lambda t: t.v, jnp.add,
+                       spec=WindowSpec(8, 4, win_type_t.CB), num_keys=K, **kw)
+
+
+@pytest.mark.parametrize("kind,K,batch,rows,fired", [
+    # Win_Seq: the insert's listed body rows (run_rows: a key's whole ring of
+    # 128 slots a row; rows of 32 slots) and the fired windows
+    ("win_seq", 3, 64, 64 // 128 + 3, 80),
+    ("win_seq", 1024, 4096, 4096 // 32 + 1024, 1088),
+    # Win_SeqFFAT, count-based: the (key, pane) runs and the fired windows
+    ("win_seqffat", 3, 64, 64 // 4 + 2 * 3, 80),
+    ("win_seqffat", 1024, 4096, 4096 // 4 + 2 * 1024, 1088),
+    # past the crossover the lists keep the binary search: no cell compared
+    ("win_seq", 1 << 17, 4096, None, 1088),
+    ("win_seqffat", 1 << 18, 4096, None, 1088),
+])
+def test_owner_compare_cells_is_rows_by_keys_at_the_engines_shapes(
+        kind, K, batch, rows, fired):
+    from windflow_tpu.observability.names import STAGE_GAUGES
+    op = _engine(kind, K)
+    op.bind_geometry(batch)
+    # known with the fired-window budget: from the first apply, or max_wins=
+    assert "owner_compare_cells" not in op.stage_counters()
+    op = _engine(kind, K, max_wins=fired)
+    op.bind_geometry(batch)
+    counters = op.stage_counters()
+    assert "owner_compare_cells" in STAGE_GAUGES
+    if kind == "win_seq":
+        assert counters["fired_window_budget"] == fired
+        assert rows is None or op.run_rows == rows
+    else:
+        assert rows is None or counters["ffat_run_budget"] == rows
+    assert counters["owner_compare_cells"] == (
+        0 if rows is None else (rows + fired) * K)
+
+
+def test_global_time_path_lists_no_rows_and_publishes_no_cells():
+    from windflow_tpu.operators.win_seqffat import Win_SeqFFAT
+    op = Win_SeqFFAT(lambda t: t.v, jnp.add,
+                     spec=WindowSpec(8, 4, win_type_t.TB), num_keys=4)
+    op.bind_geometry(64)
+    assert "fired_window_budget" in op.stage_counters()
+    assert "owner_compare_cells" not in op.stage_counters()
+
+
+@pytest.mark.parametrize("kind", ["win_seq", "win_seqffat"])
+def test_results_past_the_crossover_are_the_oracles(kind):
+    """An engine bound for 131,072 keys keeps the binary search (the gauge
+    reads 0) and delivers what the same stream gives at 3."""
+    out = {}
+    for K in (3, 1 << 17):
+        op = _engine(kind, K)
+        src = wf.Source(lambda i: {"v": (i // 3).astype(jnp.float32)},
+                        total=200, num_keys=3)
+        got = []
+
+        def cb(view):
+            if view is not None:
+                got.extend(zip(view["key"].tolist(), view["id"].tolist(),
+                               np.asarray(view["payload"]).tolist()))
+        wf.Pipeline(src, [op], wf.Sink(cb), batch_size=16).run()
+        out[K] = sorted(got)
+        cells = op.stage_counters()["owner_compare_cells"]
+        assert (cells > 0) == (K == 3)
+    assert out[3] == out[1 << 17] == oracle_cb(200, 3, 8, 4)

@@ -147,7 +147,10 @@ def test_budgets_come_from_the_deployment():
     window.bind_geometry(1 << 20)               # as the compiled chain does
     assert window.stage_counters() == {
         "archive_slots": 8192, "fired_window_budget": 200,
-        "archive_run_len": 2048, "archive_run_rows": 712}
+        "archive_run_len": 2048, "archive_run_rows": 712,
+        # the 612 body rows and the 200 fired windows find their campaign by
+        # comparison with all 100 (PR 37)
+        "owner_compare_cells": (612 + 200) * 100}
     assert window.engine.A * window.num_keys * 4 * 4 < 14e6   # four tables
 
 
@@ -208,7 +211,7 @@ def test_eos_flush_delivers_more_open_windows_than_the_budget(pattern):
         "archive_slots": 8, "fired_window_budget": 4, "archive_overwrites": 0,
         "old_drops": 0, "windows_undelivered_at_eos": 0,
         "archive_run_len": 8, "archive_run_rows": 36, "archive_run_groups": 1,
-        "archive_runs_written": 32}
+        "archive_runs_written": 32, "owner_compare_cells": (20 + 4) * K}
 
 
 def test_old_drops_and_overwrites_are_counted_on_the_device():
@@ -332,7 +335,7 @@ def test_the_insert_at_the_published_size_moves_rows_not_lanes():
     assert ops[-1].engine._budget_gauges() == {
         "archive_slots": 8192, "fired_window_budget": 200,
         "archive_run_len": 2048, "archive_run_rows": 712,
-        "archive_run_groups": 1}
+        "archive_run_groups": 1, "owner_compare_cells": (612 + 200) * 100}
     sorts = [path for eqn, path in insert if eqn.primitive.name == "sort"]
     assert sorts == [f"{window}/insert/rank/sort"]
     assert not [eqn.primitive.name for eqn, _ in insert

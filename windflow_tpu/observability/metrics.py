@@ -1155,6 +1155,9 @@ class MetricsRegistry:
                                   "one slice a ring row each",
             "archive_runs_written": "window-archive ring rows written per "
                                     "table",
+            "owner_compare_cells": "rows x keys cells one step compares to "
+                                   "find the key of every row it lists "
+                                   "(0: binary search)",
         }
         # a pattern of two archive engines publishes each one's under its stage
         for stage in PANE_STAGES:

@@ -182,7 +182,7 @@ ARCHIVE_ENGINE_COUNTERS = ("archive_overwrites", "old_drops",
                            "windows_undelivered_at_eos", "archive_runs_written")
 ARCHIVE_ENGINE_GAUGES = ("archive_slots", "fired_window_budget",
                          "archive_run_len", "archive_run_rows",
-                         "archive_run_groups")
+                         "archive_run_groups", "owner_compare_cells")
 ARCHIVE_ENGINE_DROPS = ("archive_overwrites",)
 PANE_STAGES = ("plq", "wlq")
 
@@ -244,6 +244,11 @@ STAGE_GAUGES = (
     # slice a row each; 1 where every column shares a buffer)
     "archive_slots", "fired_window_budget", "archive_run_len", "archive_run_rows",
     "archive_run_groups",
+    # both window engines, once the fired-window budget is settled: the rows x
+    # keys cells a step compares to find the key of every row it lists (ring
+    # rows or pane runs of the insert, fired windows), 0 where the lists kept
+    # the binary search (ops/segment.py::enumerate_runs)
+    "owner_compare_cells",
     # Pane_Farm's two engines' budgets, a prefix a stage
     *(f"{stage}_{gauge}" for stage in PANE_STAGES
       for gauge in ARCHIVE_ENGINE_GAUGES),

@@ -36,7 +36,8 @@ unfired window still needs: the state counts them (``overwrites``), and the OLD 
 ``flush`` adds ``windows_undelivered_at_eos``. The insert's row geometry follows the
 shapes (``_row_geometry``: batch capacity, keys, ring slots) and is published with
 them: ``archive_run_len``, ``archive_run_rows``, ``archive_run_groups`` (the gathers
-of the sorted columns a pass issues, one slice a row each), and
+of the sorted columns a pass issues, one slice a row each), ``owner_compare_cells``
+(how the listed rows and the fired windows find their key: ``enumerate_runs``), and
 ``archive_runs_written`` counts the rows the inserts wrote per table (beside the
 tuples they archived).
 
@@ -56,8 +57,10 @@ import jax.numpy as jnp
 from ..basic import routing_modes_t, role_t, DEFAULT_MAX_KEYS
 from ..batch import Batch, CTRL_DTYPE, TupleRef
 from ..meta import classify_window, classify_winupdate
-from ..ops.segment import (SLICE_GBPS, SLICE_US, enumerate_runs, range_max,
-                           sort_segments, take_windows, window_groups)
+from ..ops.lookup import table_lookup
+from ..ops.segment import (SLICE_GBPS, SLICE_US, enumerate_runs,
+                           owner_compare_cells, range_max, sort_segments,
+                           take_windows, window_groups)
 from .base import Basic_Operator
 from .window import Iterable, WindowSpec
 
@@ -247,7 +250,6 @@ class Win_Seq(Basic_Operator):
         can share a buffer (``take_windows``; ``archive_run_groups`` such
         gathers a pass, 1 where payload, id and ts are all 32-bit columns),
         because what a window costs there does not depend on what it holds."""
-        from ..ops.lookup import table_lookup
         K, A = self.num_keys, self.A
         T, body_rows = self._row_geometry(batch.capacity)
         per_key = A // T                                 # ring rows a key
@@ -393,18 +395,20 @@ class Win_Seq(Basic_Operator):
         with jax.named_scope("range"):
             lo, hi = self._fired_range(state, flush)
             n_f = hi - lo
-            csum = jnp.cumsum(n_f)
-            off = csum - n_f
-            total = csum[-1] if K > 0 else jnp.asarray(0, CTRL_DTYPE)
-            w_idx = self._wsc(jnp.arange(W, dtype=CTRL_DTYPE))
-            k_of = jnp.searchsorted(csum, w_idx, side="right").astype(CTRL_DTYPE)
-            k_safe = self._wsc(jnp.minimum(k_of, K - 1))
-            wid = self._wsc(jnp.take(lo, k_safe) + (w_idx - jnp.take(off, k_safe)))
-            valid_w = self._wsc(w_idx < jnp.minimum(total, W))
+            k_safe, i_of, valid_w = map(self._wsc, enumerate_runs(n_f, W))
+            # what a fired window reads of its key's K-sized tables comes by
+            # one select-reduce over both (exact: one term a sum), not by two
+            # gathers of an element a window (0.067 ms each at 8,704 windows).
+            # Stacked, because as two lookups they cost a ring table its place
+            # in the chip's fast memory and a row gather below 0.27 ms more
+            # (PERF.md section 6, PR 37)
+            at_key = table_lookup(jnp.stack([lo, state.count], axis=1), k_safe)
+            wid = self._wsc(at_key[:, 0] + i_of)
+            count_w = at_key[:, 1:]
 
-            # advance next_win past emitted windows
-            emitted_k = jnp.clip(jnp.minimum(total, W) - off, 0, n_f)
-            new_next = lo + emitted_k
+            # advance next_win past emitted windows: the first W of the list
+            csum = jnp.cumsum(n_f)
+            new_next = lo + jnp.clip(W - (csum - n_f), 0, n_f)
 
         with jax.named_scope("gather"):
             if s.is_cb:
@@ -414,7 +418,7 @@ class Win_Seq(Basic_Operator):
                 gflat = k_safe[:, None] * A + slot                         # [W, L]
                 def gat(tbl):
                     return jnp.take(tbl.reshape((K * A,) + tbl.shape[2:]), gflat, axis=0)
-                content_mask = (p < jnp.take(state.count, k_safe)[:, None]) & valid_w[:, None]
+                content_mask = (p < count_w) & valid_w[:, None]
                 # stale-slot guard: the slot must actually hold position p
                 content_mask &= gat(state.arch_pos) == p
                 data = jax.tree.map(gat, state.arch_payload)
@@ -431,8 +435,7 @@ class Win_Seq(Basic_Operator):
                 content_mask = ((poss >= 0) & (tss >= w_start)
                                 & (tss - w_start < s.win_len) & valid_w[:, None])
                 # ring-overwrite guard: slot must hold a live (not yet overwritten) pos
-                cnt = jnp.take(state.count, k_safe)[:, None]
-                content_mask &= poss >= jnp.maximum(0, cnt - A)
+                content_mask &= poss >= jnp.maximum(0, count_w - A)
                 data = jax.tree.map(gat, state.arch_payload)
                 ids = gat(state.arch_id)
                 # the window's last tick, or int32's last where it ends later
@@ -507,13 +510,19 @@ class Win_Seq(Basic_Operator):
         listed ones), the ``take_windows`` gathers a pass issues (each of
         them one slice a row; once the first ``_insert`` has seen the
         payload), fired windows a batch (once ``max_wins`` or the first
-        ``apply`` has settled it)."""
+        ``apply`` has settled it) and with them the rows x keys cells a step
+        compares to find the key of every listed ring row and fired window
+        (``enumerate_runs``; 0 where both lists kept the binary search)."""
         W = self.max_wins if self.max_wins is not None else self._w
+        K = self.num_keys
         return {"archive_slots": self.A, "archive_run_len": self.run_len,
-                "archive_run_rows": self.num_keys + self.run_rows,
+                "archive_run_rows": K + self.run_rows,
                 **({} if self._run_groups is None
                    else {"archive_run_groups": self._run_groups}),
-                **({} if W is None else {"fired_window_budget": W})}
+                **({} if W is None else {
+                    "fired_window_budget": W,
+                    "owner_compare_cells": owner_compare_cells(W, K)
+                    + owner_compare_cells(self.run_rows, K)})}
 
     def collect_stats(self, state=None) -> None:
         """Sync the device-resident counters into the stage counters (monitoring

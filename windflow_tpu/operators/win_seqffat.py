@@ -34,7 +34,9 @@ import jax.numpy as jnp
 from ..basic import routing_modes_t, DEFAULT_MAX_KEYS
 from ..batch import Batch, CTRL_DTYPE, TupleRef
 from ..observability import event_time as _et
-from ..ops.segment import segment_reduce
+from ..ops.lookup import table_lookup
+from ..ops.segment import (enumerate_runs, owner_compare_cells, run_budget,
+                           segment_reduce, segment_run_fold)
 from .base import Basic_Operator
 from .window import WindowSpec
 
@@ -94,8 +96,11 @@ class Win_SeqFFAT(Basic_Operator):
     sizes, ``ffat_keys``, ``ffat_pane_slots``, for count-based specs
     ``ffat_run_budget``, for time-based ones ``fired_window_budget`` once it
     is known (``max_wins=``, the ring on the global-time path, else the first
-    ``apply``); at ``collect_stats`` ``old_drops`` and, on the global-time
-    path with a lift that reads the tuple, ``ffat_ring_overruns`` (lanes
+    ``apply``) and, off the global-time path, ``owner_compare_cells`` with
+    it (how the runs and the fired windows find their key:
+    ``ops/segment.py::enumerate_runs``); at ``collect_stats`` ``old_drops``
+    and, on the global-time path with a lift that reads the tuple,
+    ``ffat_ring_overruns`` (lanes
     whose pane lay ``P`` or more past the first unfired pane: they were folded
     into a slot that an unfired pane still held; the count-lift branch folds no
     value by slot and publishes none) and, where an additive integer lift
@@ -174,11 +179,11 @@ class Win_SeqFFAT(Basic_Operator):
         gauges = {"ffat_keys": self.num_keys, "ffat_pane_slots": self.P}
         if self.spec.is_cb:
             # the (key, pane) runs a batch may hold
-            from ..ops.segment import run_budget
-            gauges["ffat_run_budget"] = run_budget(
+            gauges["ffat_run_budget"] = self._run_budget = run_budget(
                 batch_capacity, self.num_keys, self.pane_len)
         else:
             gauges.update(self._fired_budget_gauge())
+        gauges.update(self._owner_cells_gauge())
         self._publish_stage_counters(gauges)
 
     def _fired_budget_gauge(self) -> dict:
@@ -189,6 +194,20 @@ class Win_SeqFFAT(Basic_Operator):
         if W is None and self.global_time:
             W = self._resolve_w(0)
         return {} if W is None else {"fired_window_budget": W}
+
+    def _owner_cells_gauge(self) -> dict:
+        """``owner_compare_cells``: the rows x keys cells a step compares to
+        find the key of every run it folds (count-based specs) and of every
+        window it fires, 0 where both lists kept the binary search
+        (``ops/segment.py::enumerate_runs``); known once the fired-window
+        budget is. The global-time path lists neither and publishes none."""
+        W = self.max_wins if self.max_wins is not None else self._w
+        if self.global_time or W is None:
+            return {}
+        runs = self._run_budget if self.spec.is_cb else 0
+        return {"owner_compare_cells":
+                owner_compare_cells(W, self.num_keys)
+                + owner_compare_cells(runs, self.num_keys)}
 
     def out_capacity(self, in_capacity: int) -> int:
         if self.global_time:
@@ -450,7 +469,6 @@ class Win_SeqFFAT(Basic_Operator):
         (values, occupancy counts) route through the registry-selectable
         ``segment_fold`` kernel — see ``_g_insert`` for the selection
         contract. Everything from ``touched`` on is shared."""
-        from ..ops.lookup import table_lookup
         K, P = self.num_keys, self.P
         valid = batch.valid
         cb = self.spec.is_cb
@@ -535,7 +553,6 @@ class Win_SeqFFAT(Basic_Operator):
         (PERF.md section 6, PR 26). Within a batch two runs of a key never
         share a ring slot (``bind_geometry``: P > wpanes + C/pane_len + 2),
         so the writes have unique indices."""
-        from ..ops.segment import segment_run_fold
         K, P = self.num_keys, self.P
         lifted = jax.vmap(self.lift)(
             TupleRef(key=batch.key, id=batch.id, ts=batch.ts, data=batch.payload))
@@ -568,15 +585,10 @@ class Win_SeqFFAT(Basic_Operator):
         lo = state.next_win
         hi = jnp.maximum(self._due_hi(state, flush), lo)
         n_f = hi - lo
-        csum = jnp.cumsum(n_f)
-        off = csum - n_f
-        total = csum[-1]
-        w_idx = jnp.arange(W, dtype=CTRL_DTYPE)
-        k_of = jnp.searchsorted(csum, w_idx, side="right").astype(CTRL_DTYPE)
-        k_safe = jnp.minimum(k_of, K - 1)
-        wid = jnp.take(lo, k_safe) + (w_idx - jnp.take(off, k_safe))
-        valid_w = w_idx < jnp.minimum(total, W)
-        emitted_k = jnp.clip(jnp.minimum(total, W) - off, 0, n_f)
+        k_safe, i_of, valid_w = enumerate_runs(n_f, W)
+        wid = table_lookup(lo, k_safe) + i_of
+        # the first W of the list are emitted
+        emitted_k = jnp.clip(W - (jnp.cumsum(n_f) - n_f), 0, n_f)
 
         # gather the wpanes panes of each window and tree-reduce (getResult():
         # wf/flatfat.hpp root read; here a log-depth reduction over the pane axis)
@@ -699,6 +711,7 @@ class Win_SeqFFAT(Basic_Operator):
         counters = {**self.stage_counters(), "old_drops": old}
         if not self.spec.is_cb:
             counters.update(self._fired_budget_gauge())
+        counters.update(self._owner_cells_gauge())
         if (self.global_time and self.count_lift is not None
                 and not self._hist_is_fold()):
             counters["ffat_ring_overruns"] = int(

@@ -27,6 +27,8 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from .lookup import table_lookup
+
 
 def _bmask(valid, v):
     """Broadcast a [C] mask against a [C, ...] value."""
@@ -314,13 +316,11 @@ def segment_run_fold(folds, keys: jax.Array, valid: jax.Array, num_keys: int,
         lo, n = edges[:-1], edges[1:] - edges[:-1]
         first = offset // L
         n_runs = jnp.where(n > 0, (offset + n - 1) // L - first + 1, 0)
-        csum = jnp.cumsum(n_runs)
-        r = jnp.arange(R, dtype=jnp.int32)
-        live = r < csum[-1]
-        k = jnp.minimum(jnp.searchsorted(csum, r, side="right"),
-                        K - 1).astype(jnp.int32)
-        chunk = jnp.take(first, k) + r - jnp.take(csum - n_runs, k)
-        lo_k, n_k, off_k = jnp.take(lo, k), jnp.take(n, k), jnp.take(offset, k)
+        k, i, live = enumerate_runs(n_runs, R)
+        # (a run's reads of its key's tables: 0.025 ms a gather at 3,072 runs)
+        first_k, lo_k, n_k, off_k = (
+            table_lookup(t, k) for t in (first, lo, n, offset))
+        chunk = first_k + i
         start = jnp.where(live, lo_k + jnp.maximum(chunk * L - off_k, 0), 0)
         end = jnp.where(live, lo_k + jnp.minimum((chunk + 1) * L - off_k, n_k),
                         0)
@@ -379,7 +379,6 @@ def segment_prefix_scan(values: Any, keys: jax.Array, valid: jax.Array,
     (``wf/accumulator.hpp:61``, keyMap ``:103-104``) for associative user combines.
     Addition gets a cumsum fast path (segment prefix = cumsum - segment-start base);
     general combines use the segmented ``associative_scan``."""
-    from .lookup import table_lookup
     c = keys.shape[0]
     if combine in (jnp.add,):
         seg_keys, orig_idx, sv = _sort_by_key(keys, valid, values)
@@ -429,16 +428,60 @@ def sort_segments(arrays: Any, keys: jax.Array, valid: jax.Array,
     return sorted_arrays, edges[:-1], edges[1:] - edges[:-1]
 
 
+#: what finding the owners of R rows among K keys costs on one v5e (PERF.md
+#: section 6, PR 37). ``jnp.searchsorted``'s default is a binary search, a
+#: ``while`` of ``K.bit_length()`` rounds, each a gather of one element a row
+#: from the K-sized table, and XLA:TPU serializes such a gather:
+#: ``SEARCH_ROUND_NS`` a row a round (6.65-6.8 from 4,096 keys up, 7.7-8.2 at
+#: 512), the ``jnp.take`` of the key's first row after it one round more. The
+#: count of ``csum <= r`` over the K axis is the same integer from R x K cells
+#: of VPU work with nothing serialized: ``COMPARE_CELL_NS`` a cell, the masked
+#: max for the key's first row included (0.0014-0.0015 from 4,096 keys up,
+#: 0.0017-0.0020 at 512-1,024: 6.6-7.7 us for (8,704, 512) where the search
+#: takes 741).
+SEARCH_ROUND_NS = 6.7
+COMPARE_CELL_NS = 0.0015
+
+
+def owner_compare_cells(rows: int, num_keys: int) -> int:
+    """The ``rows x num_keys`` cells :func:`enumerate_runs` compares at these
+    shapes to find every row's key, 0 where it keeps the binary search (a key
+    space so large that a row's K cells cost more than its ``K.bit_length() +
+    1`` serialized rounds)."""
+    rows, K = int(rows), int(num_keys)
+    by_compare = K * COMPARE_CELL_NS <= (K.bit_length() + 1) * SEARCH_ROUND_NS
+    return rows * K if by_compare else 0
+
+
 def enumerate_runs(n_runs: jax.Array, budget: int):
-    """List ``n_runs[k]`` runs for every key k, key by key, in ``budget``
-    rows: returns ``(key[budget], index[budget], live[budget])`` with
-    ``index`` counting a key's runs from 0. K- and budget-sized work; rows
-    past the total are dead (``key`` clipped to K - 1)."""
+    """List ``n_runs[k]`` (none negative) runs for every key k, key by key, in
+    ``budget`` rows: returns ``(key[budget], index[budget], live[budget])``
+    with ``index`` counting a key's runs from 0. K- and budget-sized results;
+    rows past the total are dead (``key`` clipped to K - 1, ``index`` counted
+    on from that key's first row).
+
+    Row r belongs to key ``#{j : csum[j] <= r}`` and its key's first row is
+    ``max{off[j] : off[j] <= r}`` (``off = csum - n_runs`` never decreases):
+    two reductions over one ``[budget, K]`` comparison. That is
+    ``jnp.searchsorted(csum, r, side="right")`` and a ``jnp.take`` of ``off``,
+    which stay where :func:`owner_compare_cells` prices them lower: the
+    default binary search is a loop of per-row gathers, which cost this chip
+    more than all K comparisons do up to 65,536 keys (801 us against 1,041
+    at 8,704 rows) and less from 131,072 on (1,101 against 1,601)."""
+    K = n_runs.shape[0]
     csum = jnp.cumsum(n_runs)
+    off = csum - n_runs
     r = jnp.arange(budget, dtype=jnp.int32)
-    k = jnp.minimum(jnp.searchsorted(csum, r, side="right"),
-                    n_runs.shape[0] - 1).astype(jnp.int32)
-    return k, r - jnp.take(csum - n_runs, k), r < csum[-1]
+    if owner_compare_cells(budget, K):
+        k = jnp.sum(csum[None, :] <= r[:, None], axis=1, dtype=jnp.int32)
+        first = jnp.max(jnp.where(off[None, :] <= r[:, None], off[None, :], 0),
+                        axis=1)
+        k = jnp.minimum(k, K - 1)
+    else:
+        k = jnp.minimum(jnp.searchsorted(csum, r, side="right"),
+                        K - 1).astype(jnp.int32)
+        first = jnp.take(off, k)
+    return k, r - first, r < csum[-1]
 
 
 #: lanes of one aligned block of :func:`range_max`
