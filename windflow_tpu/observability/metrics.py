@@ -1140,6 +1140,9 @@ class MetricsRegistry:
             "ffat_fold_fallbacks": "batches whose pane value fold took the "
                                    "scatter branch (ticks out of order "
                                    "inside a chunk)",
+            "ffat_late_lanes": "tuples folded after a window holding them "
+                               "had fired (counted in the open windows "
+                               "alone)",
             "ffat_run_budget": "(key, pane) runs one batch may hold in the "
                                "count-based pane fold",
             "ffat_keys": "keys of the pane ring",

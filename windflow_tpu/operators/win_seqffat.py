@@ -82,6 +82,13 @@ class GFFATState:
     fold_fallbacks: jax.Array
     #: i32[NB] observed-lateness histogram (event-time monitoring only)
     lat_hist: Any = None
+    #: i32[] lanes folded after a window that holds them had fired: their pane
+    #: was not behind the horizon, their ``ts`` was before the end of the
+    #: newest fired window, so they count in the windows still open alone
+    #: (upstream's OLD-for-that-window); None where the spec allows no
+    #: lateness (``delay`` 0), an empty pytree subtree, so that program is
+    #: unchanged
+    late_lanes: Any = None
 
 
 class Win_SeqFFAT(Basic_Operator):
@@ -103,11 +110,13 @@ class Win_SeqFFAT(Basic_Operator):
     ``ffat_ring_overruns`` (lanes
     whose pane lay ``P`` or more past the first unfired pane: they were folded
     into a slot that an unfired pane still held; the count-lift branch folds no
-    value by slot and publishes none) and, where an additive integer lift
+    value by slot and publishes none), where an additive integer lift
     rides the occupancy histogram's contraction, ``ffat_fold_fallbacks``
     (batches whose ticks were too far out of order for it: they took the
-    exact scatters; 0 for an in-order stream); at ``flush``
-    ``windows_undelivered_at_eos``.
+    exact scatters; 0 for an in-order stream) and, where the spec allows
+    lateness (``delay > 0``), ``ffat_late_lanes`` (lanes folded after a
+    window that holds them had fired: they count in the windows still open);
+    at ``flush`` ``windows_undelivered_at_eos``.
 
     ``flush`` returns one batch of open windows a call and None once none is
     left; a pass that holds only windows without a tuple is passed over."""
@@ -242,6 +251,8 @@ class Win_SeqFFAT(Basic_Operator):
                 ring_overruns=jnp.zeros((), CTRL_DTYPE),
                 fold_fallbacks=jnp.zeros((), CTRL_DTYPE),
                 lat_hist=lat,
+                late_lanes=(jnp.zeros((), CTRL_DTYPE) if self.spec.delay > 0
+                            else None),
             )
         return FFATState(
             panes=jax.tree.map(
@@ -283,7 +294,11 @@ class Win_SeqFFAT(Basic_Operator):
         ``keyed_pane_fold`` has the XLA form alone. Slot cleanliness is
         maintained by clear-on-fire in ``_g_emit`` so no pane-id bookkeeping is
         needed; OLD tuples (pane already fired) are dropped with a scalar
-        horizon compare. The ring holds the ``P`` panes from the first unfired
+        horizon compare. A kept lane whose ``ts`` an already fired window
+        holds too (``delay`` shorter than its lateness) counts in the open
+        windows alone; with ``delay > 0`` such lanes are counted
+        (``late_lanes`` -> ``ffat_late_lanes``). The ring holds the ``P``
+        panes from the first unfired
         one: a lane further ahead shares its slot with a pane that has not
         fired, and where the partials are folded by slot such lanes are
         counted (``ring_overruns`` -> ``ffat_ring_overruns``; size the ring
@@ -300,6 +315,15 @@ class Win_SeqFFAT(Basic_Operator):
         # stragglers behind the fired horizon are DROPPED, not merely delayed
         # (global clock: per-key skew > delay loses tuples) — count them
         n_dropped = jnp.sum((batch.valid & ~valid).astype(CTRL_DTYPE))
+        late_lanes = state.late_lanes
+        if late_lanes is not None:
+            # a kept lane is late when the first window holding it has fired:
+            # the windows before ``next_win`` do not see it. In window units,
+            # so that no tick past 2^31 is formed
+            first_w = jnp.maximum(
+                0, (batch.ts - self.spec.win_len) // self.spec.slide + 1)
+            late_lanes = late_lanes + jnp.sum(
+                (valid & (first_w < state.next_win)).astype(CTRL_DTYPE))
         if self.count_lift is None:
             self.count_lift = _detect_count_lift(self.lift, batch)
         tuples = TupleRef(key=batch.key, id=batch.id, ts=batch.ts,
@@ -368,6 +392,7 @@ class Win_SeqFFAT(Basic_Operator):
             ring_overruns=ring_overruns,
             fold_fallbacks=fold_fallbacks,
             lat_hist=lat,
+            late_lanes=late_lanes,
         )
 
     def _hist_is_fold(self) -> bool:
@@ -699,10 +724,10 @@ class Win_SeqFFAT(Basic_Operator):
         counters (monitoring snapshot / EOS — scalar D2H reads, off the hot
         path): ``old_drops``; on the global-time path ``ffat_ring_overruns``
         where the fold counts them (a lift that reads the tuple: the count-lift
-        branch folds no value by slot and publishes none) and
+        branch folds no value by slot and publishes none),
         ``ffat_fold_fallbacks`` where the value fold rides the histogram's
-        contraction; for time-based specs the fired-window budget once it is
-        settled."""
+        contraction and ``ffat_late_lanes`` where the spec allows lateness;
+        for time-based specs the fired-window budget once it is settled."""
         if state is None or not hasattr(state, "dropped_old"):
             return
         import numpy as np
@@ -719,6 +744,8 @@ class Win_SeqFFAT(Basic_Operator):
         if self.global_time and self._fold_rides:
             counters["ffat_fold_fallbacks"] = int(
                 np.asarray(state.fold_fallbacks))
+        if getattr(state, "late_lanes", None) is not None:
+            counters["ffat_late_lanes"] = int(np.asarray(state.late_lanes))
         self._publish_stage_counters(counters)
 
     def drop_counters(self, state=None) -> dict:

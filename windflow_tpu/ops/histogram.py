@@ -266,6 +266,11 @@ def keyed_pane_fold(key: jax.Array, pane: jax.Array, valid: jax.Array,
     input: a batch that breaks chunk locality takes the two scatters
     (:func:`_scatter_hist`, ``ops/segment.py::segment_reduce``) inside the
     same ``lax.cond``, and ``in_bounds`` (bool[]) says which branch ran.
+    The fallback runs under a scope of its own, ``scatter``. It is taken
+    whenever one chunk of ``chunk`` consecutive lanes holds a lane
+    ``locality`` or more panes past the chunk's oldest: in a stream with
+    late tuples, one straggler that far back in any chunk sends the whole
+    batch to the scatters.
 
     The fast branch's right-hand operand holds ``1 + limbs`` column groups of
     ``locality`` columns (40 for one int32 leaf), under the 128 the MXU takes
@@ -310,10 +315,13 @@ def keyed_pane_fold(key: jax.Array, pane: jax.Array, valid: jax.Array,
         return sums[:, 0], folds
 
     def scatter(_):
-        seg = jnp.where(valid, key * P + pane % P, K * P)
-        return (_scatter_hist(key, pane, valid, K, P),
-                [u.reshape(K, P)
-                 for u in segment_reduce(leaves, seg, valid, K * P)])
+        # its own scope, so that a profile tells the fallback's device time
+        # from the fast branch's
+        with jax.named_scope("scatter"):
+            seg = jnp.where(valid, key * P + pane % P, K * P)
+            return (_scatter_hist(key, pane, valid, K, P),
+                    [u.reshape(K, P)
+                     for u in segment_reduce(leaves, seg, valid, K * P)])
 
     counts, folds = jax.lax.cond(in_bounds, fast, scatter, None)
     return counts, jax.tree.unflatten(treedef, folds), in_bounds
