@@ -256,7 +256,7 @@ def _case_pane_fold(args, rng):
     one key's one pane, so a limb column of that cell passes 2^24."""
     import jax
     import jax.numpy as jnp
-    from windflow_tpu.ops.histogram import keyed_pane_fold
+    from windflow_tpu.ops.histogram import FOLD_FAST, keyed_pane_fold
     C, K, P = args.lanes, 512, 256
     lane = np.arange(C)
     key = np.where(lane < C // 4, 7, lane % K).astype(np.int32)
@@ -272,7 +272,40 @@ def _case_pane_fold(args, rng):
     return (f"pane_fold[C={C},K={K},P={P},i32 full range,one-cell quarter]",
             {"xla": lambda: jax.jit(
                 lambda k, p, v, x: keyed_pane_fold(k, p, v, x, K, P))(*dev)},
-            (counts, sums.astype(np.int32), np.array(True)))
+            (counts, sums.astype(np.int32), np.int32(FOLD_FAST),
+             np.int32(0)))
+
+
+def _case_pane_fold_late(args, rng):
+    """``kff_late``'s stream shape: a tenth of the lanes up to 19.2 panes
+    late (the partial branch: the stragglers behind each chunk's window are
+    compacted and scattered), values across the whole int32 range."""
+    import jax
+    import jax.numpy as jnp
+    from windflow_tpu.ops.histogram import (DEFAULT_CHUNK, DEFAULT_L,
+                                            FOLD_PARTIAL, keyed_pane_fold)
+    C, K, P = args.lanes, 512, 256
+    pane_len = max(256, C // 64)
+    lane = np.arange(C) + 40 * pane_len
+    late = rng.random(C) < 0.1
+    ts = lane - np.where(late, rng.integers(1, int(19.2 * pane_len), C), 0)
+    pane = (ts // pane_len).astype(np.int32)
+    key = (lane % K).astype(np.int32)
+    vals = rng.integers(-(1 << 31), 1 << 31, C, dtype=np.int64).astype(np.int32)
+    ok = rng.random(C) < 0.97
+    counts = np.zeros((K, P), np.int32)
+    np.add.at(counts, (key[ok], pane[ok] % P), 1)
+    sums = np.zeros((K, P), np.int64)
+    np.add.at(sums, (key[ok], pane[ok] % P), vals[ok].astype(np.int64))
+    rows = np.where(ok, pane, np.iinfo(np.int32).min).reshape(-1, DEFAULT_CHUNK)
+    behind = (ok.reshape(rows.shape)
+              & (rows <= rows.max(axis=1, keepdims=True) - DEFAULT_L))
+    dev = tuple(map(jnp.asarray, (key, pane, ok, vals)))
+    return (f"pane_fold[C={C},K={K},P={P},i32 full range,a tenth late]",
+            {"xla": lambda: jax.jit(
+                lambda k, p, v, x: keyed_pane_fold(k, p, v, x, K, P))(*dev)},
+            (counts, sums.astype(np.int32), np.int32(FOLD_PARTIAL),
+             np.int32(behind.sum())))
 
 
 def _cases_lookup(args, rng):
@@ -367,6 +400,7 @@ def _kernel_cases(args, rng):
     yield _case_segment_fold(args, rng)
     yield _case_histogram(args, rng)
     yield _case_pane_fold(args, rng)
+    yield _case_pane_fold_late(args, rng)
     yield from _cases_lookup(args, rng)
     yield _case_join_probe(args, rng)
     yield _case_ordering_merge(args, rng)

@@ -1,9 +1,11 @@
 """``ops/histogram.py::keyed_pane_fold``: the occupancy counts and an additive
 fold of integers in one chunk-local one-hot contraction, bit for bit
-``jax.ops.segment_sum`` whatever the batch holds, and the one call site,
-``Win_SeqFFAT._g_insert``: which lifts ride the contraction (the code sees it
-in the lift's result), which keep the scatter, and the counter of the batches
-that fell back."""
+``jax.ops.segment_sum`` whatever the batch holds and whichever of its three
+branches the batch takes (fast, partial, whole: a numpy account of the
+locality test says which, and how many lanes the partial branch scatters),
+and the one call site, ``Win_SeqFFAT._g_insert``: which lifts ride the
+contraction (the code sees it in the lift's result), which keep the scatter,
+and the counters of the batches that left the fast branch."""
 
 import dataclasses
 
@@ -16,7 +18,9 @@ from test_histogram_lookup import tpu_default_dot  # noqa: F401 - a fixture
 from windflow_tpu.basic import win_type_t
 from windflow_tpu.batch import Batch
 from windflow_tpu.observability.names import STAGE_COUNTERS
-from windflow_tpu.ops.histogram import (K_TILE, _place_group, keyed_pane_fold,
+from windflow_tpu.ops.histogram import (DEFAULT_CHUNK, DEFAULT_L, FOLD_FAST,
+                                        FOLD_PARTIAL, FOLD_WHOLE, K_TILE,
+                                        SPILL_M, _place_group, keyed_pane_fold,
                                         pane_fold_applies)
 from windflow_tpu.operators.win_patterns import Key_FFAT
 from windflow_tpu.operators.window import WindowSpec
@@ -88,7 +92,81 @@ def breaks_locality(rng):
                 pane=rng.integers(0, 1000, C).astype(np.int32),
                 valid=rng.random(C) < 0.5,
                 values=full_range(rng, np.int32, C), K=11, P=64,
-                in_bounds=False)
+                branch=FOLD_WHOLE)
+
+
+def branch_of(pane, valid, chunk=DEFAULT_CHUNK, L=DEFAULT_L, M=SPILL_M):
+    """numpy: (branch, spilled) of ``keyed_pane_fold`` on these lanes. Fast
+    where every valid lane lies fewer than ``L`` panes past its chunk's
+    oldest; else partial, where no chunk has more than ``M`` valid lanes
+    ``L`` or more panes behind its newest (those are scattered), else
+    whole."""
+    p = np.asarray(pane, np.int64).reshape(-1, chunk)
+    v = np.asarray(valid, bool).reshape(-1, chunk)
+    oldest = np.where(v, p, np.iinfo(np.int64).max).min(axis=1, keepdims=True)
+    if not (v & (p - oldest >= L)).any():
+        return FOLD_FAST, 0
+    newest = np.where(v, p, np.iinfo(np.int64).min).max(axis=1, keepdims=True)
+    behind = (v & (p <= newest - L)).sum(axis=1)
+    return ((FOLD_WHOLE, 0) if (behind > M).any()
+            else (FOLD_PARTIAL, int(behind.sum())))
+
+
+def late_stream(dtype=np.int32, late=0.1, max_delay=3 * DEFAULT_L, K=13,
+                P=64, C=16384, live=1.0, lanes_a_pane=256, values=None):
+    """A tenth of the lanes (``late``) arrive up to ``max_delay`` panes late;
+    the rest are in order, four panes a chunk, and the ring wraps."""
+    def make(rng):
+        lane = np.arange(C)
+        d = np.where(rng.random(C) < late,
+                     rng.integers(1, max_delay * lanes_a_pane, C), 0)
+        pane = ((lane + 40 * lanes_a_pane - d) // lanes_a_pane).astype(
+            np.int32)
+        return dict(key=rng.integers(0, K, C).astype(np.int32), pane=pane,
+                    valid=rng.random(C) < live,
+                    values=(values(rng, C) if values is not None
+                            else full_range(rng, dtype, C)),
+                    K=K, P=P, branch=FOLD_PARTIAL)
+    return make
+
+
+def near_the_ends(sign):
+    """Values within 1,000 of int32's top (or bottom): the sums wrap."""
+    def values(rng, C):
+        top = rng.integers((1 << 31) - 1000, 1 << 31, C)
+        return (top if sign > 0 else -top - 1).astype(np.int32)
+    return values
+
+
+def spilling(n):
+    """In order, but ``n`` lanes of chunk 3 (spread over it) lie ten panes
+    behind the chunk's newest: partial up to ``SPILL_M``, whole past it."""
+    def make(rng):
+        case = of_dtype(np.int32, K=9, P=64, C=8192)(rng)
+        lanes = 3 * DEFAULT_CHUNK + np.sort(
+            rng.choice(DEFAULT_CHUNK, n, replace=False))
+        case["pane"][lanes] = case["pane"][4 * DEFAULT_CHUNK - 1] - 10
+        case["valid"][lanes] = True
+        case["branch"] = FOLD_PARTIAL if n <= SPILL_M else FOLD_WHOLE
+        return case
+    return make
+
+
+def far_future_outlier(rng):
+    """One lane of chunk 2 lies 1,000 panes ahead: the chunk's window moves
+    to it and every other lane of the chunk falls behind: whole."""
+    case = of_dtype(np.int32, K=9, P=64, C=8192, live=1.0)(rng)
+    case["pane"][2 * DEFAULT_CHUNK + 17] += 1000
+    case["branch"] = FOLD_WHOLE
+    return case
+
+
+def behind_and_past_the_wrap(rng):
+    """Stragglers behind each chunk's window and in-order lanes whose panes
+    cross the ring's end (``pane % P`` wraps to 0) in the same chunks."""
+    case = late_stream(K=6, P=16, C=8192, max_delay=20)(rng)
+    case["pane"] = case["pane"] - 40 + 13                 # crosses 16, 32
+    return case
 
 
 CASES = {
@@ -108,6 +186,19 @@ CASES = {
     "ring_smaller_than_locality": of_dtype(np.int32, P=4),
     "two_leaves": two_leaves,
     "breaks_locality": breaks_locality,
+    "late_int32": late_stream(),
+    "late_int8": late_stream(np.int8),
+    "late_uint8": late_stream(np.uint8),
+    "late_int16": late_stream(np.int16),
+    "late_uint32": late_stream(np.uint32),
+    "late_dead_lanes": late_stream(live=0.6),
+    "late_near_int32_max": late_stream(values=near_the_ends(+1)),
+    "late_near_int32_min": late_stream(values=near_the_ends(-1)),
+    "late_keys_above_the_tile": late_stream(K=K_TILE + 188),
+    "spills_exactly_m": spilling(SPILL_M),
+    "spills_m_plus_1": spilling(SPILL_M + 1),
+    "far_future_outlier": far_future_outlier,
+    "behind_and_past_the_wrap": behind_and_past_the_wrap,
 }
 
 
@@ -118,9 +209,11 @@ def test_fold_equals_segment_sum_bit_for_bit(tpu_default_dot, name):  # noqa: F8
     key, pane, valid = (jnp.asarray(case[f]) for f in ("key", "pane", "valid"))
     values = jax.tree.map(jnp.asarray, case["values"])
     assert pane_fold_applies(values)
-    counts, folds, in_bounds = jax.jit(
+    counts, folds, branch, spilled = jax.jit(
         lambda *a: keyed_pane_fold(*a, K, P))(key, pane, valid, values)
-    assert bool(in_bounds) is case.get("in_bounds", True)
+    assert (int(branch), int(spilled)) == branch_of(case["pane"],
+                                                    case["valid"])
+    assert int(branch) == case.get("branch", FOLD_FAST)
     seg = jnp.where(valid, key * P + pane % P, K * P)
 
     def want(v):
@@ -187,6 +280,9 @@ def pane_sums(batch, leaf, P, pane_len=16):
 
 
 IN_ORDER = np.arange(C) // 16         # 256 lanes a pane, 4 panes a chunk
+#: what ``collect_stats`` publishes of the branches the value fold took
+FOLD_COUNTERS = ("ffat_fold_fallbacks", "ffat_fold_partials",
+                 "ffat_fold_spill_lanes")
 
 
 def test_an_integer_lift_rides_the_contraction_and_counts_its_fallbacks():
@@ -195,26 +291,40 @@ def test_an_integer_lift_rides_the_contraction_and_counts_its_fallbacks():
         lambda b: jax.tree.map(lambda x: x[0], b.payload), batch_of(IN_ORDER)))
     insert = jax.jit(op._g_insert)
     first = batch_of(IN_ORDER)
-    # every scatter of the program lies in the cond's fallback branch
+    # every scatter of the program lies in the outer cond's false side,
+    # the fallbacks'
     found = list(scatters(jax.make_jaxpr(op._g_insert)(state, first).jaxpr))
     assert found and all(branch == 0 for _, branch in found), found
     state = insert(state, first)
     np.testing.assert_array_equal(state.panes, pane_sums(first, "i", op.P))
     assert int(state.cnt.sum()) == C
     op.collect_stats(state)
-    assert op.stage_counters()["ffat_fold_fallbacks"] == 0
+    assert {k: op.stage_counters()[k] for k in FOLD_COUNTERS} == dict.fromkeys(
+        FOLD_COUNTERS, 0)
     assert op.stage_counters()["ffat_ring_overruns"] == 0
-    assert "ffat_fold_fallbacks" in STAGE_COUNTERS
-    # ticks shuffled over 16 panes: the locality test fails, the scatters
-    # run, the answer is the same and the batch is counted
+    assert set(FOLD_COUNTERS) <= set(STAGE_COUNTERS)
+    # a few stragglers 9 to 12 panes back: the locality test fails, the
+    # lanes near each chunk's newest pane stay in the contraction and the
+    # stragglers alone are scattered; the answer is the same
+    late = IN_ORDER + 16 * 16
+    behind = np.random.default_rng(5).choice(C, 40, replace=False)
+    late[behind] -= 16 * np.random.default_rng(6).integers(9, 13, 40)
+    stragglers = batch_of(late, seed=2)
+    state = insert(state, stragglers)
+    # ticks shuffled over 16 panes: a chunk spills past what the partial
+    # branch scatters, the whole batch takes the scatters
     shuffled = batch_of(np.random.default_rng(7).permutation(IN_ORDER), seed=1)
     state = insert(state, shuffled)
     np.testing.assert_array_equal(
         state.panes,
-        pane_sums(first, "i", op.P) + pane_sums(shuffled, "i", op.P))
-    assert int(state.cnt.sum()) == 2 * C
+        pane_sums(first, "i", op.P) + pane_sums(stragglers, "i", op.P)
+        + pane_sums(shuffled, "i", op.P))
+    assert int(state.cnt.sum()) == 3 * C
     op.collect_stats(state)
-    assert op.stage_counters()["ffat_fold_fallbacks"] == 1
+    assert {k: op.stage_counters()[k] for k in FOLD_COUNTERS} == {
+        "ffat_fold_fallbacks": 1, "ffat_fold_partials": 1,
+        "ffat_fold_spill_lanes": 40}
+    assert branch_of(late // 16, np.ones(C, bool)) == (FOLD_PARTIAL, 40)
 
 
 @pytest.mark.parametrize("name,lift,combine,identity,capacity", [
@@ -234,10 +344,10 @@ def test_other_lifts_and_combines_keep_the_scatter_path(name, lift, combine,
     # the value fold's scatter stands outside any cond, as before
     assert any(branch is None for _, branch in found), found
     state = jax.jit(op._g_insert)(state, batch)
-    assert int(state.fold_fallbacks) == 0
+    assert int(state.fold_fallbacks) == int(state.fold_partials) == 0
     op.collect_stats(state)
     counters = op.stage_counters()
-    assert "ffat_fold_fallbacks" not in counters      # absent, not 0
+    assert not set(FOLD_COUNTERS) & set(counters)     # absent, not 0
     assert counters["ffat_ring_overruns"] == 0
     if name == "odd_capacity":
         np.testing.assert_array_equal(state.panes,
@@ -245,8 +355,8 @@ def test_other_lifts_and_combines_keep_the_scatter_path(name, lift, combine,
 
 
 def test_a_count_lift_passes_the_new_leaf_through():
-    """``ysb``'s branch: both counters leave ``_g_insert`` as the variables
-    that went in (no operation), and neither fallbacks are published."""
+    """``ysb``'s branch: the counters leave ``_g_insert`` as the variables
+    that went in (no operation), and none of the fold's are published."""
     op = engine(lambda t: 1)
     batch = batch_of(IN_ORDER)
     state = op.init_state(jax.eval_shape(
@@ -257,9 +367,10 @@ def test_a_count_lift_passes_the_new_leaf_through():
     assert len(fields) == len(jax.tree.leaves(state))     # a leaf a field
     came_in = dict(zip(fields, jaxpr.invars))
     went_out = dict(zip(fields, jaxpr.outvars))
-    for leaf in ("fold_fallbacks", "ring_overruns"):
+    for leaf in ("fold_fallbacks", "fold_partials", "fold_spill_lanes",
+                 "ring_overruns"):
         assert went_out[leaf] is came_in[leaf], leaf
     assert went_out["cnt"] is not came_in["cnt"]
     op.collect_stats(jax.jit(op._g_insert)(state, batch))
     assert op.count_lift is True
-    assert "ffat_fold_fallbacks" not in op.stage_counters()
+    assert not set(FOLD_COUNTERS) & set(op.stage_counters())

@@ -387,8 +387,14 @@ def step_operations(name, batch_capacity=8192):
     mod, cfg = load_config(name)
     _, step, args = chain_step(cfg, mod, batch_capacity)
     (call,) = jax.make_jaxpr(step)(*args).jaxpr.eqns
+    return operations(equations(call.params["jaxpr"].jaxpr))
+
+
+def operations(eqns):
+    """(count, sha256) of the lines ``step_operations`` makes of these
+    (equation, path) pairs."""
     lines = []
-    for eqn, _ in equations(call.params["jaxpr"].jaxpr):
+    for eqn, _ in eqns:
         params = sorted(
             (k, re.sub(r"0x[0-9a-f]+", "0x", str(v)))
             for k, v in eqn.params.items()
@@ -433,9 +439,14 @@ def test_the_older_cells_step_programs_are_the_parents(name):
 #: (992955a) they read (453, "cf2391ad4be611da..."), (749,
 #: "30da5f53c44d9b80...") and (1279, "cb2f241e337299e9..."). ``ysb`` (in
 #: ``PARENT_STEPS``) and ``kff`` (PR 35's pair) list no rows and did not move.
+#: ``kff``'s program changed on purpose again when the fold's fallback grew
+#: its partial branch (the lanes near each chunk's newest pane in the
+#: contraction, the stragglers compacted and scattered, two counters more):
+#: at commit 87463aa the step read (371, "e00cd3c7b7dbb22f..."). The branch
+#: ``kff``'s in-order stream takes did not move (``TAKEN_FOLD_BRANCH``).
 CHANGED_STEPS = {
-    "kff": (371, "e00cd3c7b7dbb22f7883d1be3444b39a"
-                 "f70365d3e1a250be5cc2ad7b0997aa72"),
+    "kff": (555, "fb889972b69d53450dfcc71606256c7a"
+                 "9af864a3a2dbb6152c0a5a303439118a"),
     "kcb": (445, "03555a722655e4d39e8e56654b7eb342"
                  "37af879e5058edbe40bcbbc4407047bd"),
     "ysb_wmr": (729, "9bea6c039a3f24015c46cd932f5776c9"
@@ -448,6 +459,32 @@ CHANGED_STEPS = {
 @pytest.mark.parametrize("name", sorted(CHANGED_STEPS))
 def test_the_step_programs_changed_on_purpose_are_as_recorded(name):
     assert step_operations(name) == CHANGED_STEPS[name]
+
+
+#: ``step_operations``' lines of the branch of the window's fold that an
+#: in-order stream takes (the fast one: the outer ``cond``'s second branch
+#: in ``insert/fold``), at commit 87463aa, where ``kff``'s step held no
+#: other ``cond``
+TAKEN_FOLD_BRANCH = {
+    "kff": (84, "de6e080554ea1c022688a85acd3da342"
+                "d523cd1fd7783aa49f2ff186625263b9"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(TAKEN_FOLD_BRANCH))
+def test_the_branch_an_in_order_stream_takes_did_not_move(name):
+    jax.clear_caches()
+    mod, cfg = load_config(name)
+    ops, step, args = chain_step(cfg, mod, 8192)     # step_operations' size
+    (call,) = jax.make_jaxpr(step)(*args).jaxpr.eqns
+    conds = [(eqn, path) for eqn, path in equations(call.params["jaxpr"].jaxpr)
+             if eqn.primitive.name == "cond"]
+    (outer, path), (inner, _) = conds
+    assert path == f"{ops[-1].scope_name()}/insert/fold"
+    # the partial branch's cond lies inside the outer one's first branch
+    assert inner in outer.params["branches"][0].jaxpr.eqns
+    fast = outer.params["branches"][1].jaxpr
+    assert operations(equations(fast)) == TAKEN_FOLD_BRANCH[name]
 
 
 @pytest.mark.parametrize("name", ["kcb", "kpf"])
@@ -488,17 +525,21 @@ def test_no_loop_is_left_over_the_rows_a_step_lists(name):
 
 
 def test_kffs_step_scatters_nowhere_but_in_the_fallback_branch():
-    """At rehearsal size: the step's only per-lane scatters are the two of
-    ``keyed_pane_fold``'s fallback (counts and values), inside the one
-    ``cond`` of the window's insert; the branch an in-order stream takes has
-    the two dots and no scatter, and the served path never left it."""
+    """At rehearsal size: the step's only scatters are those of
+    ``keyed_pane_fold``'s fallbacks (the whole batch's two, counts and
+    values, and the partial branch's one of its compacted stragglers), all
+    inside the first branch of the window insert's first ``cond``; the branch
+    an in-order stream takes has the two dots and no scatter, and the served
+    path never left it."""
     mod, cfg = load_config("kff")
     _, step, args = chain_step(cfg, mod, BATCH)
     jaxpr = jax.make_jaxpr(step)(*args).jaxpr
-    assert sum(e.primitive.name == "cond" for e, _ in equations(jaxpr)) == 1
-    # branch 0 is the cond's false side: the locality test failed
-    assert list(scatters(jaxpr)) == [("scatter-add", 0), ("scatter-add", 0)]
+    assert sum(e.primitive.name == "cond" for e, _ in equations(jaxpr)) == 2
+    # branch 0 of the outer cond, its false side: the locality test failed
+    assert list(scatters(jaxpr)) == [("scatter-add", 0)] * 3
     ops, _ = run_config("kff", make_pool(41, n_batches=3))
     counters = ops[-1].stage_counters()
     assert counters["ffat_fold_fallbacks"] == 0
+    assert counters["ffat_fold_partials"] == 0
+    assert counters["ffat_fold_spill_lanes"] == 0
     assert counters["ffat_ring_overruns"] == 0

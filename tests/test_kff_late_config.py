@@ -10,6 +10,7 @@ import dataclasses
 import hashlib
 import importlib.util
 import json
+import math
 import os
 import re
 import subprocess
@@ -27,6 +28,9 @@ from windflow_tpu.observability import names
 from windflow_tpu.observability.names import STAGE_COUNTERS
 from windflow_tpu.ops.histogram import keyed_pane_fold
 from windflow_tpu.operators.win_patterns import Key_FFAT
+
+from test_keyed_pane_fold import branch_of
+from windflow_tpu.ops.histogram import FOLD_PARTIAL
 
 import judge  # noqa: E402 - test_ysb_wmr_config put benchmark/ on the path
 
@@ -118,6 +122,21 @@ def test_the_reference_at_the_rehearsal_size_is_the_triggerers():
     assert dropped == 0 and late > 100
 
 
+def spill_lanes(cfg, pool):
+    """numpy: the lanes ``keyed_pane_fold``'s partial branch scatters over
+    the served batches (``test_keyed_pane_fold.branch_of``), each batch's
+    ticks stamped as ``triggerer_tb`` stamps them; every batch must take
+    that branch."""
+    pane_len = math.gcd(cfg["win_len"], cfg["slide"])
+    total = 0
+    for j in range(N_BATCHES):
+        ts = np.maximum(j * BATCH + pool[j % len(pool)].offset, 0)
+        branch, n = branch_of(ts // pane_len, np.ones(BATCH, bool))
+        assert branch == FOLD_PARTIAL, (j, branch)
+        total += n
+    return total
+
+
 def serve(pool):
     mod, cfg = load_config("kff_late")
     ops, got = run_config("kff_late", pool)
@@ -138,9 +157,12 @@ def test_served_path_equals_the_reference_and_counts_its_late_lanes(seed):
         assert w > last.get(k, -1)
         last[k] = w
     counters = ops[-1].stage_counters()
-    # every batch with a fired window behind it carries a straggler further
-    # back than the one-hot holds: each took the scatters
-    assert counters["ffat_fold_fallbacks"] == N_BATCHES
+    # every batch carries a straggler further back than the one-hot holds
+    # from its chunk's oldest lane: each took the partial branch, which
+    # scattered the lanes behind each chunk's newest window alone
+    assert counters["ffat_fold_fallbacks"] == 0
+    assert counters["ffat_fold_partials"] == N_BATCHES
+    assert counters["ffat_fold_spill_lanes"] == spill_lanes(cfg, pool) > 0
     assert counters["ffat_late_lanes"] == want["late_lanes"] > 0
     checks = mod.program_checks(cfg, ops)
     assert set(checks) == {
@@ -307,51 +329,94 @@ def jaxpr_operations(jaxpr):
     return len(lines), hashlib.sha256("\n".join(lines).encode()).hexdigest()
 
 
-#: ``keyed_pane_fold`` at C = 8,192, K = 8, P = 256 at PR 37's commit
-#: (b38da88): the whole program, the fallback branch (0), the fast one (1)
+#: ``keyed_pane_fold`` at C = 8,192, K = 8, P = 256 at commit b38da88: the
+#: fallback branch (the whole batch's two scatters) and the fast one. The
+#: partial branch changed the program on purpose around them: there the whole
+#: program read (158, "75e125695bd7babc...")
 PARENT_FOLD = {
-    "whole": (158, "75e125695bd7babc270b66f63f137d4c"
-                   "94ed50409d8d199f33bc0ea2dea6d049"),
     "scatter": (53, "8bae431394fb553754e85c515b668dfd"
                     "ddecfabe4dda694b6fbb23f000a1cbac"),
     "fast": (84, "46f2b8ef40059876777ea2e167af7ded"
                  "148785a6bc219b755bc2c3bd05784858"),
 }
+#: the same program with the partial branch: the whole of it, and the partial
+#: branch (the contraction at each chunk's newest window, the compaction of
+#: the stragglers and their scatter)
+CHANGED_FOLD = {
+    "whole": (338, "f22ac6f677a322ff204422b3146a78f8"
+                   "c506b47304c67c34fc705646ac2923bd"),
+    "partial": (152, "748153c92391649ed1586c9a61787dde"
+                     "443fd524d753c11b37a606a7597ee76a"),
+}
 
 
-def test_the_fallback_carries_its_scope_and_no_operation_moved():
-    """The scatter branch of ``keyed_pane_fold`` runs under ``scatter``, the
-    fast branch under no such scope, and both are the parent's equation for
-    equation (``kff``'s and ``ysb``'s whole step programs:
-    ``test_kff_config.py``, ``CHANGED_STEPS`` and ``PARENT_STEPS``)."""
-    C = 8192
+def fold_program(C=8192):
+    """(jitted fold, its shapes, the whole jaxpr, and its branches by name):
+    the outer ``cond``'s branches are the fallbacks (0) and the fast one (1);
+    inside the former a second ``cond`` holds the whole-batch scatters (0)
+    and the partial branch (1)."""
     args = ((jax.ShapeDtypeStruct((C,), jnp.int32),) * 2
             + (jax.ShapeDtypeStruct((C,), bool),
                jax.ShapeDtypeStruct((C,), jnp.int32)))
     fold = jax.jit(lambda k, p, v, x: keyed_pane_fold(k, p, v, x, 8, 256))
     jaxpr = jax.make_jaxpr(fold)(*args).jaxpr.eqns[0].params["jaxpr"].jaxpr
     (cond,) = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
-    scatter, fast = (b.jaxpr for b in cond.params["branches"])
+    slow, fast = (b.jaxpr for b in cond.params["branches"])
+    (inner,) = [e for e in slow.eqns if e.primitive.name == "cond"]
+    scatter, partial = (b.jaxpr for b in inner.params["branches"])
+    return fold, args, jaxpr, {"fast": fast, "scatter": scatter,
+                               "partial": partial}
+
+
+def test_the_fast_and_scatter_branches_are_the_parents():
+    """The fast branch and the whole batch's scatters are the parent's
+    equation for equation; the whole program and the partial branch are as
+    recorded (``kff``'s and ``ysb``'s whole step programs:
+    ``test_kff_config.py``, ``CHANGED_STEPS`` and ``PARENT_STEPS``)."""
+    _, _, jaxpr, branches = fold_program()
+    assert {name: jaxpr_operations(branches[name])
+            for name in PARENT_FOLD} == PARENT_FOLD
     assert {"whole": jaxpr_operations(jaxpr),
-            "scatter": jaxpr_operations(scatter),
-            "fast": jaxpr_operations(fast)} == PARENT_FOLD
-    in_scatter = [path for _, path in equations(scatter)]
+            "partial": jaxpr_operations(branches["partial"])} == CHANGED_FOLD
+
+
+def test_the_fallbacks_carry_their_scopes():
+    """The whole batch's scatters run under ``scatter``; in the partial
+    branch the compaction runs under ``spill`` and its scatter under
+    ``scatter``; the fast branch opens neither."""
+    fold, args, _, branches = fold_program()
+    in_scatter = [path for _, path in equations(branches["scatter"])]
     assert in_scatter and all(p.split("/")[0] == "scatter"
                               for p in in_scatter)
-    assert not any("scatter" in p.split("/") for _, p in equations(fast))
+    partial = {p.split("/")[0] for _, p in equations(branches["partial"])}
+    assert {"spill", "scatter"} <= partial
+    scatters_in_partial = [p for e, p in equations(branches["partial"])
+                           if e.primitive.name.startswith("scatter")]
+    assert scatters_in_partial and all(p.split("/")[0] == "scatter"
+                                       for p in scatters_in_partial)
+    assert not any({"scatter", "spill"} & set(p.split("/"))
+                   for _, p in equations(branches["fast"]))
     hlo = fold.lower(*args).as_text(debug_info=True)
-    assert re.search(r'cond/branch_0_fun/scatter/scatter-add"', hlo)
-    assert not re.search(r'branch_1_fun/scatter/', hlo)
+    assert re.search(r'cond/branch_0_fun/cond/branch_0_fun/scatter/'
+                     r'scatter-add"', hlo)
+    assert re.search(r'cond/branch_0_fun/cond/branch_1_fun/scatter/'
+                     r'scatter-add"', hlo)
+    assert re.search(r'cond/branch_0_fun/cond/branch_1_fun/spill/', hlo)
+    # the fast branch, the outer cond's second, opens neither scope
+    assert not re.search(r'[^/]+/cond/branch_1_fun/(scatter|spill)/',
+                         hlo.replace("cond/branch_0_fun/cond/", ""))
 
 
-def test_the_cells_step_carries_the_scope_under_insert_fold():
+def test_the_cells_step_carries_the_scopes_under_insert_fold():
     mod, cfg = load_config("kff_late")
     ops, step, args = chain_step(cfg, mod, BATCH)
     hlo = step.lower(*args).as_text(debug_info=True)
     window = ops[-1].scope_name()
     assert window == "Key_FFAT:kff_late_window"
-    assert re.search(
-        rf'/{window}/insert/fold/cond/branch_0_fun/scatter/scatter-add"', hlo)
+    fold = rf'/{window}/insert/fold/cond/branch_0_fun/cond/'
+    assert re.search(fold + r'branch_0_fun/scatter/scatter-add"', hlo)
+    assert re.search(fold + r'branch_1_fun/scatter/scatter-add"', hlo)
+    assert re.search(fold + r'branch_1_fun/spill/', hlo)
 
 
 # ---- the two readers -----------------------------------------------------
@@ -389,6 +454,22 @@ def test_the_scatter_reader_reads_its_scope_and_nothing_without_it():
     assert read({"trace_path": os.path.join(testdata, "kff_timeline.xplane.pb"),
                  "slice_batches": 16}) is None          # in order: never taken
     assert read({"trace_path": None, "slice_batches": 0}) is None
+
+
+def test_the_readers_take_the_partial_branchs_scatter_and_spill():
+    """The partial branch's scatter lies under ``scatter`` one ``cond``
+    deeper: the scatter reader takes it, and not the compaction under
+    ``spill``; the fold's reader takes both."""
+    scatter = reader("ffat_fold_scatter_device_ms")
+    fold = reader("ffat_fold_device_ms")
+    inner = f"{WINDOW}/insert/fold/cond/branch_0_fun/cond/branch_1_fun"
+    ops = [{"scope": f"{inner}/scatter/scatter-add", "ns": 8e6},
+           {"scope": f"{inner}/spill/reduce_sum", "ns": 4e6},
+           {"scope": f"{inner}/dot_general", "ns": 12e6}]
+    run = {"trace_path": "no file is read", "slice_batches": 4,
+           "span_reduce": {"device_ops": ops}}
+    assert scatter.read(run) == 2.0
+    assert fold.read(run) == 6.0
 
 
 def test_the_roofline_reader_counts_the_folds_least_bytes(tmp_path):

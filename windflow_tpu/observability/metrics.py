@@ -1138,8 +1138,15 @@ class MetricsRegistry:
             "ffat_ring_overruns": "tuples folded into a pane-ring slot whose "
                                   "pane had not fired yet",
             "ffat_fold_fallbacks": "batches whose pane value fold took the "
-                                   "scatter branch (ticks out of order "
-                                   "inside a chunk)",
+                                   "whole batch's scatters (a chunk held "
+                                   "more stragglers than the partial "
+                                   "branch scatters)",
+            "ffat_fold_partials": "batches whose pane value fold took the "
+                                  "partial branch (ticks out of order "
+                                  "inside a chunk: its stragglers were "
+                                  "scattered)",
+            "ffat_fold_spill_lanes": "lanes the partial branch of the pane "
+                                     "value fold scattered",
             "ffat_late_lanes": "tuples folded after a window holding them "
                                "had fired (counted in the open windows "
                                "alone)",

@@ -216,10 +216,13 @@ STAGE_COUNTERS = (
     # whose pane had not fired (their pane lay ffat_pane_slots or more past
     # the first unfired one); it publishes windows_undelivered_at_eos too;
     # batches whose integer value fold left the histogram's one-hot
-    # contraction for the exact scatters (a chunk spanned too many panes);
-    # lanes folded after a window holding them had fired (delay > 0 only:
-    # they count in the windows still open)
-    "ffat_ring_overruns", "ffat_fold_fallbacks", "ffat_late_lanes",
+    # contraction for the whole batch's exact scatters (a chunk held more
+    # stragglers than the partial branch scatters); batches that took the
+    # partial branch (a chunk spanned too many panes: its stragglers were
+    # scattered), and those stragglers; lanes folded after a window holding
+    # them had fired (delay > 0 only: they count in the windows still open)
+    "ffat_ring_overruns", "ffat_fold_fallbacks", "ffat_fold_partials",
+    "ffat_fold_spill_lanes", "ffat_late_lanes",
     # operators/win_patterns.py::Pane_Farm: its two engines' counters, each
     # under its stage's prefix (``plq_old_drops``, ``wlq_archive_overwrites``)
     *(f"{stage}_{counter}" for stage in PANE_STAGES

@@ -76,10 +76,15 @@ class GFFATState:
     #: pane lay P or more past the first unfired one (counted where the fold
     #: goes by slot; the count-lift branch passes it through)
     ring_overruns: jax.Array
-    #: i32[] batches whose integer value fold took the scatter branch (a chunk
-    #: of the batch spanned more panes than the one-hot holds: ticks far out of
-    #: order); only the fold that rides the histogram's contraction counts
+    #: i32[] batches whose integer value fold took the whole-batch scatters
+    #: (a chunk of the batch held more stragglers than the partial branch
+    #: scatters); only the fold that rides the histogram's contraction counts
     fold_fallbacks: jax.Array
+    #: i32[] batches whose integer value fold took the partial branch (a
+    #: chunk spanned more panes than the one-hot holds: ticks out of order),
+    #: and i32[] the lanes that branch scattered
+    fold_partials: jax.Array
+    fold_spill_lanes: jax.Array
     #: i32[NB] observed-lateness histogram (event-time monitoring only)
     lat_hist: Any = None
     #: i32[] lanes folded after a window that holds them had fired: their pane
@@ -111,9 +116,13 @@ class Win_SeqFFAT(Basic_Operator):
     whose pane lay ``P`` or more past the first unfired pane: they were folded
     into a slot that an unfired pane still held; the count-lift branch folds no
     value by slot and publishes none), where an additive integer lift
-    rides the occupancy histogram's contraction, ``ffat_fold_fallbacks``
-    (batches whose ticks were too far out of order for it: they took the
-    exact scatters; 0 for an in-order stream) and, where the spec allows
+    rides the occupancy histogram's contraction, ``ffat_fold_partials``
+    (batches whose ticks were out of order: the contraction held the lanes
+    near each chunk's newest pane and the stragglers were scattered),
+    ``ffat_fold_spill_lanes`` (those stragglers) and ``ffat_fold_fallbacks``
+    (batches with a chunk of more stragglers than that: they took the whole
+    batch's exact scatters; all three 0 for an in-order stream) and, where
+    the spec allows
     lateness (``delay > 0``), ``ffat_late_lanes`` (lanes folded after a
     window that holds them had fired: they count in the windows still open);
     at ``flush`` ``windows_undelivered_at_eos``.
@@ -250,6 +259,8 @@ class Win_SeqFFAT(Basic_Operator):
                 dropped_old=jnp.zeros((), CTRL_DTYPE),
                 ring_overruns=jnp.zeros((), CTRL_DTYPE),
                 fold_fallbacks=jnp.zeros((), CTRL_DTYPE),
+                fold_partials=jnp.zeros((), CTRL_DTYPE),
+                fold_spill_lanes=jnp.zeros((), CTRL_DTYPE),
                 lat_hist=lat,
                 late_lanes=(jnp.zeros((), CTRL_DTYPE) if self.spec.delay > 0
                             else None),
@@ -282,9 +293,14 @@ class Win_SeqFFAT(Basic_Operator):
         (lift(t) == 1, the YSB/windowed-count case) IS the count histogram,
         and an additive lift whose leaves are ``[C]`` integers of at most 4
         bytes rides the counts' contraction as 8-bit limbs
-        (``keyed_pane_fold``: one ``cond`` for counts and values, bit for bit
-        ``segment_sum``; ``fold_fallbacks`` -> ``ffat_fold_fallbacks`` counts
-        the batches whose locality test sent it to the scatters). Floats,
+        (``keyed_pane_fold``: bit for bit ``segment_sum``; a batch whose
+        chunks are not all local in their panes folds the lanes near each
+        chunk's newest pane in the contraction and scatters the stragglers
+        alone, and only a chunk with more stragglers than that branch takes
+        sends the whole batch to the scatters: ``fold_partials`` ->
+        ``ffat_fold_partials``, ``fold_spill_lanes`` ->
+        ``ffat_fold_spill_lanes`` and ``fold_fallbacks`` ->
+        ``ffat_fold_fallbacks`` count them). Floats,
         leaves of higher rank, odd capacities and every other combine take the
         segment-fold path (``ops/segment.py::segment_reduce``) beside the
         count histogram. ``"histogram"`` and ``"segment_fold"`` are
@@ -306,8 +322,8 @@ class Win_SeqFFAT(Basic_Operator):
         and, where the values ride it, the contraction that also counts; the
         add into the ring), ``hist`` (the occupancy histogram where it runs
         alone; the add into ``cnt``)."""
-        from ..ops.histogram import (keyed_pane_fold, keyed_pane_histogram,
-                                     pane_fold_applies)
+        from ..ops.histogram import (FOLD_PARTIAL, FOLD_WHOLE, keyed_pane_fold,
+                                     keyed_pane_histogram, pane_fold_applies)
         K, P = self.num_keys, self.P
         pane = batch.ts // self.pane_len
         horizon = state.next_win * self.spanes       # first un-fired pane (global)
@@ -338,6 +354,8 @@ class Win_SeqFFAT(Basic_Operator):
                 cnt = state.cnt + cnt_upd
         ring_overruns = state.ring_overruns
         fold_fallbacks = state.fold_fallbacks
+        fold_partials = state.fold_partials
+        fold_spill_lanes = state.fold_spill_lanes
         with jax.named_scope("fold"):
             if self._hist_is_fold():
                 # lift == 1: the value histogram IS the count histogram
@@ -353,10 +371,13 @@ class Win_SeqFFAT(Basic_Operator):
                     (valid & (pane >= horizon + P)).astype(CTRL_DTYPE))
                 lifted = jax.vmap(self.lift)(tuples)
                 if rides:
-                    cnt_upd, upd, in_bounds = keyed_pane_fold(
+                    cnt_upd, upd, branch, spilled = keyed_pane_fold(
                         batch.key, pane, valid, lifted, K, P)
                     fold_fallbacks = fold_fallbacks + (
-                        ~in_bounds).astype(CTRL_DTYPE)
+                        branch == FOLD_WHOLE).astype(CTRL_DTYPE)
+                    fold_partials = fold_partials + (
+                        branch == FOLD_PARTIAL).astype(CTRL_DTYPE)
+                    fold_spill_lanes = fold_spill_lanes + spilled
                     panes = jax.tree.map(jnp.add, state.panes, upd)
                 elif self.combine is jnp.add:
                     upd = segment_reduce(lifted, seg, valid, K * P)
@@ -391,6 +412,8 @@ class Win_SeqFFAT(Basic_Operator):
             dropped_old=state.dropped_old + n_dropped,
             ring_overruns=ring_overruns,
             fold_fallbacks=fold_fallbacks,
+            fold_partials=fold_partials,
+            fold_spill_lanes=fold_spill_lanes,
             lat_hist=lat,
             late_lanes=late_lanes,
         )
@@ -725,7 +748,8 @@ class Win_SeqFFAT(Basic_Operator):
         path): ``old_drops``; on the global-time path ``ffat_ring_overruns``
         where the fold counts them (a lift that reads the tuple: the count-lift
         branch folds no value by slot and publishes none),
-        ``ffat_fold_fallbacks`` where the value fold rides the histogram's
+        ``ffat_fold_fallbacks``, ``ffat_fold_partials`` and
+        ``ffat_fold_spill_lanes`` where the value fold rides the histogram's
         contraction and ``ffat_late_lanes`` where the spec allows lateness;
         for time-based specs the fired-window budget once it is settled."""
         if state is None or not hasattr(state, "dropped_old"):
@@ -742,8 +766,9 @@ class Win_SeqFFAT(Basic_Operator):
             counters["ffat_ring_overruns"] = int(
                 np.asarray(state.ring_overruns))
         if self.global_time and self._fold_rides:
-            counters["ffat_fold_fallbacks"] = int(
-                np.asarray(state.fold_fallbacks))
+            for name in ("fold_fallbacks", "fold_partials",
+                         "fold_spill_lanes"):
+                counters["ffat_" + name] = int(np.asarray(getattr(state, name)))
         if getattr(state, "late_lanes", None) is not None:
             counters["ffat_late_lanes"] = int(np.asarray(state.late_lanes))
         self._publish_stage_counters(counters)

@@ -34,7 +34,12 @@ A limb is an integer of magnitude ≤ 255, exact in bf16, and a chunk's limb sum
 stays under 2^24, exact in f32. The ring placement adds the chunk partials in
 groups small enough for f32 (:func:`_place_group`), the groups add in wrapping
 int32, and the limbs recombine with wrapping shifts: bit for bit
-``jax.ops.segment_sum`` on the integers, overflow included.
+``jax.ops.segment_sum`` on the integers, overflow included. Its fallback is
+partial: a batch whose chunks are not local at their oldest pane folds the
+lanes near each chunk's newest pane in the same contraction and scatters the
+stragglers alone; only a chunk with more stragglers than an eighth of it
+sends the whole batch to the scatters (:func:`keyed_pane_histogram`'s count
+fallback stays all or nothing).
 """
 
 from __future__ import annotations
@@ -45,11 +50,17 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 #: default lanes per chunk-local histogram row
 DEFAULT_CHUNK = 1024
 #: default pane-locality bound per chunk (panes spanned by one chunk)
 DEFAULT_L = 8
+#: lanes of one chunk that :func:`keyed_pane_fold`'s partial branch may
+#: scatter (those its one-hot window does not hold): an eighth of a chunk
+SPILL_M = DEFAULT_CHUNK // 8
+#: :func:`keyed_pane_fold`'s branches, as its ``branch`` output numbers them
+FOLD_FAST, FOLD_PARTIAL, FOLD_WHOLE = 0, 1, 2
 #: key-axis tile for the chunk-local one-hot (caps transient memory at ~C*K_TILE B)
 K_TILE = 512
 
@@ -69,6 +80,43 @@ def _chunk_locality(pane, valid, R, chunk, locality):
 
     in_bounds = jnp.all(ok_local == valid_r)
     return base, local, ok_local, in_bounds
+
+
+def _chunk_window(pane, valid, R, chunk, locality):
+    """Per chunk: the window of ``locality`` panes that ends at the chunk's
+    newest valid pane, ``base`` [R] its first, every lane's distance from it
+    ``local`` [R, chunk], and the valid lanes inside it ``fits``. Stragglers
+    fall behind such a window and in-order lanes stay in it; the distance
+    wraps in int32 as ``local`` does, and lies in the window exactly when
+    the lane's pane does."""
+    pane_r = pane.reshape(R, chunk)
+    valid_r = valid.reshape(R, chunk)
+    small = jnp.iinfo(pane.dtype).min
+    newest = jnp.max(jnp.where(valid_r, pane_r, small), axis=1)  # [R]
+    base = jnp.where(newest == small, 0, newest - (locality - 1))
+    local = pane_r - base[:, None]
+    fits = valid_r & (local >= 0) & (local < locality)
+    return base, local, fits
+
+
+def _compact(spill, cols, M):
+    """Each row's first ``M`` lanes that ``spill`` marks, in lane order, of
+    every ``[R, chunk]`` column: ``[R, M]`` each, 0 in the slots past a
+    row's count. A lane's slot is the count of marked lanes before it in its
+    row; a column's slots are one select-reduce over the ``[R, M, chunk]``
+    one-hot of those places (no gather, no scatter).
+
+    The count is one dot against a triangular 0/1 matrix, not ``cumsum``:
+    bf16 holds the operands and f32 the sums (at most ``chunk``), so it is
+    exact, and on a v5e it costs a tenth of the reduce-window ``cumsum``
+    lowers to (``PERF.md`` §6 has the prices of both)."""
+    lane = jnp.arange(spill.shape[1], dtype=jnp.int32)
+    upto = (lane[:, None] <= lane).astype(jnp.bfloat16)          # c <= c'
+    pos = jnp.dot(spill.astype(jnp.bfloat16), upto,
+                  preferred_element_type=jnp.float32).astype(jnp.int32) - 1
+    sel = spill[:, None, :] & (
+        pos[:, None, :] == jnp.arange(M, dtype=pos.dtype)[:, None])
+    return [jnp.sum(jnp.where(sel, c[:, None, :], 0), axis=2) for c in cols]
 
 
 def _chunk_contract(key, local, ok_local, K, R, chunk, locality, weights=None):
@@ -212,6 +260,14 @@ def pane_fold_applies(values: Any, *, chunk: int = DEFAULT_CHUNK) -> bool:
         and jnp.dtype(v.dtype).itemsize <= 4 for v in leaves)
 
 
+def _as_int32(v):
+    """An integer leaf as int32, sign- or zero-extended (uint32 bit for
+    bit): wrapping int32 sums of these, cut to the leaf's width
+    (:func:`_from_limbs`), are the leaf's own sums."""
+    return (jax.lax.bitcast_convert_type(v, jnp.int32) if v.dtype == jnp.uint32
+            else v.astype(jnp.int32))
+
+
 def _limbs(v):
     """An integer leaf as 8-bit limbs of an int32 (its own width's bits, sign-
     or zero-extended), low byte first: ``sum(limb[i] << 8 * i)`` is the value
@@ -219,8 +275,7 @@ def _limbs(v):
     sign (an arithmetic shift: [-128, 127], or [0, 255] of a narrower unsigned
     leaf)."""
     n = jnp.dtype(v.dtype).itemsize
-    x = (jax.lax.bitcast_convert_type(v, jnp.int32) if v.dtype == jnp.uint32
-         else v.astype(jnp.int32))
+    x = _as_int32(v)
     return [(x >> (8 * i)) & 255 for i in range(n - 1)] + [x >> (8 * (n - 1))]
 
 
@@ -258,21 +313,33 @@ def keyed_pane_fold(key: jax.Array, pane: jax.Array, valid: jax.Array,
                     chunk: int = DEFAULT_CHUNK, locality: int = DEFAULT_L):
     """The occupancy histogram and the additive fold of integer ``values``
     into the same (key, ``pane % ring``) cells, in ONE chunk-local contraction:
-    ``(counts i32[K, P], folds: the values' pytree of [K, P], in_bounds)``.
+    ``(counts i32[K, P], folds: the values' pytree of [K, P], branch,
+    spilled)``.
 
     ``values``: a pytree that :func:`pane_fold_applies` accepts. ``folds``
     equals ``jax.ops.segment_sum`` of each masked leaf bit for bit (wrapping at
     the leaf's width), ``counts`` equals :func:`keyed_pane_histogram`, for any
-    input: a batch that breaks chunk locality takes the two scatters
-    (:func:`_scatter_hist`, ``ops/segment.py::segment_reduce``) inside the
-    same ``lax.cond``, and ``in_bounds`` (bool[]) says which branch ran.
-    The fallback runs under a scope of its own, ``scatter``. It is taken
-    whenever one chunk of ``chunk`` consecutive lanes holds a lane
-    ``locality`` or more panes past the chunk's oldest: in a stream with
-    late tuples, one straggler that far back in any chunk sends the whole
-    batch to the scatters.
+    input, whichever of three branches the batch takes (``branch``, i32[]):
 
-    The fast branch's right-hand operand holds ``1 + limbs`` column groups of
+    - :data:`FOLD_FAST`: every chunk of ``chunk`` consecutive lanes lies
+      within ``locality`` panes of its oldest valid lane, and the contraction
+      holds them all.
+    - :data:`FOLD_PARTIAL`: it does not, and the contraction takes the lanes
+      within ``locality`` panes of each chunk's newest valid lane instead
+      (:func:`_chunk_window`); the rest, at most :data:`SPILL_M` a chunk,
+      are compacted (:func:`_compact`, scope ``spill``) and scattered (scope
+      ``scatter``), and the two parts add in wrapping int32. In a stream with
+      late tuples the in-order lanes stay in the contraction and the
+      stragglers alone are scattered: ``spilled`` (i32[]) counts them.
+    - :data:`FOLD_WHOLE`: a chunk spills more than :data:`SPILL_M` lanes, and
+      the whole batch takes the two scatters (:func:`_scatter_hist`,
+      ``ops/segment.py::segment_reduce``) under ``scatter``.
+
+    The first test is one ``lax.cond`` whose taken side is the fast branch
+    alone; the partial and whole branches lie in its other side, behind a
+    second ``cond`` on the spill counts.
+
+    The contraction's right-hand operand holds ``1 + limbs`` column groups of
     ``locality`` columns (40 for one int32 leaf), under the 128 the MXU takes
     in one pass, so values cost what counts alone cost. XLA only: the
     ``"histogram"`` registry family's Pallas forms count and do not fold."""
@@ -289,7 +356,9 @@ def keyed_pane_fold(key: jax.Array, pane: jax.Array, valid: jax.Array,
     base, local, ok_local, in_bounds = _chunk_locality(
         pane, valid, R, chunk, L)
 
-    def fast(_):
+    def contract(base, local, ok_local):
+        """Counts and every leaf's fold of the lanes ``ok_local`` holds: the
+        chunk-local contraction and the ring placement."""
         limbs = [_limbs(v) for v in leaves]
         # column group 0 counts (weight 1), then every leaf's limbs; a dead
         # lane's weights meet an all-zero one-hot row
@@ -314,6 +383,10 @@ def keyed_pane_fold(key: jax.Array, pane: jax.Array, valid: jax.Array,
             j += len(ws)
         return sums[:, 0], folds
 
+    def fast(_):
+        counts, folds = contract(base, local, ok_local)
+        return counts, folds, np.int32(FOLD_FAST), np.int32(0)
+
     def scatter(_):
         # its own scope, so that a profile tells the fallback's device time
         # from the fast branch's
@@ -323,8 +396,40 @@ def keyed_pane_fold(key: jax.Array, pane: jax.Array, valid: jax.Array,
                     [u.reshape(K, P)
                      for u in segment_reduce(leaves, seg, valid, K * P)])
 
-    counts, folds = jax.lax.cond(in_bounds, fast, scatter, None)
-    return counts, jax.tree.unflatten(treedef, folds), in_bounds
+    def slow(_):
+        base_n, local_n, fits = _chunk_window(pane, valid, R, chunk, L)
+        spill = valid.reshape(R, chunk) & ~fits
+        n_spill = jnp.sum(spill.astype(jnp.int32), axis=1)        # [R]
+
+        def partial(_):
+            counts, folds = contract(base_n, local_n, fits)
+            with jax.named_scope("spill"):
+                # a spill lane's cell less the dead one, so that a slot no
+                # lane filled (0) reads as the dead cell K * P, which the
+                # scatter drops
+                dead = K * P
+                seg, *xs = _compact(
+                    spill, [(key * P + pane % P - dead).reshape(R, chunk)]
+                    + [_as_int32(v).reshape(R, chunk) for v in leaves],
+                    SPILL_M)
+                seg = seg.reshape(-1) + dead
+                rows = jnp.stack([jnp.ones_like(seg)]
+                                 + [x.reshape(-1) for x in xs], axis=-1)
+            with jax.named_scope("scatter"):
+                sums = jax.ops.segment_sum(rows, seg, num_segments=dead)
+            return (counts + sums[:, 0].reshape(K, P),
+                    [f + _from_limbs([sums[:, 1 + i]], v.dtype).reshape(K, P)
+                     for i, (f, v) in enumerate(zip(folds, leaves))],
+                    np.int32(FOLD_PARTIAL), jnp.sum(n_spill))
+
+        def whole(_):
+            counts, folds = scatter(None)
+            return counts, folds, np.int32(FOLD_WHOLE), np.int32(0)
+
+        return jax.lax.cond(jnp.all(n_spill <= SPILL_M), partial, whole, None)
+
+    counts, folds, branch, spilled = jax.lax.cond(in_bounds, fast, slow, None)
+    return counts, jax.tree.unflatten(treedef, folds), branch, spilled
 
 
 def keyed_pane_histogram_pallas(key: jax.Array, pane: jax.Array,
