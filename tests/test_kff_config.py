@@ -242,9 +242,12 @@ def test_eos_flush_goes_on_past_empty_windows_until_none_is_open(global_time):
         "windows_undelivered_at_eos": 0,
         # the global-time path lists no rows; the per-key one finds the key
         # of its 32 fired windows by comparison with all 16 (PR 37)
-        # (PR 38: a delay publishes the late lanes, none here)
+        # (a delay publishes the late lanes, none here); the per-key
+        # path counts its ring overruns too, and how far the keys' clocks
+        # lie apart (every key's last tick is 95)
         **({"ffat_ring_overruns": 0, "ffat_late_lanes": 0} if global_time
-           else {"owner_compare_cells": 2 * K * K})}
+           else {"owner_compare_cells": 2 * K * K, "ffat_ring_overruns": 0,
+                 "ffat_key_clock_spread": 0})}
     assert op.get_StatsRecords()[0].tuples_dropped_old == 0
 
 

@@ -1150,6 +1150,9 @@ class MetricsRegistry:
             "ffat_late_lanes": "tuples folded after a window holding them "
                                "had fired (counted in the open windows "
                                "alone)",
+            "ffat_key_clock_spread": "largest per-key watermark less the "
+                                     "smallest, in ticks (per-key "
+                                     "time-based windows)",
             "ffat_run_budget": "(key, pane) runs one batch may hold in the "
                                "count-based pane fold",
             "ffat_keys": "keys of the pane ring",

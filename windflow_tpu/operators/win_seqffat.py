@@ -55,6 +55,11 @@ class FFATState:
     #: None otherwise, an empty pytree subtree, so the off program is
     #: unchanged; observability/event_time.py)
     lat_hist: Any = None
+    #: i32[] lanes folded into a ring slot that an unfired pane still held:
+    #: their pane lay P or more past their key's first unfired one
+    #: (time-based specs; None for count-based ones, an empty pytree subtree,
+    #: so that program is unchanged)
+    ring_overruns: Any = None
 
 
 @jax.tree_util.register_dataclass
@@ -111,11 +116,14 @@ class Win_SeqFFAT(Basic_Operator):
     ``apply``) and, off the global-time path, ``owner_compare_cells`` with
     it (how the runs and the fired windows find their key:
     ``ops/segment.py::enumerate_runs``); at ``collect_stats`` ``old_drops``
-    and, on the global-time path with a lift that reads the tuple,
-    ``ffat_ring_overruns`` (lanes
-    whose pane lay ``P`` or more past the first unfired pane: they were folded
-    into a slot that an unfired pane still held; the count-lift branch folds no
-    value by slot and publishes none), where an additive integer lift
+    and, for time-based specs (on the global-time path with a lift that
+    reads the tuple), ``ffat_ring_overruns`` (lanes whose pane lay ``P`` or
+    more past the first unfired pane, their key's on the per-key path: they
+    were folded into a slot that an unfired pane still held; the count-lift
+    branch folds no value by slot and publishes none), on the per-key
+    time-based path ``ffat_key_clock_spread`` (the largest per-key watermark
+    less the smallest, over the keys that have had a tuple, in ticks: how far
+    the keys' event clocks lie apart), where an additive integer lift
     rides the occupancy histogram's contraction, ``ffat_fold_partials``
     (batches whose ticks were out of order: the contraction held the lanes
     near each chunk's newest pane and the stragglers were scattered),
@@ -277,6 +285,8 @@ class Win_SeqFFAT(Basic_Operator):
             next_win=jnp.zeros((K,), CTRL_DTYPE),
             dropped_old=jnp.zeros((), CTRL_DTYPE),
             lat_hist=lat,
+            ring_overruns=(None if self.spec.is_cb
+                           else jnp.zeros((), CTRL_DTYPE)),
         )
 
     def out_spec(self, payload_spec: Any) -> Any:
@@ -516,16 +526,31 @@ class Win_SeqFFAT(Basic_Operator):
         key, keep one segment reduction per table; their additive folds
         (values, occupancy counts) route through the registry-selectable
         ``segment_fold`` kernel — see ``_g_insert`` for the selection
-        contract. Everything from ``touched`` on is shared."""
+        contract. Everything from ``touched`` on is shared.
+
+        Time-based windows keep the ``P`` panes from each key's first unfired
+        one: a lane further ahead shares its slot with a pane that has not
+        fired, and such lanes are counted (``ring_overruns`` ->
+        ``ffat_ring_overruns``). Scopes below ``insert``: count-based
+        windows ``rank`` and ``fold`` (:meth:`_cb_updates`); time-based ones
+        ``lookup`` (each lane's key's horizon), ``fold`` (the three ``[K*P]``
+        reductions and the fold into the ring) and ``keys`` (the per-key
+        count, a sum of the count table's rows, and watermark). Four
+        reductions go over the lanes, each a scatter: a pane id or a
+        watermark is read only where a count says it was written, so neither
+        pays a count of its own."""
         K, P = self.num_keys, self.P
         valid = batch.valid
         cb = self.spec.is_cb
+        ring_overruns = state.ring_overruns
         if cb:
             upd, cnt_upd, pane_id_upd, counts_add, ts_max = self._cb_updates(
                 state, batch)
             n_dropped = jnp.zeros((), CTRL_DTYPE)    # CB never drops OLD tuples
         else:
-            horizon = table_lookup(state.next_win, batch.key) * self.spec.slide
+            with jax.named_scope("lookup"):
+                first_win = table_lookup(state.next_win, batch.key)
+            horizon = first_win * self.spec.slide
             kept = valid & (batch.ts >= horizon)
             n_dropped = jnp.sum((valid & ~kept).astype(CTRL_DTYPE))
             valid = kept
@@ -534,6 +559,11 @@ class Win_SeqFFAT(Basic_Operator):
             if not cb:
                 slot = pane % P
                 seg = jnp.where(valid, batch.key * P + slot, K * P)
+                # the ring holds the key's panes [horizon, horizon + P): a
+                # lane further ahead lands in the slot of a pane not yet fired
+                ring_overruns = ring_overruns + jnp.sum(
+                    (valid & (pane >= first_win * self.spanes + P)
+                     ).astype(CTRL_DTYPE))
 
                 lifted = jax.vmap(self.lift)(
                     TupleRef(key=batch.key, id=batch.id, ts=batch.ts, data=batch.payload))
@@ -542,8 +572,9 @@ class Win_SeqFFAT(Basic_Operator):
                                      combine=None if self.combine is jnp.add else self.combine,
                                      identity=self.identity)
                 cnt_upd = segment_reduce(valid.astype(CTRL_DTYPE), seg, valid, K * P)
-                pane_id_upd = segment_reduce(pane, seg, valid, K * P,
-                                             combine=jnp.maximum, identity=-1)
+                # read only where ``touched``: an untouched slot's needs no
+                # identity, so no count of its own (dead lanes' seg is K * P)
+                pane_id_upd = jax.ops.segment_max(pane, seg, num_segments=K * P)
 
             touched = cnt_upd.reshape(K, P) > 0
             new_pane_of = jnp.where(touched, pane_id_upd.reshape(K, P), state.pane_of)
@@ -558,10 +589,14 @@ class Win_SeqFFAT(Basic_Operator):
                     return jnp.where(m, t + u, t)
                 return jnp.where(m, self.combine(t, u), t)
 
-            if not cb:
-                counts_add = segment_reduce(valid.astype(CTRL_DTYPE), batch.key, valid, K)
-                ts_max = segment_reduce(batch.ts, batch.key, valid, K,
-                                        combine=jnp.maximum, identity=-1)
+        if not cb:
+            with jax.named_scope("keys"):
+                # a key's lanes are its slots' counts; a key without one
+                # reads the least int32, below any watermark
+                counts_add = jnp.sum(cnt_upd.reshape(K, P), axis=1)
+                ts_max = jax.ops.segment_max(jnp.where(valid, batch.ts, -1),
+                                             batch.key, num_segments=K)
+        with jax.named_scope("fold" if cb else "keys"):
             wm_new = jnp.maximum(state.wm, ts_max)
         lat = state.lat_hist
         if lat is not None:
@@ -583,6 +618,7 @@ class Win_SeqFFAT(Basic_Operator):
             wm=wm_new,
             dropped_old=state.dropped_old + n_dropped,
             lat_hist=lat,
+            ring_overruns=ring_overruns,
         )
 
     def _cb_updates(self, state: FFATState, batch: Batch):
@@ -628,31 +664,40 @@ class Win_SeqFFAT(Basic_Operator):
     # ------------------------------------------------------------------ fire
 
     def _emit(self, state: FFATState, W: int, flush: bool):
+        """The due windows of every key, key by key, in ``W`` rows (count-based
+        and per-key time-based specs). Scopes: ``range`` (the rows' keys and
+        window ids: ``enumerate_runs`` and the ``lo`` lookup), ``gather``
+        (each window's ``[wpanes]`` slots out of ``pane_of`` and the
+        partials), ``reduce`` (a window's live panes into its result)."""
         K, P = self.num_keys, self.P
         s = self.spec
         lo = state.next_win
-        hi = jnp.maximum(self._due_hi(state, flush), lo)
-        n_f = hi - lo
-        k_safe, i_of, valid_w = enumerate_runs(n_f, W)
-        wid = table_lookup(lo, k_safe) + i_of
-        # the first W of the list are emitted
-        emitted_k = jnp.clip(W - (jnp.cumsum(n_f) - n_f), 0, n_f)
+        with jax.named_scope("range"):
+            hi = jnp.maximum(self._due_hi(state, flush), lo)
+            n_f = hi - lo
+            k_safe, i_of, valid_w = enumerate_runs(n_f, W)
+            wid = table_lookup(lo, k_safe) + i_of
+            # the first W of the list are emitted
+            emitted_k = jnp.clip(W - (jnp.cumsum(n_f) - n_f), 0, n_f)
 
         # gather the wpanes panes of each window and tree-reduce (getResult():
         # wf/flatfat.hpp root read; here a log-depth reduction over the pane axis)
-        pane0 = wid * self.spanes
-        pane_ids = pane0[:, None] + jnp.arange(self.wpanes, dtype=CTRL_DTYPE)[None, :]
-        slot = pane_ids % P
-        gflat = k_safe[:, None] * P + slot                      # [W, wpanes]
-        live = jnp.take(state.pane_of.reshape(K * P), gflat) == pane_ids
-        live &= valid_w[:, None]
+        with jax.named_scope("gather"):
+            pane0 = wid * self.spanes
+            pane_ids = pane0[:, None] + jnp.arange(self.wpanes, dtype=CTRL_DTYPE)[None, :]
+            slot = pane_ids % P
+            gflat = k_safe[:, None] * P + slot                  # [W, wpanes]
+            live = jnp.take(state.pane_of.reshape(K * P), gflat) == pane_ids
+            live &= valid_w[:, None]
 
         def gat_reduce(tbl):
-            g = jnp.take(tbl.reshape((K * P,) + tbl.shape[2:]), gflat, axis=0)
-            g = jnp.where(_b(live, g), g, jnp.asarray(self.identity, g.dtype))
-            if self.combine is jnp.add:
-                return jnp.sum(g, axis=1)
-            return _tree_reduce(self.combine, g, axis=1)
+            with jax.named_scope("gather"):
+                g = jnp.take(tbl.reshape((K * P,) + tbl.shape[2:]), gflat, axis=0)
+            with jax.named_scope("reduce"):
+                g = jnp.where(_b(live, g), g, jnp.asarray(self.identity, g.dtype))
+                if self.combine is jnp.add:
+                    return jnp.sum(g, axis=1)
+                return _tree_reduce(self.combine, g, axis=1)
 
         results = jax.tree.map(gat_reduce, state.panes)
         res_ts = (wid * s.slide + s.win_len - 1 if not s.is_cb
@@ -699,10 +744,13 @@ class Win_SeqFFAT(Basic_Operator):
     def apply(self, state, batch: Batch):
         """One scope per phase (``insert``, ``emit``), under the operator's own
         scope that the chain opens, and below them ``insert/rank`` and
-        ``insert/fold`` (count-based and per-key windows), ``insert/hist``,
-        ``insert/fold``, ``emit/gather``, ``emit/reduce`` and ``emit/clear``
-        (the global-time path, in the step and in the EOS flush): a profile's
-        device operations say which part of the engine they belong to."""
+        ``insert/fold`` (count-based windows), ``insert/lookup``,
+        ``insert/fold`` and ``insert/keys`` (per-key time-based windows),
+        ``emit/range``, ``emit/gather`` and ``emit/reduce`` (both, in the step
+        and in the EOS flush), ``insert/hist``, ``insert/fold``,
+        ``emit/gather``, ``emit/reduce`` and ``emit/clear`` (the global-time
+        path, in the step and in the EOS flush): a profile's device
+        operations say which part of the engine they belong to."""
         W = self._resolve_w(batch.capacity)
         self._w = W
         insert, emit = ((self._g_insert, self._g_emit) if self.global_time
@@ -745,9 +793,11 @@ class Win_SeqFFAT(Basic_Operator):
     def collect_stats(self, state=None) -> None:
         """Sync the device-resident counters into the Stats_Record and the stage
         counters (monitoring snapshot / EOS — scalar D2H reads, off the hot
-        path): ``old_drops``; on the global-time path ``ffat_ring_overruns``
-        where the fold counts them (a lift that reads the tuple: the count-lift
-        branch folds no value by slot and publishes none),
+        path): ``old_drops``; ``ffat_ring_overruns`` on the per-key
+        time-based path and on the global-time one where the fold counts
+        them (a lift that reads the tuple: the count-lift branch folds no
+        value by slot and publishes none); on the per-key time-based path
+        ``ffat_key_clock_spread``; on the global-time one
         ``ffat_fold_fallbacks``, ``ffat_fold_partials`` and
         ``ffat_fold_spill_lanes`` where the value fold rides the histogram's
         contraction and ``ffat_late_lanes`` where the spec allows lateness;
@@ -761,10 +811,15 @@ class Win_SeqFFAT(Basic_Operator):
         if not self.spec.is_cb:
             counters.update(self._fired_budget_gauge())
         counters.update(self._owner_cells_gauge())
-        if (self.global_time and self.count_lift is not None
-                and not self._hist_is_fold()):
+        per_key_time = not (self.global_time or self.spec.is_cb)
+        if per_key_time or (self.global_time and self.count_lift is not None
+                            and not self._hist_is_fold()):
             counters["ffat_ring_overruns"] = int(
                 np.asarray(state.ring_overruns))
+        if per_key_time:
+            wm = np.asarray(state.wm)[np.asarray(state.count) > 0]
+            counters["ffat_key_clock_spread"] = (
+                int(wm.max()) - int(wm.min()) if wm.size else 0)
         if self.global_time and self._fold_rides:
             for name in ("fold_fallbacks", "fold_partials",
                          "fold_spill_lanes"):
