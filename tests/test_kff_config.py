@@ -247,7 +247,9 @@ def test_eos_flush_goes_on_past_empty_windows_until_none_is_open(global_time):
         # lie apart (every key's last tick is 95)
         **({"ffat_ring_overruns": 0, "ffat_late_lanes": 0} if global_time
            else {"owner_compare_cells": 2 * K * K, "ffat_ring_overruns": 0,
-                 "ffat_key_clock_spread": 0})}
+                 "ffat_key_clock_spread": 0,
+                 # 4 panes a window in a ring of 16: whole rows
+                 "ffat_emit_row_lanes": 2 * K * 16})}
     assert op.get_StatsRecords()[0].tuples_dropped_old == 0
 
 
@@ -447,11 +449,18 @@ def test_the_older_cells_step_programs_are_the_parents(name):
 #: contraction, the stragglers compacted and scattered, two counters more):
 #: at commit 87463aa the step read (371, "e00cd3c7b7dbb22f..."). The branch
 #: ``kff``'s in-order stream takes did not move (``TAKEN_FOLD_BRANCH``).
+#: ``kcb``'s moved again for its size alone: ``Win_SeqFFAT._emit`` takes
+#: a fired window's whole ring row where a row's ``P`` lanes cost less than
+#: its ``wpanes`` single-element takes, which holds at this rehearsal size
+#: (``P`` 32, 2 panes a window) and not at the cell's (``P`` 4,096): there the
+#: element takes stay. With the element form the step is the pair it read at
+#: commit cd258cb (445, "03555a722655e4d3..."), equation for equation
+#: (``ELEMENT_FORM_STEPS``).
 CHANGED_STEPS = {
     "kff": (555, "fb889972b69d53450dfcc71606256c7a"
                  "9af864a3a2dbb6152c0a5a303439118a"),
-    "kcb": (445, "03555a722655e4d39e8e56654b7eb342"
-                 "37af879e5058edbe40bcbbc4407047bd"),
+    "kcb": (427, "921764b74a9f69ea546e177a8cfce258"
+                 "35ffc8adf3bf744cc39dd59d4d7e39d5"),
     "ysb_wmr": (729, "9bea6c039a3f24015c46cd932f5776c9"
                      "b5f4b4f073d0e836b3076763fc2d4405"),
     "kpf": (1239, "67b249f99659b352f5e55d08e28c5af5"
@@ -462,6 +471,22 @@ CHANGED_STEPS = {
 @pytest.mark.parametrize("name", sorted(CHANGED_STEPS))
 def test_the_step_programs_changed_on_purpose_are_as_recorded(name):
     assert step_operations(name) == CHANGED_STEPS[name]
+
+
+#: ``step_operations`` of the cells whose window runs ``Win_SeqFFAT._emit``,
+#: its element takes forced, at commit cd258cb (before ``_emit`` could take
+#: whole ring rows)
+ELEMENT_FORM_STEPS = {
+    "kcb": (445, "03555a722655e4d39e8e56654b7eb342"
+                 "37af879e5058edbe40bcbbc4407047bd"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(ELEMENT_FORM_STEPS))
+def test_the_emits_element_form_is_the_parents(name, monkeypatch):
+    import windflow_tpu.operators.win_seqffat as engine
+    monkeypatch.setattr(engine, "ROW_LANE_NS", float("inf"))
+    assert step_operations(name) == ELEMENT_FORM_STEPS[name]
 
 
 #: ``step_operations``' lines of the branch of the window's fold that an

@@ -307,6 +307,9 @@ def test_budgets_and_checks_come_from_the_deployment():
     window = mod.build_ops(published, 1 << 20)[-1]
     assert type(window) is Key_FFAT and not window.global_time
     assert window.P == 256 and window.max_wins == 33_280
+    # the per-key emit reads each fired window's key as one ring row
+    assert (window.stage_counters()["ffat_emit_row_lanes"]
+            == 33_280 * 256 == 8_519_680)
     ops, step, args = chain_step(published, mod, 1 << 20)
     jax.eval_shape(step, *args)
     assert mod.structure_checks(published, ops[-1]) == {
@@ -332,6 +335,16 @@ def test_budgets_and_checks_come_from_the_deployment():
     kff, kff_published = load_config("kff")[0], dict(published)
     assert mod.min_bytes_per_batch(published, 1 << 20) == \
         kff.min_bytes_per_batch(kff_published, 1 << 20)
+    # kcb's emit at its cell's shapes (2 panes a window, 4,096 slots, 2,112
+    # windows) keeps the element takes, and says so once its budget is known
+    kcb = load_config("kcb")[0]
+    with open(os.path.join(BENCH, "configs", "kcb.json")) as f:
+        kcb_published = json.load(f)
+    ops, step, args = chain_step(kcb_published, kcb, 1 << 20)
+    jax.eval_shape(step, *args)
+    ops[-1].collect_stats(args[0][-1])
+    assert (ops[-1].P, ops[-1].wpanes, ops[-1]._w) == (4096, 2, 2112)
+    assert ops[-1].stage_counters()["ffat_emit_row_lanes"] == 0
 
 
 def test_the_published_draw_lags_a_quarter_of_the_keys_past_a_window():
@@ -368,6 +381,12 @@ def test_the_global_time_step_with_a_delay_is_the_parents(name):
     assert step_operations(name) == PARENT_STEPS[name]
 
 
+def emit_gathers(jaxpr):
+    """The result shapes of the gathers under ``emit/gather``."""
+    return [eqn.outvars[0].aval.shape for eqn, path in equations(jaxpr)
+            if eqn.primitive.name == "gather" and "/emit/gather" in path]
+
+
 PER_KEY_SCOPES = ("insert/lookup", "insert/fold", "insert/keys",
                   "emit/range", "emit/gather", "emit/reduce")
 
@@ -381,7 +400,7 @@ def test_lowered_step_and_flush_carry_the_per_key_scopes():
     for sub in PER_KEY_SCOPES:
         assert f"/{window}/{sub}/" in hlo, sub
     # the [K*P] tables' scatters under fold, the watermark's under keys (the
-    # count comes from the table's rows), the [W, wpanes] takes under
+    # count comes from the table's rows), the [W, P] row takes under
     # emit/gather
     for sub, op in (("insert/fold", "scatter-add\""),
                     ("insert/fold", "scatter-max\""),
@@ -395,6 +414,18 @@ def test_lowered_step_and_flush_carry_the_per_key_scopes():
     assert sorted(eqn.outvars[0].aval.shape for eqn, _ in equations(jaxpr)
                   if eqn.primitive.name.startswith("scatter")) == (
         [(cfg["n_keys"],)] + [(cfg["n_keys"] * ops[-1].P,)] * 3)
+    # emit/gather takes the whole ring row of each fired window's key out of
+    # pane_of and the partials, and no window's [wpanes] slots one by one
+    assert emit_gathers(jaxpr) == [(ops[-1]._w, ops[-1].P)] * 2
+    # kcb's program at its cell's ring (4,096 slots, 2 panes a window) keeps
+    # the [W, 2] element takes
+    kcb = load_config("kcb")[0]
+    with open(os.path.join(BENCH, "configs", "kcb.json")) as f:
+        kcb_published = json.load(f)
+    kcb_ops, kcb_step, kcb_args = chain_step(kcb_published, kcb, 1 << 20)
+    kcb_jaxpr = jax.make_jaxpr(kcb_step)(*kcb_args).jaxpr
+    assert kcb_ops[-1].P == 4096
+    assert emit_gathers(kcb_jaxpr) == [(kcb_ops[-1]._w, 2)] * 2
     state = args[0][-1]
     ops[-1].flush(state)
     text = ops[-1]._flush_jit.lower(state).as_text(debug_info=True)

@@ -1171,6 +1171,9 @@ class MetricsRegistry:
             "owner_compare_cells": "rows x keys cells one step compares to "
                                    "find the key of every row it lists "
                                    "(0: binary search)",
+            "ffat_emit_row_lanes": "pane-ring lanes one step's emit reads "
+                                   "as whole key rows (0: one element a "
+                                   "window's pane)",
         }
         # a pattern of two archive engines publishes each one's under its stage
         for stage in PANE_STAGES:

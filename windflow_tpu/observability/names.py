@@ -255,8 +255,11 @@ STAGE_GAUGES = (
     # both window engines, once the fired-window budget is settled: the rows x
     # keys cells a step compares to find the key of every row it lists (ring
     # rows or pane runs of the insert, fired windows), 0 where the lists kept
-    # the binary search (ops/segment.py::enumerate_runs)
-    "owner_compare_cells",
+    # the binary search (ops/segment.py::enumerate_runs); win_seqffat.py off
+    # the global-time path, with it: the ring lanes a step's emit reads as
+    # whole key rows (fired windows x ring slots), 0 where it takes each
+    # window's panes one element at a time
+    "owner_compare_cells", "ffat_emit_row_lanes",
     # Pane_Farm's two engines' budgets, a prefix a stage
     *(f"{stage}_{gauge}" for stage in PANE_STAGES
       for gauge in ARCHIVE_ENGINE_GAUGES),

@@ -40,6 +40,18 @@ from ..ops.segment import (enumerate_runs, owner_compare_cells, run_budget,
 from .base import Basic_Operator
 from .window import WindowSpec
 
+#: what a fired window's read of its key's pane ring costs on one v5e
+#: (PERF.md section 6; ``_emit``): a ``jnp.take`` of single elements out of
+#: the flat ``[K*P]`` ring is serialized, ``ELEMENT_TAKE_NS`` an element
+#: (7.17 for ``[33280, 64]`` slots out of ``s32[131072]``, 13.0 for
+#: ``[2112, 2]`` out of ``s32[2097152]``); a take of whole ``[P]`` ring rows
+#: by key streams, ``ROW_LANE_NS`` a lane (0.0129 for ``[33280, 256]`` rows
+#: out of ``s32[512, 256]``, 0.0105 for ``[2112, 4096]``). ``_emit`` takes
+#: whole rows where a window's ``P`` lanes cost less than its ``wpanes``
+#: elements.
+ELEMENT_TAKE_NS = 7.2
+ROW_LANE_NS = 0.013
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
@@ -115,7 +127,9 @@ class Win_SeqFFAT(Basic_Operator):
     is known (``max_wins=``, the ring on the global-time path, else the first
     ``apply``) and, off the global-time path, ``owner_compare_cells`` with
     it (how the runs and the fired windows find their key:
-    ``ops/segment.py::enumerate_runs``); at ``collect_stats`` ``old_drops``
+    ``ops/segment.py::enumerate_runs``) and ``ffat_emit_row_lanes`` (the
+    ring lanes a step's emit reads as whole key rows, 0 where it takes
+    single elements: ``_emit``); at ``collect_stats`` ``old_drops``
     and, for time-based specs (on the global-time path with a lift that
     reads the tuple), ``ffat_ring_overruns`` (lanes whose pane lay ``P`` or
     more past the first unfired pane, their key's on the per-key path: they
@@ -209,7 +223,7 @@ class Win_SeqFFAT(Basic_Operator):
                 batch_capacity, self.num_keys, self.pane_len)
         else:
             gauges.update(self._fired_budget_gauge())
-        gauges.update(self._owner_cells_gauge())
+        gauges.update(self._per_key_gauges())
         self._publish_stage_counters(gauges)
 
     def _fired_budget_gauge(self) -> dict:
@@ -221,19 +235,32 @@ class Win_SeqFFAT(Basic_Operator):
             W = self._resolve_w(0)
         return {} if W is None else {"fired_window_budget": W}
 
-    def _owner_cells_gauge(self) -> dict:
+    def _per_key_gauges(self) -> dict:
         """``owner_compare_cells``: the rows x keys cells a step compares to
         find the key of every run it folds (count-based specs) and of every
         window it fires, 0 where both lists kept the binary search
-        (``ops/segment.py::enumerate_runs``); known once the fired-window
-        budget is. The global-time path lists neither and publishes none."""
+        (``ops/segment.py::enumerate_runs``); ``ffat_emit_row_lanes``: the
+        ring lanes ``_emit`` reads as whole key rows, W x P, 0 where it keeps
+        the element takes. Known once the fired-window budget is; the
+        global-time path lists no rows and publishes neither."""
         W = self.max_wins if self.max_wins is not None else self._w
         if self.global_time or W is None:
             return {}
         runs = self._run_budget if self.spec.is_cb else 0
         return {"owner_compare_cells":
                 owner_compare_cells(W, self.num_keys)
-                + owner_compare_cells(runs, self.num_keys)}
+                + owner_compare_cells(runs, self.num_keys),
+                "ffat_emit_row_lanes": W * self.P if self._emit_reads_rows()
+                else 0}
+
+    def _emit_reads_rows(self) -> bool:
+        """Whether ``_emit`` takes a fired window's whole ring row: where its
+        ``P`` lanes cost less than its ``wpanes`` single-element takes, and
+        only under ``jnp.add`` (a row's panes come in slot order, not pane
+        order, across the ring's wrap: integer sums are the same bit for
+        bit, a float sum may round otherwise)."""
+        return (self.combine is jnp.add
+                and self.P * ROW_LANE_NS < self.wpanes * ELEMENT_TAKE_NS)
 
     def out_capacity(self, in_capacity: int) -> int:
         if self.global_time:
@@ -667,8 +694,10 @@ class Win_SeqFFAT(Basic_Operator):
         """The due windows of every key, key by key, in ``W`` rows (count-based
         and per-key time-based specs). Scopes: ``range`` (the rows' keys and
         window ids: ``enumerate_runs`` and the ``lo`` lookup), ``gather``
-        (each window's ``[wpanes]`` slots out of ``pane_of`` and the
-        partials), ``reduce`` (a window's live panes into its result)."""
+        (each window's key's whole ``[P]`` ring row out of ``pane_of`` and
+        the partials where :meth:`_emit_reads_rows`, else its ``[wpanes]``
+        slots one element each), ``reduce`` (a window's live panes into its
+        result)."""
         K, P = self.num_keys, self.P
         s = self.spec
         lo = state.next_win
@@ -684,15 +713,30 @@ class Win_SeqFFAT(Basic_Operator):
         # wf/flatfat.hpp root read; here a log-depth reduction over the pane axis)
         with jax.named_scope("gather"):
             pane0 = wid * self.spanes
-            pane_ids = pane0[:, None] + jnp.arange(self.wpanes, dtype=CTRL_DTYPE)[None, :]
-            slot = pane_ids % P
-            gflat = k_safe[:, None] * P + slot                  # [W, wpanes]
-            live = jnp.take(state.pane_of.reshape(K * P), gflat) == pane_ids
+            if self._emit_reads_rows():
+                # a slot holds pane p only at p % P: it is live where the pane
+                # id it holds lies in the window's range (never-written slots
+                # hold -1, fired panes' older ids); the offset is compared,
+                # since the range's end may pass int32's
+                def gat(tbl):
+                    return jnp.take(tbl, k_safe, axis=0)         # [W, P, ...]
+                off = gat(state.pane_of) - pane0[:, None]
+                live = (off >= 0) & (off < self.wpanes)
+            else:
+                pane_ids = pane0[:, None] + jnp.arange(
+                    self.wpanes, dtype=CTRL_DTYPE)[None, :]
+                slot = pane_ids % P
+                gflat = k_safe[:, None] * P + slot               # [W, wpanes]
+
+                def gat(tbl):
+                    return jnp.take(tbl.reshape((K * P,) + tbl.shape[2:]),
+                                    gflat, axis=0)
+                live = jnp.take(state.pane_of.reshape(K * P), gflat) == pane_ids
             live &= valid_w[:, None]
 
         def gat_reduce(tbl):
             with jax.named_scope("gather"):
-                g = jnp.take(tbl.reshape((K * P,) + tbl.shape[2:]), gflat, axis=0)
+                g = gat(tbl)
             with jax.named_scope("reduce"):
                 g = jnp.where(_b(live, g), g, jnp.asarray(self.identity, g.dtype))
                 if self.combine is jnp.add:
@@ -810,7 +854,7 @@ class Win_SeqFFAT(Basic_Operator):
         counters = {**self.stage_counters(), "old_drops": old}
         if not self.spec.is_cb:
             counters.update(self._fired_budget_gauge())
-        counters.update(self._owner_cells_gauge())
+        counters.update(self._per_key_gauges())
         per_key_time = not (self.global_time or self.spec.is_cb)
         if per_key_time or (self.global_time and self.count_lift is not None
                             and not self._hist_is_fold()):
