@@ -399,21 +399,25 @@ def test_lowered_step_and_flush_carry_the_per_key_scopes():
     assert window == "Key_FFAT:kff_lag_window"
     for sub in PER_KEY_SCOPES:
         assert f"/{window}/{sub}/" in hlo, sub
-    # the [K*P] tables' scatters under fold, the watermark's under keys (the
-    # count comes from the table's rows), the [W, P] row takes under
-    # emit/gather
-    for sub, op in (("insert/fold", "scatter-add\""),
-                    ("insert/fold", "scatter-max\""),
-                    ("insert/keys", "scatter-max\""),
+    # the values and counts in the contraction under fold, the watermark's
+    # select-reduce under keys (the count comes from the table's rows), the
+    # [W, P] row takes under emit/gather
+    for sub, op in (("insert/fold", r"rck,rcl->rkl/dot_general\""),
+                    ("insert/keys", "reduce_max\""),
                     ("emit/gather", r"jit\(_take\)")):
         assert re.search(rf'/{window}/{sub}/[^"]*{op}', hlo), (sub, op)
-    assert not re.search(rf'/{window}/insert/keys/[^"]*scatter-add"', hlo)
-    # four scatters over the lanes: value, count and pane id into the
-    # [K*P] tables, the watermark into [K]
+    assert not re.search(rf'/{window}/insert/keys/[^"]*scatter', hlo)
+    # the scatters over the lanes lie in the fold's fallbacks alone:
+    # keyed_pane_fold's whole batch (count and value into the [K*P]
+    # tables) and partial branch (the stragglers' two-wide rows) under
+    # scatter, the pane ids of a batch that overran the ring under overrun
     jaxpr = jax.make_jaxpr(step)(*args).jaxpr
-    assert sorted(eqn.outvars[0].aval.shape for eqn, _ in equations(jaxpr)
-                  if eqn.primitive.name.startswith("scatter")) == (
-        [(cfg["n_keys"],)] + [(cfg["n_keys"] * ops[-1].P,)] * 3)
+    KP = cfg["n_keys"] * ops[-1].P
+    assert sorted((eqn.outvars[0].aval.shape, path.split(f"{window}/")[1])
+                  for eqn, path in equations(jaxpr)
+                  if eqn.primitive.name.startswith("scatter")) == [
+        ((KP,), "insert/fold/overrun"), ((KP,), "insert/fold/scatter"),
+        ((KP,), "insert/fold/scatter"), ((KP, 2), "insert/fold/scatter")]
     # emit/gather takes the whole ring row of each fired window's key out of
     # pane_of and the partials, and no window's [wpanes] slots one by one
     assert emit_gathers(jaxpr) == [(ops[-1]._w, ops[-1].P)] * 2

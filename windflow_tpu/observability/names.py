@@ -222,8 +222,9 @@ STAGE_COUNTERS = (
     # scattered), and those stragglers; lanes folded after a window holding
     # them had fired (delay > 0 only: they count in the windows still open);
     # on the per-key time-based path ffat_ring_overruns too (a lane's pane
-    # ffat_pane_slots or more past its key's first unfired one) and the
-    # largest per-key watermark less the smallest, in ticks
+    # ffat_pane_slots or more past its key's first unfired one), the three
+    # fold counters where its fold rides the contraction, and the largest
+    # per-key watermark less the smallest, in ticks
     "ffat_ring_overruns", "ffat_fold_fallbacks", "ffat_fold_partials",
     "ffat_fold_spill_lanes", "ffat_late_lanes", "ffat_key_clock_spread",
     # operators/win_patterns.py::Pane_Farm: its two engines' counters, each

@@ -72,6 +72,14 @@ class FFATState:
     #: (time-based specs; None for count-based ones, an empty pytree subtree,
     #: so that program is unchanged)
     ring_overruns: Any = None
+    #: i32[] batches whose integer value fold took ``keyed_pane_fold``'s
+    #: whole-batch scatters, i32[] those that took its partial branch, and
+    #: i32[] the lanes that branch scattered (``GFFATState``'s three; counted
+    #: where the fold rides the contraction; time-based specs, None for
+    #: count-based ones)
+    fold_fallbacks: Any = None
+    fold_partials: Any = None
+    fold_spill_lanes: Any = None
 
 
 @jax.tree_util.register_dataclass
@@ -138,7 +146,8 @@ class Win_SeqFFAT(Basic_Operator):
     time-based path ``ffat_key_clock_spread`` (the largest per-key watermark
     less the smallest, over the keys that have had a tuple, in ticks: how far
     the keys' event clocks lie apart), where an additive integer lift
-    rides the occupancy histogram's contraction, ``ffat_fold_partials``
+    rides the occupancy histogram's contraction (on either time-based
+    path), ``ffat_fold_partials``
     (batches whose ticks were out of order: the contraction held the lanes
     near each chunk's newest pane and the stragglers were scattered),
     ``ffat_fold_spill_lanes`` (those stragglers) and ``ffat_fold_fallbacks``
@@ -198,7 +207,8 @@ class Win_SeqFFAT(Basic_Operator):
         self.max_wins = max_wins
         self._w = None
         #: whether the value fold shares the occupancy histogram's contraction
-        #: (settled by the first trace of ``_g_insert``, from the lift's result)
+        #: (settled by the first trace of ``_g_insert`` or, time-based per-key,
+        #: ``_insert``, from the lift's result)
         self._fold_rides = False
         self.bind_geometry(256)        # provisional; compiler re-binds with real C
 
@@ -300,6 +310,9 @@ class Win_SeqFFAT(Basic_Operator):
                 late_lanes=(jnp.zeros((), CTRL_DTYPE) if self.spec.delay > 0
                             else None),
             )
+        # time-based specs count ring overruns and the fold's branches
+        def zero():
+            return None if self.spec.is_cb else jnp.zeros((), CTRL_DTYPE)
         return FFATState(
             panes=jax.tree.map(
                 lambda s: jnp.broadcast_to(
@@ -312,8 +325,10 @@ class Win_SeqFFAT(Basic_Operator):
             next_win=jnp.zeros((K,), CTRL_DTYPE),
             dropped_old=jnp.zeros((), CTRL_DTYPE),
             lat_hist=lat,
-            ring_overruns=(None if self.spec.is_cb
-                           else jnp.zeros((), CTRL_DTYPE)),
+            ring_overruns=zero(),
+            fold_fallbacks=zero(),
+            fold_partials=zero(),
+            fold_spill_lanes=zero(),
         )
 
     def out_spec(self, payload_spec: Any) -> Any:
@@ -550,26 +565,37 @@ class Win_SeqFFAT(Basic_Operator):
         Count-based windows build the tables in the order one sort makes
         (:meth:`_cb_updates`: no per-lane scatter). Time-based per-key
         windows, whose panes come from ``ts`` and are not contiguous inside a
-        key, keep one segment reduction per table; their additive folds
-        (values, occupancy counts) route through the registry-selectable
-        ``segment_fold`` kernel — see ``_g_insert`` for the selection
-        contract. Everything from ``touched`` on is shared.
+        key, fold an additive lift of ``[C]`` integers in
+        ``keyed_pane_fold``'s contraction on panes relative to each key's
+        first unfired one (:meth:`_pk_fold`, the same test as
+        ``_g_insert``'s: ``fold_partials`` -> ``ffat_fold_partials``,
+        ``fold_spill_lanes`` -> ``ffat_fold_spill_lanes``, ``fold_fallbacks``
+        -> ``ffat_fold_fallbacks`` count its branches) and take the
+        watermark by a select-reduce over the key one-hot
+        (:func:`_key_max`): no reduction goes over the lanes by scatter.
+        Floats, other combines, leaves of higher rank and odd capacities
+        keep one segment reduction per table (value and count through the
+        registry-selectable ``segment_fold`` kernel, the pane id and the
+        watermark through ``segment_max``): four scatters over the lanes. A
+        pane id or a watermark is read only where a count says it was
+        written, so neither pays a count of its own. Everything from
+        ``touched`` on is shared.
 
         Time-based windows keep the ``P`` panes from each key's first unfired
         one: a lane further ahead shares its slot with a pane that has not
         fired, and such lanes are counted (``ring_overruns`` ->
         ``ffat_ring_overruns``). Scopes below ``insert``: count-based
         windows ``rank`` and ``fold`` (:meth:`_cb_updates`); time-based ones
-        ``lookup`` (each lane's key's horizon), ``fold`` (the three ``[K*P]``
-        reductions and the fold into the ring) and ``keys`` (the per-key
-        count, a sum of the count table's rows, and watermark). Four
-        reductions go over the lanes, each a scatter: a pane id or a
-        watermark is read only where a count says it was written, so neither
-        pays a count of its own."""
+        ``lookup`` (each lane's key's horizon), ``fold`` (the ``[K*P]``
+        tables: the contraction and its rotation into the ring, or the
+        scatters; the fold into the ring) and ``keys`` (the per-key count, a
+        sum of the count table's rows, and watermark)."""
         K, P = self.num_keys, self.P
         valid = batch.valid
         cb = self.spec.is_cb
         ring_overruns = state.ring_overruns
+        fold_counters = (state.fold_fallbacks, state.fold_partials,
+                         state.fold_spill_lanes)
         if cb:
             upd, cnt_upd, pane_id_upd, counts_add, ts_max = self._cb_updates(
                 state, batch)
@@ -582,26 +608,43 @@ class Win_SeqFFAT(Basic_Operator):
             n_dropped = jnp.sum((valid & ~kept).astype(CTRL_DTYPE))
             valid = kept
             pane = batch.ts // self.pane_len
+            from ..ops.histogram import pane_fold_applies
+            tuples = TupleRef(key=batch.key, id=batch.id, ts=batch.ts,
+                              data=batch.payload)
+            # integers to add: the fold rides keyed_pane_fold's contraction
+            rides = self._fold_rides = (
+                self.combine is jnp.add and pane_fold_applies(
+                    jax.eval_shape(jax.vmap(self.lift), tuples)))
         with jax.named_scope("fold"):
             if not cb:
-                slot = pane % P
-                seg = jnp.where(valid, batch.key * P + slot, K * P)
+                if not rides:
+                    slot = pane % P
+                    seg = jnp.where(valid, batch.key * P + slot, K * P)
                 # the ring holds the key's panes [horizon, horizon + P): a
                 # lane further ahead lands in the slot of a pane not yet fired
-                ring_overruns = ring_overruns + jnp.sum(
-                    (valid & (pane >= first_win * self.spanes + P)
-                     ).astype(CTRL_DTYPE))
+                n_over = jnp.sum((valid & (pane >= first_win * self.spanes + P)
+                                  ).astype(CTRL_DTYPE))
+                ring_overruns = ring_overruns + n_over
 
-                lifted = jax.vmap(self.lift)(
-                    TupleRef(key=batch.key, id=batch.id, ts=batch.ts, data=batch.payload))
-                # per-(key,pane-slot) partial of this batch
-                upd = segment_reduce(lifted, seg, valid, K * P,
-                                     combine=None if self.combine is jnp.add else self.combine,
-                                     identity=self.identity)
-                cnt_upd = segment_reduce(valid.astype(CTRL_DTYPE), seg, valid, K * P)
-                # read only where ``touched``: an untouched slot's needs no
-                # identity, so no count of its own (dead lanes' seg is K * P)
-                pane_id_upd = jax.ops.segment_max(pane, seg, num_segments=K * P)
+                lifted = jax.vmap(self.lift)(tuples)
+                if rides:
+                    upd, cnt_upd, pane_id_upd, fold_counters = self._pk_fold(
+                        state, batch, pane, valid, lifted, first_win, n_over,
+                        fold_counters)
+                else:
+                    # per-(key,pane-slot) partial of this batch
+                    upd = segment_reduce(
+                        lifted, seg, valid, K * P,
+                        combine=None if self.combine is jnp.add
+                        else self.combine,
+                        identity=self.identity)
+                    cnt_upd = segment_reduce(valid.astype(CTRL_DTYPE), seg,
+                                             valid, K * P)
+                    # read only where ``touched``: an untouched slot's needs
+                    # no identity, so no count of its own (dead lanes' seg
+                    # is K * P)
+                    pane_id_upd = jax.ops.segment_max(pane, seg,
+                                                      num_segments=K * P)
 
             touched = cnt_upd.reshape(K, P) > 0
             new_pane_of = jnp.where(touched, pane_id_upd.reshape(K, P), state.pane_of)
@@ -619,10 +662,14 @@ class Win_SeqFFAT(Basic_Operator):
         if not cb:
             with jax.named_scope("keys"):
                 # a key's lanes are its slots' counts; a key without one
-                # reads the least int32, below any watermark
+                # reads -1 or the least int32, at or below any watermark
                 counts_add = jnp.sum(cnt_upd.reshape(K, P), axis=1)
-                ts_max = jax.ops.segment_max(jnp.where(valid, batch.ts, -1),
-                                             batch.key, num_segments=K)
+                if rides:
+                    ts_max = _key_max(batch.key, batch.ts, valid, K)
+                else:
+                    ts_max = jax.ops.segment_max(
+                        jnp.where(valid, batch.ts, -1), batch.key,
+                        num_segments=K)
         with jax.named_scope("fold" if cb else "keys"):
             wm_new = jnp.maximum(state.wm, ts_max)
         lat = state.lat_hist
@@ -646,7 +693,54 @@ class Win_SeqFFAT(Basic_Operator):
             dropped_old=state.dropped_old + n_dropped,
             lat_hist=lat,
             ring_overruns=ring_overruns,
+            fold_fallbacks=fold_counters[0],
+            fold_partials=fold_counters[1],
+            fold_spill_lanes=fold_counters[2],
         )
+
+    def _pk_fold(self, state: FFATState, batch: Batch, pane, valid, lifted,
+                 first_win, n_over, counters):
+        """The per-key time-based fold of integers to add, in
+        ``keyed_pane_fold``'s one contraction: ``upd`` (the values' pytree),
+        ``cnt_upd``, ``pane_id_upd`` over ``[K*P]`` as the scatters make them,
+        bit for bit, and the fold's three counters advanced by its branch.
+
+        The fold's locality test is by pane over the keys of a 1,024-lane
+        chunk, which keys whose clocks lie apart fail. A lane's pane relative
+        to its key's first unfired one, ``H = next_win * spanes`` (its
+        horizon, which follows the key's own watermark), is near every other
+        in-order key's: the fold takes that, and is indexed by ``rel % P``
+        where the ring is by ``(H + rel) % P``, so each key's row of every
+        table turns by ``H % P`` (:func:`_rotate_rows`). A kept lane lies in
+        ``[H, H + P)`` unless it overran the ring, so a touched slot ``s``
+        holds the pane ``H + (s - H) mod P``; a batch in which a lane
+        overran (``n_over``) takes the pane ids' ``segment_max`` behind a
+        ``cond`` instead (scope ``overrun``)."""
+        from ..ops.histogram import FOLD_PARTIAL, FOLD_WHOLE, keyed_pane_fold
+        K, P = self.num_keys, self.P
+        base = state.next_win * self.spanes                   # [K]: H
+        cnt, folds, branch, spilled = keyed_pane_fold(
+            batch.key, pane - first_win * self.spanes, valid, lifted, K, P)
+        shift = base % P
+
+        def place(t):                                         # [K, P] -> ring
+            return _rotate_rows(t, shift).reshape(K * P)
+
+        def closed(_):
+            s = jnp.arange(P, dtype=CTRL_DTYPE)
+            return (base[:, None] + (s - base[:, None]) % P).reshape(K * P)
+
+        def scattered(_):
+            with jax.named_scope("overrun"):
+                seg = jnp.where(valid, batch.key * P + pane % P, K * P)
+                return jax.ops.segment_max(pane, seg, num_segments=K * P)
+
+        pane_id_upd = jax.lax.cond(n_over > 0, scattered, closed, None)
+        fallbacks, partials, spill = counters
+        return (jax.tree.map(place, folds), place(cnt), pane_id_upd,
+                (fallbacks + (branch == FOLD_WHOLE).astype(CTRL_DTYPE),
+                 partials + (branch == FOLD_PARTIAL).astype(CTRL_DTYPE),
+                 spill + spilled))
 
     def _cb_updates(self, state: FFATState, batch: Batch):
         """The batch's update tables for a count-based window: ``upd``,
@@ -841,10 +935,11 @@ class Win_SeqFFAT(Basic_Operator):
         time-based path and on the global-time one where the fold counts
         them (a lift that reads the tuple: the count-lift branch folds no
         value by slot and publishes none); on the per-key time-based path
-        ``ffat_key_clock_spread``; on the global-time one
-        ``ffat_fold_fallbacks``, ``ffat_fold_partials`` and
-        ``ffat_fold_spill_lanes`` where the value fold rides the histogram's
-        contraction and ``ffat_late_lanes`` where the spec allows lateness;
+        ``ffat_key_clock_spread``; ``ffat_fold_fallbacks``,
+        ``ffat_fold_partials`` and ``ffat_fold_spill_lanes`` where the value
+        fold rides the histogram's contraction (either time-based path); on
+        the global-time one ``ffat_late_lanes`` where the spec allows
+        lateness;
         for time-based specs the fired-window budget once it is settled."""
         if state is None or not hasattr(state, "dropped_old"):
             return
@@ -864,7 +959,7 @@ class Win_SeqFFAT(Basic_Operator):
             wm = np.asarray(state.wm)[np.asarray(state.count) > 0]
             counters["ffat_key_clock_spread"] = (
                 int(wm.max()) - int(wm.min()) if wm.size else 0)
-        if self.global_time and self._fold_rides:
+        if self._fold_rides:
             for name in ("fold_fallbacks", "fold_partials",
                          "fold_spill_lanes"):
                 counters["ffat_" + name] = int(np.asarray(getattr(state, name)))
@@ -948,6 +1043,29 @@ def _detect_count_lift(lift, batch) -> bool:
 
 def _b(mask, v):
     return mask.reshape(mask.shape + (1,) * (v.ndim - mask.ndim))
+
+
+def _rotate_rows(tbl, shift):
+    """Each row ``k`` of ``tbl`` ``[K, P]`` turned right by ``shift[k]`` (in
+    ``[0, P)``): ``out[k, s] = tbl[k, (s - shift[k]) % P]``. A barrel shift,
+    one static ``jnp.roll`` a bit of the shift, kept where the key's bit is
+    set: elementwise, so exact in any dtype, where a take of single elements
+    would be serialized (``ELEMENT_TAKE_NS``)."""
+    P = tbl.shape[1]
+    for b in range((P - 1).bit_length()):
+        on = ((shift >> b) & 1) == 1
+        tbl = jnp.where(on[:, None], jnp.roll(tbl, 1 << b, axis=1), tbl)
+    return tbl
+
+
+def _key_max(key, ts, valid, K):
+    """Each key's largest ``ts`` over the ``valid`` lanes, -1 for a key with
+    none: a select-reduce over the ``[C, K]`` key one-hot, ``table_lookup``'s
+    form with the reduction over the lanes. The TPU compiler fuses the
+    compare, the select and the reduction into one elementwise pass, where a
+    ``segment_max`` over the lanes is serialized lane by lane."""
+    hit = (key[:, None] == jnp.arange(K, dtype=key.dtype)) & valid[:, None]
+    return jnp.max(jnp.where(hit, ts[:, None], -1), axis=0)
 
 
 def _tree_reduce(combine, x, axis):
