@@ -135,7 +135,7 @@ def leg_a(say, args, device):
     if window.count_lift is not True:
         raise AssertionError(
             f"window.count_lift is {window.count_lift!r}: the window stage "
-            f"took the segment-sum fallback, not the histogram path")
+            f"took the segment-sum fallback, not the count fold")
     state_devices = set()
     for leaf in jax.tree.leaves(pipe.chain.states):
         state_devices.update(leaf.devices())
@@ -224,13 +224,14 @@ def _case_segment_fold(args, rng):
             ref.astype(np.int32))
 
 
-def _case_histogram(args, rng):
-    """The YSB chain's geometry; the first half of the batch is a one-key
-    stream: ~1000 counts per (key, pane, chunk), far beyond bf16's 256."""
+def _case_pane_counts(args, rng):
+    """``keyed_pane_fold`` with no value leaf (the count lift) at the YSB
+    chain's geometry; the first half of the batch is a one-key stream: ~1000
+    counts per (key, pane, chunk), far beyond bf16's 256."""
     import jax
     import jax.numpy as jnp
     from windflow_tpu.benchmarks import ysb
-    from windflow_tpu.ops.histogram import keyed_pane_histogram
+    from windflow_tpu.ops.histogram import FOLD_FAST, keyed_pane_fold
     from windflow_tpu.operators.win_seqffat import _next_pow2
     C, K = args.lanes, ysb.N_CAMPAIGNS
     per_pane = ysb.EVENTS_PER_TICK * ysb.WIN_LEN
@@ -242,12 +243,10 @@ def _case_histogram(args, rng):
     ref = np.zeros((K, P), np.int32)
     np.add.at(ref, (key[ok], pane[ok] % P), 1)
     dev = tuple(map(jnp.asarray, (key, pane, ok)))
-    return (f"histogram[C={C},K={K},P={P},one-key half]",
-            {impl: (lambda impl=impl: jax.jit(
-                lambda k, p, v: keyed_pane_histogram(
-                    k, p, v, K, P, impl=impl))(*dev))
-             for impl in ("xla", "pallas", "pallas_mm")},
-            ref)
+    return (f"pane_counts[C={C},K={K},P={P},one-key half]",
+            {"xla": lambda: jax.jit(
+                lambda k, p, v: keyed_pane_fold(k, p, v, (), K, P))(*dev)},
+            (ref, (), np.int32(FOLD_FAST), np.int32(0)))
 
 
 def _case_pane_fold(args, rng):
@@ -398,7 +397,7 @@ def _kernel_cases(args, rng):
     """(label, {impl: thunk}, numpy reference) per kernel case; a thunk
     compiles and runs one form on the default device."""
     yield _case_segment_fold(args, rng)
-    yield _case_histogram(args, rng)
+    yield _case_pane_counts(args, rng)
     yield _case_pane_fold(args, rng)
     yield _case_pane_fold_late(args, rng)
     yield from _cases_lookup(args, rng)

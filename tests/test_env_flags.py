@@ -40,11 +40,11 @@ def test_every_env_flag_read_is_documented_with_read_time():
 
 
 def test_known_trace_time_flags_marked():
-    """The four flags read inside jitted code paths must carry the
-    trace-time marking — the footgun the inventory exists to prevent."""
+    """The flags read inside jitted code paths must carry the trace-time
+    marking — the footgun the inventory exists to prevent."""
     docs = lint.parse_env_doc(os.path.join(ROOT, CFG.env_doc))
-    for flag in ("WF_LOOKUP_IMPL", "WF_HISTOGRAM_IMPL",
-                 "WF_HISTOGRAM_FORCE_FAST", "WF_ORDERING_SKIP_SORTED"):
+    for flag in ("WF_KERNEL_IMPL", "WF_LOOKUP_IMPL",
+                 "WF_ORDERING_SKIP_SORTED"):
         assert flag in docs, f"{flag} missing from ENV_FLAGS.md"
         _lineno, cell = docs[flag]
         assert "trace" in cell.lower(), (

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from test_kff_config import operations
+from test_step_programs import operations
 from test_ysb_wmr_config import equations
 from windflow_tpu.basic import win_type_t
 from windflow_tpu.batch import Batch, CTRL_DTYPE, TupleRef

@@ -1,14 +1,20 @@
-"""MXU histogram (ops/histogram.py) and factored table lookup (ops/lookup.py):
-exactness against the scatter/gather reference on random data, including the
-locality-violation fallback and ring wrap-around."""
+"""The count histogram (ops/histogram.py::keyed_pane_fold with no value leaf)
+and the factored table lookup (ops/lookup.py): exactness against the
+scatter/gather reference on random data, including the locality-violation
+fallback and ring wrap-around."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from windflow_tpu.ops.histogram import keyed_pane_histogram, _scatter_hist
+from windflow_tpu.ops.histogram import keyed_pane_fold
 from windflow_tpu.ops.lookup import table_lookup, _factored_lookup
+
+
+def count_fold(key, pane, valid, K, P):
+    """``keyed_pane_fold`` with no value leaf: its counts."""
+    return keyed_pane_fold(key, pane, valid, (), K, P)[0]
 
 
 def ref_hist(key, pane, valid, K, P):
@@ -26,7 +32,7 @@ def test_hist_sorted_ts(C, K, P):
     # locally-clustered panes: nondecreasing ts
     pane = (np.arange(C) // 97).astype(np.int32) + 5
     valid = rng.random(C) < 0.7
-    got = jax.jit(lambda *a: keyed_pane_histogram(*a, K, P))(
+    got = jax.jit(lambda *a: count_fold(*a, K, P))(
         jnp.asarray(key), jnp.asarray(pane), jnp.asarray(valid))
     np.testing.assert_array_equal(np.asarray(got), ref_hist(key, pane, valid, K, P))
 
@@ -37,7 +43,7 @@ def test_hist_wraparound():
     key = rng.integers(0, K, C).astype(np.int32)
     pane = (np.arange(C) // 130 + P - 3).astype(np.int32)   # crosses the ring edge
     valid = np.ones(C, bool)
-    got = jax.jit(lambda *a: keyed_pane_histogram(*a, K, P))(
+    got = jax.jit(lambda *a: count_fold(*a, K, P))(
         jnp.asarray(key), jnp.asarray(pane), jnp.asarray(valid))
     np.testing.assert_array_equal(np.asarray(got), ref_hist(key, pane, valid, K, P))
 
@@ -50,7 +56,7 @@ def test_hist_fallback_unordered():
     key = rng.integers(0, K, C).astype(np.int32)
     pane = rng.integers(0, 1000, C).astype(np.int32)
     valid = rng.random(C) < 0.5
-    got = jax.jit(lambda *a: keyed_pane_histogram(*a, K, P))(
+    got = jax.jit(lambda *a: count_fold(*a, K, P))(
         jnp.asarray(key), jnp.asarray(pane), jnp.asarray(valid))
     np.testing.assert_array_equal(np.asarray(got), ref_hist(key, pane, valid, K, P))
 
@@ -60,8 +66,8 @@ def test_hist_odd_capacity_and_empty():
     key = np.zeros(C, np.int32)
     pane = np.zeros(C, np.int32)
     valid = np.zeros(C, bool)
-    got = keyed_pane_histogram(jnp.asarray(key), jnp.asarray(pane),
-                               jnp.asarray(valid), K, P)
+    got = count_fold(jnp.asarray(key), jnp.asarray(pane),
+                     jnp.asarray(valid), K, P)
     assert int(jnp.sum(got)) == 0
 
 
@@ -118,7 +124,7 @@ def test_hist_many_keys_tiled():
     key = rng.integers(0, K, C).astype(np.int32)
     pane = (np.arange(C) // 511).astype(np.int32)
     valid = rng.random(C) < 0.9
-    got = jax.jit(lambda *a: keyed_pane_histogram(*a, K, P))(
+    got = jax.jit(lambda *a: count_fold(*a, K, P))(
         jnp.asarray(key), jnp.asarray(pane), jnp.asarray(valid))
     np.testing.assert_array_equal(np.asarray(got), ref_hist(key, pane, valid, K, P))
 
@@ -165,15 +171,16 @@ def test_lookup_exact_beyond_bf16(tpu_default_dot, table, impl):
     np.testing.assert_array_equal(np.asarray(got), table[idx])
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas_mm"])
+@pytest.mark.parametrize("impl", ["xla"])
 def test_hist_counts_beyond_bf16(tpu_default_dot, impl):
-    """A one-key stream: ~1000 counts per (key, pane, chunk)."""
+    """A one-key stream: ~1000 counts per (key, pane, chunk), through the
+    fold's one form (``impl``: XLA)."""
     C, K, P = 4096, 4, 16
     key = np.zeros(C, np.int32)
     pane = (np.arange(C) // 2000 + P - 1).astype(np.int32)
     valid = np.arange(C) % 41 != 0
-    got = keyed_pane_histogram(jnp.asarray(key), jnp.asarray(pane),
-                               jnp.asarray(valid), K, P, impl=impl)
+    got = count_fold(jnp.asarray(key), jnp.asarray(pane),
+                     jnp.asarray(valid), K, P)
     want = ref_hist(key, pane, valid, K, P)
     assert want.max() > 256
     np.testing.assert_array_equal(np.asarray(got), want)

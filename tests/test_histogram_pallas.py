@@ -1,110 +1,12 @@
-"""Pallas keyed-pane histogram (ops/histogram.py::keyed_pane_histogram_pallas):
-exactness against the scatter oracle in interpret mode (CPU), under the fast
-path's locality precondition, including ring wrap-around via the spill-column
-fold and partially-invalid lanes."""
+"""Pallas factored table lookup (ops/lookup.py::_pallas_factored_lookup):
+exactness against the gather oracle in interpret mode (CPU), through the
+kernel itself and through ``table_lookup``'s impl switch, and the fallback
+of capacities the kernel cannot block."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-from windflow_tpu.ops.histogram import (DEFAULT_CHUNK, keyed_pane_histogram,
-                                        keyed_pane_histogram_pallas)
-from tests.test_histogram_lookup import ref_hist
-
-
-def _call(key, pane, valid, K, P, placement="ds"):
-    return keyed_pane_histogram_pallas(
-        jnp.asarray(key), jnp.asarray(pane), jnp.asarray(valid), K, P,
-        placement=placement, interpret=True)
-
-
-@pytest.mark.parametrize("placement", ["ds", "mm"])
-def test_pallas_hist_placements_agree(placement):
-    C, K, P = 4096, 13, 48
-    rng = np.random.default_rng(7)
-    key = rng.integers(0, K, C).astype(np.int32)
-    pane = (np.arange(C) // 700 + P - 2).astype(np.int32)   # wraps the ring
-    valid = rng.random(C) < 0.8
-    got = _call(key, pane, valid, K, P, placement=placement)
-    np.testing.assert_array_equal(np.asarray(got),
-                                  ref_hist(key, pane, valid, K, P))
-
-
-@pytest.mark.parametrize("C,K,P", [(4096, 7, 64), (8192, 100, 256)])
-def test_pallas_hist_sorted_ts(C, K, P):
-    rng = np.random.default_rng(0)
-    key = rng.integers(0, K, C).astype(np.int32)
-    # nondecreasing panes, < locality(8) distinct panes per 1024-lane chunk
-    pane = (np.arange(C) // 157).astype(np.int32) + 5
-    valid = rng.random(C) < 0.7
-    got = _call(key, pane, valid, K, P)
-    np.testing.assert_array_equal(np.asarray(got),
-                                  ref_hist(key, pane, valid, K, P))
-
-
-def test_pallas_hist_wraparound():
-    C, K, P = 4096, 5, 32
-    rng = np.random.default_rng(1)
-    key = rng.integers(0, K, C).astype(np.int32)
-    pane = (np.arange(C) // 600 + P - 2).astype(np.int32)  # crosses ring edge
-    valid = np.ones(C, bool)
-    got = _call(key, pane, valid, K, P)
-    np.testing.assert_array_equal(np.asarray(got),
-                                  ref_hist(key, pane, valid, K, P))
-
-
-def test_pallas_hist_empty_chunks():
-    C, K, P = 4096, 3, 16
-    key = np.zeros(C, np.int32)
-    pane = np.zeros(C, np.int32)
-    valid = np.zeros(C, bool)
-    valid[2048:2100] = True          # chunks 0,1,3 fully invalid
-    got = _call(key, pane, valid, K, P)
-    np.testing.assert_array_equal(np.asarray(got),
-                                  ref_hist(key, pane, valid, K, P))
-
-
-def test_pallas_matches_xla_fast_path():
-    """Same inputs through both fast-path implementations."""
-    C, K, P = 8192, 100, 2100        # YSB-like ring geometry
-    rng = np.random.default_rng(2)
-    key = rng.integers(0, K, C).astype(np.int32)
-    pane = (np.arange(C) // 200).astype(np.int32) + 1000
-    valid = rng.random(C) < 0.9
-    a = keyed_pane_histogram(jnp.asarray(key), jnp.asarray(pane),
-                             jnp.asarray(valid), K, P)
-    b = _call(key, pane, valid, K, P)
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_integrated_impl_pallas_cond_paths():
-    """keyed_pane_histogram(impl='pallas'): the locality cond routes in-bounds
-    batches through the kernel and unordered batches through the exact scatter
-    fallback — identical results either way."""
-    C, K, P = 4096, 11, 64
-    rng = np.random.default_rng(4)
-    key = rng.integers(0, K, C).astype(np.int32)
-    valid = rng.random(C) < 0.6
-    for pane in ((np.arange(C) // 600).astype(np.int32),       # in-bounds
-                 rng.integers(0, 1000, C).astype(np.int32)):   # violates -> scatter
-        got = jax.jit(lambda *a: keyed_pane_histogram(*a, K, P, impl="pallas"))(
-            jnp.asarray(key), jnp.asarray(pane), jnp.asarray(valid))
-        np.testing.assert_array_equal(np.asarray(got),
-                                      ref_hist(key, pane, valid, K, P))
-
-
-def test_ysb_chain_equal_under_impl(monkeypatch):
-    """Full YSB chain output is bit-identical under WF_HISTOGRAM_IMPL=pallas."""
-    from windflow_tpu.benchmarks import ysb
-
-    def run():
-        res = ysb.make_pipeline(8192, batch_size=2048).run()
-        return int(res["ysb_windows_total"])
-
-    base = run()
-    monkeypatch.setenv("WF_HISTOGRAM_IMPL", "pallas")
-    assert run() == base == ysb.oracle_totals(8192)
 
 
 @pytest.mark.parametrize("K,C", [(1000, 8192), (300, 512), (5000, 16384)])
@@ -136,33 +38,6 @@ def test_pallas_lookup_unblockable_capacity_falls_back():
                                   np.asarray(table)[np.asarray(idx)])
 
 
-def test_pallas_hist_fuzz_geometry():
-    """Randomized geometry x validity x placement fuzz against the scatter
-    oracle (interpret mode), inputs constructed to satisfy the fast path's
-    per-chunk locality precondition — the adoption gate for on-chip use."""
-    from windflow_tpu.ops.histogram import DEFAULT_L
-
-    rng = np.random.default_rng(42)
-    for trial in range(12):
-        chunk = DEFAULT_CHUNK
-        C = chunk * int(rng.integers(2, 9))
-        K = int(rng.integers(2, 300))
-        P = int(rng.integers(8, 4096))
-        L = DEFAULT_L
-        key = rng.integers(0, K, C).astype(np.int32)
-        # per-chunk pane base: arbitrary nondecreasing jumps (ring wraps many
-        # times); within-chunk offsets < L
-        bases = np.cumsum(rng.integers(0, 3 * P, C // chunk))
-        pane = (np.repeat(bases, chunk)
-                + rng.integers(0, L, C)).astype(np.int32)
-        valid = rng.random(C) < rng.random()
-        placement = ("ds", "mm")[trial % 2]
-        got = _call(key, pane, valid, K, P, placement=placement)
-        np.testing.assert_array_equal(
-            np.asarray(got), ref_hist(key, pane, valid, K, P),
-            err_msg=f"trial={trial} C={C} K={K} P={P} placement={placement}")
-
-
 def test_pallas_lookup_fuzz_geometry():
     from windflow_tpu.ops.lookup import _pallas_block, _pallas_factored_lookup
 
@@ -178,55 +53,3 @@ def test_pallas_lookup_fuzz_geometry():
         np.testing.assert_array_equal(
             np.asarray(got), np.asarray(table)[np.asarray(idx)],
             err_msg=f"trial={trial} K={K} C={C}")
-
-
-def test_pallas_odd_capacity_falls_back():
-    """Non-chunk-multiple capacities route to the exact scatter path."""
-    C, K, P = 1000, 3, 16
-    rng = np.random.default_rng(3)
-    key = rng.integers(0, K, C).astype(np.int32)
-    pane = rng.integers(0, 100, C).astype(np.int32)
-    valid = rng.random(C) < 0.5
-    got = _call(key, pane, valid, K, P)
-    np.testing.assert_array_equal(np.asarray(got),
-                                  ref_hist(key, pane, valid, K, P))
-
-
-def test_pallas_small_ring_routes_to_scatter():
-    """ring < locality: the kernel's single-fold wrap is shape-mismatched, so
-    the call must route to the exact scatter path (ADVICE r05 #2)."""
-    C, K, P = 2048, 5, 4                     # P=4 < locality=8
-    rng = np.random.default_rng(3)
-    key = rng.integers(0, K, C).astype(np.int32)
-    pane = rng.integers(0, 64, C).astype(np.int32)
-    valid = rng.random(C) < 0.9
-    got = _call(key, pane, valid, K, P)
-    np.testing.assert_array_equal(np.asarray(got),
-                                  ref_hist(key, pane, valid, K, P))
-    # the integrated entry point with impl="pallas" takes the same route
-    got2 = keyed_pane_histogram(jnp.asarray(key), jnp.asarray(pane),
-                                jnp.asarray(valid), K, P, impl="pallas")
-    np.testing.assert_array_equal(np.asarray(got2),
-                                  ref_hist(key, pane, valid, K, P))
-
-
-def test_histogram_force_fast_env_zero_means_off(monkeypatch):
-    """WF_HISTOGRAM_FORCE_FAST='0'/'' must DISABLE the diagnostic bypass (the
-    WF_ORDERING_SKIP_SORTED convention, ADVICE r05 #1): with the locality cond
-    active, a locality-violating batch still takes the exact scatter branch."""
-    C, K, P = 2048, 4, 32
-    rng = np.random.default_rng(9)
-    key = rng.integers(0, K, C).astype(np.int32)
-    pane = rng.integers(0, 10_000, C).astype(np.int32)   # wildly out of locality
-    valid = np.ones(C, bool)
-    oracle = ref_hist(key, pane, valid, K, P)
-    for off in ("0", ""):
-        monkeypatch.setenv("WF_HISTOGRAM_FORCE_FAST", off)
-        got = keyed_pane_histogram(jnp.asarray(key), jnp.asarray(pane),
-                                   jnp.asarray(valid), K, P)
-        np.testing.assert_array_equal(np.asarray(got), oracle)
-    # '1' still engages the bypass (wrong on this input — that is its contract)
-    monkeypatch.setenv("WF_HISTOGRAM_FORCE_FAST", "1")
-    forced = keyed_pane_histogram(jnp.asarray(key), jnp.asarray(pane),
-                                  jnp.asarray(valid), K, P)
-    assert not np.array_equal(np.asarray(forced), oracle)

@@ -21,54 +21,54 @@ from windflow_tpu.observability.names import KERNELS
 
 def _mini_registry():
     r = registry.KernelRegistry()
-    r.register_kernel("histogram", "xla", reference=True, default=True)
-    r.register_kernel("histogram", "pallas")
     r.register_kernel("lookup", "xla", reference=True, default=True)
     r.register_kernel("lookup", "pallas")
+    r.register_kernel("segment_fold", "xla", reference=True, default=True)
+    r.register_kernel("segment_fold", "pallas")
     return r
 
 
 def test_default_is_reference(monkeypatch):
     monkeypatch.delenv("WF_KERNEL_IMPL", raising=False)
-    monkeypatch.delenv("WF_HISTOGRAM_IMPL", raising=False)
+    monkeypatch.delenv("WF_LOOKUP_IMPL", raising=False)
     r = _mini_registry()
-    assert r.resolve_impl("histogram") == "xla"
-    assert r.reference_impl("histogram") == "xla"
+    assert r.resolve_impl("lookup") == "xla"
+    assert r.reference_impl("lookup") == "xla"
 
 
 def test_explicit_impl_wins_over_env(monkeypatch):
-    monkeypatch.setenv("WF_KERNEL_IMPL", "histogram=pallas")
+    monkeypatch.setenv("WF_KERNEL_IMPL", "lookup=pallas")
     r = _mini_registry()
-    assert r.resolve_impl("histogram", impl="xla") == "xla"
+    assert r.resolve_impl("lookup", impl="xla") == "xla"
 
 
 def test_env_per_kernel_beats_global(monkeypatch):
-    monkeypatch.setenv("WF_KERNEL_IMPL", "pallas,histogram=xla")
+    monkeypatch.setenv("WF_KERNEL_IMPL", "pallas,lookup=xla")
     r = _mini_registry()
-    assert r.resolve_impl("histogram") == "xla"
-    assert r.resolve_impl("lookup") == "pallas"
+    assert r.resolve_impl("lookup") == "xla"
+    assert r.resolve_impl("segment_fold") == "pallas"
 
 
 def test_env_off_values_mean_no_override(monkeypatch):
     for off in ("", "0"):
         monkeypatch.setenv("WF_KERNEL_IMPL", off)
-        assert _mini_registry().resolve_impl("histogram") == "xla"
+        assert _mini_registry().resolve_impl("lookup") == "xla"
 
 
 def test_deprecated_alias_still_honored(monkeypatch):
     monkeypatch.delenv("WF_KERNEL_IMPL", raising=False)
-    monkeypatch.setenv("WF_HISTOGRAM_IMPL", "pallas")
+    monkeypatch.setenv("WF_LOOKUP_IMPL", "pallas")
     r = _mini_registry()
-    assert r.resolve_impl("histogram") == "pallas"
+    assert r.resolve_impl("lookup") == "pallas"
     # WF_KERNEL_IMPL outranks the alias
-    monkeypatch.setenv("WF_KERNEL_IMPL", "histogram=xla")
-    assert r.resolve_impl("histogram") == "xla"
+    monkeypatch.setenv("WF_KERNEL_IMPL", "lookup=xla")
+    assert r.resolve_impl("lookup") == "xla"
     # ''/'0' = no override for the aliases too (the repo off convention —
-    # a stale WF_HISTOGRAM_IMPL=0 must not crash a pipeline at trace time)
+    # a stale WF_LOOKUP_IMPL=0 must not crash a pipeline at trace time)
     monkeypatch.delenv("WF_KERNEL_IMPL", raising=False)
     for off in ("", "0"):
-        monkeypatch.setenv("WF_HISTOGRAM_IMPL", off)
-        assert r.resolve_impl("histogram") == "xla"
+        monkeypatch.setenv("WF_LOOKUP_IMPL", off)
+        assert r.resolve_impl("lookup") == "xla"
 
 
 def test_unknown_kernel_and_impl_raise():
@@ -76,7 +76,7 @@ def test_unknown_kernel_and_impl_raise():
     with pytest.raises(ValueError, match="unknown kernel"):
         r.resolve_impl("typo_kernel")
     with pytest.raises(ValueError, match="no impl"):
-        r.resolve_impl("histogram", impl="cuda")
+        r.resolve_impl("lookup", impl="cuda")
 
 
 def test_tuning_cache_warm_start(tmp_path, monkeypatch):
@@ -85,18 +85,18 @@ def test_tuning_cache_warm_start(tmp_path, monkeypatch):
     kernels)."""
     from windflow_tpu.control.autotune import TuningCache
     monkeypatch.delenv("WF_KERNEL_IMPL", raising=False)
-    monkeypatch.delenv("WF_HISTOGRAM_IMPL", raising=False)
+    monkeypatch.delenv("WF_LOOKUP_IMPL", raising=False)
     cache = TuningCache(str(tmp_path / "tuning.json"))
     r = _mini_registry()
     r.attach_tuning_cache(cache)
-    r.persist_winner("histogram", "C1024", "pallas", tps=1e8)
+    r.persist_winner("lookup", "C1024", "pallas", tps=1e8)
     r2 = _mini_registry()
     r2.attach_tuning_cache(cache)
-    assert r2.resolve_impl("histogram", spec_key="C1024") == "pallas"
+    assert r2.resolve_impl("lookup", spec_key="C1024") == "pallas"
     # other spec keys are unaffected; env still outranks the cache
-    assert r2.resolve_impl("histogram", spec_key="C2048") == "xla"
-    monkeypatch.setenv("WF_KERNEL_IMPL", "histogram=xla")
-    assert r2.resolve_impl("histogram", spec_key="C1024") == "xla"
+    assert r2.resolve_impl("lookup", spec_key="C2048") == "xla"
+    monkeypatch.setenv("WF_KERNEL_IMPL", "lookup=xla")
+    assert r2.resolve_impl("lookup", spec_key="C1024") == "xla"
 
 
 def test_wf109_stale_selection_surfaces_in_validate(monkeypatch):
@@ -106,18 +106,18 @@ def test_wf109_stale_selection_surfaces_in_validate(monkeypatch):
     from windflow_tpu.analysis import validate
 
     monkeypatch.delenv("WF_KERNEL_IMPL", raising=False)
-    monkeypatch.delenv("WF_HISTOGRAM_IMPL", raising=False)
+    monkeypatch.delenv("WF_LOOKUP_IMPL", raising=False)
     src = wf.Source(lambda i: {"v": (i % 7).astype(jnp.float32)},
                     total=64, num_keys=2)
     p = wf.Pipeline(src, [wf.Map(lambda t: {"v": t.v + 1.0})],
                     wf.Sink(lambda view: None), batch_size=32)
     registry.REGISTRY.reset_records()   # drop leftovers from earlier tests
     try:
-        registry.REGISTRY.resolve_impl("histogram", spec_key="wf109-test")
-        monkeypatch.setenv("WF_KERNEL_IMPL", "histogram=pallas")
+        registry.REGISTRY.resolve_impl("lookup", spec_key="wf109-test")
+        monkeypatch.setenv("WF_KERNEL_IMPL", "lookup=pallas")
         report = validate(p)
         hits = [d for d in report.diagnostics if d.code == "WF109"]
-        assert hits and "histogram" in hits[0].where, str(report)
+        assert hits and "lookup" in hits[0].where, str(report)
         assert report.ok            # warning severity: stale, not broken
         monkeypatch.delenv("WF_KERNEL_IMPL")
         assert "WF109" not in validate(p).codes()
@@ -127,9 +127,9 @@ def test_wf109_stale_selection_surfaces_in_validate(monkeypatch):
 
 def test_explicit_impl_not_recorded():
     r = _mini_registry()
-    r.resolve_impl("histogram", spec_key="s", impl="pallas")
+    r.resolve_impl("lookup", spec_key="s", impl="pallas")
     assert r.trace_records() == {}
-    r.resolve_impl("histogram", spec_key="s")
+    r.resolve_impl("lookup", spec_key="s")
     assert list(r.trace_records().values()) == [frozenset({"xla"})]
 
 
@@ -138,11 +138,11 @@ def test_wf109_not_silenced_by_re_resolution(monkeypatch):
     must not overwrite the pre-flip record — the executable compiled under
     the old impl is still cached, so it stays reported as stale."""
     monkeypatch.delenv("WF_KERNEL_IMPL", raising=False)
-    monkeypatch.delenv("WF_HISTOGRAM_IMPL", raising=False)
+    monkeypatch.delenv("WF_LOOKUP_IMPL", raising=False)
     r = _mini_registry()
-    r.resolve_impl("histogram", spec_key="s")              # records 'xla'
-    monkeypatch.setenv("WF_KERNEL_IMPL", "histogram=pallas")
-    r.resolve_impl("histogram", spec_key="s")              # re-records
+    r.resolve_impl("lookup", spec_key="s")              # records 'xla'
+    monkeypatch.setenv("WF_KERNEL_IMPL", "lookup=pallas")
+    r.resolve_impl("lookup", spec_key="s")              # re-records
     [rec] = r.stale_selections()
     assert rec["recorded"] == "xla" and rec["current"] == "pallas"
 
@@ -311,6 +311,32 @@ def test_segment_fold_float_routes_to_reference():
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_segment_fold_records_the_form_that_ran(monkeypatch):
+    """Under WF_KERNEL_IMPL=segment_fold=pallas a segment space past
+    FOLD_MAX_SEGMENTS runs the XLA form, and the trace record says so; one
+    the kernel takes records ``pallas``."""
+    from windflow_tpu.ops.registry import REGISTRY
+    from windflow_tpu.ops.segment import FOLD_MAX_SEGMENTS
+    C = 2048
+    v = jnp.ones((C,), jnp.int32)
+    seg = jnp.zeros((C,), jnp.int32)
+    valid = jnp.ones((C,), bool)
+    monkeypatch.setenv("WF_KERNEL_IMPL", "segment_fold=pallas")
+    REGISTRY.reset_records()
+    try:
+        for S in (FOLD_MAX_SEGMENTS + 1, 64):
+            got = segment_fold(v, seg, valid, S)
+            assert int(got[0]) == C and int(got[1:].sum()) == 0
+        records = {spec: impls for (kernel, spec, _), impls
+                   in REGISTRY.trace_records().items()
+                   if kernel == "segment_fold"}
+    finally:
+        REGISTRY.reset_records()
+    assert records == {
+        f"C{C}xS{FOLD_MAX_SEGMENTS + 1}:int32": frozenset({"xla"}),
+        f"C{C}xS64:int32": frozenset({"pallas"})}
+
+
 def test_segment_reduce_routes_through_fold(monkeypatch):
     """The Win_SeqFFAT fold call site: segment_reduce's default-add path
     under WF_KERNEL_IMPL=segment_fold=pallas equals the reference — through
@@ -400,28 +426,7 @@ def test_join_probe_oversized_table_falls_back():
     np.testing.assert_array_equal(np.asarray(ha), np.asarray(hb))
 
 
-# ------------------------------------- parity: histogram/lookup via registry
-
-
-def test_histogram_parity_through_registry(monkeypatch):
-    """The pre-existing kernels selected THROUGH the registry env: fresh
-    shapes force a fresh trace, results byte-identical to the reference."""
-    from windflow_tpu.ops.histogram import keyed_pane_histogram
-    from tests.test_histogram_lookup import ref_hist
-    rng = np.random.default_rng(41)
-    C, K, P = 3072, 9, 64
-    key = rng.integers(0, K, C).astype(np.int32)
-    pane = (np.arange(C) // 600).astype(np.int32) + 3
-    valid = rng.random(C) < 0.75
-    want = ref_hist(key, pane, valid, K, P)
-    for impl_env in ("xla", "pallas", "pallas_mm"):
-        monkeypatch.setenv("WF_KERNEL_IMPL", f"histogram={impl_env}")
-        got = keyed_pane_histogram(jnp.asarray(key), jnp.asarray(pane),
-                                   jnp.asarray(valid), K, P)
-        np.testing.assert_array_equal(np.asarray(got), want,
-                                      err_msg=impl_env)
-    from windflow_tpu.ops.registry import REGISTRY
-    REGISTRY.reset_records()
+# ------------------------------------------------ parity: lookup via registry
 
 
 def test_lookup_parity_through_registry(monkeypatch):
@@ -444,18 +449,17 @@ def test_lookup_parity_through_registry(monkeypatch):
 
 def test_one_interpret_rule_for_every_backend(monkeypatch):
     """cpu interprets, tpu compiles, anything else is an error — and all
-    five kernel modules ask this one function."""
+    four kernel modules ask this one function."""
     import inspect
     import jax
-    from windflow_tpu.ops import (bitonic, histogram, lookup, pallas_kernels,
-                                  segment)
+    from windflow_tpu.ops import bitonic, lookup, pallas_kernels, segment
     for backend, want in (("cpu", True), ("tpu", False)):
         monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
         assert registry.pallas_interpret() is want
     monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
     with pytest.raises(RuntimeError, match="'gpu'"):
         registry.pallas_interpret()
-    for mod in (bitonic, histogram, lookup, pallas_kernels, segment):
+    for mod in (bitonic, lookup, pallas_kernels, segment):
         src = inspect.getsource(mod)
         assert "pallas_interpret()" in src and "default_backend" not in src
 
@@ -465,13 +469,13 @@ def test_refused_impl_raises_on_tpu_only(monkeypatch):
     raises with Mosaic's message when selected on tpu — explicitly or through
     the environment; nothing swaps in the XLA form."""
     import jax
-    assert registry.resolve_impl("histogram", impl="pallas",
+    assert registry.resolve_impl("ordering_merge", impl="pallas",
                                  record=False) == "pallas"
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with pytest.raises(registry.KernelRefused, match="multiple of 128"):
-        registry.resolve_impl("histogram", impl="pallas", record=False)
+    with pytest.raises(registry.KernelRefused, match="shape cast"):
+        registry.resolve_impl("ordering_merge", impl="pallas", record=False)
     monkeypatch.setenv("WF_KERNEL_IMPL", "ordering_merge=pallas")
     with pytest.raises(registry.KernelRefused, match="shape cast"):
         registry.resolve_impl("ordering_merge", record=False)
-    assert registry.resolve_impl("histogram", impl="pallas_mm",
-                                 record=False) == "pallas_mm"
+    assert registry.resolve_impl("lookup", impl="pallas",
+                                 record=False) == "pallas"

@@ -1,11 +1,13 @@
 """``ops/histogram.py::keyed_pane_fold``: the occupancy counts and an additive
 fold of integers in one chunk-local one-hot contraction, bit for bit
-``jax.ops.segment_sum`` whatever the batch holds and whichever of its three
+``jax.ops.segment_sum`` and numpy's histogram whatever the batch holds,
+with value leaves or none (the count histogram), and whichever of its three
 branches the batch takes (fast, partial, whole: a numpy account of the
 locality test says which, and how many lanes the partial branch scatters),
 and the one call site, ``Win_SeqFFAT._g_insert``: which lifts ride the
-contraction (the code sees it in the lift's result), which keep the scatter,
-and the counters of the batches that left the fast branch."""
+contraction (the code sees it in the lift's result), which keep the scatter
+beside the counts, and the counters of the batches that left the fast
+branch."""
 
 import dataclasses
 
@@ -100,7 +102,9 @@ def branch_of(pane, valid, chunk=DEFAULT_CHUNK, L=DEFAULT_L, M=SPILL_M):
     where every valid lane lies fewer than ``L`` panes past its chunk's
     oldest; else partial, where no chunk has more than ``M`` valid lanes
     ``L`` or more panes behind its newest (those are scattered), else
-    whole."""
+    whole. Lanes that are not whole chunks take the whole branch."""
+    if len(pane) % chunk or len(pane) < chunk:
+        return FOLD_WHOLE, 0
     p = np.asarray(pane, np.int64).reshape(-1, chunk)
     v = np.asarray(valid, bool).reshape(-1, chunk)
     oldest = np.where(v, p, np.iinfo(np.int64).max).min(axis=1, keepdims=True)
@@ -169,6 +173,55 @@ def behind_and_past_the_wrap(rng):
     return case
 
 
+def valueless(make):
+    """The same lanes with no value leaf: the count histogram alone."""
+    def made(rng):
+        return dict(make(rng), values=())
+    return made
+
+
+def sorted_ts(C, K, P):
+    """Non-decreasing panes, 157 lanes a pane: under the locality bound."""
+    def make(rng):
+        return dict(key=rng.integers(0, K, C).astype(np.int32),
+                    pane=in_order_panes(C, 157, first=5),
+                    valid=rng.random(C) < 0.7, values=(), K=K, P=P)
+    return make
+
+
+def empty_chunks(rng):
+    C = 4096
+    valid = np.zeros(C, bool)
+    valid[2048:2100] = True                     # chunks 0, 1 and 3 dead
+    return dict(key=np.zeros(C, np.int32), pane=np.zeros(C, np.int32),
+                valid=valid, values=(), K=3, P=16)
+
+
+def odd_capacity(rng):
+    """1,000 lanes, not a whole chunk: the scatters, statically."""
+    C = 1000
+    return dict(key=rng.integers(0, 3, C).astype(np.int32),
+                pane=rng.integers(0, 100, C).astype(np.int32),
+                valid=rng.random(C) < 0.5, values=(), K=3, P=16,
+                branch=FOLD_WHOLE)
+
+
+def fuzzed_geometry(rng):
+    """Random C, K and P; each chunk's panes a base that jumps ahead by up
+    to three rings, and offsets under the locality bound: the fast branch
+    over many wraps of the ring."""
+    C = DEFAULT_CHUNK * int(rng.integers(2, 9))
+    K, P = int(rng.integers(2, 300)), int(rng.integers(8, 4096))
+    bases = np.cumsum(rng.integers(0, 3 * P, C // DEFAULT_CHUNK))
+    pane = np.repeat(bases, DEFAULT_CHUNK) + rng.integers(0, DEFAULT_L, C)
+    return dict(key=rng.integers(0, K, C).astype(np.int32),
+                pane=pane.astype(np.int32), valid=rng.random(C) < rng.random(),
+                values=(), K=K, P=P)
+
+
+#: the count-only cases (``values=()``) are named ``valueless_*``, which
+#: sorts after the others: the index in ``sorted(CASES)`` seeds a case, and
+#: the others keep theirs
 CASES = {
     "int32_full_domain": of_dtype(np.int32),
     "int32_sums_that_wrap": sums_that_wrap,
@@ -199,6 +252,28 @@ CASES = {
     "spills_m_plus_1": spilling(SPILL_M + 1),
     "far_future_outlier": far_future_outlier,
     "behind_and_past_the_wrap": behind_and_past_the_wrap,
+    "valueless_sorted_ts_4096_7_64": sorted_ts(4096, 7, 64),
+    "valueless_sorted_ts_8192_100_256": sorted_ts(8192, 100, 256),
+    "valueless_wraparound": valueless(of_dtype(np.int32, K=5, P=32,
+                                               C=4096, live=1.0)),
+    "valueless_empty_chunks": empty_chunks,
+    "valueless_odd_capacity": odd_capacity,
+    "valueless_ring_smaller_than_locality": valueless(of_dtype(np.int32,
+                                                               P=4)),
+    "valueless_keys_above_the_tile": valueless(
+        of_dtype(np.int32, K=K_TILE + 188, C=4096)),
+    "valueless_masked_lanes": valueless(masked_lanes),
+    "valueless_breaks_locality": valueless(breaks_locality),
+    "valueless_late": valueless(late_stream()),
+    "valueless_late_dead_lanes": valueless(late_stream(live=0.6)),
+    "valueless_spills_exactly_m": valueless(spilling(SPILL_M)),
+    "valueless_spills_m_plus_1": valueless(spilling(SPILL_M + 1)),
+    "valueless_far_future_outlier": valueless(far_future_outlier),
+    "valueless_behind_and_past_the_wrap": valueless(behind_and_past_the_wrap),
+    "valueless_one_cell": valueless(one_cell(1)),
+    "valueless_fuzzed_geometry_0": fuzzed_geometry,
+    "valueless_fuzzed_geometry_1": fuzzed_geometry,
+    "valueless_fuzzed_geometry_2": fuzzed_geometry,
 }
 
 
@@ -208,7 +283,7 @@ def test_fold_equals_segment_sum_bit_for_bit(tpu_default_dot, name):  # noqa: F8
     K, P = case["K"], case["P"]
     key, pane, valid = (jnp.asarray(case[f]) for f in ("key", "pane", "valid"))
     values = jax.tree.map(jnp.asarray, case["values"])
-    assert pane_fold_applies(values)
+    assert pane_fold_applies(values) == bool(jax.tree.leaves(values))
     counts, folds, branch, spilled = jax.jit(
         lambda *a: keyed_pane_fold(*a, K, P))(key, pane, valid, values)
     assert (int(branch), int(spilled)) == branch_of(case["pane"],
@@ -220,6 +295,10 @@ def test_fold_equals_segment_sum_bit_for_bit(tpu_default_dot, name):  # noqa: F8
         return jax.ops.segment_sum(jnp.where(valid, v, 0), seg,
                                    num_segments=K * P).reshape(K, P)
     np.testing.assert_array_equal(counts, want(jnp.ones_like(key)))
+    hist = np.zeros((K, P), np.int32)
+    np.add.at(hist, (case["key"][case["valid"]],
+                     case["pane"][case["valid"]] % P), 1)
+    np.testing.assert_array_equal(counts, hist)
     assert counts.dtype == jnp.int32
     for got, v in zip(jax.tree.leaves(folds), jax.tree.leaves(values)):
         assert got.dtype == v.dtype
@@ -277,6 +356,14 @@ def pane_sums(batch, leaf, P, pane_len=16):
                      (np.asarray(batch.ts) // pane_len) % P),
               np.asarray(batch.payload[leaf]).astype(np.int64))
     return want.astype(np.int32)                    # wraps
+
+
+def pane_counts(batch, P, pane_len=16):
+    """numpy: the lanes per (key, pane % P)."""
+    want = np.zeros((K, P), np.int32)
+    np.add.at(want, (np.asarray(batch.key),
+                     (np.asarray(batch.ts) // pane_len) % P), 1)
+    return want
 
 
 IN_ORDER = np.arange(C) // 16         # 256 lanes a pane, 4 panes a chunk
@@ -344,10 +431,14 @@ def test_other_lifts_and_combines_keep_the_scatter_path(name, lift, combine,
     # the value fold's scatter stands outside any cond, as before
     assert any(branch is None for _, branch in found), found
     state = jax.jit(op._g_insert)(state, batch)
-    assert int(state.fold_fallbacks) == int(state.fold_partials) == 0
+    np.testing.assert_array_equal(state.cnt, pane_counts(batch, op.P))
     op.collect_stats(state)
     counters = op.stage_counters()
-    assert not set(FOLD_COUNTERS) & set(counters)     # absent, not 0
+    # the counts took keyed_pane_fold, and its counters say which branch:
+    # the fast one, or (odd capacity) the whole batch's scatters statically
+    assert {k: counters[k] for k in FOLD_COUNTERS} == {
+        "ffat_fold_fallbacks": int(name == "odd_capacity"),
+        "ffat_fold_partials": 0, "ffat_fold_spill_lanes": 0}
     assert counters["ffat_ring_overruns"] == 0
     if name == "odd_capacity":
         np.testing.assert_array_equal(state.panes,
@@ -355,8 +446,10 @@ def test_other_lifts_and_combines_keep_the_scatter_path(name, lift, combine,
 
 
 def test_a_count_lift_passes_the_new_leaf_through():
-    """``ysb``'s branch: the counters leave ``_g_insert`` as the variables
-    that went in (no operation), and none of the fold's are published."""
+    """``ysb``'s branch: the overrun counter leaves ``_g_insert`` as the
+    variable that went in (no operation: nothing is folded from the lift) and
+    is not published; the counts take ``keyed_pane_fold`` with no value
+    leaf, whose three counters say which branch it took."""
     op = engine(lambda t: 1)
     batch = batch_of(IN_ORDER)
     state = op.init_state(jax.eval_shape(
@@ -367,10 +460,21 @@ def test_a_count_lift_passes_the_new_leaf_through():
     assert len(fields) == len(jax.tree.leaves(state))     # a leaf a field
     came_in = dict(zip(fields, jaxpr.invars))
     went_out = dict(zip(fields, jaxpr.outvars))
+    assert went_out["ring_overruns"] is came_in["ring_overruns"]
     for leaf in ("fold_fallbacks", "fold_partials", "fold_spill_lanes",
-                 "ring_overruns"):
-        assert went_out[leaf] is came_in[leaf], leaf
-    assert went_out["cnt"] is not came_in["cnt"]
-    op.collect_stats(jax.jit(op._g_insert)(state, batch))
+                 "cnt"):
+        assert went_out[leaf] is not came_in[leaf], leaf
+    # stragglers a chunk: the counts' partial branch
+    late = IN_ORDER + 16 * 16
+    late[::64] -= 16 * 10
+    stragglers = batch_of(late)
+    state = jax.jit(op._g_insert)(state, stragglers)
+    np.testing.assert_array_equal(state.cnt, pane_counts(stragglers, op.P))
+    np.testing.assert_array_equal(state.panes, state.cnt)
+    op.collect_stats(state)
     assert op.count_lift is True
-    assert not set(FOLD_COUNTERS) & set(op.stage_counters())
+    counters = op.stage_counters()
+    assert "ffat_ring_overruns" not in counters
+    assert {k: counters[k] for k in FOLD_COUNTERS} == {
+        "ffat_fold_fallbacks": 0, "ffat_fold_partials": 1,
+        "ffat_fold_spill_lanes": C // 64}

@@ -5,10 +5,9 @@ CPU, and what the published size made the global-time path of
 ``Win_SeqFFAT`` grow: budgets and counters published for time-based specs, a
 count of the lanes that overran the ring, an EOS flush that goes on until no
 window is open, the scopes below ``insert`` / ``emit`` that the benchmark's
-three readers find the phases by; and the fence around the other four cells'
-step programs."""
+three readers find the phases by (the cells' step programs are fenced in
+``test_step_programs.py``)."""
 
-import hashlib
 import importlib.util
 import json
 import os
@@ -242,10 +241,13 @@ def test_eos_flush_goes_on_past_empty_windows_until_none_is_open(global_time):
         "windows_undelivered_at_eos": 0,
         # the global-time path lists no rows; the per-key one finds the key
         # of its 32 fired windows by comparison with all 16 (PR 37)
-        # (a delay publishes the late lanes, none here); the per-key
-        # path counts its ring overruns too, and how far the keys' clocks
-        # lie apart (every key's last tick is 95)
-        **({"ffat_ring_overruns": 0, "ffat_late_lanes": 0} if global_time
+        # (a delay publishes the late lanes, none here; every batch's counts
+        # take keyed_pane_fold, and a batch of 32 lanes, no whole chunk, its
+        # scatters); the per-key path counts its ring overruns too, and how
+        # far the keys' clocks lie apart (every key's last tick is 95)
+        **({"ffat_ring_overruns": 0, "ffat_late_lanes": 0,
+            "ffat_fold_fallbacks": 2, "ffat_fold_partials": 0,
+            "ffat_fold_spill_lanes": 0} if global_time
            else {"owner_compare_cells": 2 * K * K, "ffat_ring_overruns": 0,
                  "ffat_key_clock_spread": 0,
                  # 4 panes a window in a ring of 16: whole rows
@@ -269,8 +271,10 @@ def test_a_key_with_a_gap_in_its_ticks_does_not_end_the_flush_early():
 
 def test_counters_of_the_count_lift_and_of_count_based_windows():
     """A windowed count folds no value by slot: the overrun count stays out
-    of what it publishes (absent, not 0); a count-based window publishes its
-    run budget and, after the flush, what the flush left."""
+    of what it publishes (absent, not 0), and its counts take
+    ``keyed_pane_fold``, whose branches it counts (batches of 8 lanes, no
+    whole chunk: the scatters); a count-based window publishes its run
+    budget and, after the flush, what the flush left."""
     count = Key_FFAT(lambda t: 1, jnp.add,
                      spec=WindowSpec(10, 10, win_type_t.TB), num_keys=2,
                      pane_capacity=16, max_wins=4)
@@ -279,7 +283,9 @@ def test_counters_of_the_count_lift_and_of_count_based_windows():
     assert count.count_lift is True
     assert count.stage_counters() == {
         "ffat_keys": 2, "ffat_pane_slots": 16, "fired_window_budget": 4,
-        "old_drops": 0, "windows_undelivered_at_eos": 0}
+        "old_drops": 0, "windows_undelivered_at_eos": 0,
+        "ffat_fold_fallbacks": 5, "ffat_fold_partials": 0,
+        "ffat_fold_spill_lanes": 0}
     cb = Key_FFAT(lambda t: t.v, jnp.add, spec=WindowSpec(8, 4), num_keys=2)
     got = run_engine(cb, [0, 1] * 20, np.arange(40), batch=8)
     assert len(got) == 2 * 5 and {v for _, _, v in got} == {8, 4}
@@ -379,140 +385,6 @@ def test_rehearsal_of_the_new_cell_exits_zero(tmp_path):
     assert {"ffat_ring_overruns", "old_drops", "windows_undelivered_at_eos",
             "window_not_key_ffat_value_fold_on_global_time",
             "engine_budgets_not_the_deployments"} < set(last["compared"])
-
-
-def step_operations(name, batch_capacity=8192):
-    """A cell's step program at rehearsal sizes, scope names and source lines
-    stripped: every equation of its jaxpr, nested ones included, in order, as
-    primitive, operand and result types, and what parameters print the same
-    in every process; the call of ``jit(step)`` itself is left out (its
-    signature is the states' leaves, not an operation). -> (count, sha256 of
-    the lines)."""
-    jax.clear_caches()          # a cached inner jit keeps its first call site
-    mod, cfg = load_config(name)
-    _, step, args = chain_step(cfg, mod, batch_capacity)
-    (call,) = jax.make_jaxpr(step)(*args).jaxpr.eqns
-    return operations(equations(call.params["jaxpr"].jaxpr))
-
-
-def operations(eqns):
-    """(count, sha256) of the lines ``step_operations`` makes of these
-    (equation, path) pairs."""
-    lines = []
-    for eqn, _ in eqns:
-        params = sorted(
-            (k, re.sub(r"0x[0-9a-f]+", "0x", str(v)))
-            for k, v in eqn.params.items()
-            if not hasattr(getattr(v, "jaxpr", v), "eqns") and not callable(v)
-            and not isinstance(v, (list, tuple)))
-        lines.append(" ".join([
-            eqn.primitive.name,
-            ",".join(str(getattr(v, "aval", v)) for v in eqn.invars), "->",
-            ",".join(str(v.aval) for v in eqn.outvars), str(params)]))
-    return len(lines), hashlib.sha256("\n".join(lines).encode()).hexdigest()
-
-
-#: ``step_operations`` of ``ysb`` at PR 33's commit (64560ca), taken there with
-#: this function (``kcb``, ``ysb_wmr`` and ``kpf`` stood here with it until
-#: PR 37). PR 34 put scopes below ``insert`` and ``emit`` on the path ``ysb``
-#: runs and a counter of ring overruns where the value fold goes by slot
-#: (which ``ysb``'s count lift does not take): names changed, no operation
-#: did. A PR that changes a cell's program on purpose takes the new pair in
-#: ``CHANGED_STEPS``, and says so.
-PARENT_STEPS = {
-    "ysb": (288, "3bf2d779d9de5f066e7fcd70e83a3c3c"
-                 "106173e6b3e12e5fda01330b69b7dca9"),
-}
-
-
-@pytest.mark.parametrize("name", sorted(PARENT_STEPS))
-def test_the_older_cells_step_programs_are_the_parents(name):
-    assert step_operations(name) == PARENT_STEPS[name]
-
-
-#: PR 35 changed ``kff``'s program on purpose: its integer value fold rides
-#: the occupancy histogram's one-hot contraction (``keyed_pane_fold``: one
-#: ``cond`` for counts and values, the two scatters its fallback branch). At
-#: PR 34's commit (8ecdb84) the step read (331, "b0625d6fe9e9746a...").
-#: PR 37 changed ``kcb``, ``ysb_wmr`` and ``kpf`` on purpose: every list of
-#: rows in the two window engines (``Win_Seq``'s body rows and fired windows,
-#: ``segment_run_fold``'s runs, ``Win_SeqFFAT._emit``'s fired windows) finds
-#: its keys through ``ops/segment.py::enumerate_runs``, which at these key
-#: counts compares a row with every key where ``jnp.searchsorted`` looped
-#: (and what a run or a fired window reads of its key's K-sized tables comes
-#: by ``table_lookup``'s select-reduce, not by a take); at PR 36's commit
-#: (992955a) they read (453, "cf2391ad4be611da..."), (749,
-#: "30da5f53c44d9b80...") and (1279, "cb2f241e337299e9..."). ``ysb`` (in
-#: ``PARENT_STEPS``) and ``kff`` (PR 35's pair) list no rows and did not move.
-#: ``kff``'s program changed on purpose again when the fold's fallback grew
-#: its partial branch (the lanes near each chunk's newest pane in the
-#: contraction, the stragglers compacted and scattered, two counters more):
-#: at commit 87463aa the step read (371, "e00cd3c7b7dbb22f..."). The branch
-#: ``kff``'s in-order stream takes did not move (``TAKEN_FOLD_BRANCH``).
-#: ``kcb``'s moved again for its size alone: ``Win_SeqFFAT._emit`` takes
-#: a fired window's whole ring row where a row's ``P`` lanes cost less than
-#: its ``wpanes`` single-element takes, which holds at this rehearsal size
-#: (``P`` 32, 2 panes a window) and not at the cell's (``P`` 4,096): there the
-#: element takes stay. With the element form the step is the pair it read at
-#: commit cd258cb (445, "03555a722655e4d3..."), equation for equation
-#: (``ELEMENT_FORM_STEPS``).
-CHANGED_STEPS = {
-    "kff": (555, "fb889972b69d53450dfcc71606256c7a"
-                 "9af864a3a2dbb6152c0a5a303439118a"),
-    "kcb": (427, "921764b74a9f69ea546e177a8cfce258"
-                 "35ffc8adf3bf744cc39dd59d4d7e39d5"),
-    "ysb_wmr": (729, "9bea6c039a3f24015c46cd932f5776c9"
-                     "b5f4b4f073d0e836b3076763fc2d4405"),
-    "kpf": (1239, "67b249f99659b352f5e55d08e28c5af5"
-                  "72fce9816ad34fd54ed546a86fe7e316"),
-}
-
-
-@pytest.mark.parametrize("name", sorted(CHANGED_STEPS))
-def test_the_step_programs_changed_on_purpose_are_as_recorded(name):
-    assert step_operations(name) == CHANGED_STEPS[name]
-
-
-#: ``step_operations`` of the cells whose window runs ``Win_SeqFFAT._emit``,
-#: its element takes forced, at commit cd258cb (before ``_emit`` could take
-#: whole ring rows)
-ELEMENT_FORM_STEPS = {
-    "kcb": (445, "03555a722655e4d39e8e56654b7eb342"
-                 "37af879e5058edbe40bcbbc4407047bd"),
-}
-
-
-@pytest.mark.parametrize("name", sorted(ELEMENT_FORM_STEPS))
-def test_the_emits_element_form_is_the_parents(name, monkeypatch):
-    import windflow_tpu.operators.win_seqffat as engine
-    monkeypatch.setattr(engine, "ROW_LANE_NS", float("inf"))
-    assert step_operations(name) == ELEMENT_FORM_STEPS[name]
-
-
-#: ``step_operations``' lines of the branch of the window's fold that an
-#: in-order stream takes (the fast one: the outer ``cond``'s second branch
-#: in ``insert/fold``), at commit 87463aa, where ``kff``'s step held no
-#: other ``cond``
-TAKEN_FOLD_BRANCH = {
-    "kff": (84, "de6e080554ea1c022688a85acd3da342"
-                "d523cd1fd7783aa49f2ff186625263b9"),
-}
-
-
-@pytest.mark.parametrize("name", sorted(TAKEN_FOLD_BRANCH))
-def test_the_branch_an_in_order_stream_takes_did_not_move(name):
-    jax.clear_caches()
-    mod, cfg = load_config(name)
-    ops, step, args = chain_step(cfg, mod, 8192)     # step_operations' size
-    (call,) = jax.make_jaxpr(step)(*args).jaxpr.eqns
-    conds = [(eqn, path) for eqn, path in equations(call.params["jaxpr"].jaxpr)
-             if eqn.primitive.name == "cond"]
-    (outer, path), (inner, _) = conds
-    assert path == f"{ops[-1].scope_name()}/insert/fold"
-    # the partial branch's cond lies inside the outer one's first branch
-    assert inner in outer.params["branches"][0].jaxpr.eqns
-    fast = outer.params["branches"][1].jaxpr
-    assert operations(equations(fast)) == TAKEN_FOLD_BRANCH[name]
 
 
 @pytest.mark.parametrize("name", ["kcb", "kpf"])

@@ -4,8 +4,8 @@ rest by a lag of their own) at rehearsal sizes on the CPU: its reference
 against a tuple-by-tuple simulation of per-key ``Triggerer_TB``, the served
 path against the reference, both controls failing, the per-key arm's ring
 overruns and key-clock spread counted, the checks on the stage, the scopes
-and readers of the per-key phases, and the fence around the other cells'
-step programs."""
+and readers of the per-key phases (the cells' step programs are fenced in
+``test_step_programs.py``)."""
 
 import importlib.util
 import json
@@ -20,7 +20,6 @@ import ml_dtypes
 import numpy as np
 import pytest
 
-from test_kff_config import step_operations
 from test_ysb_wmr_config import (BATCH, BENCH, ROOT, as_grid, chain_step,
                                  equations, load_config, run_config,
                                  run_engine)
@@ -363,23 +362,7 @@ def test_a_program_without_the_spread_counter_is_refused_at_import(
         load_config("kff_lag")
 
 
-# ---- tracing: the per-key phases' scopes, and what they left as it was ---
-
-#: ``step_operations`` of ``kff_late`` at commit 448ed3d, taken there with
-#: that function: the counters this configuration added are time-based
-#: per-key only, so the global-time path with a delay is the parent's
-#: equation for equation (``kff``, ``kcb`` and ``ysb``:
-#: ``test_kff_config.py``, ``CHANGED_STEPS`` and ``PARENT_STEPS``)
-PARENT_STEPS = {
-    "kff_late": (575, "e8c7f4f69d5e006ee4f9a767899172ae"
-                      "010ea3241c962114130c8b3e62ffbe0a"),
-}
-
-
-@pytest.mark.parametrize("name", sorted(PARENT_STEPS))
-def test_the_global_time_step_with_a_delay_is_the_parents(name):
-    assert step_operations(name) == PARENT_STEPS[name]
-
+# ---- tracing: the per-key phases' scopes --------------------------------
 
 def emit_gathers(jaxpr):
     """The result shapes of the gathers under ``emit/gather``."""

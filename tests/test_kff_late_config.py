@@ -7,7 +7,6 @@ firing boundary against the reference's, both controls failing, the two new
 readers, and the scope that tells the value fold's scatter fallback apart."""
 
 import dataclasses
-import hashlib
 import importlib.util
 import json
 import math
@@ -26,10 +25,10 @@ from test_ysb_wmr_config import (BATCH, BENCH, ROOT, as_grid, chain_step,
                                  equations, load_config, run_config)
 from windflow_tpu.observability import names
 from windflow_tpu.observability.names import STAGE_COUNTERS
-from windflow_tpu.ops.histogram import keyed_pane_fold
 from windflow_tpu.operators.win_patterns import Key_FFAT
 
 from test_keyed_pane_fold import branch_of
+from test_step_programs import fold_program
 from windflow_tpu.ops.histogram import FOLD_PARTIAL
 
 import judge  # noqa: E402 - test_ysb_wmr_config put benchmark/ on the path
@@ -310,75 +309,7 @@ def test_a_run_without_lateness_is_not_correct():
     assert mod.program_checks(cfg, ops)["late_lanes_absent"] == (1, 0)
 
 
-# ---- tracing: the fallback's scope, and what it leaves as it was --------
-
-def jaxpr_operations(jaxpr):
-    """(count, sha256) of every equation, nested ones included, without the
-    scopes (``test_kff_config.py::step_operations``' form)."""
-    lines = []
-    for eqn, _ in equations(jaxpr):
-        params = sorted(
-            (k, re.sub(r"0x[0-9a-f]+", "0x", str(v)))
-            for k, v in eqn.params.items()
-            if not hasattr(getattr(v, "jaxpr", v), "eqns") and not callable(v)
-            and not isinstance(v, (list, tuple)))
-        lines.append(" ".join([
-            eqn.primitive.name,
-            ",".join(str(getattr(v, "aval", v)) for v in eqn.invars), "->",
-            ",".join(str(v.aval) for v in eqn.outvars), str(params)]))
-    return len(lines), hashlib.sha256("\n".join(lines).encode()).hexdigest()
-
-
-#: ``keyed_pane_fold`` at C = 8,192, K = 8, P = 256 at commit b38da88: the
-#: fallback branch (the whole batch's two scatters) and the fast one. The
-#: partial branch changed the program on purpose around them: there the whole
-#: program read (158, "75e125695bd7babc...")
-PARENT_FOLD = {
-    "scatter": (53, "8bae431394fb553754e85c515b668dfd"
-                    "ddecfabe4dda694b6fbb23f000a1cbac"),
-    "fast": (84, "46f2b8ef40059876777ea2e167af7ded"
-                 "148785a6bc219b755bc2c3bd05784858"),
-}
-#: the same program with the partial branch: the whole of it, and the partial
-#: branch (the contraction at each chunk's newest window, the compaction of
-#: the stragglers and their scatter)
-CHANGED_FOLD = {
-    "whole": (338, "f22ac6f677a322ff204422b3146a78f8"
-                   "c506b47304c67c34fc705646ac2923bd"),
-    "partial": (152, "748153c92391649ed1586c9a61787dde"
-                     "443fd524d753c11b37a606a7597ee76a"),
-}
-
-
-def fold_program(C=8192):
-    """(jitted fold, its shapes, the whole jaxpr, and its branches by name):
-    the outer ``cond``'s branches are the fallbacks (0) and the fast one (1);
-    inside the former a second ``cond`` holds the whole-batch scatters (0)
-    and the partial branch (1)."""
-    args = ((jax.ShapeDtypeStruct((C,), jnp.int32),) * 2
-            + (jax.ShapeDtypeStruct((C,), bool),
-               jax.ShapeDtypeStruct((C,), jnp.int32)))
-    fold = jax.jit(lambda k, p, v, x: keyed_pane_fold(k, p, v, x, 8, 256))
-    jaxpr = jax.make_jaxpr(fold)(*args).jaxpr.eqns[0].params["jaxpr"].jaxpr
-    (cond,) = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
-    slow, fast = (b.jaxpr for b in cond.params["branches"])
-    (inner,) = [e for e in slow.eqns if e.primitive.name == "cond"]
-    scatter, partial = (b.jaxpr for b in inner.params["branches"])
-    return fold, args, jaxpr, {"fast": fast, "scatter": scatter,
-                               "partial": partial}
-
-
-def test_the_fast_and_scatter_branches_are_the_parents():
-    """The fast branch and the whole batch's scatters are the parent's
-    equation for equation; the whole program and the partial branch are as
-    recorded (``kff``'s and ``ysb``'s whole step programs:
-    ``test_kff_config.py``, ``CHANGED_STEPS`` and ``PARENT_STEPS``)."""
-    _, _, jaxpr, branches = fold_program()
-    assert {name: jaxpr_operations(branches[name])
-            for name in PARENT_FOLD} == PARENT_FOLD
-    assert {"whole": jaxpr_operations(jaxpr),
-            "partial": jaxpr_operations(branches["partial"])} == CHANGED_FOLD
-
+# ---- tracing: the fallback's scope --------------------------------------
 
 def test_the_fallbacks_carry_their_scopes():
     """The whole batch's scatters run under ``scatter``; in the partial

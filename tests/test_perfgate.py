@@ -132,7 +132,7 @@ def test_compare_proxy_advisory_vs_strict():
     from windflow_tpu.observability.names import KERNELS, PERF_PROXY_FAMILIES
     current["proxy"] = {k: {"ns_per_elem": 100.0, "elems": 1}
                         for k in KERNELS + PERF_PROXY_FAMILIES}
-    baseline["proxy"] = {"histogram": {"ns_per_elem": 10.0}}
+    baseline["proxy"] = {"lookup": {"ns_per_elem": 10.0}}
     # default: proxy timings never fail the gate (noisy CI boxes)
     assert perfgate.compare(current, baseline) == []
     strict = perfgate.compare(current, baseline, strict_proxy=True)

@@ -81,7 +81,7 @@ def _build_ysb():
 def _build_mp_matrix():
     """A representative mp-matrix chain (the ``kf_ffat`` + chaining shape of
     ``tests/test_mp_matrix.py``): stateless map/filter fused ahead of a
-    keyed TB FFAT window — the fold path the segment/histogram kernels
+    keyed TB FFAT window — the fold path the segment/pane-fold kernels
     serve."""
     import jax.numpy as jnp
     from ..basic import win_type_t
@@ -278,7 +278,7 @@ def proxy_microbench(reps: int = 3) -> Dict[str, dict]:
     import jax.numpy as jnp
     import numpy as np
     from ..ops.bitonic import merge_network
-    from ..ops.histogram import keyed_pane_histogram
+    from ..ops.histogram import keyed_pane_fold
     from ..ops.lookup import join_probe, table_lookup
     from ..ops.segment import segment_fold
 
@@ -289,9 +289,9 @@ def proxy_microbench(reps: int = 3) -> Dict[str, dict]:
     key = jnp.asarray(rng.integers(0, K, C).astype(np.int32))
     pane = jnp.asarray((np.arange(C) // 200).astype(np.int32))
     ok = jnp.asarray(rng.random(C) < 0.9)
-    f = jax.jit(lambda a, b, c: keyed_pane_histogram(a, b, c, K, P))
-    out["histogram"] = {"elems": C, "seconds": _bench_one(f, key, pane, ok,
-                                                          reps=reps)}
+    f = jax.jit(lambda a, b, c: keyed_pane_fold(a, b, c, (), K, P))
+    out["pane_fold_counts"] = {"elems": C, "seconds": _bench_one(
+        f, key, pane, ok, reps=reps)}
 
     KT, CT = 1000, 8192
     table = jnp.asarray(rng.integers(0, 1 << 12, KT).astype(np.int32))

@@ -181,7 +181,7 @@ class TuningCache:
       "ladder": [...], "name": ...}`` — the autotuner's converged rung.
     - **kernel impl winners** (``kernel_tuning_key``, written by
       ``ops/registry.py::persist_winner``): ``{"impl": "pallas", "kernel":
-      "histogram", "spec": ..., "tps": ...}`` — the per-backend registry
+      "segment_fold", "spec": ..., "tps": ...}`` — the per-backend registry
       warm-starts kernel selection from these, so a chain's first trace
       already uses the best known implementation for this device.
 

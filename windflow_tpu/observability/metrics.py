@@ -1137,16 +1137,17 @@ class MetricsRegistry:
                                           "undelivered",
             "ffat_ring_overruns": "tuples folded into a pane-ring slot whose "
                                   "pane had not fired yet",
-            "ffat_fold_fallbacks": "batches whose pane value fold took the "
+            "ffat_fold_fallbacks": "batches whose pane fold took the "
                                    "whole batch's scatters (a chunk held "
                                    "more stragglers than the partial "
-                                   "branch scatters)",
-            "ffat_fold_partials": "batches whose pane value fold took the "
+                                   "branch scatters, or the batch is no "
+                                   "whole number of chunks)",
+            "ffat_fold_partials": "batches whose pane fold took the "
                                   "partial branch (ticks out of order "
                                   "inside a chunk: its stragglers were "
                                   "scattered)",
             "ffat_fold_spill_lanes": "lanes the partial branch of the pane "
-                                     "value fold scattered",
+                                     "fold scattered",
             "ffat_late_lanes": "tuples folded after a window holding them "
                                "had fired (counted in the open windows "
                                "alone)",

@@ -215,9 +215,9 @@ STAGE_COUNTERS = (
     # operators/win_seqffat.py, global-time path: lanes folded into a ring slot
     # whose pane had not fired (their pane lay ffat_pane_slots or more past
     # the first unfired one); it publishes windows_undelivered_at_eos too;
-    # batches whose integer value fold left the histogram's one-hot
-    # contraction for the whole batch's exact scatters (a chunk held more
-    # stragglers than the partial branch scatters); batches that took the
+    # batches whose pane fold (keyed_pane_fold: the counts, and the values
+    # that ride them) took the whole batch's exact scatters (a chunk held
+    # more stragglers than the partial branch scatters); batches that took the
     # partial branch (a chunk spanned too many panes: its stragglers were
     # scattered), and those stragglers; lanes folded after a window holding
     # them had fired (delay > 0 only: they count in the windows still open);
@@ -410,7 +410,6 @@ TENANT_GAUGES = (
 #: also enumerate this tuple, so a registered-but-unbenchmarked kernel fails
 #: ``tests/test_perfgate.py``.
 KERNELS = (
-    "histogram",        # ops/histogram.py keyed_pane_histogram
     "lookup",           # ops/lookup.py table_lookup (factored path)
     "ordering_merge",   # parallel/ordering.py bitonic merge/sort network
     "segment_fold",     # ops/segment.py segment_fold (window fold path)
@@ -421,6 +420,9 @@ KERNELS = (
 #: cover (``analysis/perfgate.py::compare``: a family without a proxy row is
 #: a coverage finding, the KERNELS convention).
 PERF_PROXY_FAMILIES = (
+    # "pane_fold_counts" times ops/histogram.py keyed_pane_fold with no
+    # value leaf: the count lift of Key_FFAT's global-time insert
+    "pane_fold_counts",
     # "join" times the full versioned JoinTable step (upsert + registry
     # probe, ops/lookup.py join_table_*) — the probe kernels keep their
     # microbench or tests/test_perfgate.py fails coverage
@@ -456,5 +458,4 @@ NEXMARK_QUERIES = (
 KERNEL_IMPLS = (
     "xla",              # reference formulation — always registered
     "pallas",           # fused Pallas kernel (TPU; interpret mode on CPU)
-    "pallas_mm",        # histogram only: static-store matmul placement
 )

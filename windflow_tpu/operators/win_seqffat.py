@@ -101,11 +101,11 @@ class GFFATState:
     #: pane lay P or more past the first unfired one (counted where the fold
     #: goes by slot; the count-lift branch passes it through)
     ring_overruns: jax.Array
-    #: i32[] batches whose integer value fold took the whole-batch scatters
-    #: (a chunk of the batch held more stragglers than the partial branch
-    #: scatters); only the fold that rides the histogram's contraction counts
+    #: i32[] batches whose pane fold (the counts, and the values that ride
+    #: them) took the whole-batch scatters (a chunk of the batch held more
+    #: stragglers than the partial branch scatters)
     fold_fallbacks: jax.Array
-    #: i32[] batches whose integer value fold took the partial branch (a
+    #: i32[] batches whose pane fold took the partial branch (a
     #: chunk spanned more panes than the one-hot holds: ticks out of order),
     #: and i32[] the lanes that branch scattered
     fold_partials: jax.Array
@@ -145,14 +145,15 @@ class Win_SeqFFAT(Basic_Operator):
     branch folds no value by slot and publishes none), on the per-key
     time-based path ``ffat_key_clock_spread`` (the largest per-key watermark
     less the smallest, over the keys that have had a tuple, in ticks: how far
-    the keys' event clocks lie apart), where an additive integer lift
-    rides the occupancy histogram's contraction (on either time-based
-    path), ``ffat_fold_partials``
+    the keys' event clocks lie apart), on the global-time path (every
+    batch's counts take ``keyed_pane_fold``) and on the per-key one where
+    an additive integer lift rides its contraction, ``ffat_fold_partials``
     (batches whose ticks were out of order: the contraction held the lanes
     near each chunk's newest pane and the stragglers were scattered),
     ``ffat_fold_spill_lanes`` (those stragglers) and ``ffat_fold_fallbacks``
     (batches with a chunk of more stragglers than that: they took the whole
-    batch's exact scatters; all three 0 for an in-order stream) and, where
+    batch's exact scatters; all three 0 for an in-order stream in batches
+    of whole chunks) and, where
     the spec allows
     lateness (``delay > 0``), ``ffat_late_lanes`` (lanes folded after a
     window that holds them had fired: they count in the windows still open);
@@ -206,9 +207,9 @@ class Win_SeqFFAT(Basic_Operator):
         self.P = None
         self.max_wins = max_wins
         self._w = None
-        #: whether the value fold shares the occupancy histogram's contraction
-        #: (settled by the first trace of ``_g_insert`` or, time-based per-key,
-        #: ``_insert``, from the lift's result)
+        #: whether the time-based per-key value fold shares the counts'
+        #: contraction (settled by the first trace of ``_insert``, from the
+        #: lift's result)
         self._fold_rides = False
         self.bind_geometry(256)        # provisional; compiler re-binds with real C
 
@@ -338,28 +339,19 @@ class Win_SeqFFAT(Basic_Operator):
 
     def _g_insert(self, state: GFFATState, batch: Batch):
         """Fold a batch into the [K, P] pane ring. The occupancy counts go
-        through the MXU histogram (``ops/histogram.py``: a chunk-local one-hot
-        contraction with an exact scatter fallback under one ``lax.cond``)
-        instead of a serialized scatter-add, and so do the partials where the
-        lift allows it, which the code sees for itself: a count-like lift
-        (lift(t) == 1, the YSB/windowed-count case) IS the count histogram,
-        and an additive lift whose leaves are ``[C]`` integers of at most 4
-        bytes rides the counts' contraction as 8-bit limbs
-        (``keyed_pane_fold``: bit for bit ``segment_sum``; a batch whose
-        chunks are not all local in their panes folds the lanes near each
-        chunk's newest pane in the contraction and scatters the stragglers
-        alone, and only a chunk with more stragglers than that branch takes
-        sends the whole batch to the scatters: ``fold_partials`` ->
-        ``ffat_fold_partials``, ``fold_spill_lanes`` ->
-        ``ffat_fold_spill_lanes`` and ``fold_fallbacks`` ->
-        ``ffat_fold_fallbacks`` count them). Floats,
-        leaves of higher rank, odd capacities and every other combine take the
-        segment-fold path (``ops/segment.py::segment_reduce``) beside the
-        count histogram. ``"histogram"`` and ``"segment_fold"`` are
-        kernel-registry families (``ops/registry.py``: the impl is resolved at
-        trace time per kernel, shape spec and device, so those call sites A/B
-        between XLA and the Pallas kernels via ``WF_KERNEL_IMPL``);
-        ``keyed_pane_fold`` has the XLA form alone. Slot cleanliness is
+        through ``ops/histogram.py::keyed_pane_fold`` (a chunk-local one-hot
+        contraction on the MXU, with a partial and a whole scatter fallback
+        under ``lax.cond``) instead of a serialized scatter-add, and so do
+        the partials where the lift allows it, which the code sees for
+        itself: a count-like lift (lift(t) == 1, the YSB/windowed-count
+        case) IS the counts, and an additive lift whose leaves are ``[C]``
+        integers of at most 4 bytes rides the counts' contraction as 8-bit
+        limbs (bit for bit ``segment_sum``). Floats, leaves of higher rank,
+        odd capacities and every other combine fold the values by
+        ``ops/segment.py::segment_reduce`` beside the counts. The fold's
+        branches are counted: ``fold_partials`` -> ``ffat_fold_partials``,
+        ``fold_spill_lanes`` -> ``ffat_fold_spill_lanes`` and
+        ``fold_fallbacks`` -> ``ffat_fold_fallbacks``. Slot cleanliness is
         maintained by clear-on-fire in ``_g_emit`` so no pane-id bookkeeping is
         needed; OLD tuples (pane already fired) are dropped with a scalar
         horizon compare. A kept lane whose ``ts`` an already fired window
@@ -368,14 +360,13 @@ class Win_SeqFFAT(Basic_Operator):
         (``late_lanes`` -> ``ffat_late_lanes``). The ring holds the ``P``
         panes from the first unfired
         one: a lane further ahead shares its slot with a pane that has not
-        fired, and where the partials are folded by slot such lanes are
-        counted (``ring_overruns`` -> ``ffat_ring_overruns``; size the ring
-        with ``pane_capacity=``). Scopes: ``fold`` (the lift, the value fold
-        and, where the values ride it, the contraction that also counts; the
-        add into the ring), ``hist`` (the occupancy histogram where it runs
-        alone; the add into ``cnt``)."""
+        fired, and where the partials are folded from the lift such lanes
+        are counted (``ring_overruns`` -> ``ffat_ring_overruns``; size the
+        ring with ``pane_capacity=``). Scopes: ``fold`` (the lift, the
+        contraction that counts and folds what rides it, the values'
+        scatter, the add into the ring), ``hist`` (the add into ``cnt``)."""
         from ..ops.histogram import (FOLD_PARTIAL, FOLD_WHOLE, keyed_pane_fold,
-                                     keyed_pane_histogram, pane_fold_applies)
+                                     pane_fold_applies)
         K, P = self.num_keys, self.P
         pane = batch.ts // self.pane_len
         horizon = state.next_win * self.spanes       # first un-fired pane (global)
@@ -396,57 +387,43 @@ class Win_SeqFFAT(Basic_Operator):
             self.count_lift = _detect_count_lift(self.lift, batch)
         tuples = TupleRef(key=batch.key, id=batch.id, ts=batch.ts,
                           data=batch.payload)
+        counts_are_values = self._counts_are_values()
         # integers to add: counts and values share one contraction
-        rides = self._fold_rides = (
-            not self._hist_is_fold() and self.combine is jnp.add
-            and pane_fold_applies(jax.eval_shape(jax.vmap(self.lift), tuples)))
-        if not rides:
-            with jax.named_scope("hist"):
-                cnt_upd = keyed_pane_histogram(batch.key, pane, valid, K, P)
-                cnt = state.cnt + cnt_upd
+        rides = (not counts_are_values and self.combine is jnp.add
+                 and pane_fold_applies(jax.eval_shape(jax.vmap(self.lift),
+                                                      tuples)))
         ring_overruns = state.ring_overruns
-        fold_fallbacks = state.fold_fallbacks
-        fold_partials = state.fold_partials
-        fold_spill_lanes = state.fold_spill_lanes
         with jax.named_scope("fold"):
-            if self._hist_is_fold():
-                # lift == 1: the value histogram IS the count histogram
-                panes = jax.tree.map(
-                    lambda t: t + cnt_upd.astype(t.dtype), state.panes)
-            else:
-                if not rides:
-                    slot = pane % P
-                    seg = jnp.where(valid, batch.key * P + slot, K * P)
+            if not counts_are_values:
                 # the ring holds panes [horizon, horizon + P): a lane further
                 # ahead lands in the slot of a pane that has not fired yet
                 ring_overruns = ring_overruns + jnp.sum(
                     (valid & (pane >= horizon + P)).astype(CTRL_DTYPE))
                 lifted = jax.vmap(self.lift)(tuples)
-                if rides:
-                    cnt_upd, upd, branch, spilled = keyed_pane_fold(
-                        batch.key, pane, valid, lifted, K, P)
-                    fold_fallbacks = fold_fallbacks + (
-                        branch == FOLD_WHOLE).astype(CTRL_DTYPE)
-                    fold_partials = fold_partials + (
-                        branch == FOLD_PARTIAL).astype(CTRL_DTYPE)
-                    fold_spill_lanes = fold_spill_lanes + spilled
-                    panes = jax.tree.map(jnp.add, state.panes, upd)
-                elif self.combine is jnp.add:
-                    upd = segment_reduce(lifted, seg, valid, K * P)
-                    panes = jax.tree.map(
-                        lambda t, u: t + u.reshape((K, P) + u.shape[1:]),
-                        state.panes, upd)
-                else:
-                    upd = segment_reduce(lifted, seg, valid, K * P,
-                                         combine=self.combine,
-                                         identity=self.identity)
-                    panes = jax.tree.map(
-                        lambda t, u: self.combine(
-                            t, u.reshape((K, P) + u.shape[1:])),
-                        state.panes, upd)
-        if rides:
-            with jax.named_scope("hist"):
-                cnt = state.cnt + cnt_upd
+            cnt_upd, upd, branch, spilled = keyed_pane_fold(
+                batch.key, pane, valid, lifted if rides else (), K, P)
+            fold_fallbacks = state.fold_fallbacks + (
+                branch == FOLD_WHOLE).astype(CTRL_DTYPE)
+            fold_partials = state.fold_partials + (
+                branch == FOLD_PARTIAL).astype(CTRL_DTYPE)
+            fold_spill_lanes = state.fold_spill_lanes + spilled
+            if counts_are_values:
+                panes = jax.tree.map(
+                    lambda t: t + cnt_upd.astype(t.dtype), state.panes)
+            elif rides:
+                panes = jax.tree.map(jnp.add, state.panes, upd)
+            else:
+                seg = jnp.where(valid, batch.key * P + pane % P, K * P)
+                upd = segment_reduce(
+                    lifted, seg, valid, K * P,
+                    combine=None if self.combine is jnp.add else self.combine,
+                    identity=self.identity)
+                panes = jax.tree.map(
+                    lambda t, u: self.combine(
+                        t, u.reshape((K, P) + u.shape[1:])),
+                    state.panes, upd)
+        with jax.named_scope("hist"):
+            cnt = state.cnt + cnt_upd
         wm_new = jnp.maximum(state.wm,
                              jnp.max(jnp.where(batch.valid, batch.ts, -1)))
         lat = state.lat_hist
@@ -470,9 +447,9 @@ class Win_SeqFFAT(Basic_Operator):
             late_lanes=late_lanes,
         )
 
-    def _hist_is_fold(self) -> bool:
-        """A count lift under an additive combine: the occupancy histogram is
-        the value fold, and nothing is folded by ring slot."""
+    def _counts_are_values(self) -> bool:
+        """A count lift (lift == 1) under an additive combine: the pane
+        counts are the value fold, and nothing is folded by ring slot."""
         return bool(self.count_lift) and self.combine is jnp.add
 
     def _g_emit(self, state: GFFATState, W_n: int, flush: bool):
@@ -936,8 +913,9 @@ class Win_SeqFFAT(Basic_Operator):
         them (a lift that reads the tuple: the count-lift branch folds no
         value by slot and publishes none); on the per-key time-based path
         ``ffat_key_clock_spread``; ``ffat_fold_fallbacks``,
-        ``ffat_fold_partials`` and ``ffat_fold_spill_lanes`` where the value
-        fold rides the histogram's contraction (either time-based path); on
+        ``ffat_fold_partials`` and ``ffat_fold_spill_lanes`` on the
+        global-time path (every batch's counts take ``keyed_pane_fold``) and
+        on the per-key one where the value fold rides its contraction; on
         the global-time one ``ffat_late_lanes`` where the spec allows
         lateness;
         for time-based specs the fired-window budget once it is settled."""
@@ -951,15 +929,15 @@ class Win_SeqFFAT(Basic_Operator):
             counters.update(self._fired_budget_gauge())
         counters.update(self._per_key_gauges())
         per_key_time = not (self.global_time or self.spec.is_cb)
-        if per_key_time or (self.global_time and self.count_lift is not None
-                            and not self._hist_is_fold()):
+        global_traced = self.global_time and self.count_lift is not None
+        if per_key_time or (global_traced and not self._counts_are_values()):
             counters["ffat_ring_overruns"] = int(
                 np.asarray(state.ring_overruns))
         if per_key_time:
             wm = np.asarray(state.wm)[np.asarray(state.count) > 0]
             counters["ffat_key_clock_spread"] = (
                 int(wm.max()) - int(wm.min()) if wm.size else 0)
-        if self._fold_rides:
+        if global_traced or self._fold_rides:
             for name in ("fold_fallbacks", "fold_partials",
                          "fold_spill_lanes"):
                 counters["ffat_" + name] = int(np.asarray(getattr(state, name)))

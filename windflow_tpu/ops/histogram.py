@@ -20,12 +20,12 @@ on the MXU:
    of ``(base_r + l) % P`` — column placement into the pane ring, wrap-around
    included. f32 accumulation stays exact while every count ≤ 2^24.
 
-Batches that violate the locality bound (a chunk spanning ≥ L panes — wildly
-out-of-order timestamps) are detected on device and routed through the exact
-scatter-add path with ``lax.cond``: the fast path is an optimization, never a
-semantics change.
+Batches that violate the locality bound (a chunk spanning ≥ L panes: late or
+wildly out-of-order timestamps) are detected on device and routed under
+``lax.cond``: the fast path is an optimization, never a semantics change.
 
-**Values as well as counts** (:func:`keyed_pane_fold`). An additive fold of
+**Values as well as counts.** :func:`keyed_pane_fold` is the one additive
+pane fold. With no value leaf it is the count histogram; an additive fold of
 integers into the same (key, pane) cells rides the same contraction: the local
 pane one-hot is 8 columns wide where the MXU takes 128, so beside the count's
 column group the right-hand operand carries one group per 8-bit limb of each
@@ -38,14 +38,12 @@ int32, and the limbs recombine with wrapping shifts: bit for bit
 partial: a batch whose chunks are not local at their oldest pane folds the
 lanes near each chunk's newest pane in the same contraction and scatters the
 stragglers alone; only a chunk with more stragglers than an eighth of it
-sends the whole batch to the scatters (:func:`keyed_pane_histogram`'s count
-fallback stays all or nothing).
+sends the whole batch to the scatters.
 """
 
 from __future__ import annotations
 
 import math
-import os
 from typing import Any
 
 import jax
@@ -154,94 +152,6 @@ def _ring_onehot(base, locality, P):
             == jnp.arange(P, dtype=slot.dtype)).astype(jnp.float32)
 
 
-def keyed_pane_histogram(key: jax.Array, pane: jax.Array, valid: jax.Array,
-                         num_keys: int, ring: int, *,
-                         chunk: int = DEFAULT_CHUNK, locality: int = DEFAULT_L,
-                         impl: str = None,
-                         ) -> jax.Array:
-    """Count histogram ``out[k, pane % ring] = #{lanes: key==k, pane==p}``.
-
-    ``key``: i32[C] in [0, num_keys); ``pane``: i32[C] (arbitrary, ring-mapped);
-    ``valid``: bool[C]. Returns i32[num_keys, ring]. Exact for any input (locality
-    violations fall back to scatter-add inside the same compiled program).
-
-    ``impl``: "xla" (default; the inline einsum formulation below) or "pallas"
-    (:func:`keyed_pane_histogram_pallas`'s kernel as the fast branch — same
-    locality cond, same scatter fallback). Defaults from the per-backend
-    kernel registry (``ops/registry.py``: ``WF_KERNEL_IMPL``, the deprecated
-    ``WF_HISTOGRAM_IMPL`` alias, or a persisted autotuned winner) so a whole
-    chain can be A/B'd without code changes.
-    """
-    C = key.shape[0]
-    K, P = int(num_keys), int(ring)
-    if C % chunk != 0 or C < chunk:
-        # odd capacities: scatter path (capacities are powers of two in practice)
-        return _scatter_hist(key, pane, valid, K, P)
-    # Force the inputs to materialize before the one-hot tiles consume them.
-    # In a fused chain `key` is often itself the result of a matmul-formulated
-    # lookup (e.g. the YSB campaign join); without the barrier XLA re-fuses
-    # that producer into EVERY K_TILE/locality tile of the histogram,
-    # multiplying the producer's cost by the tile count (measured: the same
-    # histogram is 15 us standalone vs ~5 ms fused in the YSB chain).
-    # Semantics-neutral.
-    key, pane, valid = jax.lax.optimization_barrier((key, pane, valid))
-    R = C // chunk
-
-    base, local, ok_local, in_bounds = _chunk_locality(
-        pane, valid, R, chunk, locality)
-
-    def fast(_):
-        h3 = _chunk_contract(key, local, ok_local, K, R, chunk, locality)
-        ohp = _ring_onehot(base, locality, P)                    # [R*L, P]
-        flat = jnp.transpose(h3, (1, 0, 2)).reshape(K, R * locality)
-        # `flat` holds per-chunk COUNTS (up to `chunk`): HIGHEST, because a
-        # TPU's default f32 dot is one bf16 pass and rounds counts past 256
-        out = jax.lax.dot_general(flat, ohp, (((1,), (0,)), ((), ())),
-                                  precision=jax.lax.Precision.HIGHEST,
-                                  preferred_element_type=jnp.float32)
-        return out.astype(jnp.int32)
-
-    # NOTE: selection (and the WF_HISTOGRAM_FORCE_FAST read below) happens at
-    # TRACE time — a jitted executable compiled before the env change keeps
-    # the old impl for the life of the process (XLA caches the traced
-    # program, not the env). The registry records this choice and validate()
-    # reports disagreements as WF109; for A/B runs force a retrace (fresh
-    # jit / different shapes) or pass impl= explicitly. The old
-    # WF_HISTOGRAM_IMPL toggle is honored as a deprecated registry alias.
-    from .registry import resolve_impl
-    impl = resolve_impl("histogram", impl=impl,
-                        spec_key=f"C{C}xK{K}xP{P}c{chunk}l{locality}")
-    # '0'/empty = off — the WF_ORDERING_SKIP_SORTED convention (a bare bool()
-    # of the string made '0' ENABLE the wrong-answer diagnostic bypass)
-    force_fast = os.environ.get("WF_HISTOGRAM_FORCE_FAST", "0") not in ("", "0")
-    if impl.startswith("pallas"):
-        if P < locality:
-            # the Pallas kernel's single-fold wrap (padded[:, :P] += padded[:,
-            # P:]) assumes locality <= ring; for P < L the [K,P] target vs
-            # [K,L] addend shapes mismatch — route to the exact scatter path
-            # (the XLA fast branch handles any P via % P, but keeping both
-            # guards identical keeps the impls interchangeable)
-            return _scatter_hist(key, pane, valid, K, P)
-        # "pallas": dynamic-slice store of the [K, L] chunk histogram into the
-        # ring (8-wide store at a traced lane offset — Mosaic refuses it on
-        # TPU, see the registration below; interpret mode only). "pallas_mm":
-        # placement by one-hot matmul into the full [K, P+L] block (static
-        # stores only, more VPU adds per chunk) — the form that compiles.
-        placement = "mm" if impl == "pallas_mm" else "ds"
-        fast = lambda _: _pallas_fast(key, pane, valid, K, P,  # noqa: E731
-                                      chunk, locality, placement=placement)
-    if force_fast:
-        # DIAGNOSTIC ONLY (WF_HISTOGRAM_FORCE_FAST): skip the locality cond and
-        # run the fast path unconditionally. If XLA flattens the cond in a
-        # larger program (select-both-branches), the serialized scatter branch
-        # executes every step even though in_bounds is always true — this
-        # bypass isolates that hypothesis in the per-prefix ablation. WRONG for
-        # inputs that violate chunk locality; never set it in production.
-        return fast(None)
-    return jax.lax.cond(in_bounds, fast,
-                        lambda _: _scatter_hist(key, pane, valid, K, P), None)
-
-
 def _scatter_hist(key, pane, valid, K, P):
     seg = jnp.where(valid, key * P + pane % P, K * P)
     return jax.ops.segment_sum(valid.astype(jnp.int32), seg,
@@ -297,14 +207,16 @@ def _from_limbs(sums, dtype):
     return acc.astype(dtype)
 
 
-def _place_group(R: int, chunk: int) -> int:
-    """Chunks whose limb partials one f32 placement dot may add: the largest
-    power of two that divides ``R`` and keeps 255 x chunk x group under 2^24
-    (64 at a chunk of 1,024), so every sum the dot makes is exact whatever the
-    batch holds (all of it one key's one pane at 2^31 - 1 included)."""
-    most = ((1 << 24) - 1) // (255 * chunk)
+def _place_group(R: int, chunk: int, weight: int = 255) -> int:
+    """Chunks whose partials one f32 placement dot may add: the largest
+    power of two that divides ``R`` and keeps ``weight`` x chunk x group
+    under 2^24, so every sum the dot makes is exact whatever the batch holds
+    (all of it one key's one pane at 2^31 - 1 included). For 8-bit limbs
+    (``weight`` 255) that is 64 chunks of 1,024; for counts alone (1), every
+    chunk of a batch of up to 2^24 lanes."""
+    most = ((1 << 24) - 1) // (weight * chunk)
     if most < 1:
-        raise ValueError(f"a chunk of {chunk} lanes can sum a limb past 2^24")
+        raise ValueError(f"a chunk of {chunk} lanes can sum past 2^24")
     return math.gcd(R, 1 << (most.bit_length() - 1))
 
 
@@ -316,10 +228,12 @@ def keyed_pane_fold(key: jax.Array, pane: jax.Array, valid: jax.Array,
     ``(counts i32[K, P], folds: the values' pytree of [K, P], branch,
     spilled)``.
 
-    ``values``: a pytree that :func:`pane_fold_applies` accepts. ``folds``
+    ``values``: a pytree that :func:`pane_fold_applies` accepts, or ``()``:
+    with no leaf the fold is the count histogram
+    ``counts[k, pane % ring] = #{valid lanes: key == k, pane}``. ``folds``
     equals ``jax.ops.segment_sum`` of each masked leaf bit for bit (wrapping at
-    the leaf's width), ``counts`` equals :func:`keyed_pane_histogram`, for any
-    input, whichever of three branches the batch takes (``branch``, i32[]):
+    the leaf's width) and ``counts`` that of the valid lanes, for any input,
+    whichever of three branches the batch takes (``branch``, i32[]):
 
     - :data:`FOLD_FAST`: every chunk of ``chunk`` consecutive lanes lies
       within ``locality`` panes of its oldest valid lane, and the contraction
@@ -333,7 +247,8 @@ def keyed_pane_fold(key: jax.Array, pane: jax.Array, valid: jax.Array,
       stragglers alone are scattered: ``spilled`` (i32[]) counts them.
     - :data:`FOLD_WHOLE`: a chunk spills more than :data:`SPILL_M` lanes, and
       the whole batch takes the two scatters (:func:`_scatter_hist`,
-      ``ops/segment.py::segment_reduce``) under ``scatter``.
+      ``ops/segment.py::segment_reduce``) under ``scatter``. A batch that is
+      not a whole number of chunks takes them statically.
 
     The first test is one ``lax.cond`` whose taken side is the fast branch
     alone; the partial and whole branches lie in its other side, behind a
@@ -341,16 +256,32 @@ def keyed_pane_fold(key: jax.Array, pane: jax.Array, valid: jax.Array,
 
     The contraction's right-hand operand holds ``1 + limbs`` column groups of
     ``locality`` columns (40 for one int32 leaf), under the 128 the MXU takes
-    in one pass, so values cost what counts alone cost. XLA only: the
-    ``"histogram"`` registry family's Pallas forms count and do not fold."""
+    in one pass, so values cost what counts alone cost."""
     from .segment import segment_reduce
     leaves, treedef = jax.tree.flatten(values)
     C = key.shape[0]
     K, P, L = int(num_keys), int(ring), int(locality)
+
+    def scatter(_):
+        # its own scope, so that a profile tells the fallback's device time
+        # from the fast branch's
+        with jax.named_scope("scatter"):
+            seg = jnp.where(valid, key * P + pane % P, K * P)
+            return (_scatter_hist(key, pane, valid, K, P),
+                    [u.reshape(K, P)
+                     for u in segment_reduce(leaves, seg, valid, K * P)])
+
+    if C % chunk != 0 or C < chunk:
+        counts, folds = scatter(None)
+        return (counts, jax.tree.unflatten(treedef, folds),
+                np.int32(FOLD_WHOLE), np.int32(0))
     R = C // chunk
-    group = _place_group(R, chunk)
-    # materialize the inputs before the one-hot tiles consume them
-    # (keyed_pane_histogram: a producer re-fused into every tile)
+    group = _place_group(R, chunk, 255 if leaves else 1)
+    # Materialize the inputs before the one-hot tiles consume them: in a
+    # fused chain ``key`` is often itself the result of a matmul-formulated
+    # lookup (the YSB campaign join), which XLA would otherwise re-fuse into
+    # every K_TILE tile (measured: 15 us standalone, ~5 ms fused in the YSB
+    # chain). Semantics-neutral.
     key, pane, valid, leaves = jax.lax.optimization_barrier(
         (key, pane, valid, leaves))
     base, local, ok_local, in_bounds = _chunk_locality(
@@ -360,12 +291,15 @@ def keyed_pane_fold(key: jax.Array, pane: jax.Array, valid: jax.Array,
         """Counts and every leaf's fold of the lanes ``ok_local`` holds: the
         chunk-local contraction and the ring placement."""
         limbs = [_limbs(v) for v in leaves]
+        J = 1 + sum(map(len, limbs))
         # column group 0 counts (weight 1), then every leaf's limbs; a dead
-        # lane's weights meet an all-zero one-hot row
-        weights = jnp.stack(
-            [jnp.ones((C,), jnp.int32)] + [w for ws in limbs for w in ws],
-            axis=-1).astype(jnp.bfloat16).reshape(R, chunk, -1)
-        J = weights.shape[2]
+        # lane's weights meet an all-zero one-hot row. No leaf: the one-hot
+        # alone counts
+        weights = None
+        if limbs:
+            weights = jnp.stack(
+                [jnp.ones((C,), jnp.int32)] + [w for ws in limbs for w in ws],
+                axis=-1).astype(jnp.bfloat16).reshape(R, chunk, -1)
         h = _chunk_contract(key, local, ok_local, K, R, chunk, L, weights)
         # ring placement, `group` chunks a dot: sums under 2^24, exact in f32
         # (HIGHEST: a TPU's default f32 dot is one bf16 pass)
@@ -386,15 +320,6 @@ def keyed_pane_fold(key: jax.Array, pane: jax.Array, valid: jax.Array,
     def fast(_):
         counts, folds = contract(base, local, ok_local)
         return counts, folds, np.int32(FOLD_FAST), np.int32(0)
-
-    def scatter(_):
-        # its own scope, so that a profile tells the fallback's device time
-        # from the fast branch's
-        with jax.named_scope("scatter"):
-            seg = jnp.where(valid, key * P + pane % P, K * P)
-            return (_scatter_hist(key, pane, valid, K, P),
-                    [u.reshape(K, P)
-                     for u in segment_reduce(leaves, seg, valid, K * P)])
 
     def slow(_):
         base_n, local_n, fits = _chunk_window(pane, valid, R, chunk, L)
@@ -430,121 +355,3 @@ def keyed_pane_fold(key: jax.Array, pane: jax.Array, valid: jax.Array,
 
     counts, folds, branch, spilled = jax.lax.cond(in_bounds, fast, slow, None)
     return counts, jax.tree.unflatten(treedef, folds), branch, spilled
-
-
-def keyed_pane_histogram_pallas(key: jax.Array, pane: jax.Array,
-                                valid: jax.Array, num_keys: int, ring: int, *,
-                                chunk: int = DEFAULT_CHUNK,
-                                locality: int = DEFAULT_L,
-                                placement: str = "ds",
-                                interpret: bool = False) -> jax.Array:
-    """Pallas formulation of :func:`keyed_pane_histogram`'s fast path: one
-    kernel owns the whole ``[C] -> [K, P]`` accumulation, so the chunk one-hots
-    and per-chunk ``[K, L]`` partials live in VMEM for their entire life — no
-    fusion decision XLA can get wrong in a larger program (the YSB chain
-    measures the XLA form at ~5 ms in-chain vs 15 us standalone; this kernel
-    exists to make the standalone cost the only cost).
-
-    Grid = one step per chunk (TPU grids run sequentially, so read-modify-write
-    accumulation into the output ref across steps is sound). Ring wrap-around
-    is handled by padding the ring with ``locality`` spill columns the kernel
-    stores into contiguously (``base % P`` never wraps past ``P + L``) and
-    folding them back afterwards — no in-kernel modular scatter.
-
-    PRECONDITION (caller-enforced, same as the XLA fast path): every chunk
-    spans < ``locality`` panes among its valid lanes. The framework wraps both
-    implementations in the same ``lax.cond`` locality check with the exact
-    scatter path as fallback (``keyed_pane_histogram(..., impl="pallas")``).
-    ``interpret=True`` runs the kernel in Pallas interpret mode (CPU-testable;
-    auto-enabled on the CPU backend)."""
-    C = key.shape[0]
-    K, P = int(num_keys), int(ring)
-    if C % chunk != 0 or C < chunk or P < locality:
-        # P < locality: the kernel's single-fold wrap-around (one [K, L] spill
-        # block folded onto the ring head) is shape-mismatched and arithmetically
-        # wrong when the spill spans the ring more than once — exact scatter
-        return _scatter_hist(key, pane, valid, K, P)
-    return _pallas_fast(key, pane, valid, K, P, chunk, locality,
-                        placement=placement, interpret=interpret)
-
-
-def _pallas_fast(key, pane, valid, K, P, chunk, locality, *,
-                 placement: str = "ds", interpret: bool = False):
-    import jax.experimental.pallas as pl
-    from .registry import pallas_interpret
-
-    C = key.shape[0]
-    L = int(locality)
-    R = C // chunk
-    big = jnp.iinfo(pane.dtype).max
-    interpret = interpret or pallas_interpret()
-
-    def kern(key_ref, pane_ref, valid_ref, out_ref):
-        r = pl.program_id(0)
-
-        @pl.when(r == 0)
-        def _zero():
-            out_ref[...] = jnp.zeros_like(out_ref)
-
-        kc = key_ref[...]
-        pc = pane_ref[...]
-        vc = valid_ref[...] != 0
-        base = jnp.min(jnp.where(vc, pc, big))
-        base = jnp.where(base == big, 0, base)
-        local = pc - base
-        ok = vc & (local < L)
-        lr = jnp.where(ok, local, 0)
-        # dead lanes are masked through the key operand (-1 matches no key):
-        # Mosaic has no i1 [chunk] -> [chunk, 1] reshape for `ok[:, None]`
-        km = jnp.where(ok, kc, -1)
-        ohk = (km[:, None] == jax.lax.broadcasted_iota(
-            kc.dtype, (chunk, K), 1)).astype(jnp.bfloat16)
-        ohl = (lr[:, None] == jax.lax.broadcasted_iota(
-            lr.dtype, (chunk, L), 1)).astype(jnp.bfloat16)
-        h = jax.lax.dot_general(ohk, ohl, (((0,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # [K, L]
-        start = base % P                      # [0, P): contiguous in P + L cols
-        if placement == "ds":
-            cur = out_ref[:, pl.ds(start, L)]
-            out_ref[:, pl.ds(start, L)] = cur + h.astype(jnp.float32)
-        else:
-            # static-store placement: one-hot [L, P+L] matmul scatters the L
-            # columns; the accumulate touches the whole block but every memory
-            # op has a static shape and offset (always lowers)
-            ohp = (jax.lax.broadcasted_iota(jnp.int32, (L, P + L), 1)
-                   == start + jax.lax.broadcasted_iota(
-                       jnp.int32, (L, P + L), 0)).astype(jnp.float32)
-            out_ref[...] += jax.lax.dot_general(
-                h, ohp, (((1,), (0,)), ((), ())),
-                precision=jax.lax.Precision.HIGHEST,    # h holds counts
-                preferred_element_type=jnp.float32)
-
-    padded = pl.pallas_call(
-        kern,
-        grid=(R,),
-        in_specs=[pl.BlockSpec((chunk,), lambda r: (r,)),
-                  pl.BlockSpec((chunk,), lambda r: (r,)),
-                  pl.BlockSpec((chunk,), lambda r: (r,))],
-        out_specs=pl.BlockSpec((K, P + L), lambda r: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((K, P + L), jnp.float32),
-        interpret=interpret,
-    )(key, pane, valid.astype(jnp.int32))
-    # fold the spill columns back onto the ring head (wrap-around completion)
-    out = padded[:, :P].at[:, :L].add(padded[:, P:])
-    return out.astype(jnp.int32)
-
-
-# ------------------------------------------------------------- registration
-
-from .registry import register_kernel  # noqa: E402  (registration footer)
-
-register_kernel("histogram", "xla", keyed_pane_histogram, reference=True,
-                backends=("xla",), default=True)
-register_kernel("histogram", "pallas", keyed_pane_histogram_pallas,
-                backends=("pallas-interpret",),
-                tpu_refusal="cannot statically prove that index in dimension "
-                            "1 is a multiple of 128 — the [K, L] chunk "
-                            "histogram is stored at the traced lane offset "
-                            "base % P (TPU v5 lite, jax 0.9.0)")
-register_kernel("histogram", "pallas_mm", keyed_pane_histogram_pallas,
-                backends=("pallas-tpu", "pallas-interpret"))

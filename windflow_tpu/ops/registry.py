@@ -1,7 +1,7 @@
 """Per-backend kernel registry — ONE selection point for every hot-op impl.
 
-PRs 1-5 grew ad-hoc trace-time env toggles (``WF_HISTOGRAM_IMPL``,
-``WF_LOOKUP_IMPL``) next to each kernel. This module promotes them into a
+Ad-hoc trace-time env toggles (``WF_LOOKUP_IMPL`` among them) once sat
+next to each kernel. This module promotes them into a
 real portability layer (the selection architecture of arXiv:2601.17526):
 every kernel family with more than one implementation — the XLA reference
 formulation, a fused Pallas kernel, its interpret-mode fallback — registers
@@ -12,17 +12,17 @@ Selection is keyed on (kernel, shape/dtype spec key, device kind) and
 resolves in precedence order:
 
 1. an explicit ``impl=`` argument at the call site (always wins);
-2. ``WF_KERNEL_IMPL`` — per-kernel (``"histogram=pallas,lookup=xla"``) or
+2. ``WF_KERNEL_IMPL`` — per-kernel (``"segment_fold=pallas,lookup=xla"``) or
    global (``"pallas"``) override;
-3. the deprecated per-kernel aliases (``WF_HISTOGRAM_IMPL``,
-   ``WF_LOOKUP_IMPL``) — still honored, read HERE and nowhere else;
+3. the deprecated per-kernel alias ``WF_LOOKUP_IMPL`` — still honored,
+   read HERE and nowhere else;
 4. a persisted autotuned winner from the PR 3 :class:`~windflow_tpu.control.
    autotune.TuningCache` (``attach_tuning_cache``), so chains warm-start
    with the best known impl for this (kernel, spec, device);
 5. the kernel's registered default (the XLA reference).
 
-TRACE-TIME HAZARD (the documented footgun of ``ops/lookup.py``/``ops/
-histogram.py``, now checkable): resolution happens at TRACE time, so a
+TRACE-TIME HAZARD (the documented footgun of ``ops/lookup.py``, now
+checkable): resolution happens at TRACE time, so a
 jitted executable compiled before an env/cache change keeps the old impl
 for the life of the process (XLA caches the traced program, not the env).
 Every resolution is therefore RECORDED under its (kernel, spec key, device)
@@ -42,18 +42,15 @@ import threading
 from typing import Callable, Dict, List, Optional, Tuple
 
 def _deprecated_alias_choice(kernel: str) -> Optional[str]:
-    """The deprecated pre-registry toggles (docs/ENV_FLAGS.md marks them
-    deprecated aliases), read HERE and nowhere else — at TRACE time, like
+    """The deprecated pre-registry toggle (docs/ENV_FLAGS.md marks it a
+    deprecated alias), read HERE and nowhere else — at TRACE time, like
     everything in this module. One literal read per flag: the WF201 env
     inventory scanner ties each flag to its ``os.environ`` line. ``''``/
     ``'0'`` = no override (the repo-wide off convention, matching
     WF_KERNEL_IMPL); anything else must be a registered impl name."""
-    if kernel == "histogram":
-        value = os.environ.get("WF_HISTOGRAM_IMPL", "")
-    elif kernel == "lookup":
-        value = os.environ.get("WF_LOOKUP_IMPL", "")
-    else:
+    if kernel != "lookup":
         return None
+    value = os.environ.get("WF_LOOKUP_IMPL", "")
     return None if value in ("", "0") else value
 
 
@@ -119,7 +116,7 @@ def pallas_backend() -> str:
 
 def _parse_kernel_impl_env(value: str) -> Dict[str, str]:
     """``WF_KERNEL_IMPL`` grammar: ``"pallas"`` (global default under key
-    ``"*"``) or ``"histogram=pallas,lookup=xla"`` (per-kernel); entries
+    ``"*"``) or ``"segment_fold=pallas,lookup=xla"`` (per-kernel); entries
     without ``=`` set the global default. ``''``/``'0'`` = no override (the
     WF_ORDERING_SKIP_SORTED off convention)."""
     out: Dict[str, str] = {}
@@ -265,23 +262,29 @@ class KernelRegistry:
                 f"kernel {kernel!r} impl {choice!r} does not compile on TPU "
                 f"(Mosaic: {refusal}); select another impl")
         if record and impl is None:
-            dk = device_kind()
-            with self._lock:
-                self._records.setdefault(
-                    (kernel, spec_key, dk), set()).add(choice)
-            # runtime-health ledger (observability/device_health.py): a
-            # resolution observed while a ledger is active journals a
-            # kernel_resolve event — the compile ledger's record of WHICH
-            # impl each executable was traced with (the WF109 evidence,
-            # live). Lazy import + None check: trace-time-rare path, and
-            # this module must stay importable before observability.
-            try:
-                from ..observability import device_health as _dh
-            except ImportError:            # minimal/fixture trees
-                _dh = None
-            if _dh is not None:
-                _dh.note_kernel_resolve(kernel, spec_key, choice, device=dk)
+            self.record_impl(kernel, spec_key, choice)
         return choice
+
+    def record_impl(self, kernel: str, spec_key: str, impl: str) -> None:
+        """Record that a program was traced with ``impl`` under (kernel,
+        spec_key, device): :meth:`resolve_impl` records its choice here, and
+        a call site that routes a shape the choice cannot take to another
+        form records the form it took."""
+        dk = device_kind()
+        with self._lock:
+            self._records.setdefault((kernel, spec_key, dk), set()).add(impl)
+        # runtime-health ledger (observability/device_health.py): a
+        # resolution observed while a ledger is active journals a
+        # kernel_resolve event — the compile ledger's record of WHICH
+        # impl each executable was traced with (the WF109 evidence,
+        # live). Lazy import + None check: trace-time-rare path, and
+        # this module must stay importable before observability.
+        try:
+            from ..observability import device_health as _dh
+        except ImportError:            # minimal/fixture trees
+            _dh = None
+        if _dh is not None:
+            _dh.note_kernel_resolve(kernel, spec_key, impl, device=dk)
 
     # ------------------------------------------------------- WF109 records
 

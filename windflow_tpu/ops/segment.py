@@ -71,17 +71,24 @@ def segment_fold(values: jax.Array, seg: jax.Array, valid: jax.Array,
     is split into 11-bit limbs so every per-chunk one-hot matmul sums are
     f32-exact, and limbs recombine/accumulate with wrapping int32 adds (the
     same two's-complement semantics XLA's integer segment_sum has on
-    overflow). Floats route to the XLA reference inside the same call —
-    selection is an optimization, never a semantics change. Invalid lanes
+    overflow). Floats, more than :data:`FOLD_MAX_SEGMENTS` segments and
+    batches that are not whole chunks route to the XLA reference inside the
+    same call — selection is an optimization, never a semantics change —
+    and the registry's trace record names the form that ran. Invalid lanes
     contribute 0; out-of-range segment ids are dropped (both impls)."""
-    from .registry import resolve_impl
+    from .registry import REGISTRY, resolve_impl
     C, S = values.shape[0], int(num_segments)
-    impl = resolve_impl("segment_fold", impl=impl,
-                        spec_key=f"C{C}xS{S}:{values.dtype}")
-    if (impl == "pallas" and jnp.issubdtype(values.dtype, jnp.integer)
+    spec_key = f"C{C}xS{S}:{values.dtype}"
+    taken = resolve_impl("segment_fold", impl=impl, spec_key=spec_key,
+                         record=False)
+    if not (jnp.issubdtype(values.dtype, jnp.integer)
             and jnp.dtype(values.dtype).itemsize <= 4
             and C % FOLD_CHUNK == 0 and C >= FOLD_CHUNK
             and S <= FOLD_MAX_SEGMENTS):
+        taken = "xla"
+    if impl is None:
+        REGISTRY.record_impl("segment_fold", spec_key, taken)
+    if taken == "pallas":
         return _pallas_segment_fold(values, seg, valid, S,
                                     interpret=interpret)
     return _xla_segment_fold(values, seg, valid, S)

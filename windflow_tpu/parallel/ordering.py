@@ -208,7 +208,7 @@ def _sort_batch(mode, batch: Batch, chan, merge_impl: str = "xla"):
     (bench_ordering_overhead), so XLA:CPU does not flatten this cond into
     select-both-branches; whether XLA:TPU does is A/B-able without code
     changes via ``WF_ORDERING_SKIP_SORTED=0`` (re-enables the unconditional
-    lexsort) — the same diagnostic pattern as WF_HISTOGRAM_FORCE_FAST."""
+    lexsort)."""
     import os
     bp, bs, bc = _masked_keys(mode, batch, chan)
     C = batch.capacity
