@@ -64,6 +64,9 @@ def reference_insert(op, state, batch):
         pane_of=new_pane_of,
         count=state.count + counts_add,
         wm=jnp.maximum(state.wm, ts_max),
+        # a combine on whole structs counts the (key, pane) runs it folded
+        ordered_runs=(None if state.ordered_runs is None else
+                      state.ordered_runs + jnp.sum(touched.astype(CTRL_DTYPE))),
     )
 
 

@@ -112,6 +112,11 @@ def fold_program(C=8192):
 #:   value leaf (the fold's partial branch and its three counters joined
 #:   the step); at 1775387 it read (288, "3bf2d779d9de5f066e7fcd70e83a3c3c"
 #:   "106173e6b3e12e5fda01330b69b7dca9"), a pair unmoved since 64560ca.
+#: - ``step`` of ``kff_drawdown``: first read with the configuration, where
+#:   ``Key_FFAT`` calls a combine that is not ``jnp.add``, ``maximum`` or
+#:   ``minimum`` on whole structs (the ordered fold: sort, segmented scan,
+#:   placement; ``ops/segment.py::ordered_reduce`` over a window's panes),
+#:   followed by ``Map(dd)``.
 PROGRAMS = {
     ("step", "ysb"): (448, "175f7f27f74840bd20e7656fa047a8a1"
                            "d295e466fd92cc56ee157c5d5d2d4174"),
@@ -127,6 +132,8 @@ PROGRAMS = {
                                 "010ea3241c962114130c8b3e62ffbe0a"),
     ("step", "kff_lag"): (728, "57672543f15dfcaac70f1ca36df8a37a"
                                "fe8b7845bdfa36cf3d664dfffbd4f15b"),
+    ("step", "kff_drawdown"): (1614, "b00f6272c95a858099bd987cc9263954"
+                                     "1bd3a5ad312fa108ce0b11309618266b"),
     ("taken_branch", "kff"): (84, "de6e080554ea1c022688a85acd3da342"
                                   "d523cd1fd7783aa49f2ff186625263b9"),
     ("fold", "whole"): (338, "f22ac6f677a322ff204422b3146a78f8"

@@ -227,6 +227,10 @@ STAGE_COUNTERS = (
     # per-key watermark less the smallest, in ticks
     "ffat_ring_overruns", "ffat_fold_fallbacks", "ffat_fold_partials",
     "ffat_fold_spill_lanes", "ffat_late_lanes", "ffat_key_clock_spread",
+    # operators/win_seqffat.py, any path whose combine is called on whole
+    # structs (not add, maximum or minimum): the (key, pane) runs its
+    # ordered fold combined, summed over batches
+    "ffat_ordered_runs",
     # operators/win_patterns.py::Pane_Farm: its two engines' counters, each
     # under its stage's prefix (``plq_old_drops``, ``wlq_archive_overwrites``)
     *(f"{stage}_{counter}" for stage in PANE_STAGES

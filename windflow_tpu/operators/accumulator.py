@@ -22,7 +22,7 @@ import jax.numpy as jnp
 
 from ..basic import routing_modes_t, DEFAULT_MAX_KEYS
 from ..batch import Batch, tuple_refs
-from ..ops.segment import segment_prefix_scan, segment_reduce
+from ..ops.segment import leafwise, segment_prefix_scan, segment_reduce
 from .base import Basic_Operator
 
 
@@ -59,11 +59,13 @@ class Accumulator(Basic_Operator):
     def apply(self, state, batch: Batch):
         vals = jax.vmap(self.value_fn)(tuple_refs(batch))
         # inclusive per-key prefix in stream order, seeded by the HBM table
-        prefix = segment_prefix_scan(vals, batch.key, batch.valid, self.combine,
+        # ``combine`` acts leaf by leaf here; the segment ops take structs
+        fold = leafwise(self.combine)
+        prefix = segment_prefix_scan(vals, batch.key, batch.valid, fold,
                                      self.identity, carry_in=state)
         # update the table with each key's total fold for this batch
         batch_red = segment_reduce(vals, batch.key, batch.valid, self.num_keys,
-                                   combine=None if self.combine is jnp.add else self.combine,
+                                   combine=None if self.combine is jnp.add else fold,
                                    identity=self.identity)
         if self.combine is jnp.add:
             state = jax.tree.map(jnp.add, state, batch_red)

@@ -179,6 +179,11 @@ class Basic_Operator:
         """Drain residual state at EOS. Returns (state, out_batch or None)."""
         return state, None
 
+    def flush_capacity(self, in_capacity: int) -> Optional[int]:
+        """Capacity of the batches ``flush`` emits behind input batches of
+        ``in_capacity``, where it is known ahead (None: unknown, or none)."""
+        return None
+
     def _mark_used(self):
         self._used = True
 

@@ -46,7 +46,7 @@ from ..basic import routing_modes_t, DEFAULT_MAX_KEYS
 from ..batch import Batch, CTRL_DTYPE, TupleRef, tuple_refs
 from ..observability import event_time as _et
 from ..ops.lookup import table_lookup
-from ..ops.segment import segment_reduce
+from ..ops.segment import leafwise, segment_reduce
 from .base import Basic_Operator
 from .window import WindowSpec
 
@@ -261,7 +261,7 @@ class SessionWindow(Basic_Operator):
         fmin = red(sts, jnp.minimum, _IMAX)
         fmax = red(sts, jnp.maximum, _IMIN)
         fcnt = red(jnp.ones((C,), jnp.int32), None, 0)
-        facc = segment_reduce(svals, seg, sv, C, combine=self.combine,
+        facc = segment_reduce(svals, seg, sv, C, combine=leafwise(self.combine),
                               identity=self.identity)
         ffirst = red(first.astype(jnp.int32), jnp.maximum, 0) > 0
         fcont = red(cont.astype(jnp.int32), jnp.maximum, 0) > 0

@@ -13,18 +13,27 @@ segment-reduced into its (key, pane) partial; a fired window combines its
 the same work-sharing as FlatFAT (each tuple touches O(1) partials; each window
 combines O(L/pane) —  with panes = slide that is the "no pane, no gain" decomposition
 the reference's Pane_Farm uses, ``wf/pane_farm.hpp:175``), expressed as segment ops the
-MXU/VPU likes instead of pointer-chasing tree levels. Non-commutative combines are
-supported: pane partials are folded in ascending pane order by an order-preserving
-tree reduction (association changes, operand order does not — the same guarantee as
-FlatFAT's prefix/suffix walks; see ``tests/test_ffat_noncommutative.py``).
+MXU/VPU likes instead of pointer-chasing tree levels.
 
-Requirements: ``combine`` associative with ``identity``; window result =
-``fold(combine, lifted tuples in window)`` — the Win_SeqFFAT contract (winLift +
-winComb functions, ``wf/builders.hpp`` WinSeqFFAT_Builder:950).
+The combine is called on whole lifted structs (upstream's ``result_t``: a field may
+read the others) and may be non-commutative: ``combine(a, b)`` always has the earlier
+tuples in ``a``. A pane's tuples fold in stream order (a stable sort keeps a (key,
+pane)'s lanes in order, the ring's partial comes first), and a window's panes are
+reduced by ``ops/segment.py::ordered_reduce`` (neighbours paired, the older on the
+left: association changes, operand order does not — the guarantee of FlatFAT's
+prefix/suffix walks; see ``tests/test_ffat_noncommutative.py``). ``jnp.add``,
+``jnp.maximum`` and ``jnp.minimum`` act leaf by leaf and keep their own paths.
+
+Requirements: ``combine`` associative with ``identity`` (a pytree prefix of the
+lift's structure: a scalar for every leaf, or a struct of the lift's type with one
+identity a field; ``ops/segment.py::identity_like``); window result = ``fold(combine,
+lifted tuples in window)`` — the Win_SeqFFAT contract (winLift + winComb functions,
+``wf/builders.hpp`` WinSeqFFAT_Builder:950).
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Callable
 
@@ -35,8 +44,9 @@ from ..basic import routing_modes_t, DEFAULT_MAX_KEYS
 from ..batch import Batch, CTRL_DTYPE, TupleRef
 from ..observability import event_time as _et
 from ..ops.lookup import table_lookup
-from ..ops.segment import (enumerate_runs, owner_compare_cells, run_budget,
-                           segment_reduce, segment_run_fold)
+from ..ops.segment import (ELEMENTWISE, enumerate_runs, identity_like,
+                           on_structs, ordered_reduce, owner_compare_cells,
+                           run_budget, segment_reduce, segment_run_fold)
 from .base import Basic_Operator
 from .window import WindowSpec
 
@@ -80,6 +90,9 @@ class FFATState:
     fold_fallbacks: Any = None
     fold_partials: Any = None
     fold_spill_lanes: Any = None
+    #: i32[] (key, pane) runs the ordered fold combined (a combine that is
+    #: not ``ELEMENTWISE``; None otherwise, so that program is unchanged)
+    ordered_runs: Any = None
 
 
 @jax.tree_util.register_dataclass
@@ -119,6 +132,8 @@ class GFFATState:
     #: lateness (``delay`` 0), an empty pytree subtree, so that program is
     #: unchanged
     late_lanes: Any = None
+    #: i32[] (key, pane) runs the ordered fold combined (``FFATState``'s)
+    ordered_runs: Any = None
 
 
 class Win_SeqFFAT(Basic_Operator):
@@ -156,8 +171,11 @@ class Win_SeqFFAT(Basic_Operator):
     of whole chunks) and, where
     the spec allows
     lateness (``delay > 0``), ``ffat_late_lanes`` (lanes folded after a
-    window that holds them had fired: they count in the windows still open);
-    at ``flush`` ``windows_undelivered_at_eos``.
+    window that holds them had fired: they count in the windows still open),
+    on any path whose combine takes whole structs (not ``jnp.add``,
+    ``maximum`` or ``minimum``) ``ffat_ordered_runs`` (the (key, pane) runs
+    its ordered fold combined, summed over batches; absent, not 0,
+    elsewhere); at ``flush`` ``windows_undelivered_at_eos``.
 
     ``flush`` returns one batch of open windows a call and None once none is
     left; a pass that holds only windows without a tuple is passed over."""
@@ -286,19 +304,40 @@ class Win_SeqFFAT(Basic_Operator):
                      ts=jax.ShapeDtypeStruct((), CTRL_DTYPE), data=payload_spec)
         return jax.eval_shape(self.lift, t)
 
+    def _ordered(self) -> bool:
+        """Whether the combine is called on whole structs in stream order
+        (any but ``ELEMENTWISE``): the insert's ordered fold."""
+        return self.combine not in ELEMENTWISE
+
+    def _reduce_panes(self, g, axis):
+        """A window's panes (``axis`` of every leaf of ``g``, empty panes the
+        identity) reduced: ``jnp.maximum`` and ``jnp.minimum`` leaf by leaf,
+        a combine of whole structs in order under ``ordered``."""
+        if not self._ordered():
+            red = jnp.max if self.combine is jnp.maximum else jnp.min
+            return jax.tree.map(lambda x: red(x, axis=axis), g)
+        with jax.named_scope("ordered"):
+            return ordered_reduce(self.combine, g, axis=axis)
+
+    def _ring(self, agg):
+        """The ``[K, P]`` ring of pane partials, every slot the identity."""
+        K, P = self.num_keys, self.P
+        return jax.tree.map(
+            lambda s, ident: jnp.broadcast_to(
+                jnp.asarray(ident, s.dtype), (K, P) + s.shape).copy(),
+            agg, identity_like(self.identity, agg))
+
     def init_state(self, payload_spec: Any):
         K, P = self.num_keys, self.P
         agg = self._lift_spec(payload_spec)
+        runs = jnp.zeros((), CTRL_DTYPE) if self._ordered() else None
         # lateness histogram: event-time monitoring on TB specs only (CB has
         # no event-time frontier); None = absent from the pytree
         lat = (_et.lateness_init()
                if self._event_time and not self.spec.is_cb else None)
         if self.global_time:
             return GFFATState(
-                panes=jax.tree.map(
-                    lambda s: jnp.broadcast_to(
-                        jnp.asarray(self.identity, s.dtype),
-                        (K, P) + s.shape).copy(), agg),
+                panes=self._ring(agg),
                 cnt=jnp.zeros((K, P), CTRL_DTYPE),
                 wm=jnp.asarray(-1, CTRL_DTYPE),
                 next_win=jnp.asarray(0, CTRL_DTYPE),
@@ -310,15 +349,13 @@ class Win_SeqFFAT(Basic_Operator):
                 lat_hist=lat,
                 late_lanes=(jnp.zeros((), CTRL_DTYPE) if self.spec.delay > 0
                             else None),
+                ordered_runs=runs,
             )
         # time-based specs count ring overruns and the fold's branches
         def zero():
             return None if self.spec.is_cb else jnp.zeros((), CTRL_DTYPE)
         return FFATState(
-            panes=jax.tree.map(
-                lambda s: jnp.broadcast_to(
-                    jnp.asarray(self.identity, s.dtype),
-                    (K, P) + s.shape).copy(), agg),
+            panes=self._ring(agg),
             pane_count=jnp.zeros((K, P), CTRL_DTYPE),
             pane_of=jnp.full((K, P), -1, CTRL_DTYPE),
             count=jnp.zeros((K,), CTRL_DTYPE),
@@ -330,6 +367,7 @@ class Win_SeqFFAT(Basic_Operator):
             fold_fallbacks=zero(),
             fold_partials=zero(),
             fold_spill_lanes=zero(),
+            ordered_runs=runs,
         )
 
     def out_spec(self, payload_spec: Any) -> Any:
@@ -351,9 +389,13 @@ class Win_SeqFFAT(Basic_Operator):
         ``ops/segment.py::segment_reduce`` beside the counts. The fold's
         branches are counted: ``fold_partials`` -> ``ffat_fold_partials``,
         ``fold_spill_lanes`` -> ``ffat_fold_spill_lanes`` and
-        ``fold_fallbacks`` -> ``ffat_fold_fallbacks``. Slot cleanliness is
-        maintained by clear-on-fire in ``_g_emit`` so no pane-id bookkeeping is
-        needed; OLD tuples (pane already fired) are dropped with a scalar
+        ``fold_fallbacks`` -> ``ffat_fold_fallbacks``. A combine on whole
+        structs (any but ``ELEMENTWISE``) folds under ``ordered``: the sorted
+        segmented scan (``sort``, ``scan``, ``place``) and the combine into
+        the ring, the ring's partial on the left; the (key, pane) runs it
+        folds are counted (``ordered_runs`` -> ``ffat_ordered_runs``). Slot
+        cleanliness is maintained by clear-on-fire in ``_g_emit`` so no
+        pane-id bookkeeping is needed; OLD tuples (pane already fired) are dropped with a scalar
         horizon compare. A kept lane whose ``ts`` an already fired window
         holds too (``delay`` shorter than its lateness) counts in the open
         windows alone; with ``delay > 0`` such lanes are counted
@@ -364,7 +406,8 @@ class Win_SeqFFAT(Basic_Operator):
         are counted (``ring_overruns`` -> ``ffat_ring_overruns``; size the
         ring with ``pane_capacity=``). Scopes: ``fold`` (the lift, the
         contraction that counts and folds what rides it, the values'
-        scatter, the add into the ring), ``hist`` (the add into ``cnt``)."""
+        scatter or ``ordered`` fold, the add into the ring), ``hist`` (the
+        add into ``cnt``)."""
         from ..ops.histogram import (FOLD_PARTIAL, FOLD_WHOLE, keyed_pane_fold,
                                      pane_fold_applies)
         K, P = self.num_keys, self.P
@@ -393,6 +436,7 @@ class Win_SeqFFAT(Basic_Operator):
                  and pane_fold_applies(jax.eval_shape(jax.vmap(self.lift),
                                                       tuples)))
         ring_overruns = state.ring_overruns
+        ordered_runs = state.ordered_runs
         with jax.named_scope("fold"):
             if not counts_are_values:
                 # the ring holds panes [horizon, horizon + P): a lane further
@@ -414,14 +458,19 @@ class Win_SeqFFAT(Basic_Operator):
                 panes = jax.tree.map(jnp.add, state.panes, upd)
             else:
                 seg = jnp.where(valid, batch.key * P + pane % P, K * P)
-                upd = segment_reduce(
-                    lifted, seg, valid, K * P,
-                    combine=None if self.combine is jnp.add else self.combine,
-                    identity=self.identity)
-                panes = jax.tree.map(
-                    lambda t, u: self.combine(
-                        t, u.reshape((K, P) + u.shape[1:])),
-                    state.panes, upd)
+                with (jax.named_scope("ordered") if ordered_runs is not None
+                      else contextlib.nullcontext()):
+                    upd = segment_reduce(
+                        lifted, seg, valid, K * P,
+                        combine=None if self.combine is jnp.add
+                        else self.combine,
+                        identity=self.identity)
+                    # the ring's partial holds the pane's earlier tuples
+                    panes = on_structs(self.combine)(state.panes, jax.tree.map(
+                        lambda u: u.reshape((K, P) + u.shape[1:]), upd))
+                    if ordered_runs is not None:
+                        ordered_runs = ordered_runs + jnp.sum(
+                            (cnt_upd > 0).astype(CTRL_DTYPE))
         with jax.named_scope("hist"):
             cnt = state.cnt + cnt_upd
         wm_new = jnp.maximum(state.wm,
@@ -445,6 +494,7 @@ class Win_SeqFFAT(Basic_Operator):
             fold_spill_lanes=fold_spill_lanes,
             lat_hist=lat,
             late_lanes=late_lanes,
+            ordered_runs=ordered_runs,
         )
 
     def _counts_are_values(self) -> bool:
@@ -499,11 +549,16 @@ class Win_SeqFFAT(Basic_Operator):
             with jax.named_scope("gather"):
                 g = gat(tbl)                                  # [K, W_n, wpanes, ...]
             with jax.named_scope("reduce"):
-                if self.combine is jnp.add:
-                    m = (cnts > 0).reshape(cnts.shape + (1,) * (g.ndim - 3))
-                    return jnp.sum(jnp.where(m, g, 0), axis=2)
-                return _tree_reduce(self.combine, g, axis=2)
-        results = jax.tree.map(reduce_w, state.panes)         # [K, W_n, ...]
+                m = (cnts > 0).reshape(cnts.shape + (1,) * (g.ndim - 3))
+                return jnp.sum(jnp.where(m, g, 0), axis=2)
+        if self.combine is jnp.add:
+            results = jax.tree.map(reduce_w, state.panes)     # [K, W_n, ...]
+        else:
+            # empty slots hold the identity (cleared on fire)
+            with jax.named_scope("gather"):
+                g = jax.tree.map(gat, state.panes)            # [K, W_n, wpanes, ...]
+            with jax.named_scope("reduce"):
+                results = self._reduce_panes(g, axis=2)
 
         valid = (win_cnt > 0) & w_valid[None, :]              # empty windows not emitted
         res_ts = wid * s.slide + (s.win_len - 1)              # [W_n]
@@ -525,10 +580,10 @@ class Win_SeqFFAT(Basic_Operator):
             rel = (pos - first % P) % P
             clear = rel < (last - first)
             panes = jax.tree.map(
-                lambda t: jnp.where(
+                lambda t, ident: jnp.where(
                     clear.reshape((1, P) + (1,) * (t.ndim - 2)),
-                    jnp.asarray(self.identity, t.dtype), t),
-                state.panes)
+                    jnp.asarray(ident, t.dtype), t),
+                state.panes, identity_like(self.identity, state.panes))
             cnt = jnp.where(clear[None, :], 0, state.cnt)
         return dataclasses.replace(state, panes=panes, cnt=cnt, next_win=hi), out
 
@@ -553,7 +608,9 @@ class Win_SeqFFAT(Basic_Operator):
         Floats, other combines, leaves of higher rank and odd capacities
         keep one segment reduction per table (value and count through the
         registry-selectable ``segment_fold`` kernel, the pane id and the
-        watermark through ``segment_max``): four scatters over the lanes. A
+        watermark through ``segment_max``): four scatters over the lanes; a
+        combine on whole structs takes the sorted segmented scan there, under
+        ``ordered``, and its runs are counted (``ordered_runs``). A
         pane id or a watermark is read only where a count says it was
         written, so neither pays a count of its own. Everything from
         ``touched`` on is shared.
@@ -609,12 +666,15 @@ class Win_SeqFFAT(Basic_Operator):
                         state, batch, pane, valid, lifted, first_win, n_over,
                         fold_counters)
                 else:
-                    # per-(key,pane-slot) partial of this batch
-                    upd = segment_reduce(
-                        lifted, seg, valid, K * P,
-                        combine=None if self.combine is jnp.add
-                        else self.combine,
-                        identity=self.identity)
+                    # per-(key,pane-slot) partial of this batch (the ordered
+                    # fold where the combine takes structs)
+                    with (jax.named_scope("ordered") if self._ordered()
+                          else contextlib.nullcontext()):
+                        upd = segment_reduce(
+                            lifted, seg, valid, K * P,
+                            combine=None if self.combine is jnp.add
+                            else self.combine,
+                            identity=self.identity)
                     cnt_upd = segment_reduce(valid.astype(CTRL_DTYPE), seg,
                                              valid, K * P)
                     # read only where ``touched``: an untouched slot's needs
@@ -627,14 +687,30 @@ class Win_SeqFFAT(Basic_Operator):
             new_pane_of = jnp.where(touched, pane_id_upd.reshape(K, P), state.pane_of)
             # a slot whose pane id advanced (ring wrap) restarts from identity
             fresh = touched & (new_pane_of != state.pane_of)
+            ids = identity_like(self.identity, state.panes)
+            ordered_runs = state.ordered_runs
+            if ordered_runs is not None:
+                ordered_runs = ordered_runs + jnp.sum(
+                    touched.astype(CTRL_DTYPE))
 
-            def fold(tbl, u):
+            def fold(tbl, u, ident):
                 u = u.reshape((K, P) + u.shape[1:])
-                t = jnp.where(_b(fresh, tbl), jnp.asarray(self.identity, tbl.dtype), tbl)
+                t = jnp.where(_b(fresh, tbl), jnp.asarray(ident, tbl.dtype), tbl)
                 m = _b(touched, tbl)
                 if self.combine is jnp.add:
                     return jnp.where(m, t + u, t)
                 return jnp.where(m, self.combine(t, u), t)
+
+            def fold_structs(panes, upd):
+                """``fold`` with the combine on whole structs, the ring's
+                partial (the pane's earlier tuples) on the left."""
+                t = jax.tree.map(lambda tbl, ident: jnp.where(
+                    _b(fresh, tbl), jnp.asarray(ident, tbl.dtype), tbl),
+                    panes, ids)
+                c = self.combine(t, jax.tree.map(
+                    lambda u: u.reshape((K, P) + u.shape[1:]), upd))
+                return jax.tree.map(
+                    lambda t, c: jnp.where(_b(touched, t), c, t), t, c)
 
         if not cb:
             with jax.named_scope("keys"):
@@ -657,7 +733,10 @@ class Win_SeqFFAT(Basic_Operator):
             lat = _et.lateness_update(lat, jnp.max(wm_new), batch.ts,
                                       batch.valid)
         with jax.named_scope("fold"):
-            panes = jax.tree.map(fold, state.panes, upd)
+            if self._ordered():
+                panes = fold_structs(state.panes, upd)
+            else:
+                panes = jax.tree.map(fold, state.panes, upd, ids)
             pane_count = (jnp.where(fresh, 0, state.pane_count)
                           + cnt_upd.reshape(K, P))
         return dataclasses.replace(
@@ -673,6 +752,7 @@ class Win_SeqFFAT(Basic_Operator):
             fold_fallbacks=fold_counters[0],
             fold_partials=fold_counters[1],
             fold_spill_lanes=fold_counters[2],
+            ordered_runs=ordered_runs,
         )
 
     def _pk_fold(self, state: FFATState, batch: Batch, pane, valid, lifted,
@@ -751,8 +831,8 @@ class Win_SeqFFAT(Basic_Operator):
                 init = jnp.broadcast_to(jnp.asarray(fill, rows.dtype),
                                         (K * P,) + rows.shape[1:])
                 return init.at[seg].set(rows, mode="drop")
-            upd = jax.tree.map(lambda rows: table(self.identity, rows),
-                               run_vals)
+            upd = jax.tree.map(lambda rows, ident: table(ident, rows),
+                               run_vals, identity_like(self.identity, run_vals))
             cnt_upd = table(0, runs.length)
             pane_id_upd = table(-1, runs.chunk)
             ts_max = jnp.full((K,), -1, run_ts.dtype).at[
@@ -768,7 +848,7 @@ class Win_SeqFFAT(Basic_Operator):
         (each window's key's whole ``[P]`` ring row out of ``pane_of`` and
         the partials where :meth:`_emit_reads_rows`, else its ``[wpanes]``
         slots one element each), ``reduce`` (a window's live panes into its
-        result)."""
+        result; ``reduce/ordered`` where the combine takes structs)."""
         K, P = self.num_keys, self.P
         s = self.spec
         lo = state.next_win
@@ -805,16 +885,19 @@ class Win_SeqFFAT(Basic_Operator):
                 live = jnp.take(state.pane_of.reshape(K * P), gflat) == pane_ids
             live &= valid_w[:, None]
 
-        def gat_reduce(tbl):
+        def gat_live(tbl, ident):
             with jax.named_scope("gather"):
                 g = gat(tbl)
             with jax.named_scope("reduce"):
-                g = jnp.where(_b(live, g), g, jnp.asarray(self.identity, g.dtype))
-                if self.combine is jnp.add:
-                    return jnp.sum(g, axis=1)
-                return _tree_reduce(self.combine, g, axis=1)
+                return jnp.where(_b(live, g), g, jnp.asarray(ident, g.dtype))
 
-        results = jax.tree.map(gat_reduce, state.panes)
+        g = jax.tree.map(gat_live, state.panes,
+                         identity_like(self.identity, state.panes))
+        with jax.named_scope("reduce"):
+            if self.combine is jnp.add:
+                results = jax.tree.map(lambda x: jnp.sum(x, axis=1), g)
+            else:
+                results = self._reduce_panes(g, axis=1)
         res_ts = (wid * s.slide + s.win_len - 1 if not s.is_cb
                   else jnp.zeros_like(wid))
         out = Batch(key=k_safe, id=wid, ts=jnp.asarray(res_ts, CTRL_DTYPE),
@@ -864,8 +947,11 @@ class Win_SeqFFAT(Basic_Operator):
         ``emit/range``, ``emit/gather`` and ``emit/reduce`` (both, in the step
         and in the EOS flush), ``insert/hist``, ``insert/fold``,
         ``emit/gather``, ``emit/reduce`` and ``emit/clear`` (the global-time
-        path, in the step and in the EOS flush): a profile's device
-        operations say which part of the engine they belong to."""
+        path, in the step and in the EOS flush), and where the combine takes
+        whole structs ``insert/fold/ordered`` (time-based; ``sort``, ``scan``
+        and ``place`` below it) and ``emit/reduce/ordered`` (every path): a
+        profile's device operations say which part of the engine they
+        belong to."""
         W = self._resolve_w(batch.capacity)
         self._w = W
         insert, emit = ((self._g_insert, self._g_emit) if self.global_time
@@ -874,6 +960,10 @@ class Win_SeqFFAT(Basic_Operator):
             state = insert(state, batch)
         with jax.named_scope("emit"):
             return emit(state, W, flush=False)
+
+    def flush_capacity(self, in_capacity: int) -> int:
+        """``flush``'s batches are a step's: ``out_capacity``."""
+        return self.out_capacity(in_capacity)
 
     def flush(self, state):
         """One batch of up to W open windows (W a key on the global-time path),
@@ -917,7 +1007,7 @@ class Win_SeqFFAT(Basic_Operator):
         global-time path (every batch's counts take ``keyed_pane_fold``) and
         on the per-key one where the value fold rides its contraction; on
         the global-time one ``ffat_late_lanes`` where the spec allows
-        lateness;
+        lateness; ``ffat_ordered_runs`` where the combine takes structs;
         for time-based specs the fired-window budget once it is settled."""
         if state is None or not hasattr(state, "dropped_old"):
             return
@@ -943,6 +1033,8 @@ class Win_SeqFFAT(Basic_Operator):
                 counters["ffat_" + name] = int(np.asarray(getattr(state, name)))
         if getattr(state, "late_lanes", None) is not None:
             counters["ffat_late_lanes"] = int(np.asarray(state.late_lanes))
+        if getattr(state, "ordered_runs", None) is not None:
+            counters["ffat_ordered_runs"] = int(np.asarray(state.ordered_runs))
         self._publish_stage_counters(counters)
 
     def drop_counters(self, state=None) -> dict:
@@ -1044,19 +1136,6 @@ def _key_max(key, ts, valid, K):
     ``segment_max`` over the lanes is serialized lane by lane."""
     hit = (key[:, None] == jnp.arange(K, dtype=key.dtype)) & valid[:, None]
     return jnp.max(jnp.where(hit, ts[:, None], -1), axis=0)
-
-
-def _tree_reduce(combine, x, axis):
-    """Log-depth reduction with an arbitrary associative combine."""
-    n = x.shape[axis]
-    while n > 1:
-        half = n // 2
-        a = jax.lax.slice_in_dim(x, 0, half, axis=axis)
-        b = jax.lax.slice_in_dim(x, half, 2 * half, axis=axis)
-        rest = jax.lax.slice_in_dim(x, 2 * half, n, axis=axis)
-        x = jnp.concatenate([combine(a, b), rest], axis=axis)
-        n = half + (n - 2 * half)
-    return jnp.squeeze(x, axis=axis)
 
 
 def _next_pow2(n):

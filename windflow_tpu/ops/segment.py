@@ -35,6 +35,72 @@ def _bmask(valid, v):
     return valid.reshape(valid.shape + (1,) * (v.ndim - 1))
 
 
+# ------------------------------------------------- combines over structs
+
+#: the combines that act leaf by leaf: applied to a struct, each maps over
+#: its leaves. Any other combine is called on whole structs (a lifted
+#: pytree), so that a field may read the others and the operands' order is
+#: kept: ``combine(a, b)`` with ``a`` the earlier.
+ELEMENTWISE = (jnp.add, jnp.maximum, jnp.minimum)
+
+
+def on_structs(combine: Callable) -> Callable:
+    """``combine`` as a function of two whole structs: ``None`` (addition)
+    and :data:`ELEMENTWISE` map over the leaves, any other is one already."""
+    if combine is None or combine in ELEMENTWISE:
+        op = combine or jnp.add
+        return lambda a, b: jax.tree.map(op, a, b)
+    return combine
+
+
+def leafwise(combine: Callable) -> Callable:
+    """A combine of single leaves as one of whole structs, mapped over the
+    leaves; :data:`ELEMENTWISE` and ``None`` are returned as they are (so
+    callers keep their fast paths)."""
+    if combine is None or combine in ELEMENTWISE:
+        return combine
+    return lambda a, b: jax.tree.map(combine, a, b)
+
+
+def identity_like(identity: Any, values: Any) -> Any:
+    """``identity`` as a pytree with ``values``' structure. ``identity`` is
+    a pytree prefix of ``values``: each of its leaves (a scalar, or one
+    array) stands for every leaf of ``values`` below it, so a scalar serves
+    every leaf and a struct like ``values`` gives one identity a leaf.
+    Raises ``ValueError`` where it is no prefix of ``values``."""
+    try:
+        return jax.tree.map(
+            lambda ident, sub: jax.tree.map(lambda _: ident, sub),
+            identity, values)
+    except ValueError as e:
+        raise ValueError(
+            f"identity {identity!r} is no pytree prefix of the lifted "
+            f"structure {jax.tree.structure(values)}") from e
+
+
+def ordered_reduce(combine: Callable, xs: Any, axis: int = 0) -> Any:
+    """``combine`` folded along ``axis`` of every leaf of ``xs`` at once,
+    operands in axis order: each round pairs neighbours, the lower index on
+    the left, and carries an odd last element into the next round, so the
+    association changes but no operand passes another (FlatFAT's guarantee
+    for a non-commutative combine, ``wf/flatfat.hpp:80-133``). ``combine``
+    sees whole structs, each leaf without ``axis`` (``jax.vmap`` over the
+    pairs). Returns ``xs`` without that axis."""
+    xs = jax.tree.map(lambda x: jnp.moveaxis(x, axis, 0), xs)
+    pair = jax.vmap(on_structs(combine))
+    n = jax.tree.leaves(xs)[0].shape[0]
+    while n > 1:
+        m = n // 2
+        paired = pair(jax.tree.map(lambda x: x[0:2 * m:2], xs),
+                      jax.tree.map(lambda x: x[1:2 * m:2], xs))
+        if n > 2 * m:
+            paired = jax.tree.map(
+                lambda p, x: jnp.concatenate([p, x[2 * m:n]], axis=0),
+                paired, xs)
+        xs, n = paired, m + n - 2 * m
+    return jax.tree.map(lambda x: x[0], xs)
+
+
 # ------------------------------------------------------- fused window fold
 
 #: lanes per grid step of the Pallas segment fold
@@ -204,7 +270,11 @@ def segment_reduce(values: Any, keys: jax.Array, valid: jax.Array, num_keys: int
     """Per-key reduction of a batch: returns a pytree of ``[num_keys, ...]`` arrays.
 
     Default combine is addition (lowered to ``segment_sum``); max/min use scatter
-    fast paths; a custom associative ``combine`` uses sort + segmented scan."""
+    fast paths; a custom associative ``combine`` uses sort + segmented scan
+    (scopes ``sort``, ``scan``, ``place``), called on whole structs, the
+    earlier lane on the left. ``identity`` may be a pytree of one identity a
+    leaf (:func:`identity_like`)."""
+    ids = identity_like(identity, values)
     if combine is None:
         def red(v):
             if v.ndim == 1:
@@ -216,50 +286,61 @@ def segment_reduce(values: Any, keys: jax.Array, valid: jax.Array, num_keys: int
         return jax.tree.map(red, values)
     if combine in (jnp.maximum, jnp.minimum):
         seg = jax.ops.segment_max if combine is jnp.maximum else jax.ops.segment_min
-        def red(v):
-            v = jnp.where(_bmask(valid, v), v, jnp.asarray(identity, v.dtype))
+        def red(v, ident):
+            v = jnp.where(_bmask(valid, v), v, jnp.asarray(ident, v.dtype))
             out = seg(v, keys, num_segments=num_keys)
             touched = jax.ops.segment_sum(valid.astype(jnp.int32), keys,
                                           num_segments=num_keys) > 0
             return jnp.where(_bmask(touched, out), out,
-                             jnp.asarray(identity, v.dtype))
-        return jax.tree.map(red, values)
+                             jnp.asarray(ident, v.dtype))
+        return jax.tree.map(red, values, ids)
     # general associative combine: sorted segmented scan, then scatter each segment's
     # last element into its key row
     scanned, seg_keys, seg_valid, _ = _sorted_segment_scan(
         values, keys, valid, combine, identity)
-    nxt = jnp.concatenate([seg_keys[1:], jnp.full((1,), -1, seg_keys.dtype)])
-    is_last = (seg_keys != nxt) & seg_valid
-    out_idx = jnp.where(is_last, jnp.minimum(seg_keys, num_keys), num_keys)
+    with jax.named_scope("place"):
+        nxt = jnp.concatenate([seg_keys[1:],
+                               jnp.full((1,), -1, seg_keys.dtype)])
+        is_last = (seg_keys != nxt) & seg_valid
+        out_idx = jnp.where(is_last, jnp.minimum(seg_keys, num_keys), num_keys)
 
-    def scatter(v):
-        shape = (num_keys + 1,) + v.shape[1:]
-        init = jnp.broadcast_to(jnp.asarray(identity, v.dtype), shape)
-        return init.at[out_idx].set(v, mode="drop")[:num_keys]
-    return jax.tree.map(scatter, scanned)
+        def scatter(v, ident):
+            shape = (num_keys + 1,) + v.shape[1:]
+            init = jnp.broadcast_to(jnp.asarray(ident, v.dtype), shape)
+            return init.at[out_idx].set(v, mode="drop")[:num_keys]
+        return jax.tree.map(scatter, scanned, ids)
 
 
 def _sorted_segment_scan(values, keys, valid, combine, identity):
-    """Multi-operand sort by key, then segmented inclusive associative scan.
+    """Multi-operand sort by key (stable: a key's lanes stay in stream
+    order), then segmented inclusive associative scan, ``combine`` called on
+    whole structs with the earlier lanes on the left (scopes ``sort`` and
+    ``scan``).
 
     Returns (scanned values in sorted order, sorted keys, sorted valid,
     original indices)."""
-    seg_keys, orig_idx, sv = _sort_by_key(keys, valid, values)
-    big = jnp.iinfo(keys.dtype).max
-    seg_valid = seg_keys != big
-    sv = jax.tree.map(lambda v: jnp.where(_bmask(seg_valid, v), v,
-                                          jnp.asarray(identity, v.dtype)), sv)
-    starts = jnp.concatenate([jnp.ones((1,), jnp.bool_),
-                              seg_keys[1:] != seg_keys[:-1]])
+    comb = on_structs(combine)
+    with jax.named_scope("sort"):
+        seg_keys, orig_idx, sv = _sort_by_key(keys, valid, values)
+    with jax.named_scope("scan"):
+        big = jnp.iinfo(keys.dtype).max
+        seg_valid = seg_keys != big
+        sv = jax.tree.map(lambda v, ident: jnp.where(
+            _bmask(seg_valid, v), v, jnp.asarray(ident, v.dtype)),
+            sv, identity_like(identity, sv))
+        starts = jnp.concatenate([jnp.ones((1,), jnp.bool_),
+                                  seg_keys[1:] != seg_keys[:-1]])
 
-    def seg_combine(a, b):
-        a_f, a_v = a
-        b_f, b_v = b
-        v = jax.tree.map(
-            lambda x, y: jnp.where(_bmask(b_f, y), y, combine(x, y)), a_v, b_v)
-        return (a_f | b_f, v)
+        def seg_combine(a, b):
+            a_f, a_v = a
+            b_f, b_v = b
+            # a segment's first lane starts afresh: the flag selects per struct
+            v = jax.tree.map(lambda y, c: jnp.where(_bmask(b_f, y), y, c),
+                             b_v, comb(a_v, b_v))
+            return (a_f | b_f, v)
 
-    _, scanned = jax.lax.associative_scan(seg_combine, (starts, sv), axis=0)
+        _, scanned = jax.lax.associative_scan(seg_combine, (starts, sv),
+                                              axis=0)
     return scanned, seg_keys, seg_valid, orig_idx
 
 
@@ -294,7 +375,9 @@ def segment_run_fold(folds, keys: jax.Array, valid: jax.Array, num_keys: int,
     (c + 1) * run_len)`` (a count-based window's pane). ``folds`` is a
     sequence of ``(values, combine, identity)``: ``values`` a pytree of
     ``[C, ...]`` leaves, ``combine`` associative (``None`` or ``jnp.add`` for
-    addition), applied leaf by leaf with the earlier lanes on the left.
+    addition), the earlier lanes on the left: :data:`ELEMENTWISE` leaf by
+    leaf, any other on whole structs; ``identity`` a pytree like
+    ``values`` or one for every leaf (:func:`identity_like`).
 
     One stable multi-operand sort by (dead, key) puts every run's lanes side
     by side in stream order; nothing returns to stream order. The runs are
@@ -348,9 +431,11 @@ def segment_run_fold(folds, keys: jax.Array, valid: jax.Array, num_keys: int,
 def _fold_runs(values, combine, identity, start, end, live, rank, run_len):
     """Per-run fold of ``values`` (sorted order) over the lane ranges
     ``[start, end)``, none longer than ``run_len``; ``rank`` is each lane's
-    distance from its run's first lane. ``[R, ...]`` leaves."""
+    distance from its run's first lane. ``[R, ...]`` leaves. A combine
+    other than :data:`ELEMENTWISE` is called on whole structs."""
     add = combine is None or combine is jnp.add
     last = jnp.maximum(end - 1, 0)
+    ids = identity_like(identity, values)
 
     def wrapping_sum(v):
         cs = jnp.cumsum(v, axis=0, dtype=v.dtype)
@@ -358,7 +443,7 @@ def _fold_runs(values, combine, identity, start, end, live, rank, run_len):
         return (jnp.take(cs, last, axis=0)
                 - jnp.where(_bmask(start > 0, before), before, 0))
 
-    def scan(v):
+    def scan(v, identity):
         op = jnp.add if add else combine
         d = 1
         while d < min(run_len, v.shape[0]):
@@ -369,18 +454,35 @@ def _fold_runs(values, combine, identity, start, end, live, rank, run_len):
             d *= 2
         return jnp.take(v, last, axis=0)
 
-    def fold(v):
+    def fold(v, identity):
         exact = add and jnp.issubdtype(v.dtype, jnp.integer)
-        u = wrapping_sum(v) if exact else scan(v)
+        u = wrapping_sum(v) if exact else scan(v, identity)
         return jnp.where(_bmask(live, u), u, jnp.asarray(identity, u.dtype))
-    return jax.tree.map(fold, values)
+    if add or combine in ELEMENTWISE:
+        return jax.tree.map(fold, values, ids)
+    # the same scan over whole structs: lane i - d (earlier) on the left
+    v, d = values, 1
+    n = jax.tree.leaves(values)[0].shape[0]
+    while d < min(run_len, n):
+        prev = jax.tree.map(lambda x, ident: jnp.concatenate(
+            [jnp.broadcast_to(jnp.asarray(ident, x.dtype), (d,) + x.shape[1:]),
+             x[:-d]], axis=0), v, ids)
+        v = jax.tree.map(lambda x, c: jnp.where(_bmask(rank >= d, x), c, x),
+                         v, combine(prev, v))
+        d *= 2
+
+    def at_last(x, ident):
+        u = jnp.take(x, last, axis=0)
+        return jnp.where(_bmask(live, u), u, jnp.asarray(ident, u.dtype))
+    return jax.tree.map(at_last, v, ids)
 
 
 def segment_prefix_scan(values: Any, keys: jax.Array, valid: jax.Array,
                         combine: Callable, identity=0, *, carry_in: Any = None) -> Any:
     """Per-key *inclusive* prefix scan in stream order: lane i receives the combine of
     all earlier live same-key lanes (plus an optional per-key ``carry_in`` table
-    ``[num_keys, ...]``), returned in original batch positions.
+    ``[num_keys, ...]``), returned in original batch positions. ``combine``
+    is called on whole structs (:func:`on_structs`).
 
     Batched counterpart of the reference Accumulator's per-key rolling reduce
     (``wf/accumulator.hpp:61``, keyMap ``:103-104``) for associative user combines.
@@ -413,8 +515,8 @@ def segment_prefix_scan(values: Any, keys: jax.Array, valid: jax.Array,
             lambda v: jnp.zeros_like(v).at[orig_idx].set(v), scanned)
     if carry_in is not None:
         # associativity: fold(carry, v1..vr) == combine(carry, fold(v1..vr))
-        out = jax.tree.map(
-            lambda v, t: combine(table_lookup(t, keys), v), out, carry_in)
+        out = on_structs(combine)(
+            jax.tree.map(lambda t: table_lookup(t, keys), carry_in), out)
     return out
 
 

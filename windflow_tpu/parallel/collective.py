@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from jax import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..ops.segment import segment_rank
+from ..ops.segment import ordered_reduce, segment_rank
 
 def _axis_size(mesh: Mesh, axis: str) -> int:
     return mesh.shape[axis]
@@ -67,25 +67,10 @@ def wmr_map_reduce(map_fn: Callable, combine: Callable, mesh: Mesh, *,
         partial = map_fn(data, valid)
         if known:
             return jax.tree.map(lambda x: reducer(x, axis), partial)
-        # generic associative combine: all_gather + order-preserving tree fold.
-        # The fold runs at the PYTREE level (combine sees whole partials, strictly
-        # pairwise via vmap), pairing adjacent elements so non-commutative combines
-        # see partials in axis order.
+        # generic associative combine: all_gather + order-preserving tree fold
+        # (combine sees whole partials, neighbours paired in axis order)
         g = jax.tree.map(lambda x: jax.lax.all_gather(x, axis), partial)
-        n = p
-        while n > 1:
-            m = n // 2
-            a = jax.tree.map(lambda x: x[0:2 * m:2], g)
-            b = jax.tree.map(lambda x: x[1:2 * m:2], g)
-            paired = jax.vmap(combine)(a, b)
-            if n > 2 * m:
-                rest = jax.tree.map(lambda x: x[2 * m:n], g)
-                g = jax.tree.map(lambda pr, r: jnp.concatenate([pr, r], axis=0),
-                                 paired, rest)
-            else:
-                g = paired
-            n = m + (n - 2 * m)
-        return jax.tree.map(lambda x: x[0], g)
+        return ordered_reduce(combine, g)
 
     # the folded all_gather of the generic path is replicated by construction, but
     # the static varying-axes checker can't prove it — disable the check there

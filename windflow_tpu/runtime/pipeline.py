@@ -160,6 +160,7 @@ class CompiledChain:  # wf-lint: single-writer[driver, stage]
         self._steps = {}
         self._push_count = 0
         self._nbytes_cache = {}     # (from_op, in capacity) -> (in, out bytes)
+        self._flush_warmed = set()  # input capacities _warm_flush has seen
         #: stage label for the health ledger's compile + device-time
         #: attribution (the flight-recorder stage convention): drivers
         #: overwrite it with their real stage name — ThreadedPipeline
@@ -179,6 +180,25 @@ class CompiledChain:  # wf-lint: single-writer[driver, stage]
         hl, t0c = self._health_begin("warm")
         self._step_fn(0)(tuple(self.states), b)
         self._health_end(hl, t0c, 0, b)
+
+    def _warm_flush(self, capacity: int) -> None:
+        """Trace + compile the steps the EOS flush pushes an operator's open
+        windows through (``ops[i + 1:]`` behind every operator that knows
+        its flush's capacity, ``flush_capacity``), on all-invalid batches
+        whose outputs are discarded, as :meth:`warm` does: at a stream's
+        first batch of ``capacity``, so that its end compiles nothing."""
+        c = capacity
+        for i, op in enumerate(self.ops[:-1]):
+            cap = op.flush_capacity(c)
+            c = op.out_capacity(c)
+            if cap is None:
+                continue
+            b = Batch.empty(cap, self.specs[i + 1])
+            if self.device is not None:
+                b = jax.device_put(b, self.device)
+            hl, t0c = self._health_begin("warm")
+            self._step_fn(i + 1)(tuple(self.states), b)
+            self._health_end(hl, t0c, i + 1, b)
 
     def reset_states(self) -> None:
         """Re-initialize every operator's state (supervised replay of a chain
@@ -396,6 +416,9 @@ class CompiledChain:  # wf-lint: single-writer[driver, stage]
               pos: Optional[int]) -> Batch:
         if self.device is not None:
             batch = jax.device_put(batch, self.device)
+        if from_op == 0 and batch.capacity not in self._flush_warmed:
+            self._flush_warmed.add(batch.capacity)
+            self._warm_flush(batch.capacity)
         hl, t0c = self._health_begin("push")
         t0 = time.perf_counter() if sampled else 0.0
         # the jit call alone (argument flattening, PjRt Execute): its start is
